@@ -1,8 +1,9 @@
 """Versioned on-disk store for AOT-serialized verifier executables.
 
 The compile tax this layer kills: every (op, bucket) recover/verify
-graph costs a fresh XLA compile per process — 129–151 s per graph on
-the ladder-kernel path (LADDER_AB.json) — so every cold node, and every
+graph costs a fresh trace + XLA compile per process — about two
+minutes of Python tracing and a quarter of a minute of compiling per
+bucket on the kernel path — so every cold node, and every
 chaos-restarted node, serves its first minutes at host-fallback
 throughput.  ``jax.export`` lowers a jitted graph once, serializes the
 StableHLO module, and any later process deserializes it in milliseconds
@@ -17,16 +18,20 @@ fingerprint (a hash over the graph-defining sources), plus a sha256
 integrity digest of the payload.  ANY mismatch — torn file, corrupted
 payload, different jaxlib ABI, edited kernel source, different device
 kind — makes :meth:`AotStore.load` return ``None`` so the caller falls
-through to a normal jit compile: the BENCH_r02 failure mode (a
-poisoned persistent cache taking the backend down with it) must
-degrade, never crash.
+through to a normal jit compile: a poisoned cache must degrade, never
+crash.
 
-Knobs:
+Placement (one rule for the persistent compile cache and the artifact
+store, :func:`cache_dir` / :func:`aot_dir`): where
+``JAX_COMPILATION_CACHE_DIR`` is set, jax's cache lives there and the
+artifacts in ``<that dir>/aot``, and no code sets another; where it is
+not, ``<checkout>/.jax_cache`` and ``<checkout>/.jax_aot``.  Never a
+temporary or per-process path — the path is part of jax's cache key,
+so a directory that moves never hits.
 
-* ``EGES_AOT_DIR`` — artifact directory (default ``<repo>/.jax_aot``);
-* ``EGES_AOT_DISABLE=1`` — disable the store entirely
-  (:func:`default_store` returns ``None``; every consumer treats that
-  as "compile like before").
+``EGES_AOT_DISABLE=1`` disables the store entirely
+(:func:`default_store` returns ``None``; every consumer treats that as
+"compile like before").
 
 This module must stay importable WITHOUT JAX (the bench parent and
 host-fallback processes import the scheduler stack, which may reach
@@ -160,10 +165,12 @@ class AotStore:
 
     def load(self, op: str, bucket: int, device_kind: str) -> bytes | None:
         """The serialized payload for one key, or ``None`` on ANY
-        mismatch (missing file, bad magic, torn/corrupted payload, a
-        different jax/jaxlib, a different code rev) — callers fall
-        through to a fresh jit compile, they never crash on a bad
-        artifact."""
+        mismatch — callers fall through to a fresh jit compile, they
+        never crash on a bad artifact.  A missing file, or an intact
+        artifact another build left (a different code rev, jax/jaxlib
+        or x64 setting — the cache directory outlives a checkout), is a
+        plain miss; a bad magic, a torn or corrupted payload, or a file
+        under the wrong key counts ``verifier.aot_load_errors``."""
         path = self.path_for(op, bucket, device_kind)
         try:
             with open(path, "rb") as fh:
@@ -177,8 +184,7 @@ class AotStore:
             (hlen,) = struct.unpack("<I", blob[8:12])
             header = json.loads(blob[12:12 + hlen])
             payload = blob[12 + hlen:]
-            for key in ("format", "op", "bucket", "device_kind",
-                        "code_rev", "jax", "jaxlib", "x64"):
+            for key in ("format", "op", "bucket", "device_kind"):
                 if header.get(key) != want[key]:
                     raise ValueError(
                         f"{key} mismatch: artifact has "
@@ -187,9 +193,18 @@ class AotStore:
                 raise ValueError("payload length mismatch (torn write?)")
             if header.get("sha256") != hashlib.sha256(payload).hexdigest():
                 raise ValueError("payload digest mismatch (corruption)")
+            stale = [key for key in ("code_rev", "jax", "jaxlib", "x64")
+                     if header.get(key) != want[key]]
+            if stale:
+                from eges_tpu.utils.log import get_logger
+
+                get_logger("geec.aot").info(
+                    "aot artifact from another build; recompiling",
+                    path=path, differs=",".join(stale))
+                return None
             return payload
         # analysis: allow-swallow(a stale/corrupted artifact degrades to
-        # a normal jit compile — the BENCH_r02 contract; the error is
+        # a normal jit compile; the error is
         # logged + counted, the caller sees a plain cache miss)
         except Exception as e:
             from eges_tpu.utils.log import get_logger
@@ -210,42 +225,56 @@ class AotStore:
             return []
 
 
+def cache_dir() -> str:
+    """Where jax's persistent compilation cache lives: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else
+    ``<checkout>/.jax_cache``.  Every launcher and test goes through
+    this one helper."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_repo_root(), ".jax_cache"))
+
+
+def aot_dir() -> str:
+    """The artifact store's directory, beside the compile cache:
+    ``<JAX_COMPILATION_CACHE_DIR>/aot`` when the variable is set, else
+    ``<checkout>/.jax_aot``."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    return (os.path.join(placed, "aot") if placed
+            else os.path.join(_repo_root(), ".jax_aot"))
+
+
 def default_store() -> AotStore | None:
-    """The process-default store per the env knobs; ``None`` when
+    """The process-default store at :func:`aot_dir`; ``None`` when
     disabled (consumers then compile exactly as before this layer)."""
     if os.environ.get("EGES_AOT_DISABLE") == "1":
         return None
-    root = os.environ.get("EGES_AOT_DIR") or os.path.join(
-        _repo_root(), ".jax_aot")
-    return AotStore(root)
+    return AotStore(aot_dir())
 
 
-def enable_persistent_cache(cache_dir: str | None = None,
-                            min_compile_s: float = 2.0) -> bool:
-    """Configure jax's persistent compilation cache, hardened for the
-    BENCH_r02 failure mode: any error (old jax without the knobs, an
-    unwritable directory, a poisoned cache implementation) is logged
-    via ``utils.log``, counted in ``verifier.compile_cache_errors``,
-    and the process continues WITHOUT the cache instead of taking the
+def enable_persistent_cache(min_compile_s: float = 2.0) -> bool:
+    """Turn on jax's persistent compilation cache at :func:`cache_dir`.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax has already read it
+    and this sets no directory at all.  Any error (an unwritable
+    directory, a poisoned cache implementation) is logged via
+    ``utils.log``, counted in ``verifier.compile_cache_errors``, and
+    the process continues WITHOUT the cache instead of taking the
     backend down.  Returns True when the cache was configured."""
     from eges_tpu.utils.log import get_logger
     from eges_tpu.utils.metrics import DEFAULT as metrics
 
-    if cache_dir is None:
-        cache_dir = os.path.join(_repo_root(), ".jax_cache")
     try:
         import jax
 
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(cache_dir))
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", cache_dir())
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_s))
         return True
     # analysis: allow-swallow(a broken persistent cache must degrade to
-    # uncached compiles, never poison the backend — BENCH_r02)
+    # uncached compiles, never poison the backend)
     except Exception as e:
         metrics.counter("verifier.compile_cache_errors").inc()
         get_logger("geec.aot").warn(
             "persistent compile cache unavailable; continuing without",
-            dir=cache_dir, err=str(e))
+            dir=cache_dir(), err=str(e))
         return False

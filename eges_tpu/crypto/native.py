@@ -22,6 +22,30 @@ def _lib_path() -> str:
     return os.path.join(root, "native", "libgeec_native.so")
 
 
+def ensure_built() -> str:
+    """Build ``native/libgeec_native.so`` if it is missing or older
+    than its sources (it is git-ignored, so a fresh checkout has none)
+    and return its path.  Raises when ``make`` fails: without the
+    library the pure-Python model carries signing and the host
+    comparisons, silently and ~10x slower — launchers that need it
+    (the test suite, ``chip_smoke.py``) fail loudly instead."""
+    import subprocess
+
+    lib = _lib_path()
+    native = os.path.dirname(lib)
+    srcs = [os.path.join(native, f) for f in (
+        "secp256k1.cpp", "keccak.cpp", "election.cpp", "Makefile")]
+    if os.path.exists(lib) and all(
+            os.path.getmtime(lib) >= os.path.getmtime(s) for s in srcs):
+        return lib
+    proc = subprocess.run(["make", "-C", native], capture_output=True,
+                          text=True)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"native lib build failed:\n{proc.stdout}\n{proc.stderr}")
+    return lib
+
+
 def _load():
     global _LIB, _TRIED
     if _TRIED:

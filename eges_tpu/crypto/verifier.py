@@ -32,6 +32,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import export as jax_export
 
 from eges_tpu.crypto.bucketing import bucket_round
 from eges_tpu.ops import bigint, ec, keccak_tpu
@@ -109,21 +110,6 @@ def verify_batch(sigs: jnp.ndarray, hashes: jnp.ndarray, pubs: jnp.ndarray):
     return ec.ecdsa_verify_point(z, r, s, qx, qy)
 
 
-def _jax_export():  # api: _jax_export
-    """The ``jax.export`` module (moved out of experimental over jax
-    releases), or ``None`` when this jax has neither spelling — every
-    AOT consumer then falls through to plain jit."""
-    try:
-        from jax import export as exp
-        return exp
-    except ImportError:
-        try:
-            from jax.experimental import export as exp
-            return exp
-        except ImportError:
-            return None
-
-
 class _StagedBatch:
     """One window mid-flight through the split-phase dispatch pipeline:
     ``stage_*`` filled + uploaded it (H2D), ``commit_*`` dispatched the
@@ -150,6 +136,19 @@ def make_sharded_ecrecover(mesh: jax.sharding.Mesh, axis: str = "dp"):
                       tally_out=2)
 
 
+def _kernel_min_bucket() -> int:
+    """The smallest bucket worth its own graph.  On the kernel path
+    every launch pads its rows to a multiple of ``LANE_BLOCK`` lane
+    columns, so a 16-row bucket does the device work of ``LANE_BLOCK``
+    rows and still costs its own two minutes of tracing on a cold
+    start: the bucket ladder starts at ``LANE_BLOCK`` there (256, 512,
+    1024 instead of seven graphs).  The plain XLA graph (CPU) keeps
+    the 16-row floor."""
+    from eges_tpu.ops import pallas_kernels as pk
+
+    return pk.LANE_BLOCK if pk.ladder_kernels_enabled() else 16
+
+
 class BatchVerifier:
     """Host facade over the jitted verifier graphs.
 
@@ -159,18 +158,21 @@ class BatchVerifier:
     """
 
     def __init__(self, mesh: jax.sharding.Mesh | None = None, axis: str = "dp",
-                 min_bucket: int = 16, debug_timing: bool | None = None,
-                 collective: str = "auto"):
+                 min_bucket: int | None = None,
+                 debug_timing: bool | None = None,
+                 collective: str = "psum"):
         self._mesh = mesh
         self._axis = axis
-        self._min_bucket = min_bucket
-        # topology-aware tally collective: "auto" resolves psum-vs-ring
-        # per (device count, bucket) from the measured MESH_SCALING.json
-        # A/B the first time each bucket is dispatched; "psum"/"ring"
-        # pin it (EGES_MESH_COLLECTIVE pins it process-wide)
+        self._min_bucket = (_kernel_min_bucket() if min_bucket is None
+                            else min_bucket)
+        # the ACK-tally collective of the full-mesh path: "psum" unless
+        # the caller asks for "ring" in code — no perf artefact or
+        # environment value steers a chip run
+        if collective not in ("psum", "ring"):
+            raise ValueError(f"collective must be psum|ring, "
+                             f"got {collective!r}")
         self._collective = collective
         self._collective_fns: dict[str, object] = {}
-        self._collective_by_bucket: dict[int, str] = {}
         if mesh is not None:
             self._ndev = mesh.shape[axis]
             self._sharded = self._sharded_dispatch
@@ -229,21 +231,12 @@ class BatchVerifier:
             hook(n)
 
     def collective_for(self, bucket: int) -> str:
-        """Resolve (and pin) the tally collective for one bucket —
-        ``"psum"`` or ``"ring"`` per the measured A/B (or the env/ctor
-        override).  Single-device facades have no collective."""
+        """The tally collective one bucket rides — the constructor's
+        ``"psum"`` or ``"ring"``.  Single-device facades have none."""
         if self._mesh is None:
             return "none"
-        name = self._collective_by_bucket.get(bucket)
-        if name is None:
-            name = self._collective
-            if name == "auto":
-                from eges_tpu.parallel.ring import preferred_collective  # analysis: allow-layer-violation(mesh-collective seam; extracted with the ROADMAP-1 multi-host fabric)
-                name = preferred_collective(self._ndev, bucket)
-            if self._ndev <= 1:
-                name = "psum"  # a 1-wide ring is just overhead
-            self._collective_by_bucket[bucket] = name
-        return name
+        # a 1-wide ring is just overhead
+        return "psum" if self._ndev <= 1 else self._collective
 
     def _sharded_dispatch(self, ds, dh):
         """The mesh path: route one padded batch through the collective
@@ -379,10 +372,19 @@ class BatchVerifier:
     def _aot_prewarm(self, buckets, store, ops) -> dict:
         info = {"buckets": list(buckets), "device_kind": self.device_kind,
                 "aot_loads": 0, "aot_compiles": 0,
-                "load_s": 0.0, "compile_s": 0.0}
+                "load_s": 0.0, "compile_s": 0.0, "warmed": []}
         for op in ops:
             for b in buckets:
-                mode, dt = self._aot_warm_one(op, b, store)
+                mode, dt, lower_s, first_s = self._aot_warm_one(
+                    op, b, store)
+                if mode is not None:
+                    # per-bucket split of the cold cost: seconds to
+                    # lower (trace + export; ~0 from an artifact) and
+                    # to the first result (backend compile + one run)
+                    info["warmed"].append({
+                        "op": op, "bucket": b, "mode": mode,
+                        "lower_s": round(lower_s, 3),
+                        "first_result_s": round(first_s, 3)})
                 if mode == "load":
                     info["aot_loads"] += 1
                     info["load_s"] += dt
@@ -393,8 +395,9 @@ class BatchVerifier:
 
     def _aot_warm_one(self, op: str, b: int, store):
         """Load-else-compile ONE (op, bucket) executable and register
-        it.  Returns ``("load"|"compile", seconds)`` or ``(None, 0.0)``
-        when another lane already holds/warms the key — the shared
+        it.  Returns ``("load"|"compile", seconds, seconds to lower,
+        seconds from there to the first result)`` or ``(None, 0.0, 0.0,
+        0.0)`` when another lane already holds/warms the key — the shared
         registry plus in-flight set is what dedupes prewarm across mesh
         lanes."""
         import time
@@ -405,26 +408,27 @@ class BatchVerifier:
         key = (op, b)
         with self._staging_lock:
             if key in self._aot_execs or key in self._aot_inflight:
-                return None, 0.0
+                return None, 0.0, 0.0, 0.0
             self._aot_inflight.add(key)
         try:
             graph = self._graph_fns()[op]
             zeros = self._zero_args(op, b)
-            exp_mod = _jax_export()
             kind = self.device_kind
             fn = None
             mode = "compile"
             t0 = time.monotonic()
-            if store is not None and exp_mod is not None:
+            if store is not None:
                 payload = store.load(op, b, kind)
                 if payload is not None:
                     try:
-                        fn = jax.jit(exp_mod.deserialize(payload).call)
+                        fn = jax.jit(jax_export.deserialize(payload).call)
+                        t_low = time.monotonic()
                         jax.block_until_ready(fn(*zeros))
+                        t_run = time.monotonic()
                         mode = "load"
                     # analysis: allow-swallow(an artifact that passed
                     # the integrity check but fails to deserialize or
-                    # run still degrades to a fresh compile — BENCH_r02)
+                    # run still degrades to a fresh compile)
                     except Exception as e:
                         metrics.counter("verifier.aot_load_errors").inc()
                         get_logger("geec.aot").warn(
@@ -433,22 +437,23 @@ class BatchVerifier:
                         fn = None
             if fn is None:
                 exported = None
-                if exp_mod is not None:
-                    try:
-                        exported = exp_mod.export(jax.jit(graph))(*zeros)
-                        fn = jax.jit(exported.call)
-                    # analysis: allow-swallow(graphs jax.export cannot
-                    # lower — e.g. exotic custom calls — still warm via
-                    # plain jit; they just never get an artifact)
-                    except Exception as e:
-                        get_logger("geec.aot").warn(
-                            "aot export unavailable; plain jit warm",
-                            op=op, bucket=b, err=str(e))
-                        exported = None
-                        fn = None
+                try:
+                    exported = jax_export.export(jax.jit(graph))(*zeros)
+                    fn = jax.jit(exported.call)
+                # analysis: allow-swallow(graphs jax.export cannot
+                # lower — e.g. exotic custom calls — still warm via
+                # plain jit; they just never get an artifact)
+                except Exception as e:
+                    get_logger("geec.aot").warn(
+                        "aot export unavailable; plain jit warm",
+                        op=op, bucket=b, err=str(e))
+                    exported = None
+                    fn = None
                 if fn is None:
                     fn = jax.jit(graph)
+                t_low = time.monotonic()
                 jax.block_until_ready(fn(*zeros))
+                t_run = time.monotonic()
                 if store is not None and exported is not None:
                     try:
                         store.save(op, b, kind, exported.serialize())
@@ -476,7 +481,7 @@ class BatchVerifier:
             else:
                 metrics.counter("verifier.aot_compiles").inc()
                 metrics.histogram("verifier.aot_export_seconds").observe(dt)
-            return mode, dt
+            return mode, dt, t_low - t0, t_run - t_low
         finally:
             with self._staging_lock:
                 self._aot_inflight.discard(key)
@@ -496,7 +501,7 @@ class BatchVerifier:
     def _record_batch(self, op: str, n: int, b: int, cached: bool,
                       t0: float, t1: float, t2: float, t3: float) -> None:
         """Device-batch observability shared by BOTH device paths
-        (SURVEY §5 metrics; VERDICT item 7): aggregate + per-bucket
+        (SURVEY §5 metrics): aggregate + per-bucket
         device time, pad waste, compile-cache behavior, and — under the
         debug-timing flag only, since measuring them forces the
         H2D-vs-compute sync — the transfer halves.
@@ -883,9 +888,9 @@ class MeshBatchVerifier(BatchVerifier):
     """
 
     def __init__(self, mesh: jax.sharding.Mesh | None = None,
-                 axis: str = "dp", min_bucket: int = 16,
+                 axis: str = "dp", min_bucket: int | None = None,
                  debug_timing: bool | None = None,
-                 collective: str = "auto"):
+                 collective: str = "psum"):
         if mesh is None:
             from eges_tpu.parallel import data_parallel_mesh  # analysis: allow-layer-violation(mesh-collective seam; extracted with the ROADMAP-1 multi-host fabric)
             mesh = data_parallel_mesh(axis=axis)
@@ -902,15 +907,32 @@ class MeshBatchVerifier(BatchVerifier):
         return list(self._targets)
 
 
+def require_accelerator(devs) -> str:
+    """The platform of ``devs`` — and a refusal to go on when it is not
+    a TPU, unless the environment asked for the CPU by name
+    (``JAX_PLATFORMS=cpu``, as the tests and the ``Makefile`` do).  A
+    process that wanted the chip and silently got the CPU backend looks
+    like a success and verifies at a thousandth of the rate."""
+    platform = devs[0].platform
+    asked = os.environ.get("JAX_PLATFORMS", "").lower().split(",")
+    if platform != "tpu" and platform not in asked:
+        raise RuntimeError(
+            f"the device verifier found platform {platform!r} "
+            f"({devs[0]}), not a TPU; set JAX_PLATFORMS={platform} to "
+            f"run on it on purpose")
+    return platform
+
+
 @functools.lru_cache(maxsize=1)
 def default_verifier() -> BatchVerifier:
     """Process-wide verifier on the default device set: a mesh-sharded
     facade over all local devices if there are several (so the attached
-    scheduler grows one window lane per device), else single-device."""
+    scheduler grows one window lane per device), else single-device.
+    Refuses a platform nobody asked for (:func:`require_accelerator`)."""
     devs = jax.devices()
+    require_accelerator(devs)
     # surface WHICH device serves the batches through thw_metrics so a
-    # cluster run's >95%-on-device claim names its hardware (BASELINE
-    # config 4 needs "TPU v5 lite0" in the evidence, not an inference)
+    # cluster run's >95%-on-device claim names its hardware
     from eges_tpu.utils.metrics import DEFAULT as metrics
 
     metrics.gauge("verifier.device_name").set(str(devs[0]))

@@ -225,15 +225,21 @@ class NodeService:
         mode = cfg.verifier_mode or ("jax" if cfg.use_tpu_verifier
                                      else "none")
         verifier = None
+        self._verifier_platform = None
         if mode == "jax":
             # share compiled verifier graphs across node processes and
-            # restarts (the recover graph is the expensive compile);
-            # hardened per BENCH_r02: a broken cache logs + counts
-            # verifier.compile_cache_errors and the node runs uncached
+            # restarts (the recover graph is the expensive compile); a
+            # broken cache logs + counts verifier.compile_cache_errors
+            # and the node runs uncached
             from eges_tpu.crypto.aotstore import enable_persistent_cache
             enable_persistent_cache()
+            # default_verifier refuses a platform nobody asked for: a
+            # node that wanted the chip never verifies on the CPU
+            # backend in silence
             from eges_tpu.crypto.verifier import default_verifier
             verifier = default_verifier()
+            self._verifier_platform = verifier.device_kind.partition(":")[0]
+            self.log.geec("verifier device", device=verifier.device_kind)
         elif mode == "native":
             from eges_tpu.crypto.verify_host import NativeBatchVerifier
             verifier = NativeBatchVerifier()
@@ -428,29 +434,37 @@ class NodeService:
         devstats_mod.DEFAULT.rebase()
         devstats_mod.DEFAULT.trace.dir = self.cfg.datadir
         if self._verifier_mode == "jax" and self._raw_verifier is not None:
-            # warm the smallest recover graph NOW: the first jit compile
-            # can take minutes on a small host, and letting it happen
-            # lazily inside a consensus message handler wedges the event
-            # loop mid-election (diagnosed via the SIGUSR1 dump).  The
-            # warm goes through the AOT artifact store: a node restarted
-            # on a machine that compiled before deserializes the stored
-            # executable in milliseconds instead of recompiling (and a
+            # warm the recover graphs NOW: a cold bucket costs about
+            # two minutes of Python tracing plus a quarter of a minute
+            # of compiling on the kernel path, and letting that happen
+            # lazily inside a consensus message handler wedges the
+            # event loop mid-election (diagnosed via the SIGUSR1 dump).
+            # The warm goes through the AOT artifact store: a node
+            # restarted on a machine that compiled before deserializes
+            # the stored executable instead of re-tracing (and a
             # first-ever compile leaves an artifact behind for the next
-            # process).  The next few buckets warm on a background
-            # thread — off the critical path, so the first non-trivial
-            # block doesn't stall either.
+            # process).  On the chip EVERY bucket the scheduler can pad
+            # a window to is warmed before the node serves; on the CPU
+            # backend (asked for by name — tests, dev rigs) a big-graph
+            # compile per bucket would outlast the run, so only the
+            # smallest warms here and the next few on a background
+            # thread, as before.
             import time as _t
 
             from eges_tpu.crypto.aotstore import default_store
             from eges_tpu.utils.metrics import DEFAULT as metrics
 
             store = default_store()
+            on_chip = self._verifier_platform == "tpu"
+            cap = self.chain.verifier.max_batch
+            every = tuple(16 << i for i in range(16) if 16 << i <= cap)
             t0 = _t.monotonic()
-            info = self._raw_verifier.aot_prewarm(buckets=(16,),
-                                                  store=store)
+            info = self._raw_verifier.aot_prewarm(
+                buckets=every if on_chip else (16,), store=store)
             cold = round(_t.monotonic() - t0, 3)
             metrics.gauge("verifier.cold_start_seconds").set(cold)
             self.log.geec("verifier warmup", dt=cold,
+                          buckets=info["buckets"],
                           aot_loads=info["aot_loads"],
                           aot_compiles=info["aot_compiles"])
             self.node.journal.record(
@@ -460,8 +474,9 @@ class NodeService:
                 load_s=round(info["load_s"], 3),
                 compile_s=round(info["compile_s"], 3),
                 cold_start_s=cold, device_kind=info["device_kind"])
-            self._raw_verifier.aot_prewarm(buckets=(32, 64, 128),
-                                           store=store, background=True)
+            if not on_chip:
+                self._raw_verifier.aot_prewarm(buckets=(32, 64, 128),
+                                               store=store, background=True)
         await self.direct.start()
         await self.gossip.start()
         if self.discovery is not None:

@@ -474,7 +474,7 @@ class FieldP(Mod):
         kernel?  True on the fused-kernel variant (TPU backends) for
         batched same-shape 16-limb operands — the round-4 census showed
         the XLA forms of these ops execute as ~3.8k separate dispatches
-        per recover on hardware (harness/hlo_census.py)."""
+        per recover on hardware."""
         from eges_tpu.ops.pallas_kernels import ladder_kernels_enabled
         if not ladder_kernels_enabled():
             return False

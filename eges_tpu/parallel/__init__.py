@@ -15,17 +15,18 @@ import numpy as np
 
 
 def shard_map_fn():
-    """Resolve ``shard_map`` across JAX versions: new releases export it
-    as ``jax.shard_map``; the pinned toolchain here still ships it under
-    ``jax.experimental.shard_map``.  Every shard_map user in the tree
-    goes through this one resolver so a JAX bump touches one line."""
+    """``jax.shard_map`` as every sharded graph in the tree uses it:
+    without the varying-mesh-axes check.  On the TPU the mapped function
+    is a chain of ``pallas_call`` kernels whose ``out_shape`` structs
+    carry no ``vma`` annotation, and with ``check_vma=True`` tracing
+    them inside the map is refused outright (found compiling the
+    four-device graph for a described v5e, PR 22 — the CPU graph path
+    has no kernels and never tripped it).  The maps here are pure row
+    parallelism plus one explicit ``psum``/``ppermute`` tally, so the
+    check buys nothing."""
     import jax
 
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-    return shard_map
+    return functools.partial(jax.shard_map, check_vma=False)
 
 
 def data_parallel_mesh(devices=None, axis: str = "dp"):

@@ -172,75 +172,3 @@ def ring_gather(fn, mesh, axis: str = "dp", *, n_in: int,
     import jax as _jax
     return _jax.jit(_shard_map_unchecked(
         shard_fn, mesh, tuple([PS(axis)] * n_in), PS()))
-
-
-# -- topology-aware collective choice (JAX-free) --------------------------
-
-# heuristic fallback when no measured A/B exists: a tree all-reduce wins
-# on small axes, nearest-neighbor ring traffic wins once the axis is
-# wide enough that the tree's fan-in hops dominate
-_RING_MIN_DEVICES = 8
-
-
-def _scaling_path() -> str:
-    import os
-    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        os.pardir, os.pardir, "MESH_SCALING.json")
-
-
-def load_collective_table(path: str | None = None) -> dict:
-    """Measured psum/ring A/B from MESH_SCALING.json as
-    ``{devices: [(rows, psum_rows_per_s, ring_rows_per_s), ...]}``.
-    Missing/unreadable artifact -> empty table (callers fall back to the
-    heuristic)."""
-    import json
-    import os
-
-    out: dict[int, list] = {}
-    p = path or _scaling_path()
-    if not os.path.exists(p):
-        return out
-    try:
-        with open(p) as f:
-            doc = json.load(f)
-        for pt in doc.get("points", []):
-            psum = (pt.get("psum") or {}).get("rows_per_s")
-            ring = (pt.get("ring") or {}).get("rows_per_s")
-            if psum is None or ring is None:
-                continue
-            out.setdefault(int(pt["devices"]), []).append(
-                (int(pt.get("rows", 0)), float(psum), float(ring)))
-    # analysis: allow-swallow(a malformed scaling artifact must never
-    # break verifier construction — the heuristic fallback takes over)
-    except Exception:
-        return {}
-    return out
-
-
-def preferred_collective(n_devices: int, bucket: int,
-                         path: str | None = None) -> str:
-    """Topology-aware psum-vs-ring choice for the ACK-tally all-reduce.
-
-    Resolution order:
-
-    1. ``EGES_MESH_COLLECTIVE=psum|ring`` pins the choice (``auto`` or
-       unset falls through);
-    2. the measured A/B in MESH_SCALING.json — the point with the
-       nearest device count (exact match preferred), then the nearest
-       ``rows`` to the requested bucket, wins by ``rows_per_s``;
-    3. heuristic: psum below ``_RING_MIN_DEVICES`` devices, ring at or
-       above (nearest-neighbor ICI traffic beats the tree fan-in on
-       wide axes).
-    """
-    import os
-
-    env = os.environ.get("EGES_MESH_COLLECTIVE", "auto").strip().lower()
-    if env in ("psum", "ring"):
-        return env
-    table = load_collective_table(path)
-    if table:
-        devs = min(table, key=lambda d: (abs(d - n_devices), -d))
-        rows, psum, ring = min(table[devs],
-                               key=lambda e: abs(e[0] - bucket))
-        return "psum" if psum >= ring else "ring"
-    return "psum" if n_devices < _RING_MIN_DEVICES else "ring"

@@ -321,6 +321,12 @@ class RpcServer:
             if self.txpool is not None:
                 out["txpool"] = dict(self.txpool.stats,
                                      pending=len(self.txpool))
+            # the verify scheduler's own counters (diverts, breaker
+            # trips, per-lane rows): a run that fell back to the host
+            # must be visible from outside the process
+            sched_stats = getattr(self.chain.verifier, "stats", None)
+            if callable(sched_stats):
+                out["scheduler"] = sched_stats()
             from eges_tpu.utils import tracing
             out["tracing"] = tracing.DEFAULT.stats()
             return out

@@ -1,8 +1,8 @@
 """recompile-hazard: unbounded jit compiles on the verifier hot path.
 
 Every distinct operand shape reaching a ``jax.jit`` function triggers a
-fresh trace + XLA compile — 129–151 s per ladder-kernel bucket on TPU
-(LADDER_AB.json).  The repo's discipline is to bound that cost two
+fresh trace + XLA compile — about two minutes of tracing and a quarter
+of a minute of compiling per kernel-path bucket.  The repo's discipline is to bound that cost two
 ways: operand shapes are snapped to the fixed bucket ladder
 (``crypto/bucketing.bucket_round`` / ``_pad``) before upload, and jit
 wrappers are built once per (mesh, bucket) behind an

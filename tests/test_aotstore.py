@@ -82,6 +82,7 @@ def test_store_rejects_version_and_code_rev_mismatch(tmp_path):
     writer = AotStore(str(tmp_path), fingerprint="a" * 16,
                       versions=versions)
     writer.save("recover", 16, "cpu:cpu", b"x" * 64)
+    before = metrics.counter("verifier.aot_load_errors").value
     # same versions, different code rev -> rejected
     assert AotStore(str(tmp_path), fingerprint="b" * 16,
                     versions=versions).load("recover", 16,
@@ -90,6 +91,9 @@ def test_store_rejects_version_and_code_rev_mismatch(tmp_path):
     assert AotStore(str(tmp_path), fingerprint="a" * 16,
                     versions={"jax": "0.0.1", "jaxlib": "0.0.2"}
                     ).load("recover", 16, "cpu:cpu") is None
+    # an intact artifact another build left is a miss, not an error:
+    # the cache directory outlives a checkout
+    assert metrics.counter("verifier.aot_load_errors").value == before
     # exact match -> loads
     assert AotStore(str(tmp_path), fingerprint="a" * 16,
                     versions=versions).load("recover", 16,
@@ -100,19 +104,54 @@ def test_default_store_knobs(tmp_path, monkeypatch):
     monkeypatch.setenv("EGES_AOT_DISABLE", "1")
     assert default_store() is None
     monkeypatch.delenv("EGES_AOT_DISABLE")
-    monkeypatch.setenv("EGES_AOT_DIR", str(tmp_path / "arts"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     st = default_store()
-    assert st is not None and st.root == str(tmp_path / "arts")
+    assert st is not None and st.root == str(tmp_path / "aot")
     assert st.fingerprint == code_fingerprint()
 
 
-def test_enable_persistent_cache_degrades(tmp_path, monkeypatch):
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_cache_placement_follows_the_environment(tmp_path, monkeypatch,
+                                                 placed):
+    """One rule for the compile cache and the artifact store: where
+    JAX_COMPILATION_CACHE_DIR is set both live under it and no code
+    sets another directory; where it is not, fixed paths in the
+    checkout — never a temporary or per-process one."""
+    import tempfile
+
+    from eges_tpu.crypto import aotstore
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want_cache, want_aot = str(tmp_path), str(tmp_path / "aot")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want_cache = os.path.join(_CHECKOUT, ".jax_cache")
+        want_aot = os.path.join(_CHECKOUT, ".jax_aot")
+    assert aotstore.cache_dir() == want_cache
+    assert aotstore.aot_dir() == want_aot
+    assert enable_persistent_cache() is True
+    dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+    assert dirs == ([] if placed else [want_cache])
+    if not placed:
+        for d in (want_cache, want_aot):
+            assert not d.startswith(tempfile.gettempdir())
+            assert str(os.getpid()) not in d
+
+
+def test_enable_persistent_cache_degrades(monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("poisoned cache")
 
     monkeypatch.setattr(jax.config, "update", boom)
     before = metrics.counter("verifier.compile_cache_errors").value
-    assert enable_persistent_cache(str(tmp_path / "cache")) is False
+    assert enable_persistent_cache() is False
     assert metrics.counter(
         "verifier.compile_cache_errors").value == before + 1
 
@@ -203,7 +242,7 @@ def test_corrupted_artifact_falls_through_to_compile(tmp_path):
 # -- cluster restart: prewarm from artifacts, journal the timing ----------
 
 def test_cluster_restart_prewarms_from_store(tmp_path, monkeypatch):
-    monkeypatch.setenv("EGES_AOT_DIR", str(tmp_path / "arts"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     # bank the artifact the way a previous process would have
     seed = ToyVerifier()
     seed.aot_prewarm(buckets=(16,))

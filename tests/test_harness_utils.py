@@ -54,7 +54,7 @@ def test_cluster_meta_round_trip(tmp_path):
     d = str(tmp_path)
     meta = {"n": 3, "hosts": "", "pids": [11, 22, 33], "boot_pid": None,
             "txn_per_block": 5, "txn_size": 100, "block_timeout": 20.0,
-            "mine": True, "use_bootnode": False, "ambient_jax": False}
+            "mine": True, "use_bootnode": False}
     _save_meta(d, meta)
     assert load_meta(d) == meta
     assert load_meta(str(tmp_path / "nope")) is None

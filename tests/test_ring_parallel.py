@@ -75,54 +75,25 @@ def test_ring_tally_on_real_ecrecover_shard():
     assert np.asarray(ok).all()
 
 
-def test_preferred_collective_resolution(tmp_path, monkeypatch):
-    """psum-vs-ring choice: env pin > measured A/B table (nearest
-    device count, then nearest rows) > device-count heuristic."""
-    import json
+def test_collective_is_psum_unless_told_otherwise_in_code(monkeypatch):
+    """No perf artefact or environment value steers the full-mesh
+    tally: psum by default, ring only when the caller builds the
+    verifier that way, anything else refused."""
+    import pytest
 
-    from eges_tpu.parallel.ring import (
-        _RING_MIN_DEVICES, load_collective_table, preferred_collective,
-    )
+    from eges_tpu.crypto.verifier import BatchVerifier
 
-    doc = {"points": [
-        {"devices": 2, "rows": 1024,
-         "psum": {"rows_per_s": 10.0}, "ring": {"rows_per_s": 20.0}},
-        {"devices": 2, "rows": 64,
-         "psum": {"rows_per_s": 30.0}, "ring": {"rows_per_s": 5.0}},
-        {"devices": 8, "rows": 1024,
-         "psum": {"rows_per_s": 30.0}, "ring": {"rows_per_s": 10.0}},
-    ]}
-    p = tmp_path / "scaling.json"
-    p.write_text(json.dumps(doc))
-    monkeypatch.delenv("EGES_MESH_COLLECTIVE", raising=False)
-
-    table = load_collective_table(str(p))
-    assert set(table) == {2, 8} and len(table[2]) == 2
-
-    # measured winner per (devices, nearest rows)
-    assert preferred_collective(2, 1024, path=str(p)) == "ring"
-    assert preferred_collective(2, 128, path=str(p)) == "psum"
-    assert preferred_collective(8, 2048, path=str(p)) == "psum"
-    # nearest device count serves unmeasured sizes
-    assert preferred_collective(7, 1024, path=str(p)) == "psum"
-    # env pin beats the table; "auto" falls through to it
-    monkeypatch.setenv("EGES_MESH_COLLECTIVE", "ring")
-    assert preferred_collective(8, 1024, path=str(p)) == "ring"
-    monkeypatch.setenv("EGES_MESH_COLLECTIVE", "auto")
-    assert preferred_collective(8, 1024, path=str(p)) == "psum"
-    # no artifact -> heuristic on the device count
-    monkeypatch.delenv("EGES_MESH_COLLECTIVE", raising=False)
-    missing = str(tmp_path / "absent.json")
-    assert load_collective_table(missing) == {}
-    assert preferred_collective(
-        _RING_MIN_DEVICES - 1, 256, path=missing) == "psum"
-    assert preferred_collective(
-        _RING_MIN_DEVICES, 256, path=missing) == "ring"
-    # malformed artifact -> empty table, heuristic again
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert load_collective_table(str(bad)) == {}
-    assert preferred_collective(2, 256, path=str(bad)) == "psum"
+    mesh = data_parallel_mesh(jax.devices()[:4])
+    monkeypatch.setenv("EGES_MESH_COLLECTIVE", "ring")  # ignored now
+    assert BatchVerifier(mesh=mesh).collective_for(1024) == "psum"
+    assert BatchVerifier(mesh=mesh,
+                         collective="ring").collective_for(1024) == "ring"
+    assert BatchVerifier().collective_for(1024) == "none"
+    one = data_parallel_mesh(jax.devices()[:1])
+    assert BatchVerifier(mesh=one,
+                         collective="ring").collective_for(16) == "psum"
+    with pytest.raises(ValueError):
+        BatchVerifier(mesh=mesh, collective="auto")
 
 
 def test_all_to_all_resplit_roundtrip():
