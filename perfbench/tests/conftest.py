@@ -1,0 +1,11 @@
+"""The benchmark's own tests: ``python -m pytest perfbench/tests`` from the
+root of the checkout.  They run on the CPU and never claim a device."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
