@@ -840,8 +840,7 @@ def _profile_stage() -> dict | None:
                 for p in ("pool_admit", "pool_queue")),
             "verify_samples": sum(
                 by_phase.get(p, 0)
-                for p in ("verify_stage", "verify_compute",
-                          "verify_collect")),
+                for p in ("verify_stage", "verify_collect")),
             "hz": rep["hz"],
             "overhead_pct": rep["overhead_pct"],
             "rows": batches * rows * passes,

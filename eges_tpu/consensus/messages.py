@@ -62,6 +62,24 @@ GOSSIP_HEADERS_REPLY = 0x1A  # header+cert batches over TCP
 GOSSIP_GET_STATE = 0x1B      # fast-sync state request, broadcast fallback
 GOSSIP_STATE_REPLY = 0x1C    # fast-sync state page over TCP (big chunks)
 
+# A message's kind, as the ``consensus.handle`` span labels it (a vote
+# rides the elect envelope: the handler tells the two apart).
+DIRECT_KINDS = {
+    UDP_EXAMINE_REPLY: "validate_reply", UDP_ELECT: "elect",
+    UDP_QUERY_REPLY: "query_reply", UDP_BLOCKS: "blocks",
+    UDP_GET_BLOCKS: "get_blocks", UDP_GET_HEADERS: "get_headers",
+    UDP_HEADERS: "headers", UDP_GET_STATE: "get_state",
+    UDP_STATE: "state",
+}
+GOSSIP_KINDS = {
+    GOSSIP_VALIDATE_REQ: "validate_req", GOSSIP_QUERY: "query",
+    GOSSIP_REGISTER_REQ: "register_req", GOSSIP_CONFIRM_BLOCK: "confirm",
+    GOSSIP_GET_BLOCKS: "get_blocks", GOSSIP_BLOCKS_REPLY: "blocks",
+    GOSSIP_TXNS: "txns", GOSSIP_GET_HEADERS: "get_headers",
+    GOSSIP_HEADERS_REPLY: "headers", GOSSIP_GET_STATE: "get_state",
+    GOSSIP_STATE_REPLY: "state",
+}
+
 
 @dataclass(frozen=True)
 class ElectMessage:

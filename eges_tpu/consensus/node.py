@@ -347,9 +347,12 @@ class GeecNode:
         returns ``{author: verified_sig}`` for every author with at least
         one valid entry (sig is ``b""`` when signing is off)."""
         out: dict[bytes, bytes] = {}
-        for (a, _, s), r in zip(entries, self._recover_entries(entries)):
-            if r is not None and a not in out:
-                out[a] = s if self._signing else b""
+        with tracing.DEFAULT.span("consensus.verify_quorum",
+                                  rows=len(entries)):
+            for (a, _, s), r in zip(entries,
+                                    self._recover_entries(entries)):
+                if r is not None and a not in out:
+                    out[a] = s if self._signing else b""
         return out
 
     # ------------------------------------------------------------------
@@ -439,10 +442,11 @@ class GeecNode:
         # to the delivering peer stamped by the transport fabric
         src = ledger.current_peer()
         with self._lock, tracing.DEFAULT.activate(ctx), \
-                ledger.bind(self.ledger, f"peer:{src}" if src else "net"):
-            self._on_gossip(data)
+                ledger.bind(self.ledger, f"peer:{src}" if src else "net"), \
+                tracing.DEFAULT.span("consensus.handle") as sp:
+            self._on_gossip(data, sp)
 
-    def _on_gossip(self, data: bytes) -> None:
+    def _on_gossip(self, data: bytes, sp: tracing.Span) -> None:
         if len(data) > self.INGRESS_MAX_BYTES:
             # decode budget enforced before ANY byte is parsed: an
             # oversized datagram costs one length check, billed to its
@@ -460,6 +464,7 @@ class GeecNode:
             # malformed datagram from a peer must not kill the loop
             self._log("malformed gossip", nbytes=len(data), err=repr(exc))
             return
+        sp.set_attr("kind", M.GOSSIP_KINDS.get(code, "other"))
         try:
             self._dispatch_gossip(code, msg)
         except Exception as exc:
@@ -499,10 +504,11 @@ class GeecNode:
         ctx, data = tracing.extract(data)
         src = ledger.current_peer()
         with self._lock, tracing.DEFAULT.activate(ctx), \
-                ledger.bind(self.ledger, f"peer:{src}" if src else "net"):
-            self._on_direct(data)
+                ledger.bind(self.ledger, f"peer:{src}" if src else "net"), \
+                tracing.DEFAULT.span("consensus.handle") as sp:
+            self._on_direct(data, sp)
 
-    def _on_direct(self, data: bytes) -> None:
+    def _on_direct(self, data: bytes, sp: tracing.Span) -> None:
         if len(data) > self.INGRESS_MAX_BYTES:
             # same decode budget as the gossip plane
             from eges_tpu.utils.metrics import DEFAULT as metrics
@@ -518,6 +524,9 @@ class GeecNode:
             # malformed/unauthenticated datagram: drop, but leave a trace
             self._log("malformed direct", nbytes=len(data), err=repr(exc))
             return
+        sp.set_attr("kind", "vote" if code == M.UDP_ELECT
+                    and msg.code == M.MSG_VOTE
+                    else M.DIRECT_KINDS.get(code, "other"))
         try:
             self._dispatch_direct(code, msg, author)
         except Exception as exc:
@@ -680,6 +689,15 @@ class GeecNode:
         if (len(wb.supporters) >= wb.election_threshold
                 and self._on_elected()):
             return
+        if retry > 0:
+            # the 1 s re-send: whose vote has not come (self excluded)
+            self.journal.record(
+                "election_resend", blk=blk_num, version=version,
+                retry=retry, have=len(wb.supporters),
+                need=wb.election_threshold,
+                missing=[m.addr.hex()[:8] for m in committee
+                         if m.addr != self.coinbase
+                         and m.addr not in wb.supporters])
         em = M.ElectMessage(code=M.MSG_ELECT, block_num=blk_num,
                             author=self.coinbase, rand=wb.my_rand,
                             version=version, retry=retry,
@@ -815,8 +833,17 @@ class GeecNode:
         if blk_num != self.wb.blk_num or self._phase != VALIDATING:
             return
         if retry > 0:
-            self.journal.record("validate_retry", blk=blk_num,
-                                version=version, retry=retry)
+            # whose ACK has not come, of the height's acceptor window
+            seed = self.seed_for(blk_num)
+            replies = self.wb.validate_replies
+            self.journal.record(
+                "validate_retry", blk=blk_num, version=version,
+                retry=retry, have=len(replies),
+                need=self.wb.validate_threshold,
+                missing=[m.addr.hex()[:8]
+                         for m in (self.membership.acceptors(seed)
+                                   if seed is not None else ())
+                         if m.addr not in replies])
         req = dataclasses.replace(self._validate_req, retry=retry)
         self.transport.gossip(M.pack_gossip(M.GOSSIP_VALIDATE_REQ, req))
         self._set_timer("validate", self.ccfg.validate_timeout_ms / 1e3,

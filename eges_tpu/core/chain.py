@@ -736,36 +736,31 @@ class BlockChain:
             return True
 
     def _insert(self, block: Block) -> None:
-        import time
-
+        from eges_tpu.utils import tracing
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
-        # analysis: allow-determinism(insert dt is metrics/volatile-only)
-        t0 = time.monotonic()
-        self._verify_header(block.header)
-        self._verify_body(block)
-        parent_state = self._states.get(block.header.parent_hash)
-        if parent_state is None:
-            raise ChainError("no state for parent")  # cannot happen in-order
-        state, receipts, _ = self._process(block, parent_state)
-        self.store.put_block(block)
-        self.store.set_head(block.hash)
-        self._head = block
-        self._remember_state(block.hash, block.number, state, receipts)
-        self._index_txns(block, receipts)
-        self.bloom_index.add(block.number, block.header.bloom)
-        from eges_tpu.utils import tracing
-
-        # analysis: allow-determinism(insert dt is metrics/volatile-only)
-        dt = time.monotonic() - t0
+        with tracing.DEFAULT.span("chain.insert", number=block.number,
+                                  txns=len(block.transactions)) as sp:
+            self._verify_header(block.header)
+            self._verify_body(block)
+            parent_state = self._states.get(block.header.parent_hash)
+            if parent_state is None:
+                # cannot happen in-order
+                raise ChainError("no state for parent")
+            state, receipts, _ = self._process(block, parent_state)
+            self.store.put_block(block)
+            self.store.set_head(block.hash)
+            self._head = block
+            self._remember_state(block.hash, block.number, state, receipts)
+            self._index_txns(block, receipts)
+            self.bloom_index.add(block.number, block.header.bloom)
+        dt = sp.duration_s  # insert dt is metrics/volatile-only
         metrics.timer("chain.insert").update(dt)
         metrics.histogram("chain.insert_seconds").observe(dt)
         metrics.counter("chain.blocks").inc()
         metrics.counter("chain.txns").inc(len(block.transactions))
         metrics.counter("chain.geec_txns").inc(len(block.geec_txns))
         metrics.gauge("chain.height").set(block.number)
-        tracing.DEFAULT.record_span("chain.insert", dt, number=block.number,
-                                    txns=len(block.transactions))
         if self.journal is not None:
             self.journal.record("block_committed", blk=block.number,
                                 txns=len(block.transactions),

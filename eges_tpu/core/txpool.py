@@ -121,7 +121,8 @@ class TxPool:
         (ref: TxPool.AddRemotes core/tx_pool.go:551)."""
         fresh = 0
         with self._lock, \
-                tracing.DEFAULT.span("txpool.ingest", owner=self.owner) as sp:
+                tracing.DEFAULT.span("txpool.ingest", root=True,
+                                     owner=self.owner) as sp:
             ctx = sp.context()
             for t in txns:
                 h = t.hash
@@ -168,7 +169,8 @@ class TxPool:
         duck type: this layer consumes the arrays, it never imports the
         decoder (core stays below ingress in the layer map)."""
         with self._lock, \
-                tracing.DEFAULT.span("txpool.ingest", owner=self.owner) as sp:
+                tracing.DEFAULT.span("txpool.ingest", root=True,
+                                     owner=self.owner) as sp:
             ctx = sp.context()
             hashes = cols.hashes
             n_undec = cols.n - int(cols.decoded.sum())
@@ -259,6 +261,18 @@ class TxPool:
             self._flush()
 
     def _flush(self) -> None:
+        """Hand everything queued to the verifier and admit what comes
+        back: one ``txpool.flush`` span, whose self time is the
+        gathering of rows (the wait is ``sched.await`` inside it, the
+        admission ``txpool.admit_window`` / ``txpool.admit``)."""
+        if not self._queue:
+            self._flush_queue()  # nothing to hand over: the timer goes
+            return
+        with tracing.DEFAULT.span("txpool.flush", owner=self.owner,
+                                  rows=self._queue_rows):
+            self._flush_queue()
+
+    def _flush_queue(self) -> None:
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None
@@ -294,7 +308,7 @@ class TxPool:
                 continue
             self._admit(t, sender)
         if self._queue:
-            self._flush()
+            self._flush_queue()
 
     def _flush_mixed(self) -> None:
         """Row-granular flush for a queue holding columnar window
@@ -600,7 +614,8 @@ class TxPool:
         """Drop txns included in a canonical block; closes each txn's
         trace with a ``tx.commit`` span so ingest -> admit -> commit is
         one linked trace even across nodes."""
-        with self._lock:
+        with self._lock, tracing.DEFAULT.span(
+                "txpool.evict", owner=self.owner, txns=len(txns)):
             for t in txns:
                 ctx = self._ingest_ctx.get(t.hash)
                 if ctx is not None:

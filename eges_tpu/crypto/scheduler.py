@@ -93,6 +93,7 @@ node/txpool lock domain.
 
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -103,7 +104,7 @@ from dataclasses import dataclass, fields, replace
 import numpy as np
 
 from eges_tpu.crypto.bucketing import bucket_round
-from eges_tpu.utils import ledger, profiler
+from eges_tpu.utils import ledger, tracing
 
 # sentinel distinguishing "cached None" (a signature that verifiably
 # fails recovery) from "not cached"
@@ -169,6 +170,26 @@ class _WindowRows:
         return self._fut.result(timeout)
 
 
+def _class_of(priority: str) -> str:
+    """The scheduler's two priority classes: ``consensus`` by name,
+    everything else ``bulk``."""
+    return "consensus" if priority == "consensus" else "bulk"
+
+
+# A synchronous call of this many rows is a ``burst`` to its spans'
+# ``size`` label (a committee's ACK replies), a smaller one a ``call``
+# (an election's votes, a header, a pool slice): their means are not to
+# be mixed.
+BURST_ROWS = 1000
+
+
+def _call_labels(priority: str, rows: int) -> dict:
+    """Attributes of a synchronous call's ``sched.submit`` and
+    ``sched.await`` spans; ``class`` and ``size`` label the histograms."""
+    return {"rows": rows, "class": _class_of(priority),
+            "size": "burst" if rows >= BURST_ROWS else "call"}
+
+
 class _WindowSlot:
     """Future duck-type occupying one row of a :class:`_WindowRows`.
 
@@ -215,7 +236,8 @@ class SchedulerConfig:
     cache_size: int = 4096        # LRU recovery-cache entries
     breaker_cooldown_s: float = 5.0  # per-lane breaker open time
     min_split: int = 16           # smallest mesh chunk worth a dispatch
-    flight_ring: int = 256        # flight-recorder ring capacity
+    flight_ring: int = 4096       # flight-recorder ring capacity (the
+    #                               most thw_flight hands out at once)
     # -- adaptive windowing (closed-loop controller) --
     adaptive: bool = False        # enable the per-window controller
     slo_p99_ms: float = 50.0      # declared p99 window objective for the
@@ -308,7 +330,7 @@ class _PendingWindow:
 
     __slots__ = ("batch", "keys", "reason", "t0", "rows", "results",
                  "staged", "probing", "diverted", "computed", "failure",
-                 "finished", "t_dispatch", "t_collect", "ticket")
+                 "finished", "t_dispatch", "t_collect", "ticket", "flight")
 
 
 class _WindowTicket:
@@ -541,7 +563,7 @@ class VerifierScheduler:
         the higher class."""
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
-        klass = "consensus" if priority == "consensus" else "bulk"
+        klass = _class_of(priority)
         fut: Future = Future()
         if len(sig) != 65 or len(sighash) != 32:
             # malformed entries never reach the device (the zero-fill
@@ -590,7 +612,6 @@ class VerifierScheduler:
                 else:
                     # analysis: allow-determinism(coalescing deadline is real-time by contract; chaos pins batching via max_batch kicks)
                     self._pending[key] = [[fut], time.monotonic(), klass]
-                    from eges_tpu.utils import tracing
                     ctx = tracing.DEFAULT.current_context()
                     if (ctx is not None and len(self._pending_trace)
                             < self._PENDING_TRACE_CAP):
@@ -634,17 +655,20 @@ class VerifierScheduler:
         delegates here when the node's verifier is a scheduler.
         ``priority="consensus"`` marks the rows consensus-critical (see
         :meth:`submit`)."""
-        futs = [self.submit(h, s, priority) for h, s in entries]
-        self.kick()
+        labels = _call_labels(priority, len(entries))
+        with tracing.DEFAULT.span("sched.submit", **labels):
+            futs = [self.submit(h, s, priority) for h, s in entries]
         out = []
-        for (h, s), f in zip(entries, futs):
-            try:
-                out.append(f.result())
-            # analysis: allow-swallow(a torn-down scheduler fails futures
-            # with an error; consensus keeps committing on the host path)
-            except Exception:
-                out.append(self._host_recover((bytes(h), bytes(s)))
-                           if len(s) == 65 and len(h) == 32 else None)
+        with tracing.DEFAULT.span("sched.await", **labels):
+            self.kick()
+            for (h, s), f in zip(entries, futs):
+                try:
+                    out.append(f.result())
+                # analysis: allow-swallow(a torn-down scheduler fails futures
+                # with an error; consensus keeps committing on the host path)
+                except Exception:
+                    out.append(self._host_recover((bytes(h), bytes(s)))
+                               if len(s) == 65 and len(h) == 32 else None)
         return out
 
     def recover_addresses(self, sigs: np.ndarray, hashes: np.ndarray,
@@ -679,7 +703,6 @@ class VerifierScheduler:
         charges at one timestamp sum to the same ledger state).  Row
         semantics — LRU touch, post-close inline recovery, class
         promotion, trace/origin capture — match per-row submit exactly."""
-        from eges_tpu.utils import tracing
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
         n = len(hashes)
@@ -689,7 +712,7 @@ class VerifierScheduler:
             return win
         if hashes.shape[1] != 32 or sigs.shape[1] != 65:
             raise ValueError("window arrays must be (n,32) and (n,65)")
-        klass = "consensus" if priority == "consensus" else "bulk"
+        klass = _class_of(priority)
         n_hits = 0
         with self._lock:
             # analysis: allow-determinism(coalescing deadline is real-time by contract; chaos pins batching via max_batch kicks)
@@ -759,9 +782,12 @@ class VerifierScheduler:
         delegates here when the pool's verifier is a scheduler.  Rows a
         torn-down scheduler failed fall back to host recovery, exactly
         like :meth:`recover_signers`."""
-        win = self.submit_window(hashes, sigs, priority)
-        self.kick()
-        out = win.result()
+        labels = _call_labels(priority, len(hashes))
+        with tracing.DEFAULT.span("sched.submit", **labels):
+            win = self.submit_window(hashes, sigs, priority)
+        with tracing.DEFAULT.span("sched.await", **labels):
+            self.kick()
+            out = win.result()
         fixed = None
         for i, v in enumerate(out):
             if isinstance(v, BaseException):
@@ -907,20 +933,34 @@ class VerifierScheduler:
 
     def flights(self, limit: int = 0) -> list[dict]:
         """Flight-recorder entries, oldest first (the ring keeps the
-        newest ``config.flight_ring`` windows — default 256 — and
-        evictions count into ``stats()["flight_dropped"]`` /
-        ``verifier.flight_dropped``); ``limit`` keeps only the newest
-        N.  Each
-        entry is one window's lifecycle: phase timestamps
-        (``t_submit``/``t_begin``/``t_dispatch``/``t_collect``/
-        ``t_done``), phase durations, and lane/device attribution."""
+        newest ``config.flight_ring`` windows — default 4096, what
+        ``thw_flight`` hands out at most — and evictions count into
+        ``stats()["flight_dropped"]`` / ``verifier.flight_dropped``);
+        ``limit`` keeps only the newest N.  Each entry is one window's
+        lifecycle: phase timestamps (``t_submit``/``t_begin``/
+        ``t_dispatch``/``t_collect``/``t_done``), phase durations
+        (``wait_ms``, ``stage_ms``, ``compute_ms``, ``resolve_ms``: from
+        the device's answer to the window's last future set, the
+        recording included) and lane/device attribution."""
         with self._lock:
-            evs = list(self._flights)
-        if limit and limit > 0:
-            evs = evs[-limit:]
-        return [dict(f) for f in evs]
+            evs = self._newest_flights(limit) if limit and limit > 0 \
+                else self._flights
+            # copied under the lock: a window that is resolving writes
+            # its ``resolve_ms`` under it
+            return [dict(f) for f in evs]
 
     # -- internals --------------------------------------------------------
+
+    # flights the hedge thresholds are derived from (the ring's whole
+    # length while it was 256 long)
+    _HEDGE_RECENT = 256
+
+    def _newest_flights(self, n: int) -> list[dict]:
+        """The newest ``n`` flight entries, oldest first, without
+        copying the whole ring.  Caller holds ``self._lock``."""
+        out = list(itertools.islice(reversed(self._flights), n))
+        out.reverse()
+        return out
 
     def _flush_target(self) -> int:
         """Rows that flush a window as "full" right now — ``max_batch``
@@ -959,21 +999,20 @@ class VerifierScheduler:
         that coalesced down to a single row, and the post-close inline
         path.  Counts into ``verifier.host_rows`` like every other host
         fallback so the device-share metric stays honest."""
-        with profiler.phase("verify_compute"):
-            h, sig = key
-            from eges_tpu.crypto.verify_host import _count_host_rows
-            _count_host_rows(1)
-            from eges_tpu.crypto import native
-            if native.available():
-                from eges_tpu.crypto.keccak import keccak256
-                pubs, okb = native.ec_recover_batch(h, sig, 1)
-                return keccak256(pubs[:64])[12:] if okb[0] else None
-            from eges_tpu.crypto import secp256k1 as host
-            try:
-                return host.recover_address(h, sig)
-            # analysis: allow-swallow(invalid signature maps to a None result)
-            except Exception:
-                return None
+        h, sig = key
+        from eges_tpu.crypto.verify_host import _count_host_rows
+        _count_host_rows(1)
+        from eges_tpu.crypto import native
+        if native.available():
+            from eges_tpu.crypto.keccak import keccak256
+            pubs, okb = native.ec_recover_batch(h, sig, 1)
+            return keccak256(pubs[:64])[12:] if okb[0] else None
+        from eges_tpu.crypto import secp256k1 as host
+        try:
+            return host.recover_address(h, sig)
+        # analysis: allow-swallow(invalid signature maps to a None result)
+        except Exception:
+            return None
 
     def _dispatch_loop(self) -> None:
         """Wrapper keeping the strand-no-future invariant: if the flush
@@ -1184,7 +1223,8 @@ class VerifierScheduler:
                 nxt_p: _PendingWindow | None = None
                 if nxt is not None:
                     if pipelined:
-                        with profiler.phase("verify_stage"):
+                        with tracing.DEFAULT.span("sched.stage",
+                                                  rows=nxt.rows):
                             nxt_p = self._begin_batch(lane, nxt.batch,
                                                       nxt.reason,
                                                       ticket=nxt)
@@ -1319,7 +1359,7 @@ class VerifierScheduler:
         inline composition of the split-phase halves: begin (fill +
         dispatch) then finish (collect + record + resolve) with no
         overlap — the pre-pipeline behavior."""
-        with profiler.phase("verify_stage"):
+        with tracing.DEFAULT.span("sched.stage", rows=len(batch)):
             p = self._begin_batch(lane, batch, reason, ticket)
         self._finish_batch(lane, p)
 
@@ -1347,6 +1387,7 @@ class VerifierScheduler:
         p.finished = False
         p.t_dispatch = None
         p.t_collect = None
+        p.flight = None
         # analysis: allow-determinism(batch latency instrumentation; dt/waited_ms are volatile-stripped)
         p.t0 = time.monotonic()
         try:
@@ -1394,9 +1435,8 @@ class VerifierScheduler:
                         self._stats["pipeline_windows"] += 1
                         lane.stats["pipeline_windows"] += 1
                 else:
-                    with profiler.phase("verify_compute"):
-                        addrs, ok = lane.target.recover_addresses(
-                            sigs, hashes)
+                    addrs, ok = lane.target.recover_addresses(
+                        sigs, hashes)
                     p.results = [bytes(addrs[i]) if ok[i] else None
                                  for i in range(p.rows)]
                     if p.probing:
@@ -1422,32 +1462,50 @@ class VerifierScheduler:
 
     def _finish_batch(self, lane: _DeviceLane, p: _PendingWindow) -> None:
         """Phase 2 of one window: collect the staged device result (if
-        split-phase), insert into the cache, record stats/metrics/
-        journal, and — always, in the ``finally`` — resolve the
-        window's futures.  Re-raises the window's failure after
-        resolution, matching the old ``_run_batch`` contract."""
-        batch, keys, rows = p.batch, p.keys, p.rows
+        split-phase, span ``sched.collect``), then under ``sched.resolve``
+        turn it into row results, insert into the cache, record
+        stats/metrics/journal, and — always, in the ``finally`` —
+        resolve the window's futures.  Re-raises the window's failure
+        after resolution, matching the old ``_run_batch`` contract."""
+        got = None
+        if p.failure is None and p.staged is not None and not p.computed:
+            try:
+                with tracing.DEFAULT.span("sched.collect", rows=p.rows):
+                    got = lane.target.collect_recover(p.staged)
+            # analysis: allow-swallow(a device exception surfacing at
+            # collect diverts exactly this window to the host model and
+            # trips the lane breaker, like a synchronous dispatch
+            # failure would)
+            except Exception:
+                self._breaker_trip(lane, p.probing)
+                p.results = [self._host_recover(k) for k in p.keys]
+                p.diverted = True
+                p.computed = True
+            except BaseException as exc:
+                p.failure = exc
+            # flight-recorder stamp: the device's answer is on the host
+            # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
+            p.t_collect = time.monotonic()
+        with tracing.DEFAULT.span("sched.resolve", rows=p.rows):
+            self._resolve_batch(lane, p, got)
+        if p.failure is not None:
+            raise p.failure
+
+    def _resolve_batch(self, lane: _DeviceLane, p: _PendingWindow,
+                       got) -> None:
+        """The tail of :meth:`_finish_batch`: ``got`` is what
+        ``collect_recover`` returned, None for a window that was
+        computed (or failed) before."""
+        batch, rows = p.batch, p.rows
         mesh = len(self._lanes) > 1
         try:
-            if p.failure is None and p.staged is not None and not p.computed:
-                try:
-                    with profiler.phase("verify_collect"):
-                        addrs, ok = lane.target.collect_recover(p.staged)
-                    p.results = [bytes(addrs[i]) if ok[i] else None
-                                 for i in range(rows)]
-                    if p.probing:
-                        self._breaker_close(lane)
-                # analysis: allow-swallow(a device exception surfacing
-                # at collect diverts exactly this window to the host
-                # model and trips the lane breaker, like a synchronous
-                # dispatch failure would)
-                except Exception:
-                    self._breaker_trip(lane, p.probing)
-                    p.results = [self._host_recover(k) for k in keys]
-                    p.diverted = True
+            if got is not None:
+                addrs, ok = got
+                p.results = [bytes(addrs[i]) if ok[i] else None
+                             for i in range(rows)]
+                if p.probing:
+                    self._breaker_close(lane)
                 p.computed = True
-                # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
-                p.t_collect = time.monotonic()
             if p.failure is None and p.computed:
                 won = True
                 tk = p.ticket
@@ -1509,22 +1567,28 @@ class VerifierScheduler:
                     else:
                         f.set_exception(p.failure or RuntimeError(
                             "verifier batch dispatch failed"))
-        if p.failure is not None:
-            raise p.failure
+            if p.flight is not None:
+                # the recording came BEFORE the futures: what a caller
+                # waited after the device's answer ends here, not at
+                # ``t_done``
+                # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
+                resolve_ms = round((time.monotonic() - p.flight[
+                    "t_collect"]) * 1e3, 3)
+                with self._lock:
+                    p.flight["resolve_ms"] = resolve_ms
 
     def _record_window(self, lane: _DeviceLane, p: _PendingWindow,
                        mesh: bool) -> None:
-        """Cache inserts + stats + metrics + tracing + journal for one
-        computed window — the bookkeeping tail shared by the inline and
-        pipelined paths (errors here propagate to ``_finish_batch``,
-        which still resolves the futures in its ``finally``)."""
-        from eges_tpu.utils import tracing
+        """Cache inserts + stats + metrics + journal for one computed
+        window — the bookkeeping tail shared by the inline and
+        pipelined paths, inside the caller's ``sched.resolve`` span
+        (errors here propagate to ``_resolve_batch``, which still
+        resolves the futures in its ``finally``)."""
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
         batch, keys, rows = p.batch, p.keys, p.rows
-        # analysis: allow-determinism(batch latency instrumentation; dt/waited_ms are volatile-stripped)
+        # analysis: allow-determinism(batch latency instrumentation; waited_ms is volatile-stripped)
         done = time.monotonic()
-        dt = done - p.t0
         pad = getattr(lane.target, "_pad", None) \
             or getattr(self._verifier, "_pad", None) or bucket_round
         bucket = pad(rows) if rows > 1 else 1  # diverted rows pad nothing
@@ -1548,6 +1612,10 @@ class VerifierScheduler:
             "wait_ms": round(waited * 1e3, 3),
             "stage_ms": round((t_dispatch - p.t0) * 1e3, 3),
             "compute_ms": round((t_collect - t_dispatch) * 1e3, 3),
+            # results to bytes and the ticket claim so far; once the
+            # recording below and the futures are through,
+            # _resolve_batch puts the whole of it here
+            "resolve_ms": round((done - t_collect) * 1e3, 3),
             "total_ms": round((done - oldest) * 1e3, 3),
             "klass": klass,
             "hedged": bool(tk is not None and tk.hedged),
@@ -1606,6 +1674,7 @@ class VerifierScheduler:
                 self._stats["flight_dropped"] += 1
                 flight_evicts = True
             self._flights.append(flight)
+            p.flight = flight
             # per-class queue-wait samples behind stats()'s percentiles
             for _k, row in batch:
                 self._class_waits[row[2]].append(
@@ -1661,10 +1730,6 @@ class VerifierScheduler:
             cache_rows=cache_rows, dedup_rows=dedup_rows,
             diverted=bool(p.diverted or rows == 1),
             hedged=flight["hedged"])
-        tracing.DEFAULT.record_span(
-            "verifier.sched_dispatch", dt, rows=rows, bucket=bucket,
-            reason=p.reason, occupancy=round(rows / bucket, 4),
-            device=lane.index, waited_ms=round(waited * 1e3, 3))
         journal = self.journal
         if journal is not None:
             journal.record("verifier_flush", rows=rows, reason=p.reason,
@@ -1736,7 +1801,7 @@ class VerifierScheduler:
             self._adapt_windows += 1
             if self._adapt_windows % max(1, cfg.adapt_every):
                 return
-            recent = list(self._flights)[-max(1, cfg.adapt_recent):]
+            recent = self._newest_flights(max(1, cfg.adapt_recent))
             totals = sorted(f["total_ms"] for f in recent)
             waits = sorted(f["wait_ms"] for f in recent)
             p99 = percentile(totals, 99.0)
@@ -1805,12 +1870,13 @@ class VerifierScheduler:
         from eges_tpu.utils.metrics import percentile
 
         cfg = self.config
-        lane_tot = sorted(f["total_ms"] for f in self._flights
+        recent = self._newest_flights(self._HEDGE_RECENT)
+        lane_tot = sorted(f["total_ms"] for f in recent
                           if f["device"] == lane_index)
         if len(lane_tot) >= cfg.hedge_min_windows:
             base = percentile(lane_tot, 50.0)
         else:
-            all_tot = sorted(f["total_ms"] for f in self._flights)
+            all_tot = sorted(f["total_ms"] for f in recent)
             base = percentile(all_tot, 50.0) if all_tot else 0.0
         return max(cfg.hedge_floor_ms, cfg.hedge_factor * base)
 

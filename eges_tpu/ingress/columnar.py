@@ -42,6 +42,7 @@ import numpy as np
 from eges_tpu.core import rlp
 from eges_tpu.core.types import Transaction
 from eges_tpu.crypto.keccak import keccak256
+from eges_tpu.utils import tracing
 
 # Hard per-frame byte gate, applied BEFORE any parsing: an oversized
 # frame must die without costing a decode or even a hash (the node's
@@ -274,6 +275,12 @@ def decode_window(frames) -> TxColumns:  # ingress-entry:bounded
     if len(frames) > WINDOW_MAX_ROWS:
         raise ValueError("window exceeds %d rows — chunk the caller"
                          % WINDOW_MAX_ROWS)
+    with tracing.DEFAULT.span("ingress.decode", rows=len(frames)):
+        return _decode_frames(frames)
+
+
+def _decode_frames(frames: list) -> TxColumns:
+    """:func:`decode_window`'s two passes over a window it has capped."""
     cols = TxColumns(len(frames))
     dec_rows: list[int] = []    # row index per decoded frame
     dec_msgs: list[bytes] = []  # the frame bytes (txhash preimage)

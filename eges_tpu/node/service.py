@@ -410,6 +410,7 @@ class NodeService:
                 interval_s=cfg.telemetry_interval_s, log=self.log)
 
         self._height_task = None
+        self._lag_timer = None
 
     def _node_log(self, kind: str, **kw) -> None:
         if kind == "breakdown":
@@ -494,6 +495,22 @@ class NodeService:
         self.log.geec("node started", coinbase=self.coinbase.hex(),
                       height=self.chain.height(), mine=self.cfg.mine)
         self._height_task = asyncio.ensure_future(self._height_loop())
+        self._lag_tick(self.clock.now())
+
+    # how often the loop is asked to look at the clock
+    LAG_TICK_S = 0.02
+
+    def _lag_tick(self, due: float) -> None:
+        """``service.loop_lag_seconds``: how late this tick fired, which
+        is what every message and RPC of this node waits before the one
+        event loop looks at it."""
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+        now = self.clock.now()
+        metrics.histogram("service.loop_lag_seconds").observe(
+            max(0.0, now - due))
+        nxt = now + self.LAG_TICK_S
+        self._lag_timer = self.clock.call_later(
+            self.LAG_TICK_S, lambda: self._lag_tick(nxt))
 
     async def _height_loop(self) -> None:
         last = -1
@@ -578,6 +595,8 @@ class NodeService:
     def close(self) -> None:
         if self._height_task is not None:
             self._height_task.cancel()
+        if self._lag_timer is not None:
+            self._lag_timer.cancel()
         if self._telemetry is not None:
             self._telemetry.close()
         from eges_tpu.utils import tracing

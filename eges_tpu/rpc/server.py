@@ -33,6 +33,7 @@ import json
 
 from eges_tpu.core import rlp
 from eges_tpu.core.types import Block, Transaction
+from eges_tpu.utils import tracing
 from eges_tpu.utils.limits import clamp_rpc_limit
 
 # Closed vocabulary of dispatched JSON-RPC methods.  The static-analysis
@@ -868,6 +869,13 @@ class RpcServer:
     # -- JSON-RPC plumbing ------------------------------------------------
 
     def _handle_body(self, body: bytes) -> bytes:  # ingress-entry:bounded
+        """One request body, a single call or a batch, to its reply:
+        the server's side of what a client times around its POST (span
+        ``rpc.handle``; a batch counts once, under its first method)."""
+        with tracing.DEFAULT.span("rpc.handle", nbytes=len(body)) as sp:
+            return self._dispatch_body(body, sp)
+
+    def _dispatch_body(self, body: bytes, sp) -> bytes:
         try:
             req = json.loads(body)
         except json.JSONDecodeError:
@@ -876,6 +884,12 @@ class RpcServer:
                                          "message": "parse error"}}).encode()
         batch = isinstance(req, list)
         reqs = req if batch else [req]
+        first = reqs[0].get("method") if reqs and isinstance(
+            reqs[0], dict) else None
+        # a label only from the closed vocabulary: the name is the
+        # client's, the registry's series must stay bounded
+        sp.set_attr("method", first if first in RPC_METHODS else "other")
+        sp.set_attr("calls", len(reqs))
         out = []
         for r in reqs:
             rid = r.get("id")

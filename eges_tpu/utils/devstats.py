@@ -220,7 +220,13 @@ class DeviceTraceArmer:
             path = os.path.join(base,
                                 "device_trace.%03d" % self._captures)
             os.makedirs(path, exist_ok=True)
-            jax_profiler.start_trace(path)
+            # the program's own spans (utils/tracing.py) say what the
+            # Python tracer's frame names said, at a fraction of the
+            # export: a trace with it on froze the node for half a
+            # minute after five traced seconds
+            opts = jax_profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax_profiler.start_trace(path, profiler_options=opts)
         # analysis: allow-swallow(backends without jax.profiler report an error state instead of tracing)
         except Exception as exc:
             self._remaining = 0

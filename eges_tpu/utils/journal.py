@@ -41,6 +41,9 @@ EVENT_TYPES = frozenset({
     # elections
     "election_started", "election_won", "election_lost",
     "vote_cast", "vote_stashed",
+    # the 1 s re-send of a candidacy whose votes have not come: have /
+    # need / missing (first 8 hex of each committee member not heard)
+    "election_resend",
     # validate round
     "validate_request", "validate_reply", "validate_retry",
     "validate_quorum",

@@ -41,6 +41,11 @@ METRIC_FAMILIES = frozenset({
     # net/ + sim/simnet.py
     "net.dead_letters", "net.direct_bytes", "net.direct_msgs",
     "net.gossip_bytes", "net.gossip_msgs", "net.peer_count",
+    # node/service.py — how late the event loop's 20 ms tick fires
+    "service.loop_lag_seconds",
+    # utils/tracing.py — every live span's duration and self time,
+    # labelled ``;name=<span>[,<label>=<value>]``
+    "span.seconds", "span.self_seconds",
     # sim/faults.py — deterministic fault injection
     "sim.faults_injected",
     # core/txpool.py
@@ -151,7 +156,13 @@ METRIC_HELP = {
     "net.gossip_bytes": "Bytes sent over the gossip plane.",
     "net.gossip_msgs": "Messages sent over the gossip plane.",
     "net.peer_count": "Currently connected peers.",
+    "service.loop_lag_seconds": (
+        "Lateness of the service event loop's 20 ms tick, in seconds."),
     "sim.faults_injected": "Scripted faults injected by the chaos harness.",
+    "span.seconds": "Duration of a program span, by name, in seconds.",
+    "span.self_seconds": (
+        "A span's duration minus what its child spans on the same "
+        "thread covered, in seconds."),
     "txpool.known_clears": "Coarse clears of the known-txn dedup set.",
     "txpool.pending": "Transactions pending in the pool.",
     "txpool.window_undecoded": (

@@ -19,9 +19,10 @@ sample with
   ``phase()`` context manager and — the bridge to the span tracer —
   set automatically for the duration of any ``Tracer.span`` whose name
   appears in :data:`SPAN_PHASES` (``txpool.ingest``/``txpool.admit``
-  -> ``pool_admit``).  The phase vocabulary is the anatomy plane's
-  ``PHASE_ORDER`` plus the verify-window interior
-  (``verify_stage``/``verify_compute``/``verify_collect``) so profile
+  -> ``pool_admit``, ``sched.stage``/``sched.collect`` ->
+  ``verify_stage``/``verify_collect``).  The phase vocabulary is the
+  anatomy plane's ``PHASE_ORDER`` plus the verify-window interior
+  (``verify_stage``/``verify_collect``) so profile
   reports and anatomy reports speak the same language.
 
 Because this is a *wall-clock* sampler (every live thread is sampled,
@@ -75,9 +76,9 @@ PROFILE_PHASES = frozenset({
     # anatomy macro phases (block pipeline)
     "pool_admit", "pool_queue", "election", "ack_quorum",
     "seal_other", "publish", "propagation",
-    # verify-window interior (scheduler fill/dispatch, device compute
-    # or host divert, blocking collect)
-    "verify_stage", "verify_compute", "verify_collect",
+    # verify-window interior (scheduler fill/dispatch, with the inline
+    # compute of a target without split-phase dispatch; blocking collect)
+    "verify_stage", "verify_collect",
     # threads carrying no tag
     "untagged",
 })
@@ -85,18 +86,24 @@ PROFILE_PHASES = frozenset({
 # Span-tracer bridge: a Tracer.span() with one of these names tags the
 # thread for the span body (see utils/tracing.py).  Only *live* spans
 # appear here — consensus phases are record_span()'d after the fact
-# from virtual-clock durations and have no live extent to sample.
+# from virtual-clock durations and have no live extent to sample.  The
+# scheduler's window interior is tagged this way and no other: a
+# window's fill/dispatch (and, on a target without split-phase
+# dispatch, its inline compute) is ``sched.stage``, the blocking
+# collect ``sched.collect``.
 SPAN_PHASES = {
     "txpool.ingest": "pool_admit",
     "txpool.admit": "pool_admit",
     "txpool.admit_window": "pool_admit",
+    "sched.stage": "verify_stage",
+    "sched.collect": "verify_collect",
 }
 
 # Host-vs-verify split used by the bench gate: what share of
 # pipeline-attributed samples is host-side ingest work rather than the
 # verify window itself.
 POOL_PHASES = ("pool_admit", "pool_queue")
-VERIFY_PHASES = ("verify_stage", "verify_compute", "verify_collect")
+VERIFY_PHASES = ("verify_stage", "verify_collect")
 
 # Thread-name prefix -> role, reusing the lockset plane's thread-entry
 # vocabulary (scheduler dispatch/lane/hedge workers, collector accept +

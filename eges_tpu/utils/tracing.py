@@ -15,12 +15,26 @@ payloads as a fixed 28-byte header::
 it on receipt, and ``payload_of`` lets protocol muxes peek the real RLP
 payload without caring whether a header is present.  Nodes that predate
 this header simply never see MAGIC and pass payloads through untouched.
+
+``Tracer.span`` is also the program's ONE timing primitive for the served
+path (:data:`SPANS` names the sites).  Besides the ring entry, a live
+span (a) enters a ``jax.profiler.TraceAnnotation`` for its body, so that
+under a profiler session it lands in the ``.xplane.pb`` on the device
+trace's clock and an idle gap of the chip can be named by the layer that
+spent it; (b) observes ``span.seconds;name=<name>`` and
+``span.self_seconds;name=<name>`` (duration minus what child spans on
+the same thread covered) in the metrics registry, which ``thw_metrics``
+exports; (c) tags the thread for the sampling profiler
+(``profiler.SPAN_PHASES``).  A ``TraceAnnotation`` belongs to a thread:
+a span goes around a synchronous section, never around an ``await``.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -28,6 +42,7 @@ from contextlib import contextmanager
 from contextvars import ContextVar
 from dataclasses import dataclass
 
+from eges_tpu.utils import metrics as metrics_mod
 from eges_tpu.utils import profiler
 
 MAGIC = b"\xd7TRC"
@@ -35,15 +50,73 @@ _HEADER_LEN = len(MAGIC) + 16 + 8
 
 _UNSET = object()
 
+# The served path's spans: name -> (label attributes, what the span
+# bounds).  One span per window, call or message, never per row
+# (``txpool.admit`` is the scalar path's exception, kept for the
+# per-transaction trace).  A label attribute's value becomes a label of
+# the span's histograms:
+# ``span.seconds;name=sched.await,class=consensus,size=burst``; every
+# label has a closed vocabulary.  Other names stay allowed; they carry
+# no label.  PERF.md section 3 copies this table.
+SPANS = {
+    "ingress.decode": ((), "a window of frames to columns, batch Keccak"),
+    "txpool.ingest": ((), "dedup against the known set, queueing; the one "
+                          "span that begins a trace (root=True)"),
+    "txpool.flush": ((), "hand the queue to the verifier, wait, admit"),
+    "txpool.admit_window": ((), "nonce/balance checks and insertion of "
+                                "one flushed slice"),
+    "txpool.admit": ((), "the same for one scalar transaction"),
+    "txpool.evict": ((), "commit eviction, with its per-transaction "
+                         "tx.commit records"),
+    "sched.submit": (("class", "size"), "cache probe, dedup, making the "
+                                        "futures, up to kick()"),
+    "sched.await": (("class", "size"), "from kick() to the last result, "
+                                       "as the caller feels it"),
+    "sched.stage": ((), "fill, H2D, dispatch of one window"),
+    "sched.collect": ((), "blocked in collect_recover: the only span "
+                          "under which the device should be busy"),
+    "sched.resolve": ((), "results to bytes, cache put, the recording, "
+                          "futures set"),
+    "consensus.handle": (("kind",), "one gossip or direct message handled, "
+                                    "under the node's lock"),
+    "consensus.verify_quorum": ((), "a quorum's signatures through the "
+                                    "scheduler"),
+    "chain.insert": ((), "execute, state root, index"),
+    "rpc.handle": (("method",), "one HTTP request body dispatched (a batch "
+                                "counts once, by its first method)"),
+}
 
-def _new_trace_id() -> str:
-    # analysis: allow-determinism(trace ids are observability-only, never journaled)
-    return os.urandom(16).hex()
+# ids: one draw of entropy a process, a counter under it.  A trace id is
+# the process's random half over the counter, a span id the counter
+# alone (it started at a random value, so two nodes' spans under one
+# trace do not meet).
+# analysis: allow-determinism(trace/span ids are observability-only, never journaled)
+_PROCESS_ID = os.urandom(8).hex()
+# analysis: allow-determinism(trace/span ids are observability-only, never journaled)
+_ids = itertools.count(int.from_bytes(os.urandom(8), "big"))
 
 
 def _new_span_id() -> str:
-    # analysis: allow-determinism(span ids are observability-only, never journaled)
-    return os.urandom(8).hex()
+    return "%016x" % (next(_ids) & 0xFFFFFFFFFFFFFFFF)
+
+
+def _new_trace_id() -> str:
+    return _PROCESS_ID + _new_span_id()
+
+
+_trace_annotation = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` once this process has imported
+    jax, else None: a node on the native verifier must not import jax
+    for its spans.  With no profiler session the annotation is a flag
+    test; that is "tracing off"."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        _trace_annotation = getattr(sys.modules.get("jax.profiler"),
+                                    "TraceAnnotation", None)
+    return _trace_annotation
 
 
 @dataclass(frozen=True)
@@ -57,10 +130,13 @@ class SpanContext:
 
 class Span:
     """One timed operation.  Finished spans land in the tracer's ring
-    buffer; unfinished ones are invisible to exporters."""
+    buffer; unfinished ones are invisible to exporters.  As a context
+    manager (what ``Tracer.span`` hands out) it is live for its body:
+    annotated, timed, and the current context unless it stands alone
+    (see :meth:`Tracer.span`)."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
-                 "end_s", "attrs", "_tracer")
+                 "end_s", "attrs", "_tracer", "_child_s", "_lone", "_live")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_id: str | None, start_s: float, attrs: dict):
@@ -72,6 +148,37 @@ class Span:
         self.start_s = start_s
         self.end_s: float | None = None
         self.attrs = attrs
+        self._child_s = 0.0  # what child spans on this thread covered
+        self._lone = False
+
+    def __enter__(self) -> "Span":
+        tracer = self._tracer
+        tracer._stack().append(self)
+        ann = _annotation()
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._live = (None if self._lone
+                      else tracer._current.set(self.context()),
+                      profiler.tag_span(self.name), ann)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        token, ptok, ann = self._live
+        if ann is not None:
+            ann.__exit__(*exc)
+        if ptok is not None:
+            profiler.pop_phase(ptok)
+        tracer = self._tracer
+        if token is not None:
+            tracer._current.reset(token)
+        self.end()
+        stack = tracer._stack()
+        stack.pop()  # self: ``with`` blocks of one thread end innermost first
+        dur = self.duration_s
+        if stack:
+            stack[-1]._child_s += dur
+        tracer._observe(self, dur, max(0.0, dur - self._child_s))
 
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
@@ -110,9 +217,17 @@ class Tracer:
     ``core/txpool.py``).
     """
 
-    def __init__(self, clock=time.monotonic, capacity: int = 4096):
+    def __init__(self, clock=time.monotonic, capacity: int = 4096,
+                 metrics: "metrics_mod.Registry | None" = None):
         self._clock = clock
+        # the registry the spans' histograms live in
+        self.metrics = metrics if metrics is not None \
+            else metrics_mod.DEFAULT
         self._lock = threading.Lock()
+        # live spans of each thread, innermost last (self time)
+        self._tls = threading.local()
+        # (name, label values...) -> the span's two histograms
+        self._hists: dict[tuple, tuple] = {}
         self._finished: deque[dict] = deque(maxlen=capacity)
         self._current: ContextVar[SpanContext | None] = ContextVar(
             "geec_trace_ctx", default=None)
@@ -135,9 +250,22 @@ class Tracer:
             self.started += 1
         return Span(self, name, trace_id, parent_id, self._clock(), attrs)
 
-    @contextmanager
-    def span(self, name: str, parent=_UNSET, **attrs):
-        """Start a span, make it current for the body, end it on exit.
+    def span(self, name: str, parent=_UNSET, root: bool = False,
+             **attrs) -> Span:
+        """A span to use as ``with tracer.span(...) as sp``: ended on
+        exit, annotated in the profiler's trace and observed into
+        ``span.seconds`` / ``span.self_seconds``.
+
+        It joins the trace it runs under (or the ``parent`` given) and
+        is the current context for its body.  Where no trace is current,
+        ``root=True`` begins one (a transaction's, at ``txpool.ingest``;
+        an explicit ``parent=None`` forces one); otherwise the span
+        stands alone: timed, recorded and annotated, but not made
+        current.  A window or a message handled for nobody's transaction
+        must not ride outbound messages as a 28-byte header nor tag the
+        journal's events with an id of its own: every consensus round
+        would become one endless trace, and the journal would stop being
+        byte-deterministic under the simulator.
 
         Span names in ``profiler.SPAN_PHASES`` also tag the calling
         thread with the matching pipeline phase for the span body — the
@@ -145,15 +273,30 @@ class Tracer:
         CPU samples to ``pool_admit`` etc. without its own hooks on
         every ingest path (one dict probe per span when unmapped)."""
         sp = self.start_span(name, parent, **attrs)
-        token = self._current.set(sp.context())
-        ptok = profiler.tag_span(name)
+        sp._lone = (parent is _UNSET and sp.parent_id is None
+                    and not root)
+        return sp
+
+    def _stack(self) -> list:
         try:
-            yield sp
-        finally:
-            if ptok is not None:
-                profiler.pop_phase(ptok)
-            self._current.reset(token)
-            sp.end()
+            return self._tls.stack
+        except AttributeError:
+            self._tls.stack = []
+            return self._tls.stack
+
+    def _observe(self, span: Span, dur: float, self_s: float) -> None:
+        labels = SPANS.get(span.name, ((),))[0]
+        key = (span.name, *[span.attrs.get(k) for k in labels])
+        pair = self._hists.get(key)
+        if pair is None:
+            tail = span.name + "".join(
+                f",{k}={v}" for k, v in zip(labels, key[1:])
+                if v is not None)
+            pair = self._hists[key] = (
+                self.metrics.histogram(f"span.seconds;name={tail}"),
+                self.metrics.histogram(f"span.self_seconds;name={tail}"))
+        pair[0].observe(dur)
+        pair[1].observe(self_s)
 
     def record_span(self, name: str, duration_s: float, parent=_UNSET,
                     **attrs) -> Span:

@@ -124,8 +124,12 @@ def test_adaptive_controller_shrinks_and_grows_on_burn():
     sched.burn_probe = lambda: (burn[0], burn[0])
     try:
         def window(salt: int) -> None:
-            futs = [sched.submit(h, s)
-                    for h, s in _sign_entries(3, salt=salt)]
+            entries = _sign_entries(3, salt=salt)
+            # under the scheduler's (re-entrant) lock, so that a flush
+            # deadline cannot fall between two submits on a loaded
+            # machine and make two windows of one
+            with sched._lock:
+                futs = [sched.submit(h, s) for h, s in entries]
             sched.kick()
             for f in futs:
                 assert f.result(30) is not None
@@ -157,8 +161,9 @@ def test_adaptive_derived_burn_without_probe():
     sched = VerifierScheduler(NativeBatchVerifier(), config=cfg)
     try:
         for k in range(5):
-            futs = [sched.submit(h, s)
-                    for h, s in _sign_entries(2, salt=k + 20)]
+            entries = _sign_entries(2, salt=k + 20)
+            with sched._lock:  # one window, whatever the machine's load
+                futs = [sched.submit(h, s) for h, s in entries]
             sched.kick()
             for f in futs:
                 assert f.result(30) is not None

@@ -51,8 +51,8 @@ def test_phase_nesting_restores_previous_tag():
     assert _current_phase() is None
     with profiler.phase("pool_admit"):
         assert _current_phase() == "pool_admit"
-        with profiler.phase("verify_compute"):
-            assert _current_phase() == "verify_compute"
+        with profiler.phase("verify_collect"):
+            assert _current_phase() == "verify_collect"
         assert _current_phase() == "pool_admit"
     assert _current_phase() is None
 
@@ -69,8 +69,8 @@ def test_host_cpu_share_split():
     assert host_cpu_share({}) is None
     assert host_cpu_share({"untagged": 50}) is None
     share = host_cpu_share({"pool_admit": 1, "pool_queue": 1,
-                            "verify_stage": 2, "verify_compute": 3,
-                            "verify_collect": 1, "untagged": 99})
+                            "verify_stage": 2, "verify_collect": 4,
+                            "untagged": 99})
     assert share == pytest.approx(100.0 * 2 / 8)
 
 
@@ -86,7 +86,7 @@ def _spin_until(evt: threading.Event, tag: str) -> None:
 def test_sampler_attributes_roles_and_phases():
     prof = SamplingProfiler(hz=499.0)
     stop = threading.Event()
-    lane = threading.Thread(target=_spin_until, args=(stop, "verify_compute"),
+    lane = threading.Thread(target=_spin_until, args=(stop, "verify_collect"),
                             name="verifier-lane-7", daemon=True)
     lane.start()
     assert prof.start()
@@ -104,7 +104,7 @@ def test_sampler_attributes_roles_and_phases():
                 time.sleep(0.002)
             rep = prof.report()
             if (rep["by_phase"].get("pool_admit", 0) >= 3
-                    and rep["by_phase"].get("verify_compute", 0) >= 3):
+                    and rep["by_phase"].get("verify_collect", 0) >= 3):
                 break
     finally:
         stop.set()
@@ -113,7 +113,7 @@ def test_sampler_attributes_roles_and_phases():
 
     rep = prof.report()
     assert rep["by_phase"].get("pool_admit", 0) >= 3, rep
-    assert rep["by_phase"].get("verify_compute", 0) >= 3, rep
+    assert rep["by_phase"].get("verify_collect", 0) >= 3, rep
     assert rep["by_role"].get("lane", 0) >= 3, rep
     assert rep["by_role"].get("main", 0) >= 1, rep
     assert rep["host_cpu_share_of_verify_pct"] is not None
@@ -133,7 +133,7 @@ def test_sampler_attributes_roles_and_phases():
         assert len(parts) >= 3 and int(n) >= 1
         counts.append(int(n))
     assert counts == sorted(counts, reverse=True)
-    assert any(";verify_compute;" in line and "_spin_until" in line
+    assert any(";verify_collect;" in line and "_spin_until" in line
                for line in lines), lines[:5]
 
     # stats block (the thw_health surface) reconciles with the report
@@ -273,7 +273,7 @@ def test_thw_profile_rpc_and_health_block(monkeypatch):
     prof = SamplingProfiler(hz=997.0)
     stop = threading.Event()
     worker = threading.Thread(target=_spin_until,
-                              args=(stop, "verify_compute"),
+                              args=(stop, "verify_collect"),
                               name="verifier-lane-1", daemon=True)
     worker.start()
     assert prof.start()
@@ -361,17 +361,17 @@ def test_observatory_renders_empty_and_populated_profiles():
     asm = ProfileAssembler()
     asm.ingest({"type": "profiler_report", "node": "profiler", "seq": 0,
                 "ts": 1.0, "hz": 97.0, "samples": 10, "dropped": 1,
-                "by_phase": {"pool_admit": 4, "verify_compute": 6},
+                "by_phase": {"pool_admit": 4, "verify_collect": 6},
                 "by_role": {"main": 4, "lane": 6},
                 "top": [["eges_tpu.core.txpool.TxPool.add_remotes",
                          "pool_admit", 4],
                         ["eges_tpu.crypto.verify_host.recover",
-                         "verify_compute", 6]],
+                         "verify_collect", 6]],
                 "overhead_pct": 0.5})
     rep = asm.report()
     assert rep["host_cpu_share_of_verify_pct"] == pytest.approx(40.0)
     text = observatory.render_profile(rep)
-    assert "pool_admit" in text and "verify_compute" in text
+    assert "pool_admit" in text and "verify_collect" in text
     assert "add_remotes" in text  # phases resolve to named functions
     assert "host CPU share of verify pipeline: 40.00%" in text
     assert "per-role:" in text and "top self-time functions" in text
@@ -381,7 +381,7 @@ def test_observatory_renders_empty_and_populated_profiles():
     summary = observatory.summarize({"profiler": [
         {"type": "profiler_report", "node": "profiler", "seq": 0,
          "ts": 1.0, "hz": 97.0, "samples": 10, "dropped": 1,
-         "by_phase": {"pool_admit": 4, "verify_compute": 6},
+         "by_phase": {"pool_admit": 4, "verify_collect": 6},
          "by_role": {"main": 4, "lane": 6}, "top": [],
          "overhead_pct": 0.5}]})
     assert summary["profiler_reports"] == {"profiler": 1}

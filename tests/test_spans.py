@@ -1,0 +1,406 @@
+"""Program spans: the one timing primitive of the served path.
+
+A live ``Tracer.span`` observes ``span.seconds`` / ``span.self_seconds``,
+lands in the profiler's trace beside the device operations, and roots no
+trace of its own unless it is a transaction's ingest; the scheduler's
+flight recorder carries ``resolve_ms`` and keeps a whole run; the 1 s
+election re-send and the validate retry say whose message was missing;
+the trace armer leaves the profiler's Python tracer off.
+"""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from eges_tpu.utils import metrics as metrics_mod
+from eges_tpu.utils import profiler, tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+class _Clock:
+    """A clock the test moves by hand."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self) -> float:
+        return self.t
+
+
+def _tracer():
+    clock, reg = _Clock(), metrics_mod.Registry()
+    return tracing.Tracer(clock=clock, metrics=reg), clock, reg
+
+
+# -- the primitive --------------------------------------------------------
+
+def test_span_observes_duration_and_self_time_without_its_child():
+    t, clock, reg = _tracer()
+    with t.span("txpool.flush"):
+        clock.t += 0.010
+        with t.span("sched.await", **{"class": "bulk", "size": "call"}):
+            clock.t += 0.030
+        clock.t += 0.005
+    snap = reg.snapshot()
+    outer = snap["span.seconds;name=txpool.flush"]
+    assert outer["count"] == 1 and outer["mean"] == pytest.approx(0.045)
+    assert snap["span.self_seconds;name=txpool.flush"]["mean"] == \
+        pytest.approx(0.015)
+    # the table's label attributes become the histogram's labels
+    inner = snap["span.seconds;name=sched.await,class=bulk,size=call"]
+    assert inner["mean"] == pytest.approx(0.030)
+    assert snap["span.self_seconds;name=sched.await,class=bulk,size=call"][
+        "mean"] == pytest.approx(0.030)
+    # and the ring still has both, innermost first
+    assert [s["name"] for s in t.finished()] == ["sched.await",
+                                                 "txpool.flush"]
+
+
+def test_self_time_is_kept_per_thread():
+    import threading
+
+    t, clock, reg = _tracer()
+
+    def other():
+        with t.span("sched.stage"):
+            pass
+
+    with t.span("txpool.flush"):
+        clock.t += 0.020
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(10)
+        assert not th.is_alive()
+    # a span on another thread is nobody's child here
+    assert reg.snapshot()["span.self_seconds;name=txpool.flush"][
+        "mean"] == pytest.approx(0.020)
+
+
+def test_a_span_joins_a_trace_and_roots_one_only_when_told():
+    t, _clock, _reg = _tracer()
+    with t.span("sched.stage") as lone:
+        # timed and recorded, but not the current context: nothing it
+        # sends carries a header, no journal event gets its id
+        assert t.current_context() is None
+        assert tracing.inject_current(b"x", t) == b"x"
+    with t.span("txpool.ingest", root=True) as root:  # a txn's trace
+        assert t.current_context() == root.context()
+        with t.span("txpool.flush") as child:
+            assert child.trace_id == root.trace_id
+            assert child.parent_id == root.span_id
+            assert t.current_context() == child.context()
+        assert t.current_context() == root.context()
+    assert t.current_context() is None
+    assert lone.trace_id != root.trace_id
+    # every name of the table is <layer>.<verb>
+    assert all(n.count(".") == 1 for n in tracing.SPANS)
+
+
+def test_span_ids_cost_no_entropy_per_span(monkeypatch):
+    def no_entropy(n):
+        raise AssertionError("a span drew entropy")
+
+    monkeypatch.setattr(os, "urandom", no_entropy)
+    t, _clock, _reg = _tracer()
+    seen = set()
+    for _ in range(50):
+        with t.span("sched.stage") as sp:
+            seen.add((sp.trace_id, sp.span_id))
+            assert len(sp.trace_id) == 32 and len(sp.span_id) == 16
+    assert len(seen) == 50
+    ctx, rest = tracing.extract(tracing.inject(sp.context(), b"payload"))
+    assert ctx == sp.context() and rest == b"payload"
+
+
+def test_scheduler_spans_tag_the_profiler_phases():
+    def phase_now():
+        import threading
+        return profiler._PHASES.get(threading.get_ident())
+
+    t, _clock, _reg = _tracer()
+    with t.span("sched.stage"):
+        assert phase_now() == "verify_stage"
+        with t.span("sched.collect"):
+            assert phase_now() == "verify_collect"
+        assert phase_now() == "verify_stage"
+    assert phase_now() is None
+
+
+def test_no_jax_import_for_a_span():
+    """A node on the native verifier has no jax in its process; a span
+    must not be what brings it in."""
+    code = (
+        "import sys\n"
+        "from eges_tpu.utils import tracing\n"
+        "from eges_tpu.core.txpool import TxPool\n"
+        "from eges_tpu.sim.simnet import SimClock\n"
+        "pool = TxPool(SimClock(), verifier=None)\n"
+        "with tracing.DEFAULT.span('txpool.flush'):\n"
+        "    with tracing.DEFAULT.span('sched.await'):\n"
+        "        pass\n"
+        "pool.remove_included([])\n"
+        "assert tracing.DEFAULT.stats()['started'] >= 3\n"
+        "assert 'jax' not in sys.modules, 'a span imported jax'\n"
+        "print('clean')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                         env=dict(os.environ, PYTHONPATH=ROOT),
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "clean"
+
+
+def test_a_pool_flush_is_found_in_the_profilers_host_plane(tmp_path):
+    """Under a real profiler session (CPU backend) the pool's flush span
+    is an event of the host plane, as ``perfbench.trace.load`` reads it:
+    that is what names an idle gap of the chip."""
+    import jax
+
+    from eges_tpu.core.txpool import TxPool
+    from eges_tpu.core.types import Transaction
+    from eges_tpu.sim.simnet import SimClock
+    from perfbench import trace as tracemod
+
+    class SlowVerifier:
+        """Every sender recovered, after the while a device takes."""
+
+        def recover_addresses(self, sigs, hashes):
+            time.sleep(0.004)
+            n = len(sigs)
+            return np.ones((n, 20), np.uint8), np.ones((n,), bool)
+
+    jax.devices()  # the backend is up before the session starts
+    pool = TxPool(SimClock(), verifier=SlowVerifier(), max_batch=4)
+    txns = [Transaction(nonce=i, gas_limit=21000, to=bytes(20),
+                        value=1).signed(bytes([7]) * 32, chain_id=1)
+            for i in range(4)]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        pool.add_remotes(txns)  # a full batch flushes inline
+    finally:
+        jax.profiler.stop_trace()
+    assert pool.stats["batches"] == 1
+    rows = tracemod.load(str(tmp_path))
+    flush = [r for r in rows if r[2] == "txpool.flush"]
+    assert flush and flush[0][0].startswith("/host:CPU")
+    assert flush[0][4] >= 4e6  # nanoseconds: it waited for the verifier
+    # no Python frame is in the trace: the armer's option, see below
+    assert not any(".py:" in r[2] for r in rows)
+
+
+# -- the scheduler's flight recorder ---------------------------------------
+
+def test_flights_carry_resolve_ms_and_a_run_of_300_windows_drops_none():
+    from eges_tpu.crypto import secp256k1 as host
+    from eges_tpu.crypto.scheduler import SchedulerConfig, VerifierScheduler
+    from eges_tpu.crypto.verify_host import NativeBatchVerifier
+
+    assert SchedulerConfig().flight_ring == 4096
+    sig = host.ecdsa_sign(b"\x11" * 32, b"\x07" * 32)
+    sched = VerifierScheduler(NativeBatchVerifier())
+    try:
+        for k in range(300):
+            # fresh rows every time (the cache answers a repeated one),
+            # two of them (a single row is recovered on the host)
+            rows = [(k.to_bytes(4, "big") * 8, sig),
+                    ((k + 1000).to_bytes(4, "big") * 8, sig)]
+            assert len(sched.recover_signers(rows)) == 2
+        flights = sched.flights()
+        st = sched.stats()
+    finally:
+        sched.close()
+    assert st["flight_dropped"] == 0 and len(flights) >= 300
+    for f in flights:
+        assert f["resolve_ms"] >= 0.0
+        # from the device's answer to the last future: never shorter
+        # than the part of it that lies before ``t_done``
+        assert f["resolve_ms"] >= round(
+            (f["t_done"] - f["t_collect"]) * 1e3, 3) - 1e-6
+    assert [f["window"] for f in sched.flights(limit=5)] == \
+        [f["window"] for f in flights[-5:]]
+    names = {s["name"] for s in tracing.DEFAULT.finished()}
+    assert {"sched.submit", "sched.await", "sched.stage",
+            "sched.resolve"} <= names
+    assert "verifier.sched_dispatch" not in names
+
+
+def test_a_burst_and_a_small_call_keep_histograms_of_their_own():
+    from eges_tpu.crypto import secp256k1 as host
+    from eges_tpu.crypto.scheduler import BURST_ROWS, VerifierScheduler
+    from eges_tpu.crypto.verify_host import NativeBatchVerifier
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    def count(span, size):
+        return metrics.histogram(f"span.seconds;name={span},"
+                                 f"class=consensus,size={size}").count
+
+    before = {(sp, z): count(sp, z) for sp in ("sched.submit", "sched.await")
+              for z in ("call", "burst")}
+    sig = host.ecdsa_sign(b"\x11" * 32, b"\x07" * 32)
+    rows = [((k + 5000).to_bytes(4, "big") * 8, sig)
+            for k in range(BURST_ROWS)]
+    sched = VerifierScheduler(NativeBatchVerifier())
+    try:
+        assert len(sched.recover_signers(rows[:32],
+                                         priority="consensus")) == 32
+        assert len(sched.recover_signers(rows, priority="consensus")) == \
+            BURST_ROWS
+    finally:
+        sched.close()
+    for key, n in before.items():
+        assert count(*key) == n + 1, key
+
+
+# -- the journal ------------------------------------------------------------
+
+def test_resend_and_retry_say_whose_message_was_missing():
+    from eges_tpu.sim.cluster import SimCluster
+
+    c = SimCluster(3, seed=5)
+    silenced = c.nodes[1].addr.hex()[:8]
+    c.net.partition("node1")
+    # the two live members hear each other 1.2 s late: a vote misses
+    # the 1 s re-send, an ACK the 500 ms validate retry
+    c.net.set_link("node2", "node0", latency_s=1.2, jitter_s=0.0)
+    c.net.set_link("node0", "node2", latency_s=1.2, jitter_s=0.0)
+    c.start()
+    c.run(8.0)
+    live = [e for name, evs in c.journals().items() if name != "node1"
+            for e in evs]
+    resends = [e for e in live if e["type"] == "election_resend"]
+    retries = [e for e in live if e["type"] == "validate_retry"]
+    assert resends and retries
+    for e in resends + retries:
+        assert e["retry"] >= 1 and e["have"] < e["need"]
+        assert silenced in e["missing"]
+        assert len(e["missing"]) <= 3 and all(
+            len(m) == 8 for m in e["missing"])
+    # a first send is no re-send
+    started = [e for e in live if e["type"] == "election_started"]
+    assert len(started) >= 1 and all("missing" not in e for e in started)
+
+
+def test_a_message_handled_is_a_span_by_its_kind():
+    from eges_tpu.sim.cluster import SimCluster
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    def count(kind):
+        return metrics.histogram(
+            f"span.seconds;name=consensus.handle,kind={kind}").count
+
+    before = {k: count(k) for k in ("elect", "vote", "validate_req",
+                                    "validate_reply", "confirm")}
+    c = SimCluster(3, seed=2)
+    c.start()
+    c.run(120, stop_condition=lambda: c.min_height() >= 2)
+    assert c.min_height() >= 2
+    for kind, n in before.items():
+        assert count(kind) > n, kind
+    assert metrics.histogram(
+        "span.seconds;name=consensus.verify_quorum").count > 0
+
+
+def test_rpc_handle_labels_by_the_first_method_of_a_closed_vocabulary():
+    import json
+
+    from eges_tpu.rpc.server import RpcServer
+    from eges_tpu.sim.cluster import SimCluster
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    def count(method):
+        return metrics.histogram(
+            f"span.seconds;name=rpc.handle,method={method}").count
+
+    c = SimCluster(3, seed=3)
+    rpc = RpcServer(c.nodes[0].chain, node=c.nodes[0].node)
+    n_known, n_other = count("eth_blockNumber"), count("other")
+    batch = [{"jsonrpc": "2.0", "id": i, "method": "eth_blockNumber",
+              "params": []} for i in range(3)]
+    out = json.loads(rpc._handle_body(json.dumps(batch).encode()))
+    assert [r["result"] for r in out] == ["0x0"] * 3
+    # a batch counts once; a name from outside never makes a series
+    assert count("eth_blockNumber") == n_known + 1
+    rpc._handle_body(json.dumps({"jsonrpc": "2.0", "id": 1, "method":
+                                 "x;name=evil,k=v", "params": []}).encode())
+    rpc._handle_body(b'{"id": 2, "method": 5}')
+    assert count("other") == n_other + 2
+    span = tracing.DEFAULT.finished(limit=3)[0]
+    assert span["name"] == "rpc.handle" and span["attrs"]["calls"] == 3
+
+
+# -- the trace armer ---------------------------------------------------------
+
+def test_armer_starts_the_profiler_with_its_python_tracer_off(monkeypatch,
+                                                              tmp_path):
+    from eges_tpu.utils.devstats import DeviceTraceArmer
+
+    calls = []
+
+    class _Options:
+        python_tracer_level = 1
+
+    class _Profiler:
+        ProfileOptions = _Options
+
+        @staticmethod
+        def start_trace(path, profiler_options=None):
+            calls.append((path, profiler_options))
+
+        @staticmethod
+        def stop_trace():
+            calls.append("stop")
+
+    class _Jax:
+        profiler = _Profiler()
+
+    monkeypatch.setitem(sys.modules, "jax", _Jax())
+    armer = DeviceTraceArmer()
+    armer.arm(2, outdir=str(tmp_path))
+    armer.step()
+    assert armer.status()["state"] == "tracing"
+    (path, opts), = calls
+    assert path.startswith(str(tmp_path))
+    assert opts is not None and opts.python_tracer_level == 0
+    assert armer.disarm()["captures"] == 1 and calls[-1] == "stop"
+
+
+# -- the event loop's lag ------------------------------------------------------
+
+def test_loop_lag_tick_observes_how_late_it_fired():
+    from eges_tpu.node.service import NodeService
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    class _Timers:
+        def __init__(self):
+            self.t, self.calls = 10.0, []
+
+        def now(self):
+            return self.t
+
+        def call_later(self, delay, fn):
+            self.calls.append((delay, fn))
+            return self
+
+        def cancel(self):
+            pass
+
+    svc = NodeService.__new__(NodeService)  # the tick needs only a clock
+    svc.clock = _Timers()
+    hist = metrics.histogram("service.loop_lag_seconds")
+    n, total = hist.count, hist.total
+    svc._lag_tick(svc.clock.now())            # on time
+    (delay, again), = svc.clock.calls
+    assert delay == NodeService.LAG_TICK_S == 0.02
+    svc.clock.t += 0.02 + 0.035               # the loop was busy 35 ms
+    again()
+    assert hist.count == n + 2
+    assert hist.total - total == pytest.approx(0.035)
+    assert len(svc.clock.calls) == 2

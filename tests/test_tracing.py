@@ -67,7 +67,7 @@ def test_ring_buffer_drops_oldest():
 def test_wire_inject_extract_roundtrip():
     t = tracing.Tracer()
     assert tracing.extract(b"no header here") == (None, b"no header here")
-    with t.span("send") as sp:
+    with t.span("send", root=True) as sp:
         data = tracing.inject_current(b"\x01payload", t)
     ctx, payload = tracing.extract(data)
     assert payload == b"\x01payload"
@@ -92,7 +92,7 @@ def test_context_propagates_across_simnet_hop():
     assert len(ta) == 2
     transport = tracing.DEFAULT  # use the process tracer like prod code
     sender = net.join("c", "10.0.0.3", 3, lambda d: None, lambda d: None)
-    with transport.span("cross-hop") as sp:
+    with transport.span("cross-hop", root=True) as sp:
         sender.gossip(b"\x05hello")
         sender.send_direct("10.0.0.2", 2, b"\x06direct")
     clock.run_until(1.0)
