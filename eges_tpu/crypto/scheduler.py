@@ -7,7 +7,13 @@ plus transfer cost.  This layer sits between those callers and the
 device facade (:class:`~eges_tpu.crypto.verifier.BatchVerifier` or the
 JAX-free :class:`~eges_tpu.crypto.verify_host.NativeBatchVerifier`):
 
-* callers :meth:`submit` ``(sighash, sig)`` requests and get futures;
+* rows enter one of two ways.  A WINDOW of rows — every synchronous
+  facade (``recover_signers``, ``recover_addresses``,
+  ``recover_window``) and the pool's ``submit_window`` — enters through
+  the one routine ``_enter_window``: one lock hold, one batched cache
+  probe and in-flight dedup sweep, one result holder, one wake-up, so
+  the dispatcher never sees half a quorum.  A genuinely asynchronous
+  SINGLE row is :meth:`submit`, which returns a future;
 * a background dispatch thread coalesces concurrent requests across
   callers (txpool sender recovery + vote quorums + single-message
   checks) into ONE batch per micro-window — flushed when the bucket
@@ -81,7 +87,8 @@ This module must stay importable WITHOUT JAX (same contract as
 ``verify_host.py``): the bench parent and host-fallback node processes
 construct schedulers around native verifiers.
 
-Thread model: ``submit``/``kick``/``close`` arrive on any caller thread
+Thread model: ``submit``/``submit_window``/the synchronous facades/
+``kick``/``close`` arrive on any caller thread
 (RPC workers, the sim clock thread, consensus dispatch); the flush loop
 runs on one daemon thread, plus one daemon worker per device lane in
 mesh mode.  Every mutable field — pending map, cache, stats, every lane
@@ -188,6 +195,20 @@ def _call_labels(priority: str, rows: int) -> dict:
     ``sched.await`` spans; ``class`` and ``size`` label the histograms."""
     return {"rows": rows, "class": _class_of(priority),
             "size": "burst" if rows >= BURST_ROWS else "call"}
+
+
+def _array_keys(hashes: np.ndarray, sigs: np.ndarray) -> list:
+    """Row keys of a columnar window: ``hashes`` (n,32) / ``sigs``
+    (n,65) uint8 rows as ``(bytes, bytes)`` pairs, cut from ONE copy of
+    each array instead of one numpy row object a key."""
+    n = len(hashes)
+    if n == 0:
+        return []
+    if hashes.shape[1] != 32 or sigs.shape[1] != 65:
+        raise ValueError("window arrays must be (n,32) and (n,65)")
+    hb, sb = hashes.tobytes(), sigs.tobytes()
+    return [(hb[i * 32:i * 32 + 32], sb[i * 65:i * 65 + 65])
+            for i in range(n)]
 
 
 class _WindowSlot:
@@ -498,9 +519,13 @@ class VerifierScheduler:
             "hedge_wasted": 0,
             # closed-loop controller + flight-ring loss accounting
             "adapt_decisions": 0, "flight_dropped": 0,
-            # window-granular admissions (submit_window): whole ingest
-            # windows entering in ONE lock hold instead of row-by-row
-            "window_submits": 0, "window_rows": 0,
+            # window-granular admissions (_enter_window): whole ingest
+            # windows and synchronous calls entering in ONE lock hold
+            # instead of row-by-row, by the priority class the caller
+            # gave (_class_of); stats() adds the two classes up as
+            # ``window_submits`` / ``window_rows``
+            "window_submits_consensus": 0, "window_rows_consensus": 0,
+            "window_submits_bulk": 0, "window_rows_bulk": 0,
         }
         # optional consensus event journal (utils/journal.py), attached
         # by the first owning node; flush decisions land in its stream
@@ -553,7 +578,10 @@ class VerifierScheduler:
         """Queue one ``(sighash32, sig65)`` recovery; the future resolves
         to the 20-byte signer address, or ``None`` for an invalid
         signature.  Cache hits resolve immediately; misses ride the next
-        coalesced batch.
+        coalesced batch.  The entry for asynchronous single rows: a
+        caller that has a batch in hand takes a window entry
+        (:meth:`recover_signers`, :meth:`submit_window`), which costs
+        one lock hold for all of it, not one a row.
 
         ``priority`` is the window class: ``"consensus"`` rows
         (election acks, QC checks — anything consensus blocks on) are
@@ -645,75 +673,30 @@ class VerifierScheduler:
                 self._stats["kicks"] += 1
                 self._lock.notify_all()
 
-    # -- synchronous facades (BatchVerifier-compatible) -------------------
+    # -- window entry + synchronous facades (BatchVerifier-compatible) ----
 
-    def recover_signers(self, entries, *, priority: str = "bulk") -> list:
-        """Batch-recover ``(sighash32, sig65)`` entries; one 20-byte
-        address or ``None`` per entry.  Submits everything, kicks the
-        window (coalescing with whatever else is pending right now), and
-        blocks for the results — ``verify_host.recover_signers``
-        delegates here when the node's verifier is a scheduler.
-        ``priority="consensus"`` marks the rows consensus-critical (see
-        :meth:`submit`)."""
-        labels = _call_labels(priority, len(entries))
-        with tracing.DEFAULT.span("sched.submit", **labels):
-            futs = [self.submit(h, s, priority) for h, s in entries]
-        out = []
-        with tracing.DEFAULT.span("sched.await", **labels):
-            self.kick()
-            for (h, s), f in zip(entries, futs):
-                try:
-                    out.append(f.result())
-                # analysis: allow-swallow(a torn-down scheduler fails futures
-                # with an error; consensus keeps committing on the host path)
-                except Exception:
-                    out.append(self._host_recover((bytes(h), bytes(s)))
-                               if len(s) == 65 and len(h) == 32 else None)
-        return out
-
-    def recover_addresses(self, sigs: np.ndarray, hashes: np.ndarray,
-                          *, priority: str = "bulk"):
-        """Array-in/array-out facade matching
-        ``BatchVerifier.recover_addresses`` so the txpool window flush,
-        block body validation, and the EVM ecrecover precompile route
-        through the cache/coalescer unchanged."""
-        n = sigs.shape[0]
-        addrs = np.zeros((n, 20), np.uint8)
-        ok = np.zeros((n,), bool)
-        if n == 0:
-            return addrs, ok
-        rec = self.recover_signers(
-            [(bytes(hashes[i]), bytes(sigs[i])) for i in range(n)],
-            priority=priority)
-        for i, r in enumerate(rec):
-            if r is not None:
-                addrs[i] = np.frombuffer(r, np.uint8)
-                ok[i] = True
-        return addrs, ok
-
-    def submit_window(self, hashes: np.ndarray, sigs: np.ndarray,
-                      priority: str = "bulk") -> _WindowRows:
-        """Window-granular :meth:`submit`: a whole columnar ingest
-        window — ``hashes`` (n,32) / ``sigs`` (n,65) uint8 rows — enters
-        in ONE lock acquisition with a batched cache probe + in-flight
-        dedup sweep, and returns ONE :class:`_WindowRows` instead of N
-        row futures.  Cache/dedup accounting aggregates into single
-        counter bumps and the cache-hit/miss split bills the ambient
-        ingress origin as ONE ``charge()`` for the whole window (N unit
-        charges at one timestamp sum to the same ledger state).  Row
-        semantics — LRU touch, post-close inline recovery, class
-        promotion, trace/origin capture — match per-row submit exactly."""
+    def _enter_window(self, keys: list, priority: str) -> _WindowRows:
+        """A window of rows enters: THE one implementation, behind
+        :meth:`submit_window` and every synchronous facade.  ``keys``
+        holds one ``(sighash32, sig65)`` pair of ``bytes`` a row, or
+        ``None`` for a malformed entry.  ONE lock acquisition covers the
+        batched cache probe (with LRU touch), post-close inline
+        recovery, the in-flight dedup sweep with class promotion, the
+        trace-id and ledger-origin capture and the aggregated stats;
+        one wake-up, then one ``ledger.charge`` for the whole window (N
+        unit charges at one timestamp sum to the same ledger state).
+        Row semantics match per-row :meth:`submit` exactly: a malformed
+        entry answers ``None``, counts as ``invalid``, is billed as a
+        reject and never reaches the device."""
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
-        n = len(hashes)
+        n = len(keys)
         win = _WindowRows(n)
         if n == 0:
             win._try_finish()
             return win
-        if hashes.shape[1] != 32 or sigs.shape[1] != 65:
-            raise ValueError("window arrays must be (n,32) and (n,65)")
         klass = _class_of(priority)
-        n_hits = 0
+        n_hits = n_invalid = 0
         with self._lock:
             # analysis: allow-determinism(coalescing deadline is real-time by contract; chaos pins batching via max_batch kicks)
             t_now = time.monotonic()
@@ -721,13 +704,15 @@ class VerifierScheduler:
             tid = ctx.trace_id if ctx is not None else None
             rec = ledger.current()
             added = False
-            for i in range(n):
-                key = (bytes(hashes[i]), bytes(sigs[i]))
+            for i, key in enumerate(keys):
+                if key is None:
+                    n_invalid += 1
+                    win.prefill(i, None)
+                    continue
                 hit = self._cache.get(key, _MISS)
                 if hit is not _MISS:
                     self._cache.move_to_end(key)
                     n_hits += 1
-                    self._cache_rows_pending += 1
                     win.prefill(i, hit)
                     continue
                 if self._closed:
@@ -757,11 +742,14 @@ class VerifierScheduler:
                             < self._PENDING_TRACE_CAP):
                         self._pending_origin[key] = rec
                     added = True
+            n_miss = n - n_hits - n_invalid
+            self._cache_rows_pending += n_hits
             self._stats["cache_hits"] += n_hits
             self._stats["cache_served_rows"] += n_hits
-            self._stats["cache_misses"] += n - n_hits
-            self._stats["window_submits"] += 1
-            self._stats["window_rows"] += n
+            self._stats["cache_misses"] += n_miss
+            self._stats["invalid"] += n_invalid
+            self._stats["window_submits_" + klass] += 1
+            self._stats["window_rows_" + klass] += n
             if added:
                 self._ensure_thread()
             if len(self._pending) >= self._flush_target():
@@ -769,33 +757,90 @@ class VerifierScheduler:
             self._lock.notify_all()
         if n_hits:
             metrics.counter("verifier.cache_hits").inc(n_hits)
-        if n > n_hits:
-            metrics.counter("verifier.cache_misses").inc(n - n_hits)
-        ledger.charge(cache_hits=n_hits, cache_misses=n - n_hits)
+        if n_miss:
+            metrics.counter("verifier.cache_misses").inc(n_miss)
+        # the invalid-sig early-out is the cheapest reject there is,
+        # which is exactly why a flood of them must stay attributed
+        ledger.charge(cache_hits=n_hits, cache_misses=n_miss,
+                      rejects=n_invalid)
         win._try_finish()  # all-prefilled windows complete right here
         return win
 
+    def _await_window(self, win: _WindowRows, keys: list,
+                      labels: dict) -> list:
+        """The blocking half of a synchronous call: one kick, one wait.
+        A row that a torn-down scheduler (or a window that died on its
+        way) failed is recovered on the host: consensus keeps
+        committing."""
+        with tracing.DEFAULT.span("sched.await", **labels):
+            self.kick()
+            out = win.result()
+        for i, v in enumerate(out):
+            if isinstance(v, BaseException):
+                out[i] = self._host_recover(keys[i])
+        return out
+
+    def recover_signers(self, entries, *, priority: str = "bulk") -> list:
+        """Batch-recover ``(sighash32, sig65)`` entries; one 20-byte
+        address or ``None`` per entry.  The whole call enters as ONE
+        window (:meth:`_enter_window`: one lock hold, no per-row future),
+        kicks it (coalescing with whatever else is pending right now)
+        and blocks for the results, so the dispatcher sees a quorum's
+        rows all at once and never cuts a call by its deadline while it
+        is still being handed over.  A call of more than ``max_batch``
+        rows is cut by the dispatcher, consensus-class rows first.
+        ``verify_host.recover_signers`` delegates here when the node's
+        verifier is a scheduler.  ``priority="consensus"`` marks the
+        rows consensus-critical (see :meth:`submit`)."""
+        labels = _call_labels(priority, len(entries))
+        with tracing.DEFAULT.span("sched.submit", **labels):
+            keys = [(bytes(h), bytes(s))
+                    if len(s) == 65 and len(h) == 32 else None
+                    for h, s in entries]
+            win = self._enter_window(keys, priority)
+        return self._await_window(win, keys, labels)
+
+    def recover_addresses(self, sigs: np.ndarray, hashes: np.ndarray,
+                          *, priority: str = "bulk"):
+        """Array-in/array-out facade matching
+        ``BatchVerifier.recover_addresses`` so block body validation
+        and the EVM ecrecover precompile route through the
+        cache/coalescer unchanged: the arrays take the window path
+        (:meth:`recover_window`) as they are."""
+        n = sigs.shape[0]
+        addrs = np.zeros((n, 20), np.uint8)
+        ok = np.zeros((n,), bool)
+        if n == 0:
+            return addrs, ok
+        for i, r in enumerate(self.recover_window(hashes, sigs,
+                                                  priority=priority)):
+            if r is not None:
+                addrs[i] = np.frombuffer(r, np.uint8)
+                ok[i] = True
+        return addrs, ok
+
+    def submit_window(self, hashes: np.ndarray, sigs: np.ndarray,
+                      priority: str = "bulk") -> _WindowRows:
+        """Window-granular :meth:`submit`: a whole columnar ingest
+        window — ``hashes`` (n,32) / ``sigs`` (n,65) uint8 rows — enters
+        through :meth:`_enter_window` and returns ONE
+        :class:`_WindowRows` instead of N row futures.  The asynchronous
+        form of the window entry; :meth:`recover_window` is its
+        synchronous facade."""
+        return self._enter_window(_array_keys(hashes, sigs), priority)
+
     def recover_window(self, hashes: np.ndarray, sigs: np.ndarray,
                        *, priority: str = "bulk") -> list:
-        """Synchronous window facade: :meth:`submit_window`, one kick,
-        one blocking wait — ``verify_host.recover_signers_window``
+        """Synchronous window facade over arrays: the window entry, one
+        kick, one blocking wait — ``verify_host.recover_signers_window``
         delegates here when the pool's verifier is a scheduler.  Rows a
         torn-down scheduler failed fall back to host recovery, exactly
         like :meth:`recover_signers`."""
         labels = _call_labels(priority, len(hashes))
         with tracing.DEFAULT.span("sched.submit", **labels):
-            win = self.submit_window(hashes, sigs, priority)
-        with tracing.DEFAULT.span("sched.await", **labels):
-            self.kick()
-            out = win.result()
-        fixed = None
-        for i, v in enumerate(out):
-            if isinstance(v, BaseException):
-                if fixed is None:
-                    fixed = list(out)
-                fixed[i] = self._host_recover(
-                    (bytes(hashes[i]), bytes(sigs[i])))
-        return fixed if fixed is not None else out
+            keys = _array_keys(hashes, sigs)
+            win = self._enter_window(keys, priority)
+        return self._await_window(win, keys, labels)
 
     def ecrecover(self, sigs: np.ndarray, hashes: np.ndarray):
         """Full-pubkey recovery delegates straight to the backing
@@ -887,6 +932,8 @@ class VerifierScheduler:
         batches / diverts / occupancy per device)."""
         with self._lock:
             out = dict(self._stats)
+            for pair in ("window_submits", "window_rows"):
+                out[pair] = out[pair + "_consensus"] + out[pair + "_bulk"]
             out["cached_entries"] = len(self._cache)
             out["pending"] = len(self._pending)
             out["breaker"] = ("open" if any(

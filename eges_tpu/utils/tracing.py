@@ -68,8 +68,10 @@ SPANS = {
     "txpool.admit": ((), "the same for one scalar transaction"),
     "txpool.evict": ((), "commit eviction, with its per-transaction "
                          "tx.commit records"),
-    "sched.submit": (("class", "size"), "cache probe, dedup, making the "
-                                        "futures, up to kick()"),
+    "sched.submit": (("class", "size"), "row keys, then the window's "
+                                        "entry (cache probe, dedup, "
+                                        "slots) under one lock hold, up "
+                                        "to kick()"),
     "sched.await": (("class", "size"), "from kick() to the last result, "
                                        "as the caller feels it"),
     "sched.stage": ((), "fill, H2D, dispatch of one window"),

@@ -237,8 +237,11 @@ def test_hedge_loser_cancelled_before_execution():
     # it must drop the already-claimed B-hedge and C-primary copies at
     # pop, without dispatching them — the "cancelled" loser outcome.
     mesh = NativeMeshVerifier(2)
+    # the straggler monitor reads its floor live: it is held off (an
+    # hour) until all three windows are where the scenario needs them,
+    # so a slow machine cannot hedge A before B is placed
     cfg = SchedulerConfig(window_ms=10_000.0, hedge=True,
-                          hedge_floor_ms=10.0, hedge_poll_ms=2.0)
+                          hedge_floor_ms=3.6e6, hedge_poll_ms=2.0)
     sched = VerifierScheduler(mesh, config=cfg)
     gates = [threading.Event(), threading.Event()]
     served: list[tuple[int, int]] = []
@@ -247,14 +250,14 @@ def test_hedge_loser_cancelled_before_execution():
 
         def _gate(sigs, hashes, _i=lane_i, _orig=orig,
                   _ev=gates[lane_i]):
-            _ev.wait(30)
+            _ev.wait(120)
             served.append((_i, len(sigs)))
             return _orig(sigs, hashes)
 
         tgt.recover_addresses = _gate
 
     def _await(cond) -> None:
-        deadline = time.monotonic() + 10.0
+        deadline = time.monotonic() + 60.0
         while time.monotonic() < deadline:
             with sched._lock:
                 if cond():
@@ -278,14 +281,12 @@ def test_hedge_loser_cancelled_before_execution():
     sched.kick()
     _await(lambda: len(sched._lanes[0].queue) == 1)
     try:
-        # wait for the hedge thread to copy C onto lane 1's queue, then
-        # release lane 1 alone: every future must resolve without lane 0
-        deadline = time.monotonic() + 10.0
-        while time.monotonic() < deadline:
-            with sched._lock:
-                if sched._stats["hedges"] >= 3:
-                    break
-            time.sleep(0.005)
+        # let the monitor loose, wait for it to copy all three windows
+        # onto their siblings' queues, then release lane 1 alone: every
+        # future must resolve without lane 0
+        with sched._lock:
+            sched.config.hedge_floor_ms = 10.0
+        _await(lambda: sched._stats["hedges"] >= 3)
         gates[1].set()
         got = [f.result(30) for f in futs]
         assert got == expect

@@ -171,6 +171,254 @@ def test_shutdown_drains_every_future_and_joins_thread():
     assert f.result(0) == _host_model(entries[:1])[0]
 
 
+# -- a window of rows enters: the synchronous facades -----------------------
+
+def _mixed_entries(n: int, salt: int) -> list[tuple[bytes, bytes]]:
+    """``n`` entries, most valid, with an invalid signature, a malformed
+    signature, a malformed hash and a duplicate in every run of ten."""
+    entries = _sign_entries(n, salt=salt)
+    for i in range(0, n, 10):
+        entries[i] = (bytes([i % 250 + 1]) * 32, b"\x00" * 65)   # bad sig
+        if i + 3 < n:
+            entries[i + 3] = (entries[i + 3][0], b"\x01" * 10)   # short sig
+        if i + 5 < n:
+            entries[i + 5] = (b"\x02" * 7, entries[i + 5][1])    # short hash
+        if i + 7 < n:
+            entries[i + 7] = entries[i + 6]                      # duplicate
+    return entries
+
+
+@pytest.mark.parametrize("priority", ["bulk", "consensus"])
+def test_window_call_larger_than_max_batch_matches_host_model(priority):
+    """One ``recover_signers`` call of more rows than a window holds,
+    with invalid, malformed and duplicate entries among them, answers
+    row for row what the host model answers; the malformed rows never
+    reach the device and are counted as per-row ``submit`` counts them."""
+    entries = _mixed_entries(40, salt=20)
+    expect = _host_model(entries)
+    malformed = sum(1 for h, s in entries if len(s) != 65 or len(h) != 32)
+    dups = len(entries) - malformed - len(
+        {e for e in entries if len(e[1]) == 65 and len(e[0]) == 32})
+    assert malformed >= 8 and dups >= 4
+
+    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=10_000.0,
+                              max_batch=16)
+    assert sched.recover_signers(entries, priority=priority) == expect
+    st = sched.stats()
+    klass, other = (("consensus", "bulk") if priority == "consensus"
+                    else ("bulk", "consensus"))
+    assert st["invalid"] == malformed
+    assert st["coalesced_rows"] == dups
+    assert st["rows"] == len(entries) - malformed - dups
+    assert st["cache_misses"] == len(entries) - malformed
+    assert st["window_submits"] == st["window_submits_" + klass] == 1
+    assert st["window_rows"] == st["window_rows_" + klass] == len(entries)
+    assert st["window_submits_" + other] == st["window_rows_" + other] == 0
+    # cut by the dispatcher alone: full windows, then the kicked rest
+    assert [f["rows"] for f in sched.flights()] == [16, 12]
+    assert st["flush_full"] == 1 and st["flush_deadline"] == 0
+    assert {f["klass"] for f in sched.flights()} == {klass}
+    # a second pass is answered by the cache, the malformed rows again
+    # by the early-out
+    assert sched.recover_signers(entries, priority=priority) == expect
+    st2 = sched.stats()
+    assert st2["batches"] == st["batches"]
+    assert st2["invalid"] == 2 * malformed
+    sched.close()
+
+
+def test_consensus_call_flies_as_one_window_past_pending_bulk_rows():
+    """A consensus call of ``max_batch`` rows made while bulk rows are
+    pending is ONE consensus-class flight of ``max_batch`` rows: it
+    enters under one lock hold, so no deadline can cut it, and it takes
+    the window's seats ahead of the older bulk rows."""
+    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=10_000.0,
+                              max_batch=32)
+    bulk = _sign_entries(5, salt=21)
+    votes = _sign_entries(32, salt=22)
+    bulk_futs = [sched.submit(h, s) for h, s in bulk]
+    assert sched.recover_signers(votes, priority="consensus") == \
+        _host_model(votes)
+    assert [f.result(30) for f in bulk_futs] == _host_model(bulk)
+    flights = sched.flights()
+    assert [(f["klass"], f["rows"], f["reason"]) for f in flights] == \
+        [("consensus", 32, "full"), ("bulk", 5, "kick")]
+    st = sched.stats()
+    assert st["flush_deadline"] == 0
+    assert (st["window_submits_consensus"], st["window_rows_consensus"]) \
+        == (1, 32)
+    assert st["window_submits_bulk"] == st["window_rows_bulk"] == 0
+    assert st["window_submits"] == 1 and st["window_rows"] == 32
+    sched.close()
+
+
+@pytest.mark.parametrize("how", ["closed", "device_fails", "torn_down"])
+def test_window_call_answers_every_row_on_the_host_path(how):
+    """No synchronous call loses a row: against a closed scheduler the
+    rows are recovered inline, a window that fails on the device is
+    host-diverted, and rows that a torn-down scheduler FAILED are
+    recovered on the host by the facade itself."""
+    entries = _mixed_entries(12, salt=23)
+    expect = _host_model(entries)
+    verifier = NativeBatchVerifier()
+    sched = VerifierScheduler(verifier, window_ms=10_000.0)
+    if how == "closed":
+        sched.close()
+        assert sched.recover_signers(entries, priority="consensus") == expect
+        assert sched.stats()["batches"] == 0
+        return
+    if how == "device_fails":
+        def boom(rows):
+            raise RuntimeError("device lost")
+        sched.failure_hook = boom
+        assert sched.recover_signers(entries, priority="consensus") == expect
+        st = sched.stats()
+        assert st["device_errors"] == 1 and st["breaker"] == "open"
+        sched.close()
+        return
+    # torn down: the dispatch thread is stuck in a first window, so the
+    # call's rows are still pending when close() gives up on it and
+    # fails them
+    gate, entered = threading.Event(), threading.Event()
+    orig = verifier.recover_addresses
+
+    def stuck(sigs, hashes):
+        entered.set()
+        gate.wait(120)
+        return orig(sigs, hashes)
+
+    verifier.recover_addresses = stuck
+    first = _sign_entries(3, salt=24)
+    got: dict = {}
+    t1 = threading.Thread(
+        target=lambda: got.update(first=sched.recover_signers(first)))
+    t1.start()
+    assert entered.wait(60)
+    t2 = threading.Thread(target=lambda: got.update(
+        second=sched.recover_signers(entries, priority="consensus")))
+    t2.start()
+    deadline = time.monotonic() + 60.0
+    while sched.stats()["pending"] == 0 and time.monotonic() < deadline:
+        time.sleep(0.002)
+    try:
+        sched.close(timeout=0.05)
+        t2.join(60)
+        assert got["second"] == expect
+    finally:
+        gate.set()
+    t1.join(60)
+    assert got["first"] == _host_model(first)
+
+
+@pytest.mark.parametrize("first", ["row", "window"])
+def test_a_row_and_a_window_share_one_computed_row(first):
+    """The same signature from two callers, one through per-row
+    ``submit`` and one through a window entry, whichever comes first:
+    both get the answer, the row is computed once."""
+    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=10_000.0)
+    shared, other = _sign_entries(2, salt=25)
+    want = _host_model([shared, other])
+    if first == "row":
+        fut = sched.submit(*shared)
+        assert sched.recover_signers([shared, other],
+                                     priority="consensus") == want
+        assert fut.result(30) == want[0]
+    else:
+        hashes = np.frombuffer(shared[0] + other[0], np.uint8).reshape(2, 32)
+        sigs = np.frombuffer(shared[1] + other[1], np.uint8).reshape(2, 65)
+        win = sched.submit_window(hashes, sigs)
+        fut = sched.submit(*shared, priority="consensus")
+        sched.kick()
+        assert fut.result(30) == want[0]
+        assert win.result(30) == want
+    st = sched.stats()
+    assert st["coalesced_rows"] == 1 and st["rows"] == 2
+    # the shared row was promoted to the higher of its callers' classes
+    assert [f["klass"] for f in sched.flights()] == ["consensus"]
+    sched.close()
+
+
+def test_rows_and_windows_from_many_threads_lose_no_update():
+    """More threads than cores, half of them through window entries and
+    half through per-row ``submit``, over overlapping rows, with the
+    interpreter switching threads a hundred times as often: every
+    caller gets the host model's answers, and every cache miss either
+    made a computed row or shared one."""
+    import sys
+
+    entries = _mixed_entries(30, salt=27)
+    expect = _host_model(entries)
+    malformed = sum(1 for h, s in entries if len(s) != 65 or len(h) != 32)
+    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=1.0,
+                              max_batch=16, cache_size=8)
+    n_threads, rounds = 12, 6
+    errs: list = []
+    barrier = threading.Barrier(n_threads)
+
+    def worker(k: int) -> None:
+        try:
+            barrier.wait(60)
+            for r in range(rounds):
+                cut = (3 * k + 5 * r) % len(entries)
+                rot = entries[cut:] + entries[:cut]
+                want = expect[cut:] + expect[:cut]
+                if k % 2:
+                    got = sched.recover_signers(
+                        rot, priority="consensus" if k % 4 == 1 else "bulk")
+                else:
+                    futs = [sched.submit(h, s) for h, s in rot]
+                    sched.kick()
+                    got = [f.result(60) for f in futs]
+                assert got == want, (k, r)
+        except BaseException as e:  # surfaced via errs
+            errs.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(5e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errs, errs[:1]
+    sched.close()
+    st = sched.stats()
+    calls = n_threads * rounds
+    assert st["invalid"] == calls * malformed
+    assert st["cache_hits"] + st["cache_misses"] == \
+        calls * (len(entries) - malformed)
+    assert st["cache_misses"] == st["rows"] + st["coalesced_rows"]
+    assert st["window_submits"] == calls // 2
+    assert st["pending"] == 0
+
+
+def test_recover_addresses_takes_the_window_path():
+    """Arrays in, arrays out, through one window entry (block bodies
+    and the EVM precompile come this way)."""
+    entries = _sign_entries(6, salt=26)
+    entries[2] = (b"\x09" * 32, b"\x00" * 65)
+    expect = _host_model(entries)
+    sigs = np.frombuffer(b"".join(s for _h, s in entries),
+                         np.uint8).reshape(6, 65)
+    hashes = np.frombuffer(b"".join(h for h, _s in entries),
+                           np.uint8).reshape(6, 32)
+    sched = VerifierScheduler(NativeBatchVerifier())
+    addrs, ok = sched.recover_addresses(sigs, hashes, priority="consensus")
+    assert [bytes(addrs[i]) if ok[i] else None for i in range(6)] == expect
+    assert not ok[2] and not addrs[2].any()
+    st = sched.stats()
+    assert (st["window_submits_consensus"], st["window_rows_consensus"]) \
+        == (1, 6)
+    e_addrs, e_ok = sched.recover_addresses(sigs[:0], hashes[:0])
+    assert e_addrs.shape == (0, 20) and e_ok.shape == (0,)
+    sched.close()
+
+
 def test_cluster_sim_no_singleton_batches_and_warm_cache():
     """4-node signed cluster over one shared scheduler: the chain
     advances, no steady-state one-row device batch ever happens, the

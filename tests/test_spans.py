@@ -259,6 +259,48 @@ def test_a_burst_and_a_small_call_keep_histograms_of_their_own():
         assert count(*key) == n + 1, key
 
 
+def test_an_ack_burst_is_one_window_under_the_burst_labels():
+    """The 1025-row ACK burst as the benchmark's driver hands it over
+    (``verify_host.recover_signers`` on a scheduler): its two spans
+    keep the names and labels that ``vote_submit_ms.vote`` and
+    ``vote_await_ms.vote`` read, and it flies as one full window and a
+    one-row tail."""
+    from eges_tpu.crypto import secp256k1 as host
+    from eges_tpu.crypto.scheduler import VerifierScheduler
+    from eges_tpu.crypto.verify_host import (
+        NativeBatchVerifier, recover_signers,
+    )
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    def hist(span):
+        return metrics.histogram(f"span.seconds;name={span},"
+                                 "class=consensus,size=burst").count
+
+    before = {sp: hist(sp) for sp in ("sched.submit", "sched.await")}
+    sig = host.ecdsa_sign(b"\x11" * 32, b"\x07" * 32)
+    rows = [((k + 9000).to_bytes(4, "big") * 8, sig) for k in range(1025)]
+    sched = VerifierScheduler(NativeBatchVerifier(), max_batch=1024)
+    try:
+        assert len(recover_signers(rows, sched, priority="consensus")) == 1025
+        flights = sched.flights()
+        st = sched.stats()
+    finally:
+        sched.close()
+    for sp, n in before.items():
+        assert hist(sp) == n + 1, sp
+    mine = [s for s in tracing.DEFAULT.finished()
+            if s["name"] in ("sched.submit", "sched.await")
+            and s["attrs"].get("rows") == 1025]
+    assert [s["name"] for s in mine[-2:]] == ["sched.submit", "sched.await"]
+    for s in mine[-2:]:
+        assert (s["attrs"]["class"], s["attrs"]["size"]) == \
+            ("consensus", "burst")
+    assert [(f["rows"], f["reason"], f["klass"]) for f in flights] == \
+        [(1024, "full", "consensus"), (1, "kick", "consensus")]
+    assert (st["window_submits_consensus"], st["window_rows_consensus"]) \
+        == (1, 1025)
+
+
 # -- the journal ------------------------------------------------------------
 
 def test_resend_and_retry_say_whose_message_was_missing():
