@@ -22,3 +22,12 @@ def bucket_round(n: int, minimum: int = 16) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def lane_chunk_cap(max_batch: int, lanes: int, min_split: int = 1) -> int:
+    """The most rows one mesh lane is handed of a window of at most
+    ``max_batch`` rows: a larger window splits into near-equal chunks on
+    distinct lanes (never into chunks below ``min_split``).  The
+    scheduler places by it and the mesh verifier warms by it, so a lane
+    is never handed a bucket its device has not run."""
+    return max(min_split, -(-max_batch // lanes))

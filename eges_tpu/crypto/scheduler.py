@@ -110,7 +110,7 @@ from dataclasses import dataclass, fields, replace
 
 import numpy as np
 
-from eges_tpu.crypto.bucketing import bucket_round
+from eges_tpu.crypto.bucketing import bucket_round, lane_chunk_cap
 from eges_tpu.utils import ledger, tracing
 
 # sentinel distinguishing "cached None" (a signature that verifiably
@@ -460,8 +460,8 @@ class VerifierScheduler:
         # placement: a window larger than this splits across lanes
         # (floor min_split keeps chunks worth a device dispatch)
         self.min_split = max(1, cfg.min_split)
-        self._chunk_cap = max(self.min_split,
-                              -(-cfg.max_batch // len(self._lanes)))
+        self._chunk_cap = lane_chunk_cap(cfg.max_batch, len(self._lanes),
+                                         self.min_split)
         self._rr = 0  # round-robin cursor breaking equal-load ties
         # LRU recovery cache: (sighash, sig) -> 20-byte address or None
         # (a deterministic recovery failure is cached too — re-gossiped
@@ -1140,7 +1140,8 @@ class VerifierScheduler:
                 # pipeline-capable targets ALSO route through the lane
                 # worker, whose begin/finish split overlaps consecutive
                 # windows (inline dispatch can't — it must block)
-                self._place(batch, reason)
+                with tracing.DEFAULT.span("sched.place", rows=len(batch)):
+                    self._place(batch, reason)
                 continue
             try:
                 # single-lane (or singleton) windows dispatch inline on
@@ -1271,7 +1272,8 @@ class VerifierScheduler:
                 if nxt is not None:
                     if pipelined:
                         with tracing.DEFAULT.span("sched.stage",
-                                                  rows=nxt.rows):
+                                                  rows=nxt.rows,
+                                                  device=lane.index):
                             nxt_p = self._begin_batch(lane, nxt.batch,
                                                       nxt.reason,
                                                       ticket=nxt)
@@ -1406,7 +1408,8 @@ class VerifierScheduler:
         inline composition of the split-phase halves: begin (fill +
         dispatch) then finish (collect + record + resolve) with no
         overlap — the pre-pipeline behavior."""
-        with tracing.DEFAULT.span("sched.stage", rows=len(batch)):
+        with tracing.DEFAULT.span("sched.stage", rows=len(batch),
+                                  device=lane.index):
             p = self._begin_batch(lane, batch, reason, ticket)
         self._finish_batch(lane, p)
 
@@ -1517,7 +1520,8 @@ class VerifierScheduler:
         got = None
         if p.failure is None and p.staged is not None and not p.computed:
             try:
-                with tracing.DEFAULT.span("sched.collect", rows=p.rows):
+                with tracing.DEFAULT.span("sched.collect", rows=p.rows,
+                                          device=lane.index):
                     got = lane.target.collect_recover(p.staged)
             # analysis: allow-swallow(a device exception surfacing at
             # collect diverts exactly this window to the host model and
@@ -1533,7 +1537,8 @@ class VerifierScheduler:
             # flight-recorder stamp: the device's answer is on the host
             # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
             p.t_collect = time.monotonic()
-        with tracing.DEFAULT.span("sched.resolve", rows=p.rows):
+        with tracing.DEFAULT.span("sched.resolve", rows=p.rows,
+                                  device=lane.index):
             self._resolve_batch(lane, p, got)
         if p.failure is not None:
             raise p.failure

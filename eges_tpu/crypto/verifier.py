@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import export as jax_export
 
-from eges_tpu.crypto.bucketing import bucket_round
+from eges_tpu.crypto.bucketing import bucket_round, lane_chunk_cap
 from eges_tpu.ops import bigint, ec, keccak_tpu
 
 
@@ -766,6 +766,29 @@ class _DeviceTarget:
         return (self._parent._aot_execs.get(("recover", b))
                 or self._parent._recover)
 
+    def warm(self, buckets) -> None:
+        """Run each bucket's executable once on THIS device, on zeros.
+        The shared registry is filled from the default device, and a
+        jitted executable is compiled for the device it first runs on:
+        without this a lane's first real window of a bucket pays that
+        compile (about a quarter of a minute on the kernel path) while
+        it serves.  Never raises: a device that cannot run now is the
+        lane breaker's to find."""
+        from eges_tpu.utils.log import get_logger
+
+        for b in buckets:
+            try:
+                zs = jax.device_put(np.zeros((b, 65), np.uint8), self.device)
+                zh = jax.device_put(np.zeros((b, 32), np.uint8), self.device)
+                jax.block_until_ready(self._exec_for(b)(zs, zh))
+            # analysis: allow-swallow(a lane whose device cannot warm
+            # still starts: its first window compiles or trips the
+            # lane's breaker, and the other lanes are warm)
+            except Exception as e:
+                get_logger("geec.aot").warn(
+                    "lane warm failed", device=str(self.device),
+                    bucket=b, err=str(e))
+
     def recover_addresses(self, sigs: np.ndarray, hashes: np.ndarray):
         import time
 
@@ -905,6 +928,35 @@ class MeshBatchVerifier(BatchVerifier):
         """The per-device dispatch facades, in device order — the
         scheduler builds one window lane per entry."""
         return list(self._targets)
+
+    def _aot_prewarm(self, buckets, store, ops) -> dict:
+        """The shared registry as the single-device facade fills it,
+        then every lane's device warm for the buckets a lane can be
+        handed: a caller warms up to its scheduler's ``max_batch``, and
+        of such a window a lane sees at most
+        :func:`~eges_tpu.crypto.bucketing.lane_chunk_cap` rows.  The
+        lanes warm side by side (a compile holds no interpreter lock);
+        ``lane_warm_s`` is what that took."""
+        import time
+
+        info = super()._aot_prewarm(buckets, store, ops)
+        if "recover" in ops and buckets:
+            cap = bucket_round(
+                lane_chunk_cap(max(buckets), len(self._targets)),
+                self._min_bucket)
+            mine = [b for b in buckets if b <= cap]
+            t0 = time.monotonic()
+            warmers = [threading.Thread(
+                target=t.warm, args=(mine,),
+                name=f"verifier-lane-warm-{t.index}", daemon=True)
+                for t in self._targets]
+            for w in warmers:
+                w.start()
+            for w in warmers:
+                w.join()
+            info["lane_buckets"] = mine
+            info["lane_warm_s"] = round(time.monotonic() - t0, 3)
+        return info
 
 
 def require_accelerator(devs) -> str:

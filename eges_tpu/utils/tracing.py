@@ -74,7 +74,12 @@ SPANS = {
                                         "to kick()"),
     "sched.await": (("class", "size"), "from kick() to the last result, "
                                        "as the caller feels it"),
-    "sched.stage": ((), "fill, H2D, dispatch of one window"),
+    "sched.place": ((), "a flushed window onto the device lanes: the "
+                        "split into chunks and the lane choice, under "
+                        "one lock hold (mesh and pipelined targets)"),
+    "sched.stage": ((), "fill, H2D, dispatch of one window; like collect "
+                        "and resolve it names its lane in the attribute "
+                        "``device``, which is no label"),
     "sched.collect": ((), "blocked in collect_recover: the only span "
                           "under which the device should be busy"),
     "sched.resolve": ((), "results to bytes, cache put, the recording, "
@@ -158,7 +163,11 @@ class Span:
         tracer._stack().append(self)
         ann = _annotation()
         if ann is not None:
-            ann = ann(self.name)
+            # whole-number attributes (a window's ``rows``, its lane's
+            # ``device``) ride the annotation as the event's stats; its
+            # name stays the span's
+            ann = ann(self.name, **{k: v for k, v in self.attrs.items()
+                                    if type(v) is int})
             ann.__enter__()
         self._live = (None if self._lone
                       else tracer._current.set(self.context()),

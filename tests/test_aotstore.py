@@ -364,3 +364,63 @@ def test_inline_path_untouched_for_plain_verifier():
     # no split-phase target -> no pipelined windows, determinism intact
     assert st["pipeline_windows"] == 0
     assert st["pipeline_overlap_ratio"] == 0.0
+
+
+# -- mesh: every lane's device warm before it serves ------------------------
+
+def test_mesh_prewarm_leaves_no_compile_to_a_lanes_first_window(tmp_path):
+    """``MeshBatchVerifier.aot_prewarm`` fills the shared registry from
+    the default device and then runs, on every lane's device, each
+    bucket a lane can be handed of a ``max(buckets)``-row window (the
+    scheduler's chunk cap): after it, a lane's first window of such a
+    bucket compiles nothing, where without the lane warm it compiles
+    once a device.  A larger bucket stays device 0's alone."""
+    import jax.monitoring as mon
+
+    from eges_tpu.crypto.scheduler import VerifierScheduler
+    from eges_tpu.crypto.verifier import MeshBatchVerifier
+
+    class ToyMesh(MeshBatchVerifier):
+        def _graph_fns(self):
+            return {"recover": toy_recover, "verify": toy_verify}
+
+    compiles = []
+
+    def on(name, _secs, **_kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(name)
+
+    mon.register_event_duration_secs_listener(on)
+    devs = jax.devices()[:4]
+    assert len(devs) == 4  # tests/conftest.py forces eight host devices
+    mesh = jax.sharding.Mesh(np.array(devs), ("dp",))
+    v = ToyMesh(mesh=mesh, min_bucket=16)
+    sched = VerifierScheduler(v, window_ms=10_000.0, max_batch=64)
+    try:
+        info = v.aot_prewarm(buckets=(16, 32, 64),
+                             store=AotStore(str(tmp_path)))
+        assert info["aot_compiles"] == 3
+        # 64 rows over four lanes: chunks of 16, so of (16, 32, 64) only
+        # the 16 bucket is a lane's; the scheduler computes the same cap
+        assert info["lane_buckets"] == [16]
+        assert sched._chunk_cap == 16
+        sigs, hashes = _rows(16)
+        want = v.device_targets()[0].recover_addresses(sigs, hashes)
+        n0 = len(compiles)
+        for t in v.device_targets():
+            st = t.commit_recover(t.stage_recover(sigs, hashes))
+            assert {d for a in st.out for d in a.devices()} == {t.device}
+            got = t.collect_recover(st)
+            assert np.array_equal(got[0], want[0])
+            assert np.array_equal(got[1], want[1])
+        assert len(compiles) == n0, "a lane's first window compiled"
+        # the control: a bucket no lane was warmed for compiles on the
+        # first lane that is handed it
+        big = _rows(32)
+        t = v.device_targets()[3]
+        t.collect_recover(t.commit_recover(t.stage_recover(*big)))
+        assert len(compiles) > n0
+    finally:
+        sched.close()
+        # jax.monitoring has no public unregister; the listener only
+        # appends to a list nobody reads after this

@@ -121,8 +121,13 @@ def test_straggler_lane_diverts_only_its_own_windows():
     def boom(_rows: int) -> None:
         raise RuntimeError("injected: device lost")
 
+    # the breaker is what this asserts: with the straggler monitor on,
+    # a chunk that a loaded machine keeps past the 25 ms hedge floor is
+    # copied to a sibling lane, and where the copy beats the victim's
+    # host divert the divert is a hedge loser (``hedge_wasted``), which
+    # records nothing: ``straggler_diverts`` then rightly reads 0
     sched = VerifierScheduler(mesh, window_ms=10_000.0, max_batch=64,
-                              min_split=8)
+                              min_split=8, hedge=False)
     sched._lanes[victim].target.failure_hook = boom
 
     # window 1: 64 rows -> 4 chunks, one per lane; the victim's chunk

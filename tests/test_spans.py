@@ -232,6 +232,61 @@ def test_flights_carry_resolve_ms_and_a_run_of_300_windows_drops_none():
     assert "verifier.sched_dispatch" not in names
 
 
+def test_a_mesh_window_is_placed_under_a_span_and_its_lane_is_no_label(
+        tmp_path):
+    """``sched.place`` goes around the split and the lane choice; the
+    lane's three spans name their lane in the attribute ``device`` of the
+    ring entry and of the profiler's event, never in a histogram's name
+    (the metric files read ``span.self_seconds;name=sched.stage``)."""
+    import jax
+
+    from eges_tpu.crypto import secp256k1 as host
+    from eges_tpu.crypto.scheduler import VerifierScheduler
+    from eges_tpu.crypto.verify_host import NativeMeshVerifier
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+    from jax.profiler import ProfileData
+
+    assert tracing.SPANS["sched.place"][0] == ()
+    assert tracing.SPANS["sched.stage"][0] == ()
+    sig = host.ecdsa_sign(b"\x12" * 32, b"\x07" * 32)
+    rows = [(k.to_bytes(4, "big") * 8, sig) for k in range(5000, 5032)]
+    tracing.DEFAULT.clear()
+    places = metrics.histogram("span.seconds;name=sched.place").count
+    jax.devices()  # the backend is up before the session starts
+    sched = VerifierScheduler(NativeMeshVerifier(4), max_batch=32,
+                              min_split=4, hedge=False)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        assert len(sched.recover_signers(rows)) == 32
+    finally:
+        jax.profiler.stop_trace()
+        sched.close()
+    assert sched.stats()["window_splits"] == 1
+    done = tracing.DEFAULT.finished()
+    place = [s for s in done if s["name"] == "sched.place"]
+    assert len(place) == 1 and place[0]["attrs"] == {"rows": 32}
+    assert metrics.histogram(
+        "span.seconds;name=sched.place").count == places + 1
+    for name in ("sched.stage", "sched.resolve"):
+        lanes = sorted(s["attrs"]["device"] for s in done
+                       if s["name"] == name)
+        assert lanes == [0, 1, 2, 3], (name, lanes)
+    assert not [n for n in metrics.snapshot()
+                if n.startswith("span.") and "device" in n]
+    # in the trace the event keeps the span's name; the lane is a stat
+    import glob
+    pb = sorted(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                          recursive=True))[-1]
+    staged = [ev for pl in ProfileData.from_file(pb).planes
+              if pl.name.startswith("/host:CPU")
+              for ln in pl.lines for ev in ln.events
+              if ev.name == "sched.stage"]
+    assert sorted(dict(ev.stats)["device"] for ev in staged) == [0, 1, 2, 3]
+    assert all(dict(ev.stats)["rows"] == 8 for ev in staged)
+
+
 def test_a_burst_and_a_small_call_keep_histograms_of_their_own():
     from eges_tpu.crypto import secp256k1 as host
     from eges_tpu.crypto.scheduler import BURST_ROWS, VerifierScheduler
