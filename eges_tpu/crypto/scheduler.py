@@ -104,7 +104,7 @@ import itertools
 import os
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from concurrent.futures import Future
 from dataclasses import dataclass, fields, replace
 
@@ -146,14 +146,25 @@ class _WindowRows:
         self._fut: Future = Future()
         self._finished = False
 
-    def _slot_set(self, idx: int, value) -> None:
+    def _set_rows(self, idxs, values) -> None:
+        """Write the rows ``idxs`` not yet written and complete the
+        window when they were its last: ONE lock hold however many rows
+        (a resolving batch hands over all of its rows of this window
+        together).  A row is written exactly once: a hedge loser finds
+        every row done and changes nothing."""
         with self._lock:
-            if self._done[idx]:
-                return  # exactly-once per row (hedge losers re-resolve)
-            self._done[idx] = 1
-            self.results[idx] = value
-            self._remaining -= 1
-        self._try_finish()
+            done, results = self._done, self.results
+            n = 0
+            for i, v in zip(idxs, values):
+                if not done[i]:
+                    done[i] = 1
+                    results[i] = v
+                    n += 1
+            self._remaining -= n
+            if self._remaining or self._finished:
+                return
+            self._finished = True
+        self._fut.set_result(self.results)
 
     def prefill(self, idx: int, value) -> None:
         """Construction-time row fill (cache hits, post-close rows) —
@@ -167,11 +178,7 @@ class _WindowRows:
             self._remaining -= 1
 
     def _try_finish(self) -> None:
-        with self._lock:
-            if self._remaining or self._finished:
-                return
-            self._finished = True
-        self._fut.set_result(self.results)
+        self._set_rows((), ())
 
     def result(self, timeout: float | None = None) -> list:
         return self._fut.result(timeout)
@@ -211,6 +218,26 @@ def _array_keys(hashes: np.ndarray, sigs: np.ndarray) -> list:
             for i in range(n)]
 
 
+def _key_arrays(keys: list) -> tuple[np.ndarray, np.ndarray]:
+    """The inverse of :func:`_array_keys`: a window's row keys as
+    ``(hashes (n,32), sigs (n,65))`` uint8 arrays, ONE join and one
+    buffer view a column instead of two row assignments a key.  The
+    views are read-only: a target copies them into its staging buffers."""
+    n = len(keys)
+    hashes = np.frombuffer(b"".join([k[0] for k in keys]), np.uint8)
+    sigs = np.frombuffer(b"".join([k[1] for k in keys]), np.uint8)
+    return hashes.reshape(n, 32), sigs.reshape(n, 65)
+
+
+def _row_results(addrs, ok) -> list:
+    """A target's ``(addrs (n,20) uint8, ok (n,))`` as one result a row
+    (the 20-byte address, ``None`` for an invalid row), cut from ONE
+    copy of each array instead of one numpy row object a result."""
+    ab = np.asarray(addrs, np.uint8).tobytes()
+    return [ab[i * 20:i * 20 + 20] if good else None
+            for i, good in enumerate(np.asarray(ok).tolist())]
+
+
 class _WindowSlot:
     """Future duck-type occupying one row of a :class:`_WindowRows`.
 
@@ -230,10 +257,10 @@ class _WindowSlot:
         return bool(self._win._done[self._idx])
 
     def set_result(self, value) -> None:
-        self._win._slot_set(self._idx, value)
+        self._win._set_rows((self._idx,), (value,))
 
     def set_exception(self, exc: BaseException) -> None:
-        self._win._slot_set(self._idx, exc)
+        self._win._set_rows((self._idx,), (exc,))
 
 
 @dataclass
@@ -511,6 +538,10 @@ class VerifierScheduler:
             "breaker_diverted": 0, "window_splits": 0,
             "straggler_diverts": 0, "pipeline_windows": 0,
             "pipeline_overlapped": 0,
+            # lock holds that answered the recorded windows' holders: one
+            # a _WindowRows of the batch, one a plain future; ``rows``
+            # over this is the rows answered a hold
+            "resolve_holds": 0,
             # hedged re-dispatch accounting: every hedge ends as either
             # a cancelled loser (never ran) or a wasted loser (ran,
             # discarded) — hedges == hedge_cancelled + hedge_wasted at
@@ -1035,10 +1066,18 @@ class VerifierScheduler:
 
     def _cache_put(self, key: tuple, addr) -> None:
         # caller holds self._lock
-        self._cache[key] = addr
-        self._cache.move_to_end(key)
-        while len(self._cache) > self.cache_size:
-            self._cache.popitem(last=False)
+        self._cache_put_many((key,), (addr,))
+
+    def _cache_put_many(self, keys, addrs) -> None:
+        """A window's results into the LRU in row order, the overflow
+        evicted once: what is left is what one put a row leaves.
+        Caller holds ``self._lock``."""
+        cache = self._cache
+        for key, addr in zip(keys, addrs):
+            cache[key] = addr
+            cache.move_to_end(key)
+        while len(cache) > self.cache_size:
+            cache.popitem(last=False)
 
     def _host_recover(self, key: tuple):
         """One host-path recovery (native C++ single recover when built,
@@ -1464,11 +1503,7 @@ class VerifierScheduler:
                     lane.stats["breaker_diverted"] += p.rows
                 p.computed = True
                 return p
-            sigs = np.zeros((p.rows, 65), np.uint8)
-            hashes = np.zeros((p.rows, 32), np.uint8)
-            for i, (h, sig) in enumerate(p.keys):
-                sigs[i] = np.frombuffer(sig, np.uint8)
-                hashes[i] = np.frombuffer(h, np.uint8)
+            hashes, sigs = _key_arrays(p.keys)
             stage = getattr(lane.target, "stage_recover", None)
             try:
                 hook = self.failure_hook
@@ -1487,8 +1522,7 @@ class VerifierScheduler:
                 else:
                     addrs, ok = lane.target.recover_addresses(
                         sigs, hashes)
-                    p.results = [bytes(addrs[i]) if ok[i] else None
-                                 for i in range(p.rows)]
+                    p.results = _row_results(addrs, ok)
                     if p.probing:
                         self._breaker_close(lane)
                     p.computed = True
@@ -1513,10 +1547,10 @@ class VerifierScheduler:
     def _finish_batch(self, lane: _DeviceLane, p: _PendingWindow) -> None:
         """Phase 2 of one window: collect the staged device result (if
         split-phase, span ``sched.collect``), then under ``sched.resolve``
-        turn it into row results, insert into the cache, record
-        stats/metrics/journal, and — always, in the ``finally`` —
-        resolve the window's futures.  Re-raises the window's failure
-        after resolution, matching the old ``_run_batch`` contract."""
+        turn it into row results, insert them into the cache, record
+        the window and, always, answer its holders
+        (:meth:`_resolve_batch`).  Re-raises the window's failure after
+        resolution, matching the old ``_run_batch`` contract."""
         got = None
         if p.failure is None and p.staged is not None and not p.computed:
             try:
@@ -1545,110 +1579,158 @@ class VerifierScheduler:
 
     def _resolve_batch(self, lane: _DeviceLane, p: _PendingWindow,
                        got) -> None:
-        """The tail of :meth:`_finish_batch`: ``got`` is what
+        """The tail of :meth:`_finish_batch`, in steps a WINDOW and one
+        a waiting call, never several a row.  ``got`` is what
         ``collect_recover`` returned, None for a window that was
-        computed (or failed) before."""
-        batch, rows = p.batch, p.rows
-        mesh = len(self._lanes) > 1
+        computed (or failed) before.  In order: the results, cut from
+        one copy; the hedge ticket's claim and the cache inserts, one
+        lock hold (:meth:`_claim`); the recording
+        (:meth:`_record_window`); the holders, one hold per waiting
+        call (:meth:`_answer`); last what only the end of that can
+        know, ``resolve_ms`` and ``resolve_holds``.
+
+        The recording stands in front of the holders, and is a constant
+        number of steps: the journal's events, the devstats deltas and
+        ``stats()`` are protocol content to the deterministic sims,
+        which must find them settled the moment an answer is out; and
+        under one interpreter lock a woken caller runs only when this
+        thread next blocks, whichever came first."""
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+
         try:
             if got is not None:
-                addrs, ok = got
-                p.results = [bytes(addrs[i]) if ok[i] else None
-                             for i in range(rows)]
+                p.results = _row_results(*got)
                 if p.probing:
                     self._breaker_close(lane)
                 p.computed = True
-            if p.failure is None and p.computed:
-                won = True
-                tk = p.ticket
-                if tk is not None:
-                    hedge_won = False
-                    with self._lock:
-                        if tk.winner is None:
-                            # first dispatch to finish claims the window
-                            tk.winner = lane.index
-                            self._tickets.discard(tk)
-                            if tk.hedged and lane.index == tk.hedge_lane:
-                                self._stats["hedge_wins"] += 1
-                                hedge_won = True
-                        else:
-                            # the sibling dispatch won while we computed:
-                            # discard these (bit-identical) results —
-                            # skipping _record_window keeps stats,
-                            # journal, flights and ledger charges
-                            # exactly-once per window
-                            won = False
-                            self._stats["hedge_wasted"] += 1
-                    if hedge_won:
-                        from eges_tpu.utils.metrics import DEFAULT as metrics
-                        metrics.counter("verifier.hedge_wins").inc()
-                    elif not won:
-                        from eges_tpu.utils.metrics import DEFAULT as metrics
-                        metrics.counter("verifier.hedge_wasted").inc()
-                        # a loser window burned a full padded bucket on
-                        # its lane for nothing — bill the waste to the
-                        # device-efficiency ledger at the padded size
-                        from eges_tpu.utils import devstats
-                        pad = getattr(lane.target, "_pad", None) \
-                            or getattr(self._verifier, "_pad", None) \
-                            or bucket_round
-                        devstats.DEFAULT.observe_hedge_waste(
-                            lane.index, p.rows,
-                            pad(p.rows) if p.rows > 1 else 1)
-                if won:
-                    self._record_window(lane, p, mesh)
+            if p.failure is None and p.computed and self._claim(lane, p):
+                self._record_window(lane, p, len(self._lanes) > 1)
         except BaseException as exc:
             if p.failure is None:
                 p.failure = exc
         finally:
-            # futures resolve even if the instrumentation path raises —
-            # a blocked recover_signers caller is a wedged consensus
-            # node.  If the batch died before results were computed,
-            # its futures FAIL with that error rather than masquerading
-            # as None ("invalid signature").  A hedge loser runs this
-            # loop too: the winner resolved everything already, so the
-            # done() guard makes it a no-op (and both dispatches compute
-            # the same batch, so the results are bit-identical anyway).
+            # the holders are answered even if the recording raised: a
+            # blocked recover_signers caller is a wedged consensus node
             p.finished = True
-            for (_, row), r in zip(batch, p.results):
-                for f in row[0]:
-                    if f.done():
-                        continue
-                    if p.computed:
+            holds = self._answer(p)
+        flight = p.flight
+        if flight is None:
+            return  # a hedge loser, or a window that died: not recorded
+        # what a caller waited after the device's answer ends here, at
+        # the last holder set, not at ``t_done``
+        # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
+        resolve_s = time.monotonic() - flight["t_collect"]
+        with self._lock:
+            flight["resolve_ms"] = round(resolve_s * 1e3, 3)
+            self._stats["resolve_holds"] += holds
+        # the window's own stage and resolve beside the caller's
+        # sched.submit / sched.await, under the same two labels: a
+        # 1024-row burst's means are not mixed with a 32-row call's
+        labels = _call_labels(flight["klass"], p.rows)
+        tail = ";class=%s,size=%s" % (labels["class"], labels["size"])
+        metrics.histogram("verifier.window_stage_seconds" + tail) \
+            .observe(flight["stage_ms"] / 1e3)
+        metrics.histogram("verifier.window_resolve_seconds" + tail) \
+            .observe(resolve_s)
+
+    def _claim(self, lane: _DeviceLane, p: _PendingWindow) -> bool:
+        """ONE lock hold between a computed window's results and
+        anything that can wake a caller: the hedge ticket's claim (the
+        first dispatch to finish wins the window) and, for the winner,
+        the cache inserts in row order, so a caller that has its answer
+        and asks again finds it cached.  Returns whether this dispatch
+        won: a loser's (bit-identical) results are discarded and it
+        skips :meth:`_record_window`, which keeps cache, stats, journal,
+        flights and ledger charges exactly-once per window."""
+        tk = p.ticket
+        won, hedge_won = True, False
+        with self._lock:
+            if tk is not None:
+                if tk.winner is None:
+                    tk.winner = lane.index
+                    self._tickets.discard(tk)
+                    if tk.hedged and lane.index == tk.hedge_lane:
+                        self._stats["hedge_wins"] += 1
+                        hedge_won = True
+                else:
+                    won = False  # the sibling won while we computed
+                    self._stats["hedge_wasted"] += 1
+            if won:
+                self._cache_put_many(p.keys, p.results)
+        if hedge_won:
+            from eges_tpu.utils.metrics import DEFAULT as metrics
+            metrics.counter("verifier.hedge_wins").inc()
+        elif not won:
+            from eges_tpu.utils.metrics import DEFAULT as metrics
+            metrics.counter("verifier.hedge_wasted").inc()
+            # a loser window burned a full padded bucket on its lane
+            # for nothing — bill the waste to the device-efficiency
+            # ledger at the padded size
+            from eges_tpu.utils import devstats
+            pad = getattr(lane.target, "_pad", None) \
+                or getattr(self._verifier, "_pad", None) or bucket_round
+            devstats.DEFAULT.observe_hedge_waste(
+                lane.index, p.rows, pad(p.rows) if p.rows > 1 else 1)
+        return won
+
+    def _answer(self, p: _PendingWindow) -> int:
+        """Every holder of the batch gets its row's value exactly once:
+        the slots of one :class:`_WindowRows` together in ONE hold, a
+        plain future (a row that came through :meth:`submit`) by itself,
+        each holder of a dedup-shared row.  Returns the holds taken.  If
+        the batch died before it had results, its holders FAIL with
+        that error rather than masquerading as None ("invalid
+        signature"); a window keeps the error as its rows' value.  A
+        hedge loser comes through here too: the winner has answered
+        everything, so the done guards make it a no-op."""
+        failure = None if p.computed else (p.failure or RuntimeError(
+            "verifier batch dispatch failed"))
+        windows: dict = {}
+        holds = 0
+        for (_, row), r in zip(p.batch, p.results):
+            for f in row[0]:
+                if isinstance(f, _WindowSlot):
+                    rows = windows.get(f._win)
+                    if rows is None:
+                        rows = windows[f._win] = ([], [])
+                    rows[0].append(f._idx)
+                    rows[1].append(r if failure is None else failure)
+                elif not f.done():
+                    holds += 1
+                    if failure is None:
                         f.set_result(r)
                     else:
-                        f.set_exception(p.failure or RuntimeError(
-                            "verifier batch dispatch failed"))
-            if p.flight is not None:
-                # the recording came BEFORE the futures: what a caller
-                # waited after the device's answer ends here, not at
-                # ``t_done``
-                # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
-                resolve_ms = round((time.monotonic() - p.flight[
-                    "t_collect"]) * 1e3, 3)
-                with self._lock:
-                    p.flight["resolve_ms"] = resolve_ms
+                        f.set_exception(failure)
+        for win, (idxs, values) in windows.items():
+            win._set_rows(idxs, values)
+        return holds + len(windows)
 
     def _record_window(self, lane: _DeviceLane, p: _PendingWindow,
                        mesh: bool) -> None:
-        """Cache inserts + stats + metrics + journal for one computed
-        window — the bookkeeping tail shared by the inline and
-        pipelined paths, inside the caller's ``sched.resolve`` span
-        (errors here propagate to ``_resolve_batch``, which still
-        resolves the futures in its ``finally``)."""
+        """Stats + flight + metrics + journal for one computed window —
+        the bookkeeping tail shared by the inline and pipelined paths,
+        inside the caller's ``sched.resolve`` span (errors here
+        propagate to ``_resolve_batch``, which still answers the
+        holders in its ``finally``).  Every plane is written once a
+        WINDOW: the rows that entered together share one submit time
+        and one class, so the queue-wait planes, which count ROWS, take
+        one weighted observation per such group."""
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
         batch, keys, rows = p.batch, p.keys, p.rows
+        # (t_submit, class) -> rows: one pass over the batch, behind
+        # the oldest entry, the window's class and the queue waits
+        groups = Counter([(row[1], row[2]) for _, row in batch])
         # analysis: allow-determinism(batch latency instrumentation; waited_ms is volatile-stripped)
         done = time.monotonic()
         pad = getattr(lane.target, "_pad", None) \
             or getattr(self._verifier, "_pad", None) or bucket_round
         bucket = pad(rows) if rows > 1 else 1  # diverted rows pad nothing
-        oldest = min(row[1] for _, row in batch)
+        oldest = min(t for t, _ in groups)
         waited = p.t0 - oldest
         tk = p.ticket
-        klass = ("consensus" if any(row[2] == "consensus"
-                                    for _, row in batch) else "bulk")
+        klass = ("consensus" if any(k == "consensus"
+                                    for _, k in groups) else "bulk")
         # one flight-recorder entry per computed window: lifecycle phase
         # boundaries + lane attribution (the thw_flight RPC surface)
         t_dispatch = p.t_dispatch if p.t_dispatch is not None else done
@@ -1664,8 +1746,8 @@ class VerifierScheduler:
             "wait_ms": round(waited * 1e3, 3),
             "stage_ms": round((t_dispatch - p.t0) * 1e3, 3),
             "compute_ms": round((t_collect - t_dispatch) * 1e3, 3),
-            # results to bytes and the ticket claim so far; once the
-            # recording below and the futures are through,
+            # results, the ticket claim and the cache inserts so far;
+            # once the recording below and the holders are through,
             # _resolve_batch puts the whole of it here
             "resolve_ms": round((done - t_collect) * 1e3, 3),
             "total_ms": round((done - oldest) * 1e3, 3),
@@ -1680,22 +1762,24 @@ class VerifierScheduler:
             # blk/trace linkage: distinct submitter trace ids riding this
             # window (txpool ingest spans, quorum verifies) — popped here
             # so the map never outlives its window
+            # (a map that holds nothing, as when no submitter has a
+            # trace or a ledger bound, is not probed a row)
             traces = sorted({t for t in (self._pending_trace.pop(k, None)
-                                         for k in keys) if t})
+                                         for k in keys) if t}) \
+                if self._pending_trace else []
             # ingress provenance: rows per captured (ledger, origin) —
             # tallied under the lock, charged after release (the ledger
             # emits metrics; fail-under-lock hygiene)
             origin_rows: dict[tuple, int] = {}
-            for k in keys:
-                rec = self._pending_origin.pop(k, None)
-                if rec is not None:
-                    origin_rows[rec] = origin_rows.get(rec, 0) + 1
+            if self._pending_origin:
+                for k in keys:
+                    rec = self._pending_origin.pop(k, None)
+                    if rec is not None:
+                        origin_rows[rec] = origin_rows.get(rec, 0) + 1
             cache_rows = self._cache_rows_pending
             self._cache_rows_pending = 0
             dedup_rows = self._dedup_rows_pending
             self._dedup_rows_pending = 0
-            for k, r in zip(keys, p.results):
-                self._cache_put(k, r)
             self._stats["batches"] += 1
             self._stats["rows"] += rows
             self._stats["bucket_rows"] += bucket
@@ -1728,9 +1812,9 @@ class VerifierScheduler:
             self._flights.append(flight)
             p.flight = flight
             # per-class queue-wait samples behind stats()'s percentiles
-            for _k, row in batch:
-                self._class_waits[row[2]].append(
-                    (p.t0 - row[1]) * 1e3)
+            for (t_submit, k), n in groups.items():
+                self._class_waits[k].extend(
+                    itertools.repeat((p.t0 - t_submit) * 1e3, n))
         # per-origin window cost: each captured origin gets its row
         # count plus its row-share of the window's wall-clock interior,
         # booked as host-ms when the rows were host-served (singleton
@@ -1746,15 +1830,15 @@ class VerifierScheduler:
         metrics.counter("verifier.flight_windows").inc()
         if flight_evicts:
             metrics.counter("verifier.flight_dropped").inc()
-        for _, row in batch:
-            w = p.t0 - row[1]
-            metrics.histogram("verifier.sched_queue_wait_seconds") \
-                .observe(w)
-            # per-class queue-wait: the priority-preemption deliverable
-            # is visible as a class-labeled histogram split
-            metrics.histogram(
-                "verifier.sched_queue_wait_seconds;class=%s"
-                % row[2]).observe(w)
+        # per-class queue-wait: the priority-preemption deliverable is
+        # visible as a class-labeled histogram split
+        wait_all = metrics.histogram("verifier.sched_queue_wait_seconds")
+        wait_of = {k: metrics.histogram(
+            "verifier.sched_queue_wait_seconds;class=%s" % k)
+            for k in {k for _, k in groups}}
+        for (t_submit, k), n in groups.items():
+            wait_all.observe(p.t0 - t_submit, n)
+            wait_of[k].observe(p.t0 - t_submit, n)
         metrics.histogram("verifier.sched_batch_rows").observe(rows)
         metrics.histogram("verifier.sched_occupancy") \
             .observe(rows / bucket)

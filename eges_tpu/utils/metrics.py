@@ -16,6 +16,7 @@ that back into real labels; ``snapshot()`` keeps the flat names.
 
 from __future__ import annotations
 
+import math
 import random
 import re
 import threading
@@ -395,19 +396,35 @@ class Histogram:
         self.min = float("inf")
         self.max = 0.0
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, n: int = 1) -> None:
+        """``n`` observations of one value (a window's rows that waited
+        the same time) in one lock hold: ``count``, ``total``, ``min``,
+        ``max`` and the mean are what ``n`` calls give.  The reservoir
+        takes those there is room for; the rest overwrite as many
+        random slots as Algorithm R accepts of them in expectation (the
+        sum of ``RESERVOIR / count`` over their counts)."""
+        if n < 1:
+            return
         v = float(value)
         with self._lock:
-            self.count += 1
-            self.total += v
+            self.count += n
+            self.total += v * n
             self.min = min(self.min, v)
             self.max = max(self.max, v)
-            if len(self._sample) < self.RESERVOIR:
-                self._sample.append(v)
-            else:
+            room = min(n, self.RESERVOIR - len(self._sample))
+            if room > 0:
+                self._sample.extend([v] * room)
+                n -= room
+            if n == 1:
                 j = self._rng.randrange(self.count)
                 if j < self.RESERVOIR:
                     self._sample[j] = v
+            elif n > 1:
+                keep = self.RESERVOIR * math.log(
+                    self.count / (self.count - n))
+                for _ in range(int(keep)
+                               + (self._rng.random() < keep % 1.0)):
+                    self._sample[self._rng.randrange(self.RESERVOIR)] = v
 
     def percentile(self, q: float) -> float:
         with self._lock:

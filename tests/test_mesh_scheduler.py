@@ -74,6 +74,38 @@ def test_saturated_window_reaches_every_device():
     sched.close()
 
 
+def test_a_split_window_answers_each_call_once_a_chunk():
+    """Two window entries fill one 32-row window that splits into four
+    chunks on four lanes; the boundary between the two calls falls
+    inside the third chunk.  A chunk takes one hold for each call it
+    has rows of: five in all, for 32 rows."""
+    sched = VerifierScheduler(NativeMeshVerifier(4), window_ms=10_000.0,
+                              max_batch=32, min_split=4, hedge=False)
+    entries = _sign_entries(32, salt=14)
+    expect = _host_model(entries)
+
+    def arrays(part):
+        return (np.frombuffer(b"".join(h for h, _ in part),
+                              np.uint8).reshape(-1, 32),
+                np.frombuffer(b"".join(s for _, s in part),
+                              np.uint8).reshape(-1, 65))
+
+    win_a = sched.submit_window(*arrays(entries[:20]))
+    win_b = sched.submit_window(*arrays(entries[20:]),
+                                priority="consensus")  # fills the bucket
+    assert win_a.result(60) + win_b.result(60) == expect
+    sched.close()
+    st = sched.stats()
+    assert st["window_splits"] == 1 and st["batches"] == 4
+    assert [d["rows"] for d in st["devices"]] == [8, 8, 8, 8]
+    assert st["resolve_holds"] == 5
+    assert all(f["resolve_ms"] > 0 for f in sched.flights())
+    assert sorted(f["klass"] for f in sched.flights()) == \
+        ["bulk", "bulk", "consensus", "consensus"]
+    waits = st["class_wait_ms"]
+    assert (waits["bulk"]["count"], waits["consensus"]["count"]) == (20, 12)
+
+
 def test_concurrent_mesh_submitters_bit_identical():
     """8 caller threads over a 4-lane mesh: every caller gets exactly
     the host model's answers, lane row counts account for every

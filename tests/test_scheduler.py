@@ -419,6 +419,212 @@ def test_recover_addresses_takes_the_window_path():
     sched.close()
 
 
+def _arrays(entries):
+    hashes = np.frombuffer(b"".join(h for h, _s in entries),
+                           np.uint8).reshape(len(entries), 32)
+    sigs = np.frombuffer(b"".join(s for _h, s in entries),
+                         np.uint8).reshape(len(entries), 65)
+    return hashes, sigs
+
+
+class _Dead(BaseException):
+    """A window's death that is no device error: nothing diverts it."""
+
+
+@pytest.mark.parametrize("how", ["computed", "diverted", "died"])
+def test_a_mixed_batch_answers_every_holder_exactly_once(how):
+    """One batch whose rows belong to two windows, to plain ``submit``
+    futures, to a row three callers share, to an invalid signature and
+    to two holders that a hedge's winner had answered before: every
+    holder gets, once, what setting it row by row gave; a window takes
+    one hold for all of its rows.  A batch that died hands its error to
+    the holders and records nothing."""
+    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=10_000.0)
+    e = _sign_entries(7, salt=30)
+    bad = (b"\x07" * 32, b"\x00" * 65)
+    m = _host_model(e)
+    win_a = sched.submit_window(*_arrays([e[0], e[1], e[2], bad]))
+    win_b = sched.submit_window(*_arrays([e[3], e[1], e[4]]),
+                                priority="consensus")
+    futs = [sched.submit(*e[5]), sched.submit(*e[1]), sched.submit(*e[6])]
+    # the hedge's winner was here first (its value would be the same
+    # bit for bit; another one shows that nothing is written twice)
+    won = b"\x5a" * 20
+    win_a._set_rows((2,), (won,))
+    futs[2].set_result(won)
+    with sched._lock:
+        batch = [(k, sched._pending.pop(k)) for k in list(sched._pending)]
+    assert len(batch) == 8 and sched.stats()["coalesced_rows"] == 2
+
+    def hook(rows):
+        raise (_Dead if how == "died" else RuntimeError)("lost")
+
+    if how != "computed":
+        sched.failure_hook = hook
+    if how == "died":
+        with pytest.raises(_Dead):
+            sched._run_batch(sched._lanes[0], batch, "kick")
+        dead = [isinstance(v, _Dead) for v in win_a.result(0)]
+        assert dead == [True, True, False, True]
+        assert all(isinstance(v, _Dead) for v in win_b.result(0))
+        for f in futs[:2]:
+            with pytest.raises(_Dead):
+                f.result(0)
+        st = sched.stats()
+        assert st["batches"] == st["rows"] == st["resolve_holds"] == 0
+        assert st["cached_entries"] == 0 and sched.flights() == []
+    else:
+        sched._run_batch(sched._lanes[0], batch, "kick")
+        assert win_a.result(0) == [m[0], m[1], won, None]
+        assert win_b.result(0) == [m[3], m[1], m[4]]
+        assert [f.result(0) for f in futs[:2]] == [m[5], m[1]]
+        st = sched.stats()
+        # two windows and the two plain futures that were still open
+        assert st["resolve_holds"] == 4
+        assert (st["batches"], st["rows"]) == (1, 8)
+        assert st["device_errors"] == (how == "diverted")
+        flight = sched.flights()[-1]
+        assert flight["rows"] == 8 and flight["klass"] == "consensus"
+        assert flight["diverted"] == (how == "diverted")
+        assert flight["resolve_ms"] > 0
+        assert st["class_wait_ms"]["consensus"]["count"] == 3
+        assert st["class_wait_ms"]["bulk"]["count"] == 5
+        # the cache holds what the batch computed, not the early value
+        assert sched.submit(*e[2]).result(0) == m[2]
+        assert sched.submit(*bad).result(0) is None
+    assert futs[2].result(0) == won
+    assert win_a._remaining == win_b._remaining == 0
+    sched.close()
+
+
+def _targets(kind: str):
+    from eges_tpu.crypto.verify_host import (
+        NativeMeshVerifier, PipelinedNativeVerifier,
+    )
+    return {"inline": NativeBatchVerifier,
+            "pipelined": PipelinedNativeVerifier,
+            "mesh": lambda: NativeMeshVerifier(4)}[kind]()
+
+
+@pytest.mark.parametrize("target", ["inline", "pipelined", "mesh"])
+def test_a_woken_caller_finds_its_rows_cached_and_its_windows_recorded(
+        target):
+    """The moment a synchronous call returns, with no wait: every one of
+    its keys is answered by the cache, and ``stats()`` and the flight
+    ring hold its windows (the recording stands in front of the
+    holders); on the mesh the call was three chunks on three lanes."""
+    sched = VerifierScheduler(_targets(target), window_ms=10_000.0,
+                              max_batch=32, min_split=4, hedge=False)
+    entries = _sign_entries(24, salt=31)
+    want = _host_model(entries)
+    assert sched.recover_signers(entries, priority="consensus") == want
+    st = sched.stats()
+    assert st["rows"] == 24 and st["cache_hits"] == 0
+    assert st["batches"] == (3 if target == "mesh" else 1)
+    assert sum(f["rows"] for f in sched.flights()) == 24
+    again = [sched.submit(h, s) for h, s in entries]
+    assert all(f.done() for f in again)
+    assert [f.result(0) for f in again] == want
+    assert sched.stats()["cache_hits"] == 24
+    sched.close()
+    st = sched.stats()
+    # one window of the caller's in every device window
+    assert st["resolve_holds"] == st["batches"] == len(sched.flights())
+    assert all(f["resolve_ms"] > 0 for f in sched.flights())
+
+
+@pytest.mark.parametrize("target", ["inline", "pipelined"])
+def test_a_recording_that_raises_costs_no_caller_its_answer(target,
+                                                            monkeypatch):
+    """The registry raises inside ``_record_window``: the window's
+    callers have their answers all the same, ``_finish_batch`` raises
+    the error when they are answered, and the lane (or the dispatch
+    thread) serves the next window."""
+    from eges_tpu.utils.metrics import DEFAULT as registry
+
+    sched = VerifierScheduler(_targets(target), window_ms=10_000.0)
+    real, left = registry.histogram, [1]
+
+    def histogram(name):
+        if name == "verifier.sched_batch_rows" and left[0]:
+            left[0] -= 1
+            raise RuntimeError("registry down")
+        return real(name)
+
+    monkeypatch.setattr(registry, "histogram", histogram)
+    finish, raised = sched._finish_batch, []
+
+    def spy(lane, p):
+        try:
+            finish(lane, p)
+        except BaseException as exc:
+            raised.append(str(exc))
+            raise
+
+    sched._finish_batch = spy
+    first, second = _sign_entries(6, salt=32), _sign_entries(5, salt=33)
+    assert sched.recover_signers(first) == _host_model(first)
+    assert sched.recover_signers(second) == _host_model(second)
+    assert all(sched.submit(h, s).done() for h, s in first + second)
+    sched.close()
+    assert raised == ["registry down"] and left == [0]
+    assert [f["rows"] for f in sched.flights()] == [6, 5]
+
+
+class _AnswersAtOnce:
+    """A pipelined target with nothing to compute: address 01 00.. for
+    every row but each 64th, which it calls invalid."""
+
+    def stage_recover(self, sigs, hashes):
+        return len(sigs)
+
+    def commit_recover(self, staged):
+        return staged
+
+    def collect_recover(self, n):
+        addrs = np.zeros((n, 20), np.uint8)
+        addrs[:, 0] = 1
+        return addrs, np.arange(n) % 64 != 0
+
+
+def test_a_burst_leaves_the_lane_in_steps_a_window_not_a_row():
+    """A 1025-row consensus call on a pipelined lane is one 1024-row
+    window and a one-row tail: one hold answers each, the window's
+    flight has its ``resolve_ms``, the queue-wait histograms still count
+    rows, and the burst's stage and resolve have histograms of their
+    own."""
+    from eges_tpu.utils.metrics import DEFAULT as registry
+
+    def counts():
+        snap = registry.snapshot()
+        return {k: snap.get(k, {"count": 0})["count"] for k in (
+            "verifier.sched_queue_wait_seconds",
+            "verifier.sched_queue_wait_seconds;class=consensus",
+            "verifier.window_stage_seconds;class=consensus,size=burst",
+            "verifier.window_resolve_seconds;class=consensus,size=burst",
+            "verifier.window_resolve_seconds;class=consensus,size=call")}
+
+    sched = VerifierScheduler(_AnswersAtOnce(), window_ms=10_000.0,
+                              max_batch=1024)
+    entries = [(i.to_bytes(4, "big") * 8, bytes([i % 250 + 1]) * 65)
+               for i in range(1025)]
+    before = counts()
+    out = sched.recover_signers(entries, priority="consensus")
+    sched.close()
+    assert out[:1024] == [None if i % 64 == 0 else b"\x01" + b"\x00" * 19
+                          for i in range(1024)]
+    # (the one-row tail is recovered on the dispatch thread, beside
+    # the lane: which of the two is recorded first is not fixed)
+    tail, burst = sorted(sched.flights(), key=lambda f: f["rows"])
+    assert (tail["rows"], tail["reason"]) == (1, "kick")
+    assert (burst["rows"], burst["reason"]) == (1024, "full")
+    assert burst["pipelined"] and burst["resolve_ms"] > 0
+    st = sched.stats()
+    assert st["rows"] == 1025 and st["resolve_holds"] == 2
+    grown = {k: v - before[k] for k, v in counts().items()}
+    assert list(grown.values()) == [1025, 1025, 1, 1, 1]
+
+
 def test_cluster_sim_no_singleton_batches_and_warm_cache():
     """4-node signed cluster over one shared scheduler: the chain
     advances, no steady-state one-row device batch ever happens, the

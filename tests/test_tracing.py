@@ -120,6 +120,34 @@ def test_histogram_percentiles_match_numpy():
     assert h.min == pytest.approx(float(vals.min()))
 
 
+@pytest.mark.parametrize("n", [1, 7, 300, 5000])
+def test_histogram_weighted_observation_equals_n_observations(n):
+    """``observe(v, n)`` is ``n`` observations of ``v``: count, total,
+    extremes and mean are theirs, and below the reservoir's size so is
+    every percentile; beyond it the reservoir keeps about the share of
+    them that one draw an observation keeps."""
+    one, many = Histogram(), Histogram()
+    for v in (0.25, 2.0, 0.5):
+        one.observe(v, n)
+        for _ in range(n):
+            many.observe(v)
+    assert (one.count, one.min, one.max) == (many.count, many.min,
+                                             many.max) == (3 * n, 0.25, 2.0)
+    assert one.total == pytest.approx(many.total, rel=1e-12)
+    assert one.mean == pytest.approx(many.mean, rel=1e-12)
+    if 3 * n <= Histogram.RESERVOIR:
+        assert sorted(one._sample) == sorted(many._sample)
+        assert one.percentiles((1, 50, 95, 99)) == \
+            many.percentiles((1, 50, 95, 99))
+    else:
+        assert len(one._sample) == Histogram.RESERVOIR
+        for v in (0.25, 2.0, 0.5):
+            assert one._sample.count(v) == pytest.approx(
+                Histogram.RESERVOIR / 3, rel=0.2)
+    one.observe(9.0, 0)
+    assert one.count == 3 * n and one.max == 2.0
+
+
 def test_histogram_reservoir_is_bounded():
     h = Histogram()
     for i in range(5 * Histogram.RESERVOIR):
