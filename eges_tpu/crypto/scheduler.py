@@ -7,13 +7,13 @@ plus transfer cost.  This layer sits between those callers and the
 device facade (:class:`~eges_tpu.crypto.verifier.BatchVerifier` or the
 JAX-free :class:`~eges_tpu.crypto.verify_host.NativeBatchVerifier`):
 
-* rows enter one of two ways.  A WINDOW of rows — every synchronous
-  facade (``recover_signers``, ``recover_addresses``,
-  ``recover_window``) and the pool's ``submit_window`` — enters through
-  the one routine ``_enter_window``: one lock hold, one batched cache
-  probe and in-flight dedup sweep, one result holder, one wake-up, so
-  the dispatcher never sees half a quorum.  A genuinely asynchronous
-  SINGLE row is :meth:`submit`, which returns a future;
+* rows enter ONE way, as a WINDOW: every synchronous facade
+  (``recover_signers``, ``recover_addresses``, ``recover_window``), the
+  pool's ``submit_window`` and the single-row :meth:`submit` (a one-row
+  window behind a future) go through the one routine ``_enter_window``:
+  one lock hold, one batched cache probe and in-flight dedup sweep, one
+  result holder, one wake-up, so the dispatcher never sees half a
+  quorum;
 * a background dispatch thread coalesces concurrent requests across
   callers (txpool sender recovery + vote quorums + single-message
   checks) into ONE batch per micro-window — flushed when the bucket
@@ -46,7 +46,7 @@ lane* per device instead of calling the verifier inline:
   one breaker, that lane's windows host-divert, every other lane keeps
   the device path (per-lane ``straggler_diverts`` counts the rescue);
 * completion is per chunk — each chunk resolves (or fails) its own
-  futures independently, reusing the fail-safe resolution, so one
+  rows independently, reusing the fail-safe resolution, so one
   device's death diverts exactly its own in-flight windows.
 
 With one visible device the lane machinery collapses to the PR 4/5
@@ -65,27 +65,22 @@ the overlap actually happened.  Native verifiers don't expose the trio,
 so sims and the chaos harness keep the inline path and its
 byte-deterministic event ordering.
 
-**SLO-driven adaptive scheduling.** Every real-time knob lives in
-:class:`SchedulerConfig` (env-overridable as ``EGES_SCHED_*``).  With
-``adaptive=True`` a closed-loop controller runs one step per recorded
-window: it reads the flight recorder's recent wait/stage/compute
-timings plus the SLO engine's commit-latency burn rate (injectable
-:attr:`VerifierScheduler.burn_probe`) and steers the flush deadline and
-target bucket — large occupancy-biased windows while the burn is calm,
-small deadline-biased windows while the p99 objective is burning.
-Decisions journal as ``sched_adapt``.  Windows carry a priority class:
-``"consensus"`` submissions (election acks, QC checks) flush ahead of
-``"bulk"`` tx-ingest rows and their windows preempt bulk windows at
-lane placement, with per-class queue-wait metrics.  In mesh mode a
-straggler monitor hedges: a window whose wall-clock age exceeds its
-lane's flight-derived threshold (median × ``hedge_factor``) is
-speculatively re-placed on the least-loaded sibling lane; the first
-result wins, the loser is cancelled (or its results discarded), and
-stats/journal/ledger all record the window exactly once.
+**Priority classes and hedging.** The window policy is static: the
+keyword arguments of :class:`VerifierScheduler` are the only way to set
+it (no environment variable is read, nothing retunes it at run time).
+Windows carry a priority class: ``"consensus"`` submissions (election
+acks, QC checks) flush ahead of ``"bulk"`` tx-ingest rows and their
+windows preempt bulk windows at lane placement, with per-class
+queue-wait metrics.  In mesh mode a straggler monitor hedges: a window
+whose wall-clock age exceeds its lane's flight-derived threshold
+(median × ``HEDGE_FACTOR``) is speculatively re-placed on the
+least-loaded sibling lane; the first result wins, the loser is
+cancelled (or its results discarded), and stats/journal/ledger all
+record the window exactly once.
 
 This module must stay importable WITHOUT JAX (same contract as
-``verify_host.py``): the bench parent and host-fallback node processes
-construct schedulers around native verifiers.
+``verify_host.py``): host-fallback node processes and the benchmark's
+parent construct schedulers around native verifiers.
 
 Thread model: ``submit``/``submit_window``/the synchronous facades/
 ``kick``/``close`` arrive on any caller thread
@@ -101,12 +96,10 @@ node/txpool lock domain.
 from __future__ import annotations
 
 import itertools
-import os
 import threading
 import time
 from collections import Counter, OrderedDict, deque
 from concurrent.futures import Future
-from dataclasses import dataclass, fields, replace
 
 import numpy as np
 
@@ -128,9 +121,9 @@ class _WindowRows:
     these instead of N per-row futures, so a 16k-row ingest window
     costs one wait-side object and one wakeup.
 
-    Each row is occupied by a :class:`_WindowSlot` riding the normal
-    pending map; the backing future resolves with the full ``results``
-    list once every row has resolved.  Row failures are stored as
+    Each row that has to be computed rides the pending map as a
+    ``(window, index)`` pair; the backing future resolves with the full
+    ``results`` list once every row has resolved.  Row failures are stored as
     exception VALUES (never raised here) so one dead row cannot poison
     its window — callers decide per row (``recover_window`` host-
     diverts them, mirroring ``recover_signers``)."""
@@ -168,9 +161,9 @@ class _WindowRows:
 
     def prefill(self, idx: int, value) -> None:
         """Construction-time row fill (cache hits, post-close rows) —
-        called before any slot of this window is visible to the lanes,
+        called before any row of this window is visible to the lanes,
         so the row lock is uncontended; taken anyway to keep every
-        write to the shared slots under the same lock.  The window
+        write to the shared rows under the same lock.  The window
         future completes later via :meth:`_try_finish`."""
         with self._lock:
             self._done[idx] = 1
@@ -195,6 +188,10 @@ def _class_of(priority: str) -> str:
 # (an election's votes, a header, a pool slice): their means are not to
 # be mixed.
 BURST_ROWS = 1000
+
+# A lane's window is a straggler, and is hedged onto a sibling lane, once
+# its age exceeds this many medians of the lane's recent window totals.
+HEDGE_FACTOR = 3.0
 
 
 def _call_labels(priority: str, rows: int) -> dict:
@@ -236,97 +233,6 @@ def _row_results(addrs, ok) -> list:
     ab = np.asarray(addrs, np.uint8).tobytes()
     return [ab[i * 20:i * 20 + 20] if good else None
             for i, good in enumerate(np.asarray(ok).tolist())]
-
-
-class _WindowSlot:
-    """Future duck-type occupying one row of a :class:`_WindowRows`.
-
-    Exposes exactly the surface the scheduler's resolution paths use on
-    a real ``Future`` — ``done()`` / ``set_result`` / ``set_exception``
-    — so window rows ride the pending map, dedup, lane dispatch, hedge
-    and close() drains unchanged.  Exceptions become stored row values
-    (see ``_WindowRows``)."""
-
-    __slots__ = ("_win", "_idx")
-
-    def __init__(self, win: _WindowRows, idx: int):
-        self._win = win
-        self._idx = idx
-
-    def done(self) -> bool:
-        return bool(self._win._done[self._idx])
-
-    def set_result(self, value) -> None:
-        self._win._set_rows((self._idx,), (value,))
-
-    def set_exception(self, exc: BaseException) -> None:
-        self._win._set_rows((self._idx,), (exc,))
-
-
-@dataclass
-class SchedulerConfig:
-    """Every real-time knob of the scheduler in one bundle.
-
-    The scattered constructor kwargs (flush deadline, bucket cap, cache
-    size, breaker cooldown, mesh split floor) plus the adaptive
-    controller gains and hedging thresholds live here so bench runs and
-    tests can sweep them without monkeypatching scheduler internals.
-    Any field can be overridden from the environment as
-    ``EGES_SCHED_<FIELD>`` (upper-cased field name) — e.g.
-    ``EGES_SCHED_WINDOW_MS=0.5`` or ``EGES_SCHED_ADAPTIVE=1`` — read
-    once per :meth:`from_env` call (which is what the scheduler
-    constructor uses when no explicit config is passed).
-    """
-
-    # -- static window policy (the pre-adaptive scheduler surface) --
-    window_ms: float = 2.0        # flush deadline from the oldest entry
-    max_batch: int = 1024         # hard bucket cap per window
-    cache_size: int = 4096        # LRU recovery-cache entries
-    breaker_cooldown_s: float = 5.0  # per-lane breaker open time
-    min_split: int = 16           # smallest mesh chunk worth a dispatch
-    flight_ring: int = 4096       # flight-recorder ring capacity (the
-    #                               most thw_flight hands out at once)
-    # -- adaptive windowing (closed-loop controller) --
-    adaptive: bool = False        # enable the per-window controller
-    slo_p99_ms: float = 50.0      # declared p99 window objective for the
-    #                               derived burn (no SLO probe attached)
-    min_window_ms: float = 0.25   # deadline floor when shrinking
-    max_window_ms: float = 8.0    # deadline ceiling when growing
-    min_target_rows: int = 32     # bucket floor when shrinking
-    shrink_gain: float = 0.5      # deadline multiplier while burning
-    grow_gain: float = 1.5        # deadline multiplier while calm
-    burn_shrink: float = 1.0      # burn >= this -> latency-bias
-    burn_relax: float = 0.5       # burn <= this -> occupancy-bias
-    adapt_every: int = 1          # controller period, recorded windows
-    adapt_recent: int = 32        # flight entries per decision
-    # -- hedged re-dispatch (mesh straggler speculation) --
-    hedge: bool = True            # speculative straggler re-placement
-    hedge_factor: float = 3.0     # straggler = age > lane median x this
-    hedge_min_windows: int = 4    # lane flights before its own median
-    #                               outranks the all-lane median
-    hedge_floor_ms: float = 25.0  # never hedge a window younger than this
-    hedge_poll_ms: float = 5.0    # straggler monitor poll period
-
-    @classmethod
-    def from_env(cls, env=None) -> "SchedulerConfig":
-        """A config built from defaults plus ``EGES_SCHED_*`` overrides
-        (field types are inferred from the defaults; booleans accept
-        1/true/yes/on).  A malformed value raises — a bad sweep knob
-        must fail loudly, not silently run the defaults."""
-        env = os.environ if env is None else env
-        kw = {}
-        for f in fields(cls):
-            raw = env.get("EGES_SCHED_" + f.name.upper())
-            if raw is None:
-                continue
-            if isinstance(f.default, bool):
-                kw[f.name] = raw.strip().lower() in ("1", "true",
-                                                     "yes", "on")
-            elif isinstance(f.default, int):
-                kw[f.name] = int(raw)
-            else:
-                kw[f.name] = float(raw)
-        return cls(**kw)
 
 
 class _DeviceLane:
@@ -425,29 +331,37 @@ class VerifierScheduler:
     / ``recover_signers`` / ``ecrecover`` / ``verify`` all exist, so the
     chain, txpool, EVM precompile, and consensus node can hold a
     scheduler wherever they previously held a ``BatchVerifier``.
+
+    The keyword arguments are the whole policy surface, and the only
+    way to set it: no config object, no environment variable (a stray
+    one in an operator's shell must not change a validator's window
+    policy), nothing retuned at run time.
+
+    * ``window_ms`` — flush deadline from the oldest pending entry;
+    * ``max_batch`` — hard bucket cap per window;
+    * ``cache_size`` — LRU recovery-cache entries;
+    * ``breaker_cooldown_s`` — per-lane breaker open time;
+    * ``min_split`` — smallest mesh chunk worth a dispatch;
+    * ``flight_ring`` — flight-recorder ring capacity (the most
+      ``thw_flight`` hands out at once);
+    * ``hedge`` — speculative straggler re-placement (mesh only);
+    * ``hedge_min_windows`` — lane flights before its own median
+      outranks the all-lane median;
+    * ``hedge_floor_ms`` — never hedge a window younger than this;
+    * ``hedge_poll_ms`` — straggler monitor poll period;
+    * ``breaker_clock`` — injectable clock of the breaker's cooldown.
     """
 
-    def __init__(self, verifier, *, config: SchedulerConfig | None = None,
-                 breaker_clock=None, **overrides):
-        # config consolidation: explicit kwargs (the historical
-        # ``window_ms=``/``max_batch=``/... surface every call site
-        # already uses) override a copy of the passed config, which
-        # itself defaults to SchedulerConfig.from_env() — so env sweeps,
-        # config objects and legacy kwargs compose without ambiguity
-        cfg = config if config is not None else SchedulerConfig.from_env()
-        if overrides:
-            cfg = replace(cfg, **overrides)
-        self.config = cfg
+    def __init__(self, verifier, *, window_ms: float = 2.0,
+                 max_batch: int = 1024, cache_size: int = 4096,
+                 breaker_cooldown_s: float = 5.0, min_split: int = 16,
+                 flight_ring: int = 4096, hedge: bool = True,
+                 hedge_min_windows: int = 4, hedge_floor_ms: float = 25.0,
+                 hedge_poll_ms: float = 5.0, breaker_clock=None):
         self._verifier = verifier
-        window_ms = cfg.window_ms
-        if cfg.adaptive:
-            # the controller moves the deadline inside
-            # [min_window_ms, max_window_ms]; start inside the band
-            window_ms = min(max(window_ms, cfg.min_window_ms),
-                            cfg.max_window_ms)
-        self._window_s = window_ms / 1e3  # guarded-by: _lock
-        self.max_batch = cfg.max_batch
-        self.cache_size = cfg.cache_size
+        self._window_s = window_ms / 1e3
+        self.max_batch = max_batch
+        self.cache_size = cache_size
         # injectable device-failure hook (chaos harness / tests): called
         # with the row count right before every device dispatch, on any
         # lane; raising is treated exactly like the device itself
@@ -461,7 +375,7 @@ class VerifierScheduler:
         # lane's breaker, failure re-opens it.  ``breaker_clock`` is
         # injectable so chaos runs can measure the cooldown in
         # deterministic virtual time.
-        self.breaker_cooldown_s = cfg.breaker_cooldown_s
+        self.breaker_cooldown_s = breaker_cooldown_s
         self.breaker_clock = breaker_clock or time.monotonic
         # ONE condition guards every mutable field below (including all
         # lane queues); dispatch + lane threads wait on it.
@@ -486,16 +400,18 @@ class VerifierScheduler:
             for lane in self._lanes)
         # placement: a window larger than this splits across lanes
         # (floor min_split keeps chunks worth a device dispatch)
-        self.min_split = max(1, cfg.min_split)
-        self._chunk_cap = lane_chunk_cap(cfg.max_batch, len(self._lanes),
+        self.min_split = max(1, min_split)
+        self._chunk_cap = lane_chunk_cap(max_batch, len(self._lanes),
                                          self.min_split)
         self._rr = 0  # round-robin cursor breaking equal-load ties
         # LRU recovery cache: (sighash, sig) -> 20-byte address or None
         # (a deterministic recovery failure is cached too — re-gossiped
         # garbage must not re-reach the device either)
         self._cache: OrderedDict[tuple, object] = OrderedDict()  # guarded-by: _lock
-        # key -> [futures, t_submit, klass]: identical in-flight keys
-        # share one row (in-batch dedup), arrival order preserved.
+        # key -> [holders, t_submit, klass], each holder the
+        # (_WindowRows, index) pair of a row that waits for this key:
+        # identical in-flight keys share one row (in-batch dedup),
+        # arrival order preserved.
         # ``klass`` is the priority class ("consensus" | "bulk"): dedup
         # promotes a shared row to the higher class, and the flush
         # selects consensus rows first when the window cannot take
@@ -538,9 +454,9 @@ class VerifierScheduler:
             "breaker_diverted": 0, "window_splits": 0,
             "straggler_diverts": 0, "pipeline_windows": 0,
             "pipeline_overlapped": 0,
-            # lock holds that answered the recorded windows' holders: one
-            # a _WindowRows of the batch, one a plain future; ``rows``
-            # over this is the rows answered a hold
+            # lock holds that answered the recorded windows' holders:
+            # one a _WindowRows of the batch; ``rows`` over this is the
+            # rows answered a hold
             "resolve_holds": 0,
             # hedged re-dispatch accounting: every hedge ends as either
             # a cancelled loser (never ran) or a wasted loser (ran,
@@ -548,8 +464,8 @@ class VerifierScheduler:
             # quiescence is the exactly-once recording invariant
             "hedges": 0, "hedge_wins": 0, "hedge_cancelled": 0,
             "hedge_wasted": 0,
-            # closed-loop controller + flight-ring loss accounting
-            "adapt_decisions": 0, "flight_dropped": 0,
+            # flight-ring loss accounting
+            "flight_dropped": 0,
             # window-granular admissions (_enter_window): whole ingest
             # windows and synchronous calls entering in ONE lock hold
             # instead of row-by-row, by the priority class the caller
@@ -570,23 +486,10 @@ class VerifierScheduler:
         # is configurable (flight_ring) and an append that evicts the
         # oldest entry counts into stats["flight_dropped"] +
         # verifier.flight_dropped — silent loss under load is visible.
-        self._flights: deque = deque(maxlen=max(1, cfg.flight_ring))  # guarded-by: _lock
+        self._flights: deque = deque(maxlen=max(1, flight_ring))  # guarded-by: _lock
         self._flight_seq = 0  # guarded-by: _lock
-        # adaptive windowing: the controller consumes recent flight
-        # timings plus the SLO burn probe and steers the flush deadline
-        # (_window_s) and target bucket (_target_rows) per window
-        self._adaptive = cfg.adaptive
-        self._target_rows = cfg.max_batch  # guarded-by: _lock
-        self._adapt_windows = 0  # guarded-by: _lock
-        # injectable SLO feedback: a zero-arg callable returning the
-        # (fast, slow) burn-rate pair of the commit-latency objective
-        # (harness/slo.py SLOEngine.burn_probe); set like failure_hook /
-        # breaker_clock before traffic.  Without one the controller
-        # derives burn from recent window p99 against config.slo_p99_ms.
-        self.burn_probe = None
         # per-class queue-wait samples (ms) behind stats()'s
-        # class_wait_ms percentiles — the bench adaptive stage reads
-        # per-class p99 here without scraping the labeled histograms
+        # class_wait_ms percentiles
         self._class_waits = {
             "bulk": deque(maxlen=2048),
             "consensus": deque(maxlen=2048),
@@ -594,8 +497,10 @@ class VerifierScheduler:
         # hedged re-dispatch: live (unrecorded) window tickets the
         # straggler monitor scans; mesh-only — with one lane there is
         # no sibling to hedge onto
-        self._hedge_on = bool(cfg.hedge) and len(self._lanes) > 1
-        self._hedge_poll_s = max(0.5e-3, cfg.hedge_poll_ms / 1e3)
+        self._hedge_on = bool(hedge) and len(self._lanes) > 1
+        self.hedge_min_windows = hedge_min_windows
+        self.hedge_floor_ms = hedge_floor_ms
+        self._hedge_poll_s = max(0.5e-3, hedge_poll_ms / 1e3)
         self._tickets: set = set()  # guarded-by: _lock
         self._hedge_thread: threading.Thread | None = None
         if len(self._lanes) > 1:
@@ -608,8 +513,10 @@ class VerifierScheduler:
                priority: str = "bulk") -> Future:  # thread-entry hot-path-entry
         """Queue one ``(sighash32, sig65)`` recovery; the future resolves
         to the 20-byte signer address, or ``None`` for an invalid
-        signature.  Cache hits resolve immediately; misses ride the next
-        coalesced batch.  The entry for asynchronous single rows: a
+        signature.  A one-row window (:meth:`_enter_window`) behind a
+        future: a cache hit resolves immediately, a miss rides the next
+        coalesced batch, and a batch that died FAILS the future with its
+        error rather than answering ``None`` ("invalid signature").  A
         caller that has a batch in hand takes a window entry
         (:meth:`recover_signers`, :meth:`submit_window`), which costs
         one lock hold for all of it, not one a row.
@@ -620,78 +527,18 @@ class VerifierScheduler:
         take everything pending, and their windows preempt bulk windows
         at lane placement.  In-flight dedup promotes a shared row to
         the higher class."""
-        from eges_tpu.utils.metrics import DEFAULT as metrics
-
-        klass = _class_of(priority)
+        key = ((bytes(sighash), bytes(sig))
+               if len(sig) == 65 and len(sighash) == 32 else None)
         fut: Future = Future()
-        if len(sig) != 65 or len(sighash) != 32:
-            # malformed entries never reach the device (the zero-fill
-            # rows of verify_host.recover_signers recover as invalid —
-            # same observable result, no batch slot burned)
-            with self._lock:
-                self._stats["invalid"] += 1
-            # invalid-sig early-out: billed to the ambient ingress
-            # origin (utils/ledger.py) — the cheapest reject there is,
-            # which is exactly why a flood of them must stay attributed
-            ledger.charge(rejects=1)
-            fut.set_result(None)
-            return fut
-        key = (bytes(sighash), bytes(sig))
-        resolve = _MISS
-        with self._lock:
-            hit = self._cache.get(key, _MISS)
-            if hit is not _MISS:
-                self._cache.move_to_end(key)
-                self._stats["cache_hits"] += 1
-                # a cache-served row is still a served row: without this
-                # accounting a 100% warm-cache flood looks free in
-                # stats()/flight rows (drained into the next window's
-                # flight entry as cache_rows)
-                self._stats["cache_served_rows"] += 1
-                self._cache_rows_pending += 1
-                resolve = hit
-            elif self._closed:
-                # post-close stragglers execute inline on the caller —
-                # the contract is "no lost futures", not "no work"
-                self._stats["cache_misses"] += 1
-                resolve = self._host_recover(key)
-                self._cache_put(key, resolve)
+
+        def _row_done(done) -> None:  # the window's completed future
+            value = done.result()[0]
+            if isinstance(value, BaseException):
+                fut.set_exception(value)
             else:
-                self._stats["cache_misses"] += 1
-                row = self._pending.get(key)
-                if row is not None:
-                    # in-flight dedup: same signature already queued by
-                    # another caller — share its batch row (and promote
-                    # it if this caller is consensus-critical)
-                    row[0].append(fut)
-                    self._stats["coalesced_rows"] += 1
-                    self._dedup_rows_pending += 1
-                    if klass == "consensus":
-                        row[2] = "consensus"
-                else:
-                    # analysis: allow-determinism(coalescing deadline is real-time by contract; chaos pins batching via max_batch kicks)
-                    self._pending[key] = [[fut], time.monotonic(), klass]
-                    ctx = tracing.DEFAULT.current_context()
-                    if (ctx is not None and len(self._pending_trace)
-                            < self._PENDING_TRACE_CAP):
-                        self._pending_trace[key] = ctx.trace_id
-                    rec = ledger.current()
-                    if (rec is not None and len(self._pending_origin)
-                            < self._PENDING_TRACE_CAP):
-                        self._pending_origin[key] = rec
-                    self._ensure_thread()
-                if len(self._pending) >= self._flush_target():
-                    self._kick = True
-                self._lock.notify_all()
-        if resolve is not _MISS:
-            metrics.counter("verifier.cache_hits" if hit is not _MISS
-                            else "verifier.cache_misses").inc()
-            ledger.charge(cache_hits=1 if hit is not _MISS else 0,
-                          cache_misses=0 if hit is not _MISS else 1)
-            fut.set_result(resolve)
-            return fut
-        metrics.counter("verifier.cache_misses").inc()
-        ledger.charge(cache_misses=1)
+                fut.set_result(value)
+
+        self._enter_window([key], priority)._fut.add_done_callback(_row_done)
         return fut
 
     def kick(self) -> None:  # thread-entry hot-path-entry
@@ -716,9 +563,10 @@ class VerifierScheduler:
         trace-id and ledger-origin capture and the aggregated stats;
         one wake-up, then one ``ledger.charge`` for the whole window (N
         unit charges at one timestamp sum to the same ledger state).
-        Row semantics match per-row :meth:`submit` exactly: a malformed
-        entry answers ``None``, counts as ``invalid``, is billed as a
-        reject and never reaches the device."""
+        A malformed entry answers ``None``, counts as ``invalid``, is
+        billed as a reject and never reaches the device (the zero-fill
+        rows of ``verify_host.recover_signers`` recover as invalid: the
+        same observable result, no batch slot burned)."""
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
         n = len(keys)
@@ -748,9 +596,10 @@ class VerifierScheduler:
                     continue
                 if self._closed:
                     # post-close stragglers execute inline on the
-                    # caller — no lost rows, same as per-row submit
+                    # caller — the contract is "no lost rows", not "no
+                    # work"
                     v = self._host_recover(key)
-                    self._cache_put(key, v)
+                    self._cache_put_many((key,), (v,))
                     win.prefill(i, v)
                     continue
                 row = self._pending.get(key)
@@ -758,14 +607,13 @@ class VerifierScheduler:
                     # in-flight dedup (intra-window duplicates land
                     # here too: the first occurrence owns the batch
                     # row, later ones share it)
-                    row[0].append(_WindowSlot(win, i))
+                    row[0].append((win, i))
                     self._stats["coalesced_rows"] += 1
                     self._dedup_rows_pending += 1
                     if klass == "consensus":
                         row[2] = "consensus"
                 else:
-                    self._pending[key] = [[_WindowSlot(win, i)], t_now,
-                                          klass]
+                    self._pending[key] = [[(win, i)], t_now, klass]
                     if (tid is not None and len(self._pending_trace)
                             < self._PENDING_TRACE_CAP):
                         self._pending_trace[key] = tid
@@ -774,6 +622,10 @@ class VerifierScheduler:
                         self._pending_origin[key] = rec
                     added = True
             n_miss = n - n_hits - n_invalid
+            # a cache-served row is still a served row: without this
+            # accounting a 100% warm-cache flood looks free in
+            # stats()/flight rows (drained into the next window's
+            # flight entry as cache_rows)
             self._cache_rows_pending += n_hits
             self._stats["cache_hits"] += n_hits
             self._stats["cache_served_rows"] += n_hits
@@ -783,7 +635,7 @@ class VerifierScheduler:
             self._stats["window_rows_" + klass] += n
             if added:
                 self._ensure_thread()
-            if len(self._pending) >= self._flush_target():
+            if len(self._pending) >= self.max_batch:
                 self._kick = True
             self._lock.notify_all()
         if n_hits:
@@ -893,8 +745,8 @@ class VerifierScheduler:
             return self._closed
 
     def close(self, timeout: float | None = 30.0) -> None:  # thread-entry
-        """Drain every pending future, then stop and join every thread —
-        no lost futures, no leaked threads.
+        """Drain every pending row, then stop and join every thread —
+        no lost rows, no leaked threads.
 
         The drain order is deterministic and documented:
 
@@ -948,14 +800,11 @@ class VerifierScheduler:
             self._pending.clear()
             self._pending_trace.clear()
             self._pending_origin.clear()
-        for row in leftovers:
-            for f in row[0]:
-                if not f.done():
-                    f.set_exception(RuntimeError(
-                        "verifier scheduler closed with unresolved futures"))
+        self._fail_rows(leftovers, RuntimeError(
+            "verifier scheduler closed with unresolved futures"))
 
     def stats(self) -> dict:
-        """Snapshot of scheduler counters (tests and the bench stage
+        """Snapshot of scheduler counters (tests and the benchmark
         read deltas here instead of the process-global registry).  The
         flat keys are scheduler-wide aggregates — exactly the pre-mesh
         surface — plus ``lanes`` and a ``devices`` list of per-lane
@@ -994,9 +843,7 @@ class VerifierScheduler:
             out["devices"] = devices
             out["flight_windows"] = self._flight_seq
             out["flight_capacity"] = self._flights.maxlen
-            out["adaptive"] = self._adaptive
             out["window_ms"] = round(self._window_s * 1e3, 4)
-            out["target_rows"] = self._target_rows
             from eges_tpu.utils.metrics import percentile
             class_wait = {}
             for klass in sorted(self._class_waits):
@@ -1011,7 +858,7 @@ class VerifierScheduler:
 
     def flights(self, limit: int = 0) -> list[dict]:
         """Flight-recorder entries, oldest first (the ring keeps the
-        newest ``config.flight_ring`` windows — default 4096, what
+        newest ``flight_ring`` windows — default 4096, what
         ``thw_flight`` hands out at most — and evictions count into
         ``stats()["flight_dropped"]`` / ``verifier.flight_dropped``);
         ``limit`` keeps only the newest N.  Each entry is one window's
@@ -1040,11 +887,17 @@ class VerifierScheduler:
         out.reverse()
         return out
 
-    def _flush_target(self) -> int:
-        """Rows that flush a window as "full" right now — ``max_batch``
-        statically, the controller's ``_target_rows`` (never above the
-        cap) when adaptive.  Caller holds ``self._lock``."""
-        return min(self.max_batch, max(1, self._target_rows))
+    @staticmethod
+    def _fail_rows(rows, exc: BaseException) -> None:
+        """Every holder of the pending ``rows`` that has no answer yet
+        takes ``exc`` as its row's value (a row is written once: one
+        that a batch answered meanwhile keeps its answer), so no caller
+        hangs on a window that will never run.  The synchronous facades
+        recover such a row on the host; :meth:`submit`'s future raises
+        it."""
+        for row in rows:
+            for win, idx in row[0]:
+                win._set_rows((idx,), (exc,))
 
     def _ensure_thread(self) -> None:
         # caller holds self._lock
@@ -1063,10 +916,6 @@ class VerifierScheduler:
                 target=self._lane_loop, args=(lane,),
                 name=f"verifier-lane-{lane.index}", daemon=True)
             lane.thread.start()
-
-    def _cache_put(self, key: tuple, addr) -> None:
-        # caller holds self._lock
-        self._cache_put_many((key,), (addr,))
 
     def _cache_put_many(self, keys, addrs) -> None:
         """A window's results into the LRU in row order, the overflow
@@ -1101,20 +950,17 @@ class VerifierScheduler:
             return None
 
     def _dispatch_loop(self) -> None:
-        """Wrapper keeping the strand-no-future invariant: if the flush
-        loop itself dies on an unexpected error, every queued future is
+        """Wrapper keeping the strand-no-row invariant: if the flush
+        loop itself dies on an unexpected error, every queued row is
         failed with that error instead of hanging its caller forever
-        (``_ensure_thread`` restarts a thread on the next submit)."""
+        (``_ensure_thread`` restarts a thread on the next entry)."""
         try:
             self._dispatch_forever()
         except BaseException as exc:
             with self._lock:
                 leftovers = list(self._pending.values())
                 self._pending.clear()
-            for row in leftovers:
-                for f in row[0]:
-                    if not f.done():
-                        f.set_exception(exc)
+            self._fail_rows(leftovers, exc)
             raise
         finally:
             with self._lock:
@@ -1134,13 +980,10 @@ class VerifierScheduler:
                 if not self._pending and self._closed:
                     return
                 # coalescing window: more submitters may land until the
-                # bucket fills (the adaptive controller's target, capped
-                # at max_batch), a sync caller kicks, close drains, or
-                # the deadline measured from the OLDEST entry expires —
-                # both the target and the deadline are re-read each
-                # iteration so a controller decision applies to the
-                # window being coalesced right now
-                while (len(self._pending) < self._flush_target()
+                # bucket fills (max_batch), a sync caller kicks, close
+                # drains, or the deadline measured from the OLDEST entry
+                # expires
+                while (len(self._pending) < self.max_batch
                         and not self._kick and not self._closed
                         and self._pending):
                     oldest = next(iter(self._pending.values()))[1]
@@ -1154,7 +997,7 @@ class VerifierScheduler:
                 # "close" outranks "kick": close() raises the kick flag
                 # to wake the window wait, and the shutdown drain must
                 # be journaled as the documented flush_close step
-                limit = self._flush_target()
+                limit = self.max_batch
                 reason = ("full" if len(self._pending) >= limit
                           else "close" if self._closed
                           else "kick" if self._kick else "deadline")
@@ -1361,18 +1204,11 @@ class VerifierScheduler:
             for p in unfinished:
                 with self._lock:
                     lane.inflight_rows -= p.rows
-                for _k, row in p.batch:
-                    for f in row[0]:
-                        if not f.done():
-                            f.set_exception(exc)
+                self._fail_rows((row for _k, row in p.batch), exc)
             for tk in leftovers:
-                # a hedged ticket's sibling dispatch may still win; only
-                # fail futures no other lane will resolve (done() guards
-                # make the race harmless either way)
-                for _k, row in tk.batch:
-                    for f in row[0]:
-                        if not f.done():
-                            f.set_exception(exc)
+                # a hedged ticket's sibling dispatch may still win; a
+                # row is written once, so the race is harmless either way
+                self._fail_rows((row for _k, row in tk.batch), exc)
             raise
 
     def _finish_lane_window(self, lane: _DeviceLane,
@@ -1675,35 +1511,26 @@ class VerifierScheduler:
 
     def _answer(self, p: _PendingWindow) -> int:
         """Every holder of the batch gets its row's value exactly once:
-        the slots of one :class:`_WindowRows` together in ONE hold, a
-        plain future (a row that came through :meth:`submit`) by itself,
-        each holder of a dedup-shared row.  Returns the holds taken.  If
-        the batch died before it had results, its holders FAIL with
-        that error rather than masquerading as None ("invalid
-        signature"); a window keeps the error as its rows' value.  A
+        the rows of one :class:`_WindowRows` together in ONE hold, each
+        holder of a dedup-shared row.  Returns the holds taken.  If the
+        batch died before it had results, its holders take that error
+        as their rows' value rather than a None masquerading as
+        "invalid signature" (:meth:`submit`'s future raises it).  A
         hedge loser comes through here too: the winner has answered
-        everything, so the done guards make it a no-op."""
+        everything, and a row is written once, so it changes nothing."""
         failure = None if p.computed else (p.failure or RuntimeError(
             "verifier batch dispatch failed"))
         windows: dict = {}
-        holds = 0
         for (_, row), r in zip(p.batch, p.results):
-            for f in row[0]:
-                if isinstance(f, _WindowSlot):
-                    rows = windows.get(f._win)
-                    if rows is None:
-                        rows = windows[f._win] = ([], [])
-                    rows[0].append(f._idx)
-                    rows[1].append(r if failure is None else failure)
-                elif not f.done():
-                    holds += 1
-                    if failure is None:
-                        f.set_result(r)
-                    else:
-                        f.set_exception(failure)
+            for win, idx in row[0]:
+                rows = windows.get(win)
+                if rows is None:
+                    rows = windows[win] = ([], [])
+                rows[0].append(idx)
+                rows[1].append(r if failure is None else failure)
         for win, (idxs, values) in windows.items():
             win._set_rows(idxs, values)
-        return holds + len(windows)
+        return len(windows)
 
     def _record_window(self, lane: _DeviceLane, p: _PendingWindow,
                        mesh: bool) -> None:
@@ -1891,99 +1718,6 @@ class VerifierScheduler:
                                occupancy=round(rows / bucket, 4),
                                diverted=p.diverted,
                                queue_wait_ms=round(waited * 1e3, 3))
-        if self._adaptive:
-            # one controller step per RECORDED window (hedge losers
-            # never get here), after the window's own journal events so
-            # a sched_adapt decision always follows the flush it saw
-            self._adapt_step()
-
-
-    # -- adaptive windowing (closed-loop controller) ----------------------
-
-    def _adapt_step(self) -> None:  # hot-path-entry
-        """One closed-loop controller step: telemetry in, window policy
-        out.
-
-        Inputs are the flight recorder's recent wait/stage/compute/total
-        timings plus the SLO engine's commit-latency burn rate (via the
-        injectable :attr:`burn_probe`; without one, burn derives from
-        the recent window p99 against ``config.slo_p99_ms``).  Output is
-        the flush deadline (``_window_s``) and target bucket
-        (``_target_rows``) the NEXT windows coalesce under: burning the
-        p99 objective shrinks both (deadline-biased small buckets, less
-        queueing ahead of each dispatch); a calm burn grows them back
-        toward occupancy.  Every decision journals as ``sched_adapt``
-        with its inputs — the measured value attrs are wall-clock
-        derived and volatile-stripped by the chaos canonical dump, while
-        the event COUNT stays pinned by kick-driven batching, so
-        determinism checks still byte-match under the virtual clock.
-        """
-        from eges_tpu.utils.metrics import DEFAULT as metrics
-        from eges_tpu.utils.metrics import percentile
-
-        cfg = self.config
-        probe = self.burn_probe
-        burn_fast = burn_slow = None
-        if probe is not None:
-            try:
-                burn_fast, burn_slow = probe()
-            # analysis: allow-swallow(a torn-down SLO engine must not
-            # take the verify hot path down with it — the controller
-            # falls back to the flight-derived burn)
-            except Exception:
-                burn_fast = burn_slow = None
-        decision = None
-        with self._lock:
-            self._adapt_windows += 1
-            if self._adapt_windows % max(1, cfg.adapt_every):
-                return
-            recent = self._newest_flights(max(1, cfg.adapt_recent))
-            totals = sorted(f["total_ms"] for f in recent)
-            waits = sorted(f["wait_ms"] for f in recent)
-            p99 = percentile(totals, 99.0)
-            if burn_fast is None:
-                derived = (p99 / cfg.slo_p99_ms
-                           if cfg.slo_p99_ms > 0 else 0.0)
-                burn_fast = burn_slow = derived
-            burn = max(burn_fast, burn_slow)
-            window_ms = self._window_s * 1e3
-            target = self._target_rows
-            if burn >= cfg.burn_shrink:
-                # the p99 objective is burning: bias to latency —
-                # shorter deadline, smaller bucket
-                window_ms = max(cfg.min_window_ms,
-                                window_ms * cfg.shrink_gain)
-                target = max(cfg.min_target_rows, target // 2)
-                why = "shrink"
-            elif burn <= cfg.burn_relax:
-                # calm: trade latency headroom back for occupancy
-                window_ms = min(cfg.max_window_ms,
-                                window_ms * cfg.grow_gain)
-                target = min(cfg.max_batch, target * 2)
-                why = "grow"
-            else:
-                why = "hold"
-            self._window_s = window_ms / 1e3
-            self._target_rows = target
-            self._stats["adapt_decisions"] += 1
-            decision = {
-                "window_ms": round(window_ms, 4),
-                "target_rows": target,
-                "burn_fast": round(float(burn_fast), 4),
-                "burn_slow": round(float(burn_slow), 4),
-                "p99_ms": round(p99, 3),
-                "wait_p50_ms": round(percentile(waits, 50.0), 3),
-                "decision": why,
-            }
-        # gauges + journal OUTSIDE the condition (fail-under-lock)
-        metrics.gauge("verifier.sched_window_ms").set(
-            decision["window_ms"])
-        metrics.gauge("verifier.sched_target_rows").set(
-            decision["target_rows"])
-        metrics.counter("verifier.adapt_decisions").inc()
-        journal = self.journal
-        if journal is not None:
-            journal.record("sched_adapt", **decision)
 
     # -- hedged re-dispatch (straggler speculation) -----------------------
 
@@ -1999,22 +1733,21 @@ class VerifierScheduler:
 
     def _lane_threshold_ms(self, lane_index: int) -> float:
         """Straggler threshold for one lane: the median window total
-        over this lane's recent flights × ``hedge_factor`` — the
+        over this lane's recent flights × ``HEDGE_FACTOR`` — the
         all-lane median until the lane has ``hedge_min_windows`` of its
         own history — floored at ``hedge_floor_ms`` so an idle mesh
         never hedges on noise.  Caller holds ``self._lock``."""
         from eges_tpu.utils.metrics import percentile
 
-        cfg = self.config
         recent = self._newest_flights(self._HEDGE_RECENT)
         lane_tot = sorted(f["total_ms"] for f in recent
                           if f["device"] == lane_index)
-        if len(lane_tot) >= cfg.hedge_min_windows:
+        if len(lane_tot) >= self.hedge_min_windows:
             base = percentile(lane_tot, 50.0)
         else:
             all_tot = sorted(f["total_ms"] for f in recent)
             base = percentile(all_tot, 50.0) if all_tot else 0.0
-        return max(cfg.hedge_floor_ms, cfg.hedge_factor * base)
+        return max(self.hedge_floor_ms, HEDGE_FACTOR * base)
 
     def _hedge_scan(self) -> list:
         """One straggler-monitor pass (caller holds ``self._lock``):

@@ -1,9 +1,8 @@
 """Flagship model: batched secp256k1 sender recovery.
 
 One shared definition of the jittable forward step and its example
-inputs, used by ``__graft_entry__.entry()``, ``bench.py`` and tests —
-so "the model" the driver compiles is exactly what the benchmark
-measures and the consensus layer runs (ref: the cgo hot path it
+inputs, used by ``__graft_entry__.entry()`` and tests — so "the
+model" the driver compiles is exactly what the consensus layer runs (ref: the cgo hot path it
 replaces, crypto/secp256k1/secp256.go:105 +
 core/types/transaction_signing.go:222-241).
 """

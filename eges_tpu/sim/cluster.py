@@ -40,7 +40,7 @@ class SimCluster:
                  verifier=None, mine=None, signed: bool = True,
                  alloc: dict | None = None, txpool: bool = False,
                  fast_sync: set | None = None, defer: set | None = None,
-                 mesh_devices: int | None = None, sched_config=None,
+                 mesh_devices: int | None = None,
                  columnar: bool = True, checkpoint_every: int = 0):
         self.clock = SimClock()
         self.net = SimNet(self.clock, seed=seed, drop_rate=drop_rate)
@@ -60,13 +60,8 @@ class SimCluster:
         # that shared scheduler a mesh dispatcher — one window lane per
         # device, shared by every sim node.  verifier=None (host
         # fallback) passes through untouched.
-        # sched_config (a crypto.scheduler.SchedulerConfig) pins the
-        # shared scheduler's knobs for this cluster — chaos scenarios
-        # use it to enable adaptive windowing / hedging with the sim's
-        # deterministic flush discipline instead of env overrides
         from eges_tpu.crypto.scheduler import scheduler_for
-        kw = {"config": sched_config} if sched_config is not None else {}
-        verifier = scheduler_for(verifier, **kw)
+        verifier = scheduler_for(verifier)
         self.verifier = verifier
 
         if n_bootstrap is None:

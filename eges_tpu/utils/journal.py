@@ -99,12 +99,6 @@ EVENT_TYPES = frozenset({
     # when anything was charged — deterministic counts/deltas plus the
     # wall-clock "costs" account the chaos canonical dump strips
     "ingress_ledger",
-    # adaptive scheduler controller (crypto/scheduler.py): one
-    # window-sizing decision per controller step — chosen flush deadline
-    # and target rows plus the burn/latency inputs that drove it (the
-    # timing-derived attrs are volatile-stripped by the chaos canonical
-    # dump; the decision COUNT stays deterministic)
-    "sched_adapt",
     # continuous sampling profiler (eges_tpu/utils/profiler.py): one
     # aggregate per-phase/per-role sample-count report per profiling
     # interval.  Sampled stacks are wall-clock by nature, so these are

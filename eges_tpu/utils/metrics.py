@@ -88,10 +88,6 @@ METRIC_FAMILIES = frozenset({
     # crypto/scheduler.py — flight-ring overflow (oldest window evicted
     # before anything read it; the ring's silent-loss signal)
     "verifier.flight_dropped",
-    # crypto/scheduler.py — SLO-driven adaptive window controller:
-    # chosen deadline/bucket per step plus the decision count
-    "verifier.adapt_decisions", "verifier.sched_target_rows",
-    "verifier.sched_window_ms",
     # crypto/scheduler.py — hedged re-dispatch of straggling windows:
     # speculative duplicates placed, duplicates that won, losers
     # cancelled before execution, losers that ran to waste
@@ -218,12 +214,6 @@ METRIC_HELP = {
         "Windows recorded by the lifecycle flight recorder.",
     "verifier.flight_dropped":
         "Flight-recorder windows evicted unread by ring overflow.",
-    "verifier.adapt_decisions":
-        "Window-sizing decisions taken by the adaptive controller.",
-    "verifier.sched_target_rows":
-        "Current adaptive target rows per coalesced window.",
-    "verifier.sched_window_ms":
-        "Current adaptive flush deadline in milliseconds.",
     "verifier.hedge_cancelled":
         "Hedged duplicates cancelled before execution (winner first).",
     "verifier.hedge_wasted":

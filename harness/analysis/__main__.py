@@ -3,7 +3,7 @@
 Exit status is the CI gate: 0 only when every finding is waived or
 baselined (and the baseline itself is well-formed).  ``--summary FILE``
 appends one ``findings_by_rule`` JSON line so the counts can be trended
-alongside bench_history.jsonl.
+(``harness/check_regression.py --analysis`` gates the trend).
 """
 
 from __future__ import annotations

@@ -343,7 +343,7 @@ def save_baseline(path: str, findings: list[Finding]) -> None:
 
 # -- runner -------------------------------------------------------------
 
-DEFAULT_PATHS = ("eges_tpu", "harness", "bench.py")
+DEFAULT_PATHS = ("eges_tpu", "harness")
 DEFAULT_BASELINE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "baseline.json")
 

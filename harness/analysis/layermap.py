@@ -52,7 +52,7 @@ MANIFEST = {
                       "eges_tpu.ingress", "eges_tpu.bootnode",
                       "eges_tpu.keytool", "eges_tpu.console"]},
         {"name": "L4-harness",
-         "packages": ["eges_tpu.sim", "harness", "bench"]},
+         "packages": ["eges_tpu.sim", "harness"]},
     ],
     # modules allowed to touch `# ingress-entry` functions directly:
     # the facade, and the four surfaces that OWN raw ingress bytes

@@ -80,12 +80,6 @@ VOLATILE_KEYS = {
     # device/host ms) under this ONE top-level key by design; the
     # decayed counts and deltas are virtual-time deterministic
     "ingress_ledger": ("costs",),
-    # the adaptive controller's inputs (flight p99, queue wait, burn
-    # rates) and therefore its outputs are wall-clock measurements; the
-    # decision COUNT is protocol content (one per recorded window,
-    # pinned by kick-driven batching) and stays in the dump
-    "sched_adapt": ("window_ms", "target_rows", "burn_fast",
-                    "burn_slow", "p99_ms", "wait_p50_ms", "decision"),
 }
 
 
@@ -486,35 +480,24 @@ def _scn_straggler_hedge(seed: int, fast: bool) -> dict:
     both phases must stay byte-deterministic."""
     import threading
 
-    from eges_tpu.crypto.scheduler import SchedulerConfig, VerifierScheduler
+    from eges_tpu.crypto.scheduler import VerifierScheduler
     from eges_tpu.crypto.verify_host import NativeMeshVerifier
     from eges_tpu.utils.metrics import percentile
-
-    # kick-driven flushes (deterministic rows) with the adaptive
-    # controller ON but PINNED — min == max on both control outputs —
-    # so every window journals a sched_adapt decision without the
-    # controller ever altering window membership; a huge cooldown keeps
-    # both breakers closed so hedging (not the breaker) is the rescue
-    def _cfg() -> SchedulerConfig:
-        return SchedulerConfig(
-            window_ms=10_000.0, breaker_cooldown_s=1e9,
-            adaptive=True, min_window_ms=10_000.0,
-            max_window_ms=10_000.0, min_target_rows=1024,
-            hedge=True, hedge_min_windows=4, hedge_floor_ms=25.0,
-            hedge_poll_ms=2.0)
 
     blocks = 3 if fast else 5
 
     def _phase(pin: bool):
         mesh = NativeMeshVerifier(2)
-        sched = VerifierScheduler(mesh, config=_cfg())
+        # kick-driven flushes (deterministic rows); a huge cooldown
+        # keeps both breakers closed so hedging (not the breaker) is
+        # the rescue
+        sched = VerifierScheduler(
+            mesh, window_ms=10_000.0, breaker_cooldown_s=1e9,
+            hedge=True, hedge_min_windows=4, hedge_floor_ms=25.0,
+            hedge_poll_ms=2.0)
         cluster = SimCluster(4, seed=seed, verifier=sched, signed=True)
         sched.breaker_clock = cluster.clock.now
         col = _enable_slo(cluster)
-        # close the loop end-to-end: the controller's burn input is the
-        # live collector's commit-latency burn rate (its value attrs
-        # are volatile-stripped from the sched_adapt events)
-        sched.burn_probe = col.burn_probe("commit_latency")
         release = threading.Event()
         if pin:
             victim = mesh.device_targets()[0]
@@ -558,7 +541,7 @@ def _scn_straggler_hedge(seed: int, fast: bool) -> dict:
     # cannot act before the straggler threshold (hedge_floor_ms) plus a
     # poll tick, so a sub-millisecond healthy baseline does not demand
     # a sub-millisecond rescue
-    bound_ms = 2.0 * max(p99_a, sched_b.config.hedge_floor_ms)
+    bound_ms = 2.0 * max(p99_a, sched_b.hedge_floor_ms)
     # exactly-once billing: only the winning dispatch runs the window's
     # bookkeeping (the loser never touches the pending-origin map), so
     # rows billed across every node ledger can never exceed the rows
@@ -574,7 +557,6 @@ def _scn_straggler_hedge(seed: int, fast: bool) -> dict:
             stats["hedge_cancelled"] + stats["hedge_wasted"]),
         "p99_recovered": p99_b <= bound_ms,
         "no_double_billing": billed <= stats["rows"],
-        "controller_stepped": stats["adapt_decisions"] > 0,
     })
     # fold the healthy phase's streams into the dump under a distinct
     # prefix so --check-determinism byte-compares BOTH phases

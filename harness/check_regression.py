@@ -1,48 +1,7 @@
-"""Bench regression gate over ``harness/bench_history.jsonl``.
+"""Static-analysis trend gate over ``harness/analysis_history.jsonl``.
 
-Each ``bench.py`` round appends its final JSON line to the history
-file.  This gate groups entries by their ``metric`` name (legacy lines
-without one form their own group), compares each group's newest
-``value`` against its previous one, and exits non-zero when ANY metric
-dropped more than the threshold (default 20%) — the CI tripwire for
-perf regressions that unit tests can't see.  The verifier bench's
-``secp256k1_ecrecover_verifies_per_sec_per_chip``, the mesh stage's
-aggregate ``mesh_sharded_rows_per_s`` and the wire-speed ingest
-stage's ``ingest_rows_per_s`` (the columnar datagram->pool pipeline,
-raced against a per-tx baseline) gate independently: a mesh dispatch
-or host-ingest regression cannot hide behind a healthy single-chip
-number.
-Metrics in ``LOWER_IS_BETTER`` (``cold_start_seconds`` — the AOT
-artifact store's deliverable — ``commit_p99_ms`` — the commit
-anatomy stage's end-to-end p99 — and ``ledger_overhead_pct`` — the
-attribution cost the ingress provenance ledger adds to the verify hot
-path, and the adaptive-scheduler stage's ``sched_p99_window_ms`` /
-``sched_queue_wait_p99_ms_consensus`` / ``sched_queue_wait_p99_ms_bulk``
-— p99 window latency and per-class queue wait under the bursty
-workload — and ``host_cpu_share_of_verify_pct`` — the continuous
-profiler's phase-attributed split: the share of pipeline CPU samples
-spent in host-side pool phases rather than the verify window — and
-``device_mem_peak_bytes`` — the devstats stage's HBM peak watermark,
-0 on host-only runs so the gate arms the first time a real backend
-reports) gate in
-the opposite direction: a RISE past the threshold fails, so a broken
-artifact store, a commit-path latency regression, provenance cost
-creeping onto the hot path, a controller that stops shrinking the
-window under burn, ingest overhead growing relative to verify
-compute, or a growing device-memory footprint cannot hide behind a
-healthy steady-state throughput number.  The devstats stage's
-``goodput_ratio`` (useful rows / padded device rows over a fixed burst
-schedule — exactly 552/576 unless the scheduler starts over-padding)
-gates in the default direction: any drop past the threshold fails.  Metrics in
-``ZERO_TOLERANCE`` (``slo_false_positive_alerts`` — alerts fired by
-the burn-rate SLO engine on a calm, fault-free sim) gate on the
-newest value alone: it must be exactly 0, even with a single history
-entry — one false page on a healthy cluster means the thresholds or
-the engine regressed.
-
-``--analysis [analysis_history.jsonl]`` gates the static-analysis
-trend instead: the newest ``unsuppressed_by_rule`` line (appended by
-``python -m harness.analysis --summary`` in the bench path) is compared
+The newest ``unsuppressed_by_rule`` line (appended by
+``python -m harness.analysis --summary``) is compared
 against the previous one, and ANY rise in unsuppressed findings for any
 rule fails — zero tolerance, no threshold: suppressions are explicit
 (waiver/baseline), so a rise always means un-reviewed debt landed.
@@ -54,12 +13,11 @@ a rule present in the previous line but missing from the newest one
 fails outright — a renamed or deleted rule would otherwise silently
 stop gating while its findings kept accumulating.
 
-Exit codes: 0 ok (or fewer than two comparable entries per metric),
-1 regression, 2 unreadable history.
+Exit codes: 0 ok (or fewer than two comparable entries), 1 regression,
+2 unreadable history.
 
 Usage::
 
-    python harness/check_regression.py [history.jsonl] [--threshold 0.2]
     python harness/check_regression.py --analysis [analysis_history.jsonl]
 """
 
@@ -69,108 +27,6 @@ import argparse
 import json
 import os
 import sys
-
-_DEFAULT_HISTORY = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "bench_history.jsonl")
-
-# metrics where smaller is the win (durations): the gate fails on a
-# RISE past the threshold instead of a drop
-LOWER_IS_BETTER = frozenset({"cold_start_seconds", "commit_p99_ms",
-                             "device_mem_peak_bytes",
-                             "host_cpu_share_of_verify_pct",
-                             "ledger_overhead_pct",
-                             "rejoin_replayed_blocks",
-                             "rejoin_seconds",
-                             "sched_p99_window_ms",
-                             "sched_queue_wait_p99_ms_bulk",
-                             "sched_queue_wait_p99_ms_consensus"})
-
-# metrics whose newest value must be EXACTLY zero — no threshold, no
-# previous-entry requirement: any count at all is a failure
-ZERO_TOLERANCE = frozenset({"slo_false_positive_alerts"})
-
-
-def load_history(path: str) -> list[dict]:
-    """Entries with a numeric primary metric, oldest first; torn or
-    non-JSON lines are skipped (same tolerance as journal.load)."""
-    out = []
-    with open(path, "r", encoding="utf-8") as fh:
-        for line in fh:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                obj = json.loads(line)
-            except ValueError:
-                continue
-            if isinstance(obj, dict) and isinstance(
-                    obj.get("value"), (int, float)):
-                out.append(obj)
-    return out
-
-
-def check(entries: list[dict], threshold: float = 0.20) -> tuple[int, str]:
-    """(exit_code, message) for the per-metric newest-vs-previous
-    comparison.  Entries are grouped by their ``metric`` name; legacy
-    lines without one share the verifier bench's default group so the
-    pre-mesh history keeps gating unchanged."""
-    groups: dict[str, list[dict]] = {}
-    for e in entries:
-        name = e.get("metric")
-        if not isinstance(name, str) or not name:
-            name = "secp256k1_ecrecover_verifies_per_sec_per_chip"
-        groups.setdefault(name, []).append(e)
-    lines, code = [], 0
-    for name in sorted(groups):
-        series = groups[name]
-        if name in ZERO_TOLERANCE:
-            lv = float(series[-1]["value"])
-            if lv != 0.0:
-                code = 1
-                lines.append("REGRESSION [%s]: newest value %g must be "
-                             "exactly 0 (zero-tolerance metric)"
-                             % (name, lv))
-            else:
-                lines.append("ok [%s]: newest value 0 (zero-tolerance "
-                             "metric)" % name)
-            continue
-        if len(series) < 2:
-            lines.append("ok [%s]: %d comparable entr%s — nothing to "
-                         "compare" % (name, len(series),
-                                      "y" if len(series) == 1 else "ies"))
-            continue
-        prev, last = series[-2], series[-1]
-        pv, lv = float(prev["value"]), float(last["value"])
-        if pv <= 0:
-            lines.append("ok [%s]: previous value %.1f is not a usable "
-                         "baseline" % (name, pv))
-            continue
-        if name in LOWER_IS_BETTER:
-            rise = (lv - pv) / pv
-            detail = "%.3f -> %.3f %s (%+.1f%%, lower is better)" % (
-                pv, lv, last.get("unit", ""), rise * 100.0)
-            if rise > threshold:
-                code = 1
-                lines.append("REGRESSION [%s]: %s exceeds the %.0f%% "
-                             "threshold" % (name, detail,
-                                            threshold * 100.0))
-            else:
-                lines.append("ok [%s]: %s within the %.0f%% threshold"
-                             % (name, detail, threshold * 100.0))
-            continue
-        drop = (pv - lv) / pv
-        detail = "%.1f -> %.1f %s (%+.1f%%)" % (
-            pv, lv, last.get("unit", ""), -drop * 100.0)
-        if drop > threshold:
-            code = 1
-            lines.append("REGRESSION [%s]: %s exceeds the %.0f%% "
-                         "threshold" % (name, detail, threshold * 100.0))
-        else:
-            lines.append("ok [%s]: %s within the %.0f%% threshold" % (
-                name, detail, threshold * 100.0))
-    if not lines:
-        return 0, "ok: 0 comparable entries — nothing to compare"
-    return code, "\n".join(lines)
 
 
 def load_analysis_history(path: str) -> list[dict]:
@@ -234,29 +90,17 @@ _DEFAULT_ANALYSIS_HISTORY = os.path.join(
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("history", nargs="?", default=None)
-    ap.add_argument("--threshold", type=float, default=0.20,
-                    help="fractional drop that fails the gate")
-    ap.add_argument("--analysis", action="store_true",
+    ap.add_argument("--analysis", action="store_true", required=True,
                     help="gate the static-analysis unsuppressed-by-rule "
-                         "trend instead of the bench metrics")
+                         "trend (the one gate this script has)")
     args = ap.parse_args(argv)
-    if args.analysis:
-        path = args.history or _DEFAULT_ANALYSIS_HISTORY
-        try:
-            entries = load_analysis_history(path)
-        except OSError as e:
-            print("cannot read %s: %s" % (path, e), file=sys.stderr)
-            return 2
-        code, msg = check_analysis(entries)
-        print(msg)
-        return code
-    path = args.history or _DEFAULT_HISTORY
+    path = args.history or _DEFAULT_ANALYSIS_HISTORY
     try:
-        entries = load_history(path)
+        entries = load_analysis_history(path)
     except OSError as e:
         print("cannot read %s: %s" % (path, e), file=sys.stderr)
         return 2
-    code, msg = check(entries, args.threshold)
+    code, msg = check_analysis(entries)
     print(msg)
     return code
 

@@ -143,13 +143,6 @@ class ClusterCollector:
     def alerts(self) -> list[dict]:
         return self.slo.alerts()
 
-    def burn_probe(self, objective: str = "commit_latency"):
-        """Passthrough to :meth:`SLOEngine.burn_probe`: the closure a
-        cluster wires into its adaptive verifier scheduler
-        (``VerifierScheduler.burn_probe``) so dispatch-window sizing
-        tracks the collector's live commit-latency burn rate."""
-        return self.slo.burn_probe(objective)
-
     def report(self) -> dict:
         """Deterministic aggregate view: per-node event counts, the
         bounded series rings, and the full alert stream + states."""

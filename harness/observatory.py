@@ -129,11 +129,6 @@ def summarize(by_node: dict[str, list[dict]],
     # telemetry sampler heartbeats, merged across streams
     slo_alerts: list[tuple] = []
     telemetry_samples: dict[str, int] = {}
-    # adaptive scheduler controller decisions (crypto/scheduler.py):
-    # per-node shrink/grow/hold tallies; the sizing inputs themselves
-    # are wall-clock-derived and deliberately excluded (same rationale
-    # as mesh queue wait above)
-    sched_adapt: dict[str, dict] = {}
     # continuous-profiler report counts per stream; the attribution
     # itself is folded by profiler.assemble below
     profiler_reports: dict[str, int] = {}
@@ -185,13 +180,6 @@ def summarize(by_node: dict[str, list[dict]],
                 d["load_s"] += float(ev.get("load_s", 0.0))
                 d["compile_s"] += float(ev.get("compile_s", 0.0))
                 d["cold_start_s"] += float(ev.get("cold_start_s", 0.0))
-                continue
-            if typ == "sched_adapt":
-                d = sched_adapt.setdefault(name, {
-                    "decisions": 0, "shrink": 0, "grow": 0, "hold": 0})
-                d["decisions"] += 1
-                verdict = str(ev.get("decision", "hold"))
-                d[verdict if verdict in d else "hold"] += 1
                 continue
             if typ == "verifier_mesh_dispatch":
                 d = mesh.setdefault(int(ev.get("device", -1)), {
@@ -328,9 +316,6 @@ def summarize(by_node: dict[str, list[dict]],
         "telemetry_samples": {
             name: telemetry_samples[name]
             for name in sorted(telemetry_samples)},
-        "sched_adapt": {
-            name: dict(sched_adapt[name])
-            for name in sorted(sched_adapt)},
         "profiler_reports": {
             name: profiler_reports[name]
             for name in sorted(profiler_reports)},

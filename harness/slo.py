@@ -212,19 +212,6 @@ class SLOEngine:
                 self._bad_fraction(objective, now, o.slow_window_s)
                 / o.budget)
 
-    def burn_probe(self, objective: str = "commit_latency"):
-        """A zero-arg closure returning this objective's ``(fast, slow)``
-        burn rates at the engine's newest evaluated timestamp — the
-        feedback hook the adaptive verifier scheduler consumes
-        (``VerifierScheduler.burn_probe``).  Reading at the last
-        evaluation point (rather than taking a ``now``) keeps the probe
-        clock-free: under the simulator the engine already advances on
-        virtual-time telemetry barriers, and the scheduler's dispatch
-        threads have no clock of their own to offer."""
-        def probe() -> tuple[float, float]:
-            return self.burn_rates(objective, self._now)
-        return probe
-
     def evaluate(self, now: float) -> list[dict]:
         """Advance every objective's state machine to ``now``; returns
         the transition events recorded this step."""

@@ -1,6 +1,6 @@
-"""SLO-driven adaptive scheduler: the consolidated ``SchedulerConfig``
-(env + legacy-kwarg overrides), the configurable flight ring with its
-``flight_dropped`` loss signal, the closed-loop window controller, the
+"""The scheduler's policy surface (keyword arguments, and nothing
+else: no environment variable, no controller), the configurable flight
+ring with its ``flight_dropped`` loss signal, the
 hedged re-dispatch contract (bit-identical results, loser cancelled or
 wasted — never recorded — and exactly-once ledger billing), and
 priority-class preemption at placement.
@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from eges_tpu.crypto import secp256k1 as host
-from eges_tpu.crypto.scheduler import SchedulerConfig, VerifierScheduler
+from eges_tpu.crypto.scheduler import VerifierScheduler, scheduler_for
 from eges_tpu.crypto.verify_host import (
     NativeBatchVerifier,
     NativeMeshVerifier,
@@ -52,45 +52,42 @@ def _host_model(entries) -> list:
     return out
 
 
-# -- SchedulerConfig ------------------------------------------------------
+# -- the policy surface ---------------------------------------------------
 
-def test_config_env_overrides():
-    cfg = SchedulerConfig.from_env({
-        "EGES_SCHED_WINDOW_MS": "7.5",
-        "EGES_SCHED_FLIGHT_RING": "8",
-        "EGES_SCHED_ADAPTIVE": "yes",
-        "EGES_SCHED_HEDGE": "0",
-    })
-    assert cfg.window_ms == 7.5
-    assert cfg.flight_ring == 8
-    assert cfg.adaptive is True
-    assert cfg.hedge is False
-    # untouched fields keep their defaults
-    assert cfg.max_batch == SchedulerConfig().max_batch
+def test_a_keyword_argument_reaches_the_scheduler():
+    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=3.0,
+                              flight_ring=8)
+    try:
+        st = sched.stats()
+        assert st["window_ms"] == 3.0
+        assert st["flight_capacity"] == sched._flights.maxlen == 8
+        # untouched values keep their defaults
+        assert sched.max_batch == 1024
+    finally:
+        sched.close()
 
 
-def test_config_malformed_env_raises():
-    with pytest.raises(ValueError):
-        SchedulerConfig.from_env({"EGES_SCHED_MAX_BATCH": "lots"})
-
-
-def test_config_reaches_scheduler_and_legacy_kwargs_win(monkeypatch):
+def test_scheduler_reads_no_environment(monkeypatch):
+    """A stray variable in an operator's shell changes nothing, and a
+    malformed one does not stop the node."""
+    monkeypatch.setenv("EGES_SCHED_MAX_BATCH", "lots")
+    monkeypatch.setenv("EGES_SCHED_ADAPTIVE", "1")
     monkeypatch.setenv("EGES_SCHED_WINDOW_MS", "7.5")
-    monkeypatch.setenv("EGES_SCHED_FLIGHT_RING", "8")
-    # no explicit config: the constructor reads the environment ...
     sched = VerifierScheduler(NativeBatchVerifier())
     try:
-        assert sched.config.window_ms == 7.5
-        assert sched._flights.maxlen == 8
+        st = sched.stats()
+        assert sched.max_batch == 1024
+        assert st["window_ms"] == 2.0
+        assert "adaptive" not in st
     finally:
         sched.close()
-    # ... and a legacy constructor kwarg overrides the env field
-    sched = VerifierScheduler(NativeBatchVerifier(), window_ms=3.0)
-    try:
-        assert sched.config.window_ms == 3.0
-        assert sched.config.flight_ring == 8
-    finally:
-        sched.close()
+
+
+def test_unknown_policy_keyword_is_refused():
+    with pytest.raises(TypeError):
+        VerifierScheduler(NativeBatchVerifier(), adaptive=True)
+    with pytest.raises(TypeError):
+        scheduler_for(NativeBatchVerifier(), slo_p99_ms=20)
 
 
 # -- flight ring loss signal ----------------------------------------------
@@ -114,67 +111,6 @@ def test_flight_ring_size_and_dropped_counter():
         sched.close()
 
 
-# -- closed-loop controller ----------------------------------------------
-
-def test_adaptive_controller_shrinks_and_grows_on_burn():
-    cfg = SchedulerConfig(window_ms=4.0, max_batch=64, adaptive=True,
-                          min_window_ms=0.5, max_window_ms=8.0,
-                          min_target_rows=4, adapt_recent=4)
-    sched = VerifierScheduler(NativeBatchVerifier(), config=cfg)
-    burn = [2.0]
-    sched.burn_probe = lambda: (burn[0], burn[0])
-    try:
-        def window(salt: int) -> None:
-            entries = _sign_entries(3, salt=salt)
-            # under the scheduler's (re-entrant) lock, so that a flush
-            # deadline cannot fall between two submits on a loaded
-            # machine and make two windows of one
-            with sched._lock:
-                futs = [sched.submit(h, s) for h, s in entries]
-            sched.kick()
-            for f in futs:
-                assert f.result(30) is not None
-
-        for k in range(3):      # burning: shrink every recorded window
-            window(k + 1)
-        st = sched.stats()
-        assert st["adapt_decisions"] == 3
-        assert st["window_ms"] == 0.5         # 4 -> 2 -> 1 -> clamp 0.5
-        assert st["target_rows"] == 8         # 64 -> 32 -> 16 -> 8
-
-        burn[0] = 0.0           # calm: grow back toward occupancy
-        for k in range(3):
-            window(k + 10)
-        st = sched.stats()
-        assert st["adapt_decisions"] == 6
-        assert st["window_ms"] > 0.5
-        assert st["target_rows"] == 64        # 8 -> 16 -> 32 -> 64
-    finally:
-        sched.close()
-
-
-def test_adaptive_derived_burn_without_probe():
-    # no probe attached: burn derives from flight p99 vs slo_p99_ms; an
-    # absurdly tight objective must drive the deadline to its floor
-    cfg = SchedulerConfig(window_ms=4.0, max_batch=64, adaptive=True,
-                          slo_p99_ms=1e-4, min_window_ms=0.25,
-                          min_target_rows=4)
-    sched = VerifierScheduler(NativeBatchVerifier(), config=cfg)
-    try:
-        for k in range(5):
-            entries = _sign_entries(2, salt=k + 20)
-            with sched._lock:  # one window, whatever the machine's load
-                futs = [sched.submit(h, s) for h, s in entries]
-            sched.kick()
-            for f in futs:
-                assert f.result(30) is not None
-        st = sched.stats()
-        assert st["adapt_decisions"] == 5
-        assert st["window_ms"] == 0.25
-    finally:
-        sched.close()
-
-
 # -- hedged re-dispatch ---------------------------------------------------
 
 @pytest.mark.parametrize("entry", ["futures", "window"])
@@ -183,9 +119,8 @@ def test_hedge_bit_identical_results_and_exactly_once_billing(entry):
     alike: the hedge's winner answers them, the healed loser finds
     every holder answered and changes nothing."""
     mesh = NativeMeshVerifier(2)
-    cfg = SchedulerConfig(window_ms=10_000.0, hedge=True,
-                          hedge_floor_ms=10.0, hedge_poll_ms=2.0)
-    sched = VerifierScheduler(mesh, config=cfg)
+    sched = VerifierScheduler(mesh, window_ms=10_000.0, hedge=True,
+                              hedge_floor_ms=10.0, hedge_poll_ms=2.0)
     release = threading.Event()
     victim = mesh.device_targets()[0]
     orig = victim.recover_addresses
@@ -230,7 +165,8 @@ def test_hedge_bit_identical_results_and_exactly_once_billing(entry):
         st = sched.stats()
         assert st["rows"] == rows_before
         assert len(sched.flights()) == flights_before
-        # one hold for the window, one a future; none for the loser
+        # one hold for the window, one a ``submit`` (a one-row window);
+        # none for the loser
         assert st["resolve_holds"] == (1 if entry == "window"
                                        else len(entries))
         if entry == "window":
@@ -260,9 +196,8 @@ def test_hedge_loser_cancelled_before_execution():
     # the straggler monitor reads its floor live: it is held off (an
     # hour) until all three windows are where the scenario needs them,
     # so a slow machine cannot hedge A before B is placed
-    cfg = SchedulerConfig(window_ms=10_000.0, hedge=True,
-                          hedge_floor_ms=3.6e6, hedge_poll_ms=2.0)
-    sched = VerifierScheduler(mesh, config=cfg)
+    sched = VerifierScheduler(mesh, window_ms=10_000.0, hedge=True,
+                              hedge_floor_ms=3.6e6, hedge_poll_ms=2.0)
     gates = [threading.Event(), threading.Event()]
     served: list[tuple[int, int]] = []
     for lane_i, tgt in enumerate(mesh.device_targets()):
@@ -305,7 +240,7 @@ def test_hedge_loser_cancelled_before_execution():
         # onto their siblings' queues, then release lane 1 alone: every
         # future must resolve without lane 0
         with sched._lock:
-            sched.config.hedge_floor_ms = 10.0
+            sched.hedge_floor_ms = 10.0
         _await(lambda: sched._stats["hedges"] >= 3)
         gates[1].set()
         got = [f.result(30) for f in futs]
@@ -333,8 +268,7 @@ def test_hedge_loser_cancelled_before_execution():
 
 def test_consensus_preempts_bulk_at_placement():
     mesh = NativeMeshVerifier(2)
-    cfg = SchedulerConfig(window_ms=10_000.0, hedge=False)
-    sched = VerifierScheduler(mesh, config=cfg)
+    sched = VerifierScheduler(mesh, window_ms=10_000.0, hedge=False)
     gates = [threading.Event(), threading.Event()]
     for lane_i, tgt in enumerate(mesh.device_targets()):
         orig = tgt.recover_addresses

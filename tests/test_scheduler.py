@@ -247,8 +247,9 @@ def test_consensus_call_flies_as_one_window_past_pending_bulk_rows():
     assert st["flush_deadline"] == 0
     assert (st["window_submits_consensus"], st["window_rows_consensus"]) \
         == (1, 32)
-    assert st["window_submits_bulk"] == st["window_rows_bulk"] == 0
-    assert st["window_submits"] == 1 and st["window_rows"] == 32
+    # each bulk ``submit`` was a one-row window
+    assert st["window_submits_bulk"] == st["window_rows_bulk"] == 5
+    assert st["window_submits"] == 6 and st["window_rows"] == 37
     sched.close()
 
 
@@ -338,6 +339,67 @@ def test_a_row_and_a_window_share_one_computed_row(first):
     sched.close()
 
 
+@pytest.mark.parametrize("case", ["cache_hit", "malformed",
+                                  "dedup_promotes", "closed"])
+def test_single_row_submit_is_a_one_row_window(case):
+    """``submit`` is the window entry with one row behind a future: the
+    same row in the same state gets the same answer and moves every
+    counter of ``stats()`` as a one-row window does,
+    ``window_submits_<class>`` included."""
+    row, other = _sign_entries(2, salt=34)
+    if case == "malformed":
+        row = (row[0], row[1][:10])
+    prio = "consensus" if case == "dedup_promotes" else "bulk"
+
+    def one_row(sched, entry):
+        if entry == "submit":
+            fut = sched.submit(*row, priority=prio)
+            sched.kick()
+            return fut.result(30)
+        if case == "malformed":
+            # no array holds a malformed row: the list facade takes it
+            return sched.recover_signers([row], priority=prio)[0]
+        win = sched.submit_window(*_arrays([row]), priority=prio)
+        sched.kick()
+        return win.result(30)[0]
+
+    answers, deltas = [], []
+    for entry in ("submit", "window"):
+        sched = VerifierScheduler(NativeBatchVerifier(), window_ms=10_000.0)
+        earlier = None
+        if case == "cache_hit":
+            sched.recover_signers([row, other])
+        elif case == "dedup_promotes":
+            earlier = sched.submit_window(*_arrays([row, other]))  # bulk
+        elif case == "closed":
+            sched.close()
+        before = sched.stats()
+        answers.append(one_row(sched, entry))
+        if earlier is not None:
+            assert earlier.result(30) == _host_model([row, other])
+            assert [f["klass"] for f in sched.flights()] == ["consensus"]
+        sched.close()  # the dispatch thread's last counters are in
+        after = sched.stats()
+        deltas.append({k: after[k] - before[k] for k in before
+                       if type(before[k]) is int})
+    assert answers[0] == answers[1] == _host_model([row])[0]
+    assert deltas[0] == deltas[1]
+    moved = {k: v for k, v in deltas[0].items() if v}
+    assert moved.pop("window_submits_" + prio) == 1
+    assert moved.pop("window_rows_" + prio) == 1
+    assert moved.pop("window_submits") == moved.pop("window_rows") == 1
+    assert moved == {
+        "cache_hit": {"cache_hits": 1, "cache_served_rows": 1},
+        "malformed": {"invalid": 1},
+        "dedup_promotes": {
+            "cache_misses": 1, "coalesced_rows": 1, "kicks": 1,
+            "flush_kick": 1, "batches": 1, "rows": 2, "bucket_rows": 16,
+            "resolve_holds": 2, "cached_entries": 2, "pending": -2,
+            "flight_windows": 1},
+        "closed": {"cache_misses": 1, "cached_entries": 1},
+    }[case]
+
+
 def test_rows_and_windows_from_many_threads_lose_no_update():
     """More threads than cores, half of them through window entries and
     half through per-row ``submit``, over overlapping rows, with the
@@ -393,7 +455,9 @@ def test_rows_and_windows_from_many_threads_lose_no_update():
     assert st["cache_hits"] + st["cache_misses"] == \
         calls * (len(entries) - malformed)
     assert st["cache_misses"] == st["rows"] + st["coalesced_rows"]
-    assert st["window_submits"] == calls // 2
+    # half the calls were one window each, the other half one one-row
+    # window a ``submit``
+    assert st["window_submits"] == calls // 2 * (1 + len(entries))
     assert st["pending"] == 0
 
 
@@ -434,7 +498,7 @@ class _Dead(BaseException):
 @pytest.mark.parametrize("how", ["computed", "diverted", "died"])
 def test_a_mixed_batch_answers_every_holder_exactly_once(how):
     """One batch whose rows belong to two windows, to plain ``submit``
-    futures, to a row three callers share, to an invalid signature and
+    futures (one-row windows), to a row three callers share, to an invalid signature and
     to two holders that a hedge's winner had answered before: every
     holder gets, once, what setting it row by row gave; a window takes
     one hold for all of its rows.  A batch that died hands its error to
@@ -451,7 +515,8 @@ def test_a_mixed_batch_answers_every_holder_exactly_once(how):
     # bit for bit; another one shows that nothing is written twice)
     won = b"\x5a" * 20
     win_a._set_rows((2,), (won,))
-    futs[2].set_result(won)
+    (win_6, idx_6), = sched._pending[e[6]][0]  # what futs[2] stands for
+    win_6._set_rows((idx_6,), (won,))
     with sched._lock:
         batch = [(k, sched._pending.pop(k)) for k in list(sched._pending)]
     assert len(batch) == 8 and sched.stats()["coalesced_rows"] == 2
@@ -479,8 +544,9 @@ def test_a_mixed_batch_answers_every_holder_exactly_once(how):
         assert win_b.result(0) == [m[3], m[1], m[4]]
         assert [f.result(0) for f in futs[:2]] == [m[5], m[1]]
         st = sched.stats()
-        # two windows and the two plain futures that were still open
-        assert st["resolve_holds"] == 4
+        # two windows and the three one-row windows behind the plain
+        # futures (one had its answer: its hold changed nothing)
+        assert st["resolve_holds"] == 5
         assert (st["batches"], st["rows"]) == (1, 8)
         assert st["device_errors"] == (how == "diverted")
         flight = sched.flights()[-1]

@@ -200,12 +200,12 @@ def test_a_pool_flush_is_found_in_the_profilers_host_plane(tmp_path):
 
 def test_flights_carry_resolve_ms_and_a_run_of_300_windows_drops_none():
     from eges_tpu.crypto import secp256k1 as host
-    from eges_tpu.crypto.scheduler import SchedulerConfig, VerifierScheduler
+    from eges_tpu.crypto.scheduler import VerifierScheduler
     from eges_tpu.crypto.verify_host import NativeBatchVerifier
 
-    assert SchedulerConfig().flight_ring == 4096
     sig = host.ecdsa_sign(b"\x11" * 32, b"\x07" * 32)
     sched = VerifierScheduler(NativeBatchVerifier())
+    assert sched.stats()["flight_capacity"] == 4096
     try:
         for k in range(300):
             # fresh rows every time (the cache answers a repeated one),
