@@ -34,7 +34,8 @@ def ensure_built() -> str:
     lib = _lib_path()
     native = os.path.dirname(lib)
     srcs = [os.path.join(native, f) for f in (
-        "secp256k1.cpp", "keccak.cpp", "election.cpp", "Makefile")]
+        "secp256k1.cpp", "keccak.cpp", "election.cpp", "ingress.cpp",
+        "Makefile")]
     if os.path.exists(lib) and all(
             os.path.getmtime(lib) >= os.path.getmtime(s) for s in srcs):
         return lib
@@ -68,10 +69,11 @@ def _load():
     lib.geec_ec_recover_batch.argtypes = [
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64,
         ctypes.c_char_p, ctypes.c_char_p]
-    try:  # variable-length keccak batch; absent in old builds
-        lib.geec_keccak256_multi.argtypes = [
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_uint64, ctypes.c_char_p]
+    try:  # window decoder (native/ingress.cpp); absent in old builds
+        lib.geec_decode_txn_window.argtypes = (
+            [ctypes.c_char_p, ctypes.c_void_p, ctypes.c_uint64]
+            + [ctypes.c_void_p] * 8)
+        lib.geec_decode_txn_window.restype = ctypes.c_int
     except AttributeError:
         pass
     try:  # election component (native/election.cpp); absent in old builds
@@ -98,19 +100,48 @@ def keccak256(data: bytes) -> bytes:
     return out.raw
 
 
-def keccak256_multi(data: bytes, offsets) -> bytes:
-    """``n`` variable-length messages packed back-to-back in ``data``
-    (message ``i`` spans ``offsets[i]..offsets[i+1]``; ``offsets`` has
-    n+1 entries) -> flat ``n*32`` digest bytes, ONE library call.  The
-    columnar ingest decoder's whole-window digest path; raises
-    AttributeError on libraries built before the entry existed (callers
-    fall back to per-message :func:`keccak256`)."""
+# (argument, dtype, bytes a row) of geec_decode_txn_window's outputs, in
+# the call's order
+_WINDOW_COLUMNS = (("decoded", "bool", 1), ("valid", "bool", 1),
+                   ("txhash", "uint8", 32), ("sighash", "uint8", 32),
+                   ("sig", "uint8", 65), ("nonce", "uint64", 8),
+                   ("gas_price", "uint64", 8), ("spans", "uint32", 80))
+
+
+def has_decode_window() -> bool:
+    lib = _load()
+    return lib is not None and hasattr(lib, "geec_decode_txn_window")
+
+
+def decode_txn_window(data: bytes, offsets, **columns) -> None:
+    """One gossip window of txn frames, packed back to back in ``data``
+    (frame ``i`` spans ``offsets[i]..offsets[i+1]``; ``offsets`` is a
+    uint64 numpy array of n+1 entries, an empty span a dead frame),
+    scanned, ruled on and digested in ONE library call that holds no
+    GIL and writes the rows straight into ``columns``: the zeroed,
+    C-contiguous numpy arrays :data:`_WINDOW_COLUMNS` names (rows that
+    fail are left as they came).  ``native/ingress.cpp`` has the rules;
+    raises AttributeError on a library built before the entry existed."""
     lib = _load()
     n = len(offsets) - 1
-    out = ctypes.create_string_buffer(32 * n)
-    offs = (ctypes.c_uint64 * (n + 1))(*offsets)
-    lib.geec_keccak256_multi(data, offs, n, out)
-    return out.raw
+    # the pointers are only as good as these checks: the library trusts
+    # the spans and the row counts it is given
+    if (offsets.dtype != "uint64" or not offsets.flags.c_contiguous
+            or n < 0 or int(offsets[0]) != 0
+            or int(offsets[-1]) != len(data)
+            or (offsets[1:] < offsets[:-1]).any()):
+        raise ValueError("offsets do not span the window's bytes")
+    ptrs = []
+    for name, dtype, width in _WINDOW_COLUMNS:
+        col = columns[name]
+        if (col.dtype != dtype or col.nbytes != n * width
+                or not col.flags.c_contiguous
+                or not col.flags.writeable):
+            raise ValueError(f"column {name!r} is not n x {width} bytes "
+                             f"of {dtype}")
+        ptrs.append(col.ctypes.data)
+    if lib.geec_decode_txn_window(data, offsets.ctypes.data, n, *ptrs):
+        raise MemoryError("native window decoder found no scratch memory")
 
 
 def ec_recover(msg_hash: bytes, sig: bytes) -> bytes:
@@ -188,3 +219,55 @@ def self_check() -> None:
     assert sig == ps.ecdsa_sign(msg, priv), "sign mismatch vs golden model"
     assert ec_recover(msg, sig) == ps.privkey_to_pubkey(priv)
     assert ec_verify(msg, sig[:64], ps.privkey_to_pubkey(priv))
+    _check_decode_window()
+
+
+def _check_decode_window() -> None:
+    """``geec_decode_txn_window`` on a few fixed frames, valid and
+    malformed, against answers the golden Keccak gives (this module
+    sits below the decoder's Python oracle, which the tier-1
+    differential test holds it to case by case)."""
+    import numpy as np
+
+    from eges_tpu.crypto import keccak as pk
+
+    # nonce 9, gas price 2**70, gas 21000, to 00..13, value 7, "chk"
+    body = bytes.fromhex("0989400000000000000000825208940001020304050607"
+                         "08090a0b0c0d0e0f10111213078363686b")
+    r, s = pk.keccak256(b"r"), pk.keccak256(b"s")
+
+    def frame(tail: bytes) -> bytes:
+        p = body + tail
+        return (bytes([0xC0 + len(p)]) if len(p) < 56
+                else b"\xf8" + bytes([len(p)])) + p
+
+    good = frame(b"\x80\x81\xbe\xa0" + r + b"\xa0" + s)  # v 190: chain 77
+    frames = [good, frame(b"\x80\x1c\xa0" + r + b"\xa0" + s),  # v 28
+              frame(b"\x80\x80\x80\x80"),   # unsigned
+              frame(b"\x80\x1e\x01\x01"),   # v 30 names no chain
+              b"", good[:-1], good + b"\x00", b"\xc0",
+              b"\xff" * 9 + good, good[:3] + b"\xbf" + good[4:]]
+    pre = [b"\xeb" + body + b"\x4d\x80\x80", b"\xe8" + body]
+    n = len(frames)
+    offsets = np.zeros((n + 1,), np.uint64)
+    np.cumsum([len(f) for f in frames], dtype=np.uint64, out=offsets[1:])
+    cols = {name: np.zeros((n, width // np.dtype(dtype).itemsize), dtype)
+            for name, dtype, width in _WINDOW_COLUMNS}
+    decode_txn_window(b"".join(frames), offsets, **cols)
+    assert cols["decoded"].ravel().tolist() == [True] * 4 + [False] * 6
+    assert cols["valid"].ravel().tolist() == [True] * 2 + [False] * 8
+    for i in range(n):
+        ok = i < 4
+        assert bytes(cols["txhash"][i]) == (
+            pk.keccak256(frames[i]) if ok else bytes(32)), "txhash"
+        assert cols["nonce"][i, 0] == (9 if ok else 0)
+        assert cols["gas_price"][i, 0] == (2**64 - 1 if ok else 0)
+        assert bytes(cols["sig"][i]) == (
+            r + s + b"\x01" if i < 2 else bytes(65)), "sig"
+        assert bytes(cols["sighash"][i]) == (
+            pk.keccak256(pre[i]) if i < 2 else bytes(32)), "sighash"
+    # the payload spans of row 0: the ten fields back out of the frame
+    fields = [good[a:b] for a, b in cols["spans"][0].reshape(10, 2).tolist()]
+    assert fields == [b"\x09", bytes.fromhex("400000000000000000"),
+                      b"\x52\x08", bytes(range(20)), b"\x07", b"chk", b"",
+                      b"\xbe", r, s], "spans"

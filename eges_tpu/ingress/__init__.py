@@ -108,9 +108,10 @@ def admit_locals(pool, txns) -> None:
 
 def decode_txn_window(frames):
     """Decode a whole window of raw txn frames into columnar arrays
-    (``ingress.columnar.TxColumns``): one canonical scan + one keccak
-    per frame, sighash preimages sliced straight out of the frame
-    bytes, ``Transaction`` construction deferred to admission time."""
+    (``ingress.columnar.TxColumns``) in one native call that holds no
+    GIL: one canonical scan + both keccaks per frame, sighash preimages
+    sliced straight out of the frame bytes, ``Transaction``
+    construction deferred to admission time."""
     from eges_tpu.ingress.columnar import decode_window
 
     return decode_window(frames)

@@ -41,8 +41,9 @@ import numpy as np
 
 from eges_tpu.core import rlp
 from eges_tpu.core.types import Transaction
+from eges_tpu.crypto import native
 from eges_tpu.crypto.keccak import keccak256
-from eges_tpu.utils import tracing
+from eges_tpu.utils import metrics, tracing
 
 # Hard per-frame byte gate, applied BEFORE any parsing: an oversized
 # frame must die without costing a decode or even a hash (the node's
@@ -69,7 +70,8 @@ class TxColumns:
     """
 
     __slots__ = ("n", "sighash", "sig", "txhash", "gas_price", "nonce",
-                 "decoded", "valid", "hashes", "_items", "_txns")
+                 "decoded", "valid", "hashes", "_data", "_offsets",
+                 "_spans", "_txns")
 
     def __init__(self, n: int):
         self.n = n
@@ -83,7 +85,11 @@ class TxColumns:
         # python-object mirror of ``txhash`` for set-based dedup (the
         # pool's ``_known`` difference is one C-level set op over these)
         self.hashes: list[bytes | None] = [None] * n
-        self._items: list = [None] * n  # parsed RLP items, decode path
+        # decode path only (_pack): the window's frames packed back to
+        # back (frame i at _offsets[i].._offsets[i+1], a dead frame an
+        # empty span) and each decoded row's ten payload spans (start,
+        # end), relative to its frame — all txn() needs of the wire
+        self._data = self._offsets = self._spans = None
         self._txns: list = [None] * n   # materialized / original txns
 
     def txn(self, i: int) -> Transaction:
@@ -96,14 +102,16 @@ class TxColumns:
             # r/s/v widths, `to` length), so int.from_bytes over the
             # raw payloads builds the identical object without a
             # second decode pass
-            it = self._items[i]
+            base, data = int(self._offsets[i]), self._data
+            it = [data[base + a:base + b]
+                  for a, b in self._spans[i].tolist()]
             t = Transaction(
                 nonce=int.from_bytes(it[0], "big"),
                 gas_price=int.from_bytes(it[1], "big"),
                 gas_limit=int.from_bytes(it[2], "big"),
-                to=bytes(it[3]) if it[3] else None,
+                to=it[3] or None,
                 value=int.from_bytes(it[4], "big"),
-                payload=bytes(it[5]),
+                payload=it[5],
                 is_geec=bool(int.from_bytes(it[6], "big")),
                 v=int.from_bytes(it[7], "big"),
                 r=int.from_bytes(it[8], "big"),
@@ -221,53 +229,38 @@ def _list_header(n: int) -> bytes:
     return bytes([0xC0 + 55 + len(lb)]) + lb
 
 
-def _dispatch_keccak_many():
-    """Prefer the native variable-length batch digest (ONE FFI call
-    per window instead of one per hash); per-message :func:`keccak256`
-    stays the golden fallback for old library builds."""
-    try:
-        from eges_tpu.crypto import native
-
-        if native.available() and native.keccak256_multi(
-                b"ab", (0, 1, 2)) == keccak256(b"a") + keccak256(b"b"):
-            return native.keccak256_multi
-    # analysis: allow-swallow(optional native-accel probe; falls back to python)
-    except Exception:
-        pass
-    return None
-
-
-_KECCAK_MULTI = _dispatch_keccak_many()
-
-
-def _keccak_many(msgs: list) -> bytes:
-    """Flat ``len(msgs)*32`` digest bytes for a list of messages."""
-    if not msgs:
-        return b""
-    if _KECCAK_MULTI is None:
-        return b"".join(keccak256(m) for m in msgs)
-    offsets = [0]
-    push = offsets.append
-    total = 0
-    for m in msgs:
-        total += len(m)
-        push(total)
-    return _KECCAK_MULTI(b"".join(msgs), offsets)
+def _pack(frames: list) -> TxColumns:
+    """A window's columns, empty but for its bytes: the per-frame byte
+    gate BEFORE any copy (an empty or oversized frame contributes an
+    empty span, so it dies without a parse or a hash), then one join
+    and the offsets from one cumsum."""
+    n = len(frames)
+    cols = TxColumns(n)
+    kept = [f if 0 < len(f) <= FRAME_MAX_BYTES else b"" for f in frames]
+    cols._data = b"".join(kept)  # bounded-by: WINDOW_MAX_ROWS * FRAME_MAX_BYTES (gate above, row cap in decode_window)
+    cols._offsets = np.zeros((n + 1,), np.uint64)
+    np.cumsum([len(f) for f in kept], dtype=np.uint64,
+              out=cols._offsets[1:])
+    cols._spans = np.zeros((n, 10, 2), np.uint32)
+    if int(cols._offsets[-1]) != len(cols._data):
+        raise ValueError("a frame's len() is not its size in bytes")
+    return cols
 
 
 def decode_window(frames) -> TxColumns:  # ingress-entry:bounded
     """Vectorized envelope/signature extraction: a whole window of raw
     txn frames (length-capped by the transport) into one
-    :class:`TxColumns` — O(1) Python-level transitions per window on
-    the downstream path instead of O(rows).
+    :class:`TxColumns` — O(1) Python-level transitions per window, here
+    and on the downstream path, instead of O(rows).
 
-    Two passes.  Scan: per frame the byte gate (oversized frames die
-    pre-decode, pre-hash), one canonical scan recording field spans,
-    and ``signature_parts``'s exact v/r/s rules — the sighash preimage
-    is sliced straight out of the frame (list header + first six field
-    encodings + EIP155 suffix), no re-encode, no ``Transaction``.
-    Fill: ONE batched keccak call digests every txhash and sighash in
-    the window, then the columns fill with whole-array writes.  Decode
+    The byte gate runs first, in Python and before any copy (oversized
+    frames die pre-decode, pre-hash).  Then ONE native call
+    (``native/ingress.cpp``), which holds no GIL, does per frame what
+    :func:`_decode_frames` does: one canonical scan recording field
+    spans, ``signature_parts``'s exact v/r/s rules, the sighash
+    preimage sliced straight out of the frame (list header + first six
+    field encodings + EIP155 suffix; no re-encode, no ``Transaction``)
+    and both digests, written into the columns as they stand.  Decode
     or signature failures mask the row out instead of raising
     (mask-don't-raise, the batch contract); invalid-signature rows
     never pay a sighash keccak."""
@@ -276,34 +269,51 @@ def decode_window(frames) -> TxColumns:  # ingress-entry:bounded
         raise ValueError("window exceeds %d rows — chunk the caller"
                          % WINDOW_MAX_ROWS)
     with tracing.DEFAULT.span("ingress.decode", rows=len(frames)):
-        return _decode_frames(frames)
+        cols = _DECODE(frames)
+    metrics.DEFAULT.counter("ingress.decode_rows").inc(len(frames))
+    if _DECODE is _decode_native:
+        metrics.DEFAULT.counter("ingress.decode_native_rows").inc(
+            len(frames))
+    return cols
+
+
+def _decode_native(frames: list) -> TxColumns:
+    """:func:`decode_window`'s one library call over a window it has
+    capped."""
+    cols = _pack(frames)
+    native.decode_txn_window(
+        cols._data, cols._offsets, decoded=cols.decoded, valid=cols.valid,
+        txhash=cols.txhash, sighash=cols.sighash, sig=cols.sig,
+        nonce=cols.nonce, gas_price=cols.gas_price, spans=cols._spans)
+    th = cols.txhash.tobytes()
+    cols.hashes = [th[32 * i:32 * i + 32] if ok else None
+                   for i, ok in enumerate(cols.decoded.tolist())]
+    return cols
 
 
 def _decode_frames(frames: list) -> TxColumns:
-    """:func:`decode_window`'s two passes over a window it has capped."""
-    cols = TxColumns(len(frames))
-    dec_rows: list[int] = []    # row index per decoded frame
-    dec_msgs: list[bytes] = []  # the frame bytes (txhash preimage)
-    nonces: list[int] = []
-    prices: list[int] = []
-    sig_rows: list[int] = []    # row index per signature-valid row
-    sig_blobs: list[bytes] = []  # 65-byte wire sig per valid row
-    sig_pre: list[bytes] = []   # sighash preimage per valid row
-    for i, frame in enumerate(frames):
-        if not frame or len(frame) > FRAME_MAX_BYTES:
-            continue  # oversized/empty: dead before any parse or copy
-        frame = bytes(frame)  # bounded-by: len(frame) <= FRAME_MAX_BYTES (guard above)
+    """The same window in Python, a frame at a time: the oracle the
+    native decoder is held to (tests/test_columnar_ingest.py), and the
+    fallback for a checkout whose library lacks it."""
+    cols = _pack(frames)
+    data, offsets = cols._data, cols._offsets.tolist()
+    for i in range(cols.n):
+        frame = data[offsets[i]:offsets[i + 1]]
+        if not frame:
+            continue  # oversized/empty: dead before any parse
         try:
             items, spans = _scan_txn_frame(frame)
         except rlp.RLPError:
             continue
-        cols._items[i] = items
-        dec_rows.append(i)
-        dec_msgs.append(frame)
-        nonces.append(min(int.from_bytes(items[0], "big"),
-                          (1 << 64) - 1))
-        prices.append(min(int.from_bytes(items[1], "big"),
-                          (1 << 64) - 1))
+        cols.decoded[i] = True
+        cols.hashes[i] = h = keccak256(frame)
+        cols.txhash[i] = np.frombuffer(h, np.uint8)
+        # a payload ends where its encoding does
+        cols._spans[i] = [(end - len(it), end)
+                          for it, (_, end) in zip(items, spans)]
+        cols.nonce[i] = min(int.from_bytes(items[0], "big"), (1 << 64) - 1)
+        cols.gas_price[i] = min(int.from_bytes(items[1], "big"),
+                                (1 << 64) - 1)
         # signature_parts()'s exact v/r/s rules, span-sliced
         v = int.from_bytes(items[7], "big")
         protected = v not in (27, 28) and v != 0
@@ -316,35 +326,45 @@ def _decode_frames(frames: list) -> TxColumns:
         if not (0 <= recid <= 3 and 0 < r < _SECP_MAX
                 and 0 < s < _SECP_MAX):
             continue
-        sig_rows.append(i)
-        sig_blobs.append(r.to_bytes(32, "big") + s.to_bytes(32, "big")
-                         + bytes([recid]))
+        cols.valid[i] = True
+        cols.sig[i] = np.frombuffer(
+            r.to_bytes(32, "big") + s.to_bytes(32, "big") + bytes([recid]),
+            np.uint8)
         body = frame[spans[0][0]:spans[5][1]]
         if cid is not None:
             body = body + rlp.encode(cid) + b"\x80\x80"
-        sig_pre.append(_list_header(len(body)) + body)
-    # one digest batch for the whole window: txhashes first, sighashes
-    # after — sliced back apart by count
-    digests = _keccak_many(dec_msgs + sig_pre)
-    n_dec = len(dec_rows)
-    if n_dec:
-        rows = np.asarray(dec_rows, np.int64)
-        cols.decoded[rows] = True
-        th = digests[:32 * n_dec]
-        cols.txhash[rows] = np.frombuffer(th, np.uint8).reshape(-1, 32)
-        hashes = cols.hashes
-        for k, i in enumerate(dec_rows):
-            hashes[i] = th[32 * k:32 * k + 32]
-        cols.nonce[rows] = nonces
-        cols.gas_price[rows] = prices
-    if sig_rows:
-        rows = np.asarray(sig_rows, np.int64)
-        cols.valid[rows] = True
-        cols.sig[rows] = np.frombuffer(b"".join(sig_blobs),
-                                       np.uint8).reshape(-1, 65)
-        cols.sighash[rows] = np.frombuffer(digests[32 * n_dec:],
-                                           np.uint8).reshape(-1, 32)
+        cols.sighash[i] = np.frombuffer(
+            keccak256(_list_header(len(body)) + body), np.uint8)
     return cols
+
+
+def _same_columns(a: TxColumns, b: TxColumns) -> bool:
+    return a.hashes == b.hashes and all(
+        np.array_equal(getattr(a, name), getattr(b, name))
+        for name in ("decoded", "valid", "txhash", "sighash", "sig",
+                     "nonce", "gas_price", "_spans"))
+
+
+def _dispatch_decode():
+    """Which rung :func:`decode_window` runs, decided once: the native
+    window decoder where the library has it and it answers a known
+    window (a protected row, an unsigned one, a malformed one) as the
+    oracle does, else the oracle itself."""
+    try:
+        probe = [Transaction(nonce=1, gas_price=2, gas_limit=21000,
+                             to=bytes(20), value=3, payload=b"probe",
+                             v=37, r=5, s=6).encode(),
+                 Transaction(nonce=1 << 70).encode(), b"\xc1\x80"]
+        if native.has_decode_window() and _same_columns(
+                _decode_native(probe), _decode_frames(probe)):
+            return _decode_native
+    # analysis: allow-swallow(optional native-accel probe; falls back to python)
+    except Exception:
+        pass
+    return _decode_frames
+
+
+_DECODE = _dispatch_decode()
 
 
 def columns_from_txns(txns) -> TxColumns:  # ingress-entry:bounded

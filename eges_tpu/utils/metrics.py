@@ -39,6 +39,9 @@ METRIC_FAMILIES = frozenset({
     "consensus.geec_txn_dropped", "consensus.ingress_oversized",
     "consensus.phase_seconds", "consensus.reg_req_dropped",
     "consensus.sealed", "membership.min_ttl", "membership.size",
+    # ingress/columnar.py — frames handed to decode_window, and those
+    # that went through the native window decoder
+    "ingress.decode_native_rows", "ingress.decode_rows",
     # net/ + sim/simnet.py
     "net.dead_letters", "net.direct_bytes", "net.direct_msgs",
     "net.gossip_bytes", "net.gossip_msgs", "net.peer_count",
@@ -147,6 +150,11 @@ METRIC_HELP = {
     "consensus.sealed": "Blocks sealed by this node.",
     "membership.min_ttl": "Minimum TTL across registered members.",
     "membership.size": "Registered committee members.",
+    "ingress.decode_native_rows": (
+        "Frames of columnar ingest windows that went through the "
+        "native window decoder."),
+    "ingress.decode_rows": (
+        "Frames handed to the columnar window decoder."),
     "net.dead_letters": "Messages dropped with no deliverable peer.",
     "net.direct_bytes": "Bytes sent over the direct (point-to-point) plane.",
     "net.direct_msgs": "Messages sent over the direct plane.",

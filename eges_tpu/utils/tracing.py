@@ -59,7 +59,8 @@ _UNSET = object()
 # label has a closed vocabulary.  Other names stay allowed; they carry
 # no label.  PERF.md section 3 copies this table.
 SPANS = {
-    "ingress.decode": ((), "a window of frames to columns, batch Keccak"),
+    "ingress.decode": ((), "a window of frames to columns: one native "
+                           "call that holds no GIL"),
     "txpool.ingest": ((), "dedup against the known set, queueing; the one "
                           "span that begins a trace (root=True)"),
     "txpool.flush": ((), "hand the queue to the verifier, wait, admit"),

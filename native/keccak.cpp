@@ -94,16 +94,4 @@ void geec_keccak256_batch(const uint8_t* data, uint64_t n, uint64_t msg_len,
     geec_keccak256(data + i * msg_len, msg_len, out + i * 32);
 }
 
-// Variable-length batch: n messages packed back-to-back in `data`,
-// message i spanning [offsets[i], offsets[i+1]) — offsets holds n+1
-// entries.  The columnar ingest decoder digests a whole gossip window
-// (one txhash per frame plus one sighash per signed row) in a single
-// library call instead of paying the FFI boundary per digest.
-void geec_keccak256_multi(const uint8_t* data, const uint64_t* offsets,
-                          uint64_t n, uint8_t* out /* n*32 */) {
-  for (uint64_t i = 0; i < n; i++)
-    geec_keccak256(data + offsets[i], offsets[i + 1] - offsets[i],
-                   out + i * 32);
-}
-
 }  // extern "C"

@@ -2,8 +2,9 @@
 
 Covers: the vectorized window decoder (``eges_tpu/ingress/columnar.py``)
 against the scalar ``Transaction.decode`` oracle — per-field columns,
-malformed/non-canonical frame rejection, the native keccak-multi
-fallback — the columnar pool admission path
+malformed/non-canonical frame rejection, the native window decoder
+against its Python oracle case by case, the fallback without it — the
+columnar pool admission path
 (``TxPool.add_remotes_window``) against the legacy scalar path over the
 same stream (identical stats, admission order and ledger billing), the
 scheduler's window submit, the invalid-signature flood reject path
@@ -16,17 +17,22 @@ dumps.
 import dataclasses
 import json
 import os
+import random
 import sys
+import threading
 
 import numpy as np
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from eges_tpu.core import rlp
 from eges_tpu.core.txpool import TxPool
 from eges_tpu.core.rlp import RLPError
 from eges_tpu.core.types import Transaction
+from eges_tpu.crypto.keccak import keccak256
 from eges_tpu.ingress import (admit_remotes, admit_remotes_window,
                               decode_txn_window)
 from eges_tpu.ingress import columnar
@@ -120,18 +126,329 @@ def test_decode_window_rejects_exactly_what_scalar_decode_rejects():
             f"dropped: {frame.hex()}")
 
 
-def test_decode_window_without_native_keccak_multi_is_identical():
+def test_decode_window_without_the_native_decoder_is_identical(monkeypatch):
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
     frames = [t.encode() for t in _mixed_stream(20)]
     ref = decode_txn_window(frames)
-    saved = columnar._KECCAK_MULTI
-    columnar._KECCAK_MULTI = None  # force the pure-Python digest loop
+    # force the Python rung, as a library without the entry would
+    monkeypatch.setattr(columnar, "_DECODE", columnar._decode_frames)
+    rows = metrics.counter("ingress.decode_rows").value
+    native_rows = metrics.counter("ingress.decode_native_rows").value
+    got = decode_txn_window(frames)
+    assert columnar._same_columns(got, ref)
+    assert metrics.counter("ingress.decode_rows").value == rows + 20
+    assert metrics.counter("ingress.decode_native_rows").value == \
+        native_rows
+
+
+# -- the native window decoder vs its oracle, a case per kind -------------
+
+_R = int.from_bytes(bytes(range(7, 39)), "big")
+_S = int.from_bytes(bytes(range(9, 41)), "big")
+
+
+def _tx(**kw) -> Transaction:
+    base = dict(nonce=3, gas_price=7, gas_limit=21000, to=bytes(20),
+                value=5, payload=b"p" * 100, v=27, r=_R, s=_S)
+    base.update(kw)
+    return Transaction(**base)
+
+
+def _raw(fields: list) -> bytes:
+    """A list frame around field ENCODINGS given as they should stand
+    on the wire, canonical or not."""
+    body = b"".join(fields)
+    return rlp._encode_length(len(body), 0xC0) + body
+
+
+def _enc_fields(t: Transaction) -> list:
+    return [rlp.encode(x) for x in t.to_rlp()]
+
+
+def _with_field(k: int, enc: bytes) -> bytes:
+    fields = _enc_fields(_tx())
+    fields[k] = enc
+    return _raw(fields)
+
+
+def _case_generator():
+    from perfbench import gen
+
+    x = gen.Transfers(2**31 + 7, accounts=4, count=40, payload_bytes=100,
+                      gas_limit=29000)
+    return list(x.frames) + [t.encode() for t in _mixed_stream(30)]
+
+
+def _case_eip155_v():
+    return [_tx(v=v).encode()
+            for v in (35, 36, 37, 38, 2**63, 2**63 + 1, 2**64 - 1)]
+
+
+def _case_v_unassigned():
+    return [_tx(v=v).encode() for v in [*range(1, 27), *range(29, 35)]]
+
+
+def _case_recid_out_of_range():
+    # an unprotected v of 0 reads as recid -27; 27 and 28 are the two
+    # that stand
+    return [_tx(v=v).encode() for v in (0, 27, 28)]
+
+
+def _case_r_s():
+    return [_tx(r=0).encode(), _tx(s=0).encode(), _tx(r=0, s=0).encode(),
+            _tx(r=1 << 256).encode(), _tx(s=(1 << 256) + 9).encode(),
+            _tx(r=1, s=1).encode(), _tx(r=(1 << 256) - 1).encode(),
+            _with_field(8, b"\x82\x00\x05"), _with_field(9, b"\x00"),
+            _with_field(9, b"\xa1\x00" + bytes(range(1, 33)))]
+
+
+def _case_wide_nonce_and_price():
+    return [_tx(nonce=n, gas_price=g).encode()
+            for n, g in ((2**64 - 1, 2**64 - 1), (2**64, 1), (1, 2**64),
+                         (2**200 + 3, 2**64 + 5), (0, 0), (127, 128),
+                         (2**900, 2**56))]
+
+
+def _case_to_width():
+    return [_tx(to=bytes(n)).encode() for n in (19, 21, 1, 32)] + \
+        [_tx(to=None).encode(), _tx(to=b"\x00" * 19 + b"\x01").encode()]
+
+
+def _case_field_count():
+    f = _enc_fields(_tx())
+    return [_raw(f[:9]), _raw(f + [b"\x01"]), _raw(f[:1]), _raw([]),
+            _raw(f + f)]
+
+
+def _case_nested_list():
+    return [_with_field(k, b"\xc1\x05") for k in range(10)] + \
+        [_with_field(5, b"\xc0"), _with_field(0, b"\xf8\x38" + b"\x01" * 56)]
+
+
+def _case_trailing_bytes():
+    good = _tx().encode()
+    short = _tx(payload=b"", r=1, s=2).encode()  # a one-byte list header
+    return [good + b"\x00", good + good, short + b"\x80",
+            # the header claims one byte more than the frame has, and
+            # one fewer
+            bytes([short[0] + 1]) + short[1:],
+            bytes([short[0] - 1]) + short[1:],
+            good[:2] + bytes([good[2] + 1]) + good[3:] + b"\x00"]
+
+
+def _case_truncations():
+    out = []
+    for good in (_tx().encode(), _tx(payload=b"", r=1, s=2).encode(),
+                 _tx(v=2**63, payload=b"q" * 300).encode()):
+        out += [good[:k] for k in range(len(good) + 1)]
+    return out
+
+
+def _case_non_canonical_lengths():
+    sixty = b"z" * 60
+    return [
+        _with_field(0, b"\x81\x05"),            # a byte under 0x80, headed
+        _with_field(5, b"\x81\x7f"),
+        _with_field(5, b"\x81\x80"),            # canonical: stands
+        _with_field(5, b"\xb8\x05hello"),        # long form under 56
+        _with_field(5, b"\xb8\x37" + b"z" * 55),
+        _with_field(5, b"\xb8\x38" + b"z" * 56),  # canonical: stands
+        _with_field(5, b"\xb9\x00\x3c" + sixty),  # zero-led length
+        _with_field(5, b"\xba\x00\x00\x3c" + sixty),
+        b"\xf8\x20" + b"\x01" * 0x20,           # long list under 56
+        b"\xf9\x00\x80" + b"\x01" * 0x80,       # zero-led list length
+        b"\xf8",                                 # a length with no bytes
+        b"\xb8\x38" + b"z" * 56,                 # a string, not a list
+    ]
+
+
+def _case_length_overflow():
+    eight = lambda x: x.to_bytes(8, "big")  # noqa: E731
+    out = [b"\xff" + eight(n) + b"\x01" * 64
+           for n in (2**64 - 1, 2**64 - 9, 2**63, 56, 64)]
+    for n in (2**64 - 1, 2**64 - 2, 2**64 - 150, 2**63, 2**32):
+        for k in (0, 5, 9):
+            out.append(_with_field(k, b"\xbf" + eight(n) + b"\x01" * 8))
+    out.append(_with_field(5, b"\xbb" + (2**32 - 1).to_bytes(4, "big")))
+    return out
+
+
+def _case_gate_and_buffer_kinds():
+    good = _tx().encode()
+    big = _tx(payload=b"b" * (columnar.FRAME_MAX_BYTES - 200)).encode()
+    over = _tx(payload=b"b" * columnar.FRAME_MAX_BYTES).encode()
+    assert len(big) <= columnar.FRAME_MAX_BYTES < len(over)
+    return [b"", good, over, bytearray(good), memoryview(good), big,
+            bytearray(), memoryview(bytearray(over)), good]
+
+
+def _case_mutations():
+    rng = random.Random(20260928)
+    bases = [_tx().encode(), _tx(v=37, payload=b"").encode(),
+             _tx(to=None, v=28, nonce=2**64, payload=b"m" * 70).encode(),
+             _tx(r=1, s=2, payload=b"").encode()]
+    out = []
+    for _ in range(2000):
+        f = bytearray(rng.choice(bases))
+        f[rng.randrange(len(f))] = rng.randrange(256)
+        out.append(bytes(f))
+    return out
+
+
+_CASES = {name[len("_case_"):]: fn for name, fn in sorted(globals().items())
+          if name.startswith("_case_")}
+
+
+@pytest.mark.parametrize("kind", sorted(_CASES))
+def test_native_window_decoder_matches_the_oracle(kind):
+    """Three readings of one window: the rung ``decode_window`` runs
+    (the native decoder, where the library has it), the Python oracle
+    a frame at a time, and the scalar ``Transaction.decode``."""
+    frames = _CASES[kind]()
+    want = columnar._decode_frames(list(frames))
+    got = decode_txn_window(frames)
+    assert got.n == want.n == len(frames)
+    for name in ("decoded", "valid", "txhash", "sighash", "sig", "nonce",
+                 "gas_price", "_spans"):
+        a, b = getattr(got, name), getattr(want, name)
+        assert a.dtype == b.dtype and np.array_equal(a, b), (kind, name)
+    assert got.hashes == want.hashes
+    assert not (got.valid & ~got.decoded).any()
+    for i, frame in enumerate(frames):
+        frame = bytes(frame)
+        if got.decoded[i]:
+            ref = Transaction.decode(frame)
+            t = got.txn(i)
+            assert t == ref == want.txn(i), (kind, i)
+            assert t.hash == got.hashes[i] == bytes(got.txhash[i]) == \
+                keccak256(frame)
+            # one field is lossy: any non-zero `is_geec` reads True and
+            # re-encodes as 1, so only such a frame is not its own
+            # re-encoding (the scalar path then hashes another string)
+            assert (ref.hash == t.hash) == (ref.encode() == frame)
+            assert ref.encode() == frame or rlp.decode(frame)[6] != b"\x01"
+            parts = ref.signature_parts()
+            assert bool(got.valid[i]) == (parts is not None), (kind, i)
+            if parts is not None:
+                assert (bytes(got.sig[i]), bytes(got.sighash[i])) == parts
+            assert got.nonce[i] == min(ref.nonce, 2**64 - 1)
+            assert got.gas_price[i] == min(ref.gas_price, 2**64 - 1)
+            continue
+        # a dead row leaves nothing behind, and the scalar decoder
+        # refuses whatever the gate let through
+        assert got.hashes[i] is None and not got.txhash[i].any()
+        assert not got.sig[i].any() and not got.sighash[i].any()
+        if not 0 < len(frame) <= columnar.FRAME_MAX_BYTES:
+            continue
+        try:
+            items = rlp.decode(frame)
+        except RLPError:
+            continue
+        # a list where a field should be is the scalar path's blind
+        # spot (an empty one reads as the integer 0, any other trips a
+        # TypeError); the window decoders refuse both
+        if isinstance(items, list) and any(isinstance(x, list)
+                                           for x in items):
+            continue
+        with pytest.raises((RLPError, ValueError, IndexError)):
+            Transaction.from_rlp(items)
+    # non-vacuous: each kind but the all-dead ones decodes something
+    if kind not in ("field_count", "nested_list", "trailing_bytes",
+                    "length_overflow"):
+        assert got.decoded.any(), kind
+    if kind not in ("generator", "eip155_v", "wide_nonce_and_price"):
+        assert not (got.decoded & got.valid).all(), kind
+
+
+def test_the_native_decoder_is_the_rung_that_runs():
+    """``tests/conftest.py`` built the library, so the probe at import
+    must have chosen the native call; a silent fall to the Python rung
+    would pass every differential case and lose the whole gain."""
+    from eges_tpu.crypto import native
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    assert native.has_decode_window()
+    assert columnar._DECODE is columnar._decode_native
+    rows = metrics.counter("ingress.decode_rows").value
+    native_rows = metrics.counter("ingress.decode_native_rows").value
+    decode_txn_window([_tx().encode(), b"", b"\xc0"])
+    assert metrics.counter("ingress.decode_rows").value == rows + 3
+    assert metrics.counter("ingress.decode_native_rows").value == \
+        native_rows + 3
+
+
+def test_native_self_check_holds_the_window_decoder_to_fixed_answers():
+    """What ``make -C native test`` runs: the library against the golden
+    model, the window decoder's fixed frames among it."""
+    from eges_tpu.crypto import native
+
+    native.self_check()
+
+
+def test_native_wrapper_refuses_columns_that_do_not_fit():
+    """The library trusts its pointers: the wrapper checks every size,
+    dtype and the offsets before it hands them over."""
+    from eges_tpu.crypto import native
+
+    frames = [_tx().encode()] * 3
+
+    def call(**swap):
+        cols = columnar._pack(frames)
+        kw = dict(decoded=cols.decoded, valid=cols.valid,
+                  txhash=cols.txhash, sighash=cols.sighash, sig=cols.sig,
+                  nonce=cols.nonce, gas_price=cols.gas_price,
+                  spans=cols._spans)
+        offsets = swap.pop("offsets", cols._offsets)
+        kw.update(swap)
+        native.decode_txn_window(cols._data, offsets, **kw)
+        return cols
+
+    assert call().decoded.all()
+    bad = [dict(sig=np.zeros((3, 64), np.uint8)),
+           dict(txhash=np.zeros((2, 32), np.uint8)),
+           dict(nonce=np.zeros((3,), np.int32)),
+           dict(decoded=np.zeros((6,), bool)[::2]),
+           dict(spans=np.zeros((3, 10, 2), np.uint64)),
+           dict(offsets=np.array([0, 5, 3, 3 * len(frames[0])], np.uint64)),
+           dict(offsets=np.array([0, 1, 2, 3], np.uint64)),
+           dict(offsets=np.array([0, 1, 2, 3 * len(frames[0])], np.int64))]
+    for swap in bad:
+        with pytest.raises(ValueError):
+            call(**swap)
+
+
+def test_two_threads_decoding_at_once_each_get_their_own_window():
+    """The native call holds no GIL, so two workers are inside it at
+    once: nothing in it may be shared between calls."""
+    windows = [[_tx(nonce=1000 * w + i, payload=bytes([w]) * (40 + i % 90),
+                    v=27 + (i + w) % 2 if i % 5 else 0).encode()
+                for i in range(250)] for w in range(2)]
+    want = [columnar._decode_frames(w) for w in windows]
+    assert want[0].hashes != want[1].hashes
+    wrong: list = []
+    start = threading.Barrier(2)
+
+    def work(k: int) -> None:
+        start.wait(timeout=30)
+        for _ in range(60):
+            got = decode_txn_window(windows[k])
+            if not columnar._same_columns(got, want[k]) or \
+                    got.txn(7) != want[k].txn(7):
+                wrong.append(k)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
     try:
-        got = decode_txn_window(frames)
+        threads = [threading.Thread(target=work, args=(k,)) for k in (0, 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
     finally:
-        columnar._KECCAK_MULTI = saved
-    for name in ("sighash", "sig", "txhash", "decoded", "valid"):
-        assert np.array_equal(getattr(got, name), getattr(ref, name)), name
-    assert got.hashes == ref.hashes
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert wrong == []
 
 
 # -- pool admission: columnar vs legacy over the same stream --------------
