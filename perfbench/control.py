@@ -10,6 +10,11 @@ a test and PERF.md gives the readings.
   block of frames and of votes, so the program's caches remember every row
   that comes again.  Breaks what makes a pass cost what fresh rows would;
   it is the control for ``cache_hit_share_pct``.
+* ``one_lane`` (one-process deployments on several chips): the mesh
+  verifier shows the scheduler the first of its devices alone, so one lane
+  serves every window while the process holds all the chips.  Breaks the
+  configuration's ``layout`` (one lane a chip); it is the control for
+  ``lanes``.
 * ``host_verifier`` (cluster deployments): the chip node runs the host
   verifier, so no sender is recovered on the device.  Breaks "the chip
   node's device rows carried the senders".
@@ -39,3 +44,16 @@ class AcceptAll:
         bad = ~np.asarray(ok, bool)
         addrs[bad, 0] |= 1  # some sender, never the null address
         return addrs, np.ones(len(addrs), bool)
+
+
+class OneLane:
+    """The real verifier, of whose devices the scheduler sees the first."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def device_targets(self) -> list:
+        return self._inner.device_targets()[:1]

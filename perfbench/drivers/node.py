@@ -24,6 +24,7 @@ import time
 
 from perfbench import gen, harness, peaks
 from perfbench.clock import ThreadClock
+from perfbench.readers import lane_rows
 from perfbench.ref import secp
 
 
@@ -201,6 +202,21 @@ def _reference(feed, verdict: dict, sample: set) -> tuple:
     return rows, bad
 
 
+def layout_checks(checks: harness.Checks, chips: int, obs: dict) -> None:
+    """A cell on several chips is held to its layout: the scheduler drives
+    one lane a chip, and in the window every lane served its part of the
+    rows, a quarter of an even share at the least (an even share is 25% on
+    four chips and reads 24.5-24.9; a lane that served nothing reads 0).
+    A one-chip cell gets neither comparison."""
+    if chips <= 1:
+        return
+    checks.equals("lanes", (obs["after"].get("scheduler") or {}).get(
+        "lanes"), chips)
+    checks.at_least("lane_rows_min_share_pct",
+                    lane_rows.read(obs, stat="min_share"),
+                    100.0 / (4 * chips))
+
+
 def _no_span(name: str):
     """In place of ``jax.profiler.TraceAnnotation`` where no jax is."""
     return contextlib.nullcontext()
@@ -241,6 +257,9 @@ def run(cell: harness.Cell, args, t0: float) -> int:
         raw = AcceptAll(raw)
     elif args.control == "short_cycle":
         d = {**d, "pool_blocks": 1, "vote_pool_blocks": 1}
+    elif args.control == "one_lane":
+        from perfbench.control import OneLane
+        raw = OneLane(raw)
     elif args.control:
         raise SystemExit(f"no control {args.control!r} for this driver")
 
@@ -464,6 +483,8 @@ def run(cell: harness.Cell, args, t0: float) -> int:
                        / max(dev_rows + host_rows, 1),
                        d["host_row_share_limit_pct"])
     checks.at_most("compiles_in_window", compiles_in, 0)
+    if rehearse != "native":  # the host C++ verifier is one lane by nature
+        layout_checks(checks, cell.chips, obs)
     # the cycle of rows is longer than the program's caches remember;
     # hits beyond the few that a cleared dedup history lets through
     # would mean a pass costs less than fresh rows would

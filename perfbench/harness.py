@@ -11,7 +11,10 @@ import os
 import sys
 import time
 
-HERE = os.path.dirname(os.path.abspath(__file__))
+HERE = os.path.dirname(os.path.abspath(__file__))  # the code, the tests' data
+# The ONE place the benchmark's data files are found from: BENCHMARK.json,
+# configs/, traffic/ and metrics/ are read under ROOT each time they are
+# asked for, so a test that points ROOT at a copy of them reads the copy.
 ROOT = os.path.dirname(HERE)
 
 
@@ -72,13 +75,11 @@ def metric_file(name: str) -> dict:
     file of the name without its last suffix (``sched_wait_ms.lat`` is
     read as ``sched_wait_ms.json`` describes, with the suffix's
     arguments laid over the shared ones)."""
-    path = os.path.join(HERE, "metrics", name + ".json")
-    if os.path.exists(path):
-        with open(path) as f:
-            return json.load(f)
+    if os.path.exists(os.path.join(ROOT, "perfbench", "metrics",
+                                   name + ".json")):
+        return load_json("perfbench", "metrics", name + ".json")
     base, _, suffix = name.rpartition(".")
-    with open(os.path.join(HERE, "metrics", base + ".json")) as f:
-        spec = json.load(f)
+    spec = load_json("perfbench", "metrics", base + ".json")
     per = spec.get("suffixes", {}).get("." + suffix)
     if per is None:
         raise KeyError(f"{base}.json names no suffix .{suffix}")
@@ -156,6 +157,9 @@ class Checks:
     def at_least(self, name: str, value, limit) -> None:
         self._add(name, value, ">=", limit,
                   value is not None and value >= limit)
+
+    def equals(self, name: str, value, limit) -> None:
+        self._add(name, value, "==", limit, value == limit)
 
     @property
     def correct(self) -> bool:

@@ -1,7 +1,7 @@
 """A quantile of one field of the scheduler's flight recorder
 (``thw_flight`` / ``VerifierScheduler.flights()``) over the windows that
 finished inside the measured window, of one class where ``klass`` is
-given.  The recorder keeps the newest 256 windows."""
+given.  The recorder keeps the newest 4096 windows."""
 
 from perfbench.harness import quantile
 

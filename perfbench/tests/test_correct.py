@@ -25,7 +25,8 @@ def drive(*extra, workload="c1024.mixed-steady", seed=2**31 + 17):
 
 
 def failed(line) -> list:
-    ok = {"<=": lambda v, lim: v <= lim, ">=": lambda v, lim: v >= lim}
+    ok = {"<=": lambda v, lim: v <= lim, ">=": lambda v, lim: v >= lim,
+          "==": lambda v, lim: v == lim}
     return [n for n, (v, rule, lim) in line["checks"].items()
             if not ok[rule](v, lim)]
 
@@ -94,6 +95,48 @@ def test_a_part_of_the_batch_left_out_is_caught(monkeypatch):
     monkeypatch.setattr(NativeBatchVerifier, "recover_addresses", halved)
     _, line, _ = drive()
     assert line["correct"] is False and failed(line)
+
+
+def _lanes_obs(rows: list, lanes=None) -> dict:
+    """A window's snapshots as the node driver takes them: ``rows`` a lane
+    served in the window, on top of 1000 each before it."""
+    def snap(extra):
+        return {"scheduler": {
+            "lanes": len(rows) if lanes is None else lanes,
+            "devices": [{"device": i, "rows": 1000 + r, "batches": 4 + r}
+                        for i, r in enumerate(extra)]}}
+    return {"before": snap([0] * len(rows)), "after": snap(rows)}
+
+
+@pytest.mark.parametrize("chips, obs, fails", [
+    # four lanes share the rows as the ledger reads them: a sound run
+    (4, _lanes_obs([24600, 25100, 25400, 24900]), []),
+    # one lane idle through the window (the breaker open, a placement
+    # that forgets it): its share reads 0
+    (4, _lanes_obs([33000, 33500, 0, 33500]), ["lane_rows_min_share_pct"]),
+    # a process that holds four chips and drives one lane
+    (4, _lanes_obs([100000], lanes=1), ["lanes"]),
+    # a program without the per-lane breakdown has nothing to be held to
+    (4, {"before": {}, "after": {}}, ["lanes", "lane_rows_min_share_pct"]),
+    # a one-chip cell gets neither comparison
+    (1, _lanes_obs([100000]), None),
+])
+def test_a_cell_on_several_chips_is_held_to_its_layout(chips, obs, fails):
+    from perfbench import harness
+    from perfbench.drivers.node import layout_checks
+
+    checks = harness.Checks()
+    layout_checks(checks, chips, obs)
+    if fails is None:
+        assert checks.rows == []
+        return
+    assert [r["name"] for r in checks.rows] == ["lanes",
+                                                "lane_rows_min_share_pct"]
+    assert [r["name"] for r in checks.rows if not r["ok"]] == fails
+    assert checks.correct is (not fails)
+    floor = next(r for r in checks.rows
+                 if r["name"] == "lane_rows_min_share_pct")
+    assert floor["limit"] == pytest.approx(6.25)
 
 
 def test_no_program_no_result(tmp_path):

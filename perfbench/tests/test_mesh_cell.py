@@ -1,6 +1,8 @@
 """The four-chip cell ``c1024x4.mixed-backlog``: it finds its files, it is
 the one-chip backlog cell's deployment and traffic number for number, and
-each reader it brings returns what a hand-made ``obs`` says."""
+each reader it brings returns what a hand-made ``obs`` says.  What holds for
+EVERY cell on several chips is an invariant of its own, so that a later PR
+adds one without an edit here."""
 
 import pytest
 
@@ -48,13 +50,32 @@ def test_the_cell_finds_its_files_and_differs_from_one_chip_in_layout_alone():
     # the plain reader would show a quarter of a row's cost on four planes
     assert "recover_us_per_row.rows" not in mine
     assert {m["name"] for m in one.per_layer()}.isdisjoint(MESH)
+    for m in cell.per_layer():
+        if m["name"] in MESH:
+            assert m["moves"] == "verify_rows_per_s"
+
+
+def test_every_cell_on_several_chips_keeps_its_layout_and_the_lanes_metrics():
+    """Invariants for any number of cells: a cell that asks for several
+    chips runs a configuration laid out over exactly that many, and a
+    metric of the lanes (``.mesh*``) is listed in such cells alone, in at
+    least one, and moves an end-to-end metric each of them reports."""
     bench = harness.load_json("BENCHMARK.json")
-    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == \
-        [CELL]
-    for name in MESH:
-        entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL]
-        assert entry["moves"] == "verify_rows_per_s"
+    wide = [w["name"] for w in bench["workloads"] if w["chips"] > 1]
+    assert CELL in wide
+    for name in wide:
+        cell = harness.Cell(name, rehearse=False)
+        lay = cell.config.get("layout", {})
+        assert lay.get("devices") == lay.get("lanes") == cell.chips, name
+    lanes_metrics = [m for m in bench["per_layer"]
+                     if m["name"].rpartition(".")[2].startswith("mesh")]
+    assert {m["name"] for m in lanes_metrics} >= set(MESH)
+    for m in lanes_metrics:
+        assert m["workloads"] and set(m["workloads"]) <= set(wide), m["name"]
+        for name in m["workloads"]:
+            reports = {e["name"] for e in harness.Cell(
+                name, rehearse=False).end_to_end()}
+            assert m["moves"] in reports, (m["name"], name)
 
 
 def test_the_least_lanes_share_and_the_rows_a_device_window():

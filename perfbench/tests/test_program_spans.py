@@ -12,7 +12,8 @@ import os
 import pytest
 
 from perfbench import harness, trace
-from perfbench.readers import flight_field_opt, histogram_share
+from perfbench.readers import (flight_field_opt, histogram_share,
+                               journal_count, journal_mean)
 
 ROWS = json.load(open(os.path.join(harness.HERE, "tests", "data",
                                    "spans_gap.json")))
@@ -72,26 +73,77 @@ def test_a_flight_field_the_program_does_not_write_reads_as_nothing():
                                  field="resolve_ms", q=0.5) is None
 
 
+def test_a_phase_is_the_mean_over_every_proposers_events_in_the_window():
+    won = [{"type": "election_won", "node": n, "ts": ts, "dt": dt}
+           for n, ts, dt in (("aa", 0.5, 9.0),     # before the window
+                             ("aa", 1.5, 0.010), ("bb", 2.0, 0.030),
+                             ("cc", 2.5, 0.080),
+                             ("bb", 9.5, 9.0))]    # after it
+    other = [{"type": "validate_quorum", "node": "bb", "ts": 2.0,
+              "dt": 0.2},
+             {"type": "election_resend", "node": "bb", "ts": 2.0}]
+    obs = {"journal": won + other, "t_begin": 1.0, "t_end": 3.0}
+    assert journal_mean.read(obs, types=["election_won"], field="dt",
+                             scale=1e3) == pytest.approx(40.0)
+    assert journal_mean.read(obs, types=["validate_quorum"], field="dt",
+                             scale=1e3) == pytest.approx(200.0)
+    # whichever node proposed: a window in which one node (the chip node,
+    # say) won nothing still reads the others'
+    assert journal_mean.read(
+        {**obs, "journal": [e for e in won if e["node"] != "aa"]},
+        types=["election_won"], field="dt") == pytest.approx(0.055)
+    # nothing to read is None, never 0: no journal, no such event in the
+    # window, an event without the field
+    assert journal_mean.read({**obs, "journal": None},
+                             types=["election_won"], field="dt") is None
+    assert journal_mean.read({**obs, "t_begin": 3.5, "t_end": 9.0},
+                             types=["election_won"], field="dt") is None
+    assert journal_mean.read(obs, types=["election_resend"],
+                             field="dt") is None
+    # a count, beside it, is 0 where nothing happened
+    assert journal_count.read(obs, types=["election_resend"]) == 1
+    assert journal_count.read(obs, types=["validate_retry"]) == 0
+
+
 def test_the_span_metrics_sit_in_their_cells():
     bench = harness.load_json("BENCHMARK.json")
-    cells = {m["name"]: m["workloads"] for m in bench["per_layer"]}
+    per = {m["name"]: m for m in bench["per_layer"]}
+    reports = {w["name"]: {e["name"] for e in harness.Cell(
+        w["name"], rehearse=False).end_to_end()} for w in bench["workloads"]}
+
+    def sits_in(metric: str, cell: str) -> bool:
+        """The metric lists ``cell``, and every cell it lists reports the
+        end-to-end metric it moves (a later PR may list more)."""
+        m = per[metric]
+        return cell in m["workloads"] and all(
+            m["moves"] in reports[w] for w in m["workloads"])
+
     shares = ["decode_share", "pool_ingest_share", "pool_flush_share",
               "pool_admit_share", "pool_evict_share", "sched_submit_share",
               "sched_stage_share", "sched_collect_share",
               "sched_resolve_share"]
     for name in shares:
-        assert cells[name + ".rows"] == ["c1024.mixed-backlog"]
+        assert sits_in(name + ".rows", "c1024.mixed-backlog")
         spec = harness.metric_file(name + ".rows")
         assert spec["reader"] == "histogram_share"
         assert all(n.startswith("span.self_seconds;name=")
                    for n in spec["args"]["names"])
     for name in ("vote_submit_ms", "vote_await_ms", "sched_stage_ms",
                  "sched_resolve_ms"):
-        assert cells[name + ".vote"] == ["c1024.mixed-steady"]
+        assert sits_in(name + ".vote", "c1024.mixed-steady")
     for name in ("election_ms", "ack_ms", "chain_insert_ms",
                  "confirm_handle_ms", "rpc_handle_ms", "loop_lag_ms",
-                 "election_resends", "quorum_verify_ms"):
-        assert cells[name + ".lat"] == ["ref3.signed-steady"]
+                 "election_resends"):
+        assert sits_in(name + ".lat", "ref3.signed-steady")
+    # the proposer's two phases are read where every proposer writes them,
+    # the journal, and not in the chip node's registry, which is empty in
+    # a run where node 0 wins no election (3 of 95 blocks are its own)
+    for name, event in (("election_ms", "election_won"),
+                        ("ack_ms", "validate_quorum")):
+        spec = harness.metric_file(name + ".lat")
+        assert spec["reader"] == "journal_mean"
+        assert spec["args"] == {"types": [event], "field": "dt",
+                                "scale": 1000.0}
     # the two burst metrics read the burst's own histograms, not the
     # mean over the 32-row election call as well
     for name, span in (("vote_submit_ms", "sched.submit"),
