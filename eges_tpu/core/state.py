@@ -10,10 +10,11 @@ for now — Geec's operating workload is value-carrier transactions
 ref: core/block_validator.go:72) — so ``to=None`` creations transfer
 value to the derived contract address without running code.
 
-TPU-first note: sender recovery for a whole block arrives as ONE device
-batch (``recover_senders``); execution itself is sequential host work by
-nature (nonce ordering), exactly like the reference's loop — minus its
-one-cgo-call-per-tx cost (SURVEY §3.5).
+TPU-first note: sender recovery for a whole block arrives as ONE call
+(``recover_senders``: one device batch, or behind the scheduler one window
+that is part cache, part in flight, part device); execution itself is
+sequential host work by nature (nonce ordering), exactly like the
+reference's loop — minus its one-cgo-call-per-tx cost (SURVEY §3.5).
 
 Account RLP matches geth's shape ``[nonce, balance, storageRoot,
 codeHash]`` (ref: core/state/state_object.go Account) so state roots are
@@ -380,11 +381,42 @@ def contract_address(sender: bytes, nonce: int) -> bytes:
 
 
 def recover_senders(txns, verifier) -> list:
-    """One device batch of sender recovery for a block's signed txns;
-    geec/fake/unsigned rows come back as None (they carry no sender and
-    never execute).  Raises StateError on a malformed signature — a
-    rooted txn that cannot name a sender invalidates the block
-    (ref: core/state_processor.go:93 aborts on AsMessage error)."""
+    """Sender recovery for a block's signed txns, all rows in ONE call
+    to the verifier; geec/fake/unsigned rows come back as None (they
+    carry no sender and never execute).  Raises StateError on a
+    malformed signature — a rooted txn that cannot name a sender
+    invalidates the block (ref: core/state_processor.go:93 aborts on
+    AsMessage error).
+
+    Behind a plain batch verifier the call is one device batch.  Behind
+    the node's :class:`~eges_tpu.crypto.scheduler.VerifierScheduler` it
+    is one WINDOW of the scheduler: the rows gossip already brought are
+    answered by the recovery cache, a row that is pending for another
+    caller shares that caller's batch row, and only the rest reach the
+    device, coalesced with whatever else is pending (class ``bulk``).
+
+    Span ``chain.recover_senders`` bounds the body (attrs ``rows``: rows
+    handed to the verifier, ``cached``, ``coalesced``, ``refused``);
+    counters ``chain.sender_rows``, ``chain.sender_cached_rows``,
+    ``chain.sender_coalesced_rows`` and ``chain.blocks_refused`` take
+    one ``inc(n)`` a call."""
+    from eges_tpu.utils import tracing
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    with tracing.DEFAULT.span("chain.recover_senders", rows=0, cached=0,
+                              coalesced=0, refused=0) as sp:
+        try:
+            return _recover_senders(txns, verifier, sp)
+        except StateError:
+            sp.set_attr("refused", 1)
+            metrics.counter("chain.blocks_refused").inc()
+            raise
+
+
+def _recover_senders(txns, verifier, sp) -> list:
+    """The body of :func:`recover_senders`, under its span ``sp``."""
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
     senders: list = [None] * len(txns)
     rows = []
     for i, t in enumerate(txns):
@@ -396,6 +428,8 @@ def recover_senders(txns, verifier) -> list:
         rows.append((i, parts))
     if not rows:
         return senders
+    sp.set_attr("rows", len(rows))
+    metrics.counter("chain.sender_rows").inc(len(rows))
     if verifier is None:
         from eges_tpu.crypto.verify_host import _count_host_rows
         _count_host_rows(len(rows))
@@ -410,7 +444,18 @@ def recover_senders(txns, verifier) -> list:
     for k, (_, (sig, h)) in enumerate(rows):
         sigs[k] = np.frombuffer(sig, np.uint8)
         hashes[k] = np.frombuffer(h, np.uint8)
-    addrs, ok = verifier.recover_addresses(sigs, hashes)
+    answer = verifier.recover_addresses(sigs, hashes)
+    addrs, ok = answer
+    # a scheduler says what answered the window; a plain verifier
+    # computed every row
+    cached = getattr(answer, "cached", 0)
+    coalesced = getattr(answer, "coalesced", 0)
+    sp.set_attr("cached", cached)
+    sp.set_attr("coalesced", coalesced)
+    if cached:
+        metrics.counter("chain.sender_cached_rows").inc(cached)
+    if coalesced:
+        metrics.counter("chain.sender_coalesced_rows").inc(coalesced)
     for k, (i, _) in enumerate(rows):
         if not ok[k]:
             raise StateError("unrecoverable transaction signature")

@@ -128,11 +128,16 @@ class _WindowRows:
     its window — callers decide per row (``recover_window`` host-
     diverts them, mirroring ``recover_signers``)."""
 
-    __slots__ = ("results", "_done", "_remaining", "_lock", "_fut",
-                 "_finished")
+    __slots__ = ("results", "cached", "coalesced", "_done", "_remaining",
+                 "_lock", "_fut", "_finished")
 
     def __init__(self, n: int):
         self.results: list = [None] * n
+        # what answered the window when it entered (``_enter_window``):
+        # rows the recovery cache held, rows that joined a row already
+        # pending; the others ride a batch of their own
+        self.cached = 0
+        self.coalesced = 0
         self._done = bytearray(n)
         self._remaining = n
         self._lock = threading.Lock()
@@ -175,6 +180,32 @@ class _WindowRows:
 
     def result(self, timeout: float | None = None) -> list:
         return self._fut.result(timeout)
+
+
+class WindowAnswers(list):
+    """A synchronous window call's answers, one a row, with what the
+    window's holder knew when it entered: ``cached`` rows the recovery
+    cache answered, ``coalesced`` rows that joined a row already pending
+    (a window in flight, or an earlier row of the same call)."""
+
+    __slots__ = ("cached", "coalesced")
+
+    def __init__(self, win: _WindowRows):
+        super().__init__(win.results)
+        self.cached = win.cached
+        self.coalesced = win.coalesced
+
+
+class RecoveredAddresses(tuple):
+    """``(addrs, ok)`` as ``BatchVerifier.recover_addresses`` returns
+    them, with the :class:`WindowAnswers` counts of the window that
+    carried the call."""
+
+    def __new__(cls, addrs, ok, answers: WindowAnswers):
+        self = super().__new__(cls, (addrs, ok))
+        self.cached = answers.cached
+        self.coalesced = answers.coalesced
+        return self
 
 
 def _class_of(priority: str) -> str:
@@ -575,7 +606,7 @@ class VerifierScheduler:
             win._try_finish()
             return win
         klass = _class_of(priority)
-        n_hits = n_invalid = 0
+        n_hits = n_invalid = n_joined = 0
         with self._lock:
             # analysis: allow-determinism(coalescing deadline is real-time by contract; chaos pins batching via max_batch kicks)
             t_now = time.monotonic()
@@ -608,8 +639,7 @@ class VerifierScheduler:
                     # here too: the first occurrence owns the batch
                     # row, later ones share it)
                     row[0].append((win, i))
-                    self._stats["coalesced_rows"] += 1
-                    self._dedup_rows_pending += 1
+                    n_joined += 1
                     if klass == "consensus":
                         row[2] = "consensus"
                 else:
@@ -622,6 +652,9 @@ class VerifierScheduler:
                         self._pending_origin[key] = rec
                     added = True
             n_miss = n - n_hits - n_invalid
+            win.cached, win.coalesced = n_hits, n_joined
+            self._stats["coalesced_rows"] += n_joined
+            self._dedup_rows_pending += n_joined
             # a cache-served row is still a served row: without this
             # accounting a 100% warm-cache flood looks free in
             # stats()/flight rows (drained into the next window's
@@ -650,14 +683,15 @@ class VerifierScheduler:
         return win
 
     def _await_window(self, win: _WindowRows, keys: list,
-                      labels: dict) -> list:
+                      labels: dict) -> WindowAnswers:
         """The blocking half of a synchronous call: one kick, one wait.
         A row that a torn-down scheduler (or a window that died on its
         way) failed is recovered on the host: consensus keeps
         committing."""
         with tracing.DEFAULT.span("sched.await", **labels):
             self.kick()
-            out = win.result()
+            win.result()
+        out = WindowAnswers(win)
         for i, v in enumerate(out):
             if isinstance(v, BaseException):
                 out[i] = self._host_recover(keys[i])
@@ -689,18 +723,20 @@ class VerifierScheduler:
         ``BatchVerifier.recover_addresses`` so block body validation
         and the EVM ecrecover precompile route through the
         cache/coalescer unchanged: the arrays take the window path
-        (:meth:`recover_window`) as they are."""
+        (:meth:`recover_window`) as they are.  The pair that comes back
+        is a :class:`RecoveredAddresses`: it also says how many of the
+        rows the cache answered and how many joined a pending row."""
         n = sigs.shape[0]
         addrs = np.zeros((n, 20), np.uint8)
         ok = np.zeros((n,), bool)
         if n == 0:
             return addrs, ok
-        for i, r in enumerate(self.recover_window(hashes, sigs,
-                                                  priority=priority)):
+        answers = self.recover_window(hashes, sigs, priority=priority)
+        for i, r in enumerate(answers):
             if r is not None:
                 addrs[i] = np.frombuffer(r, np.uint8)
                 ok[i] = True
-        return addrs, ok
+        return RecoveredAddresses(addrs, ok, answers)
 
     def submit_window(self, hashes: np.ndarray, sigs: np.ndarray,
                       priority: str = "bulk") -> _WindowRows:
@@ -718,7 +754,9 @@ class VerifierScheduler:
         kick, one blocking wait — ``verify_host.recover_signers_window``
         delegates here when the pool's verifier is a scheduler.  Rows a
         torn-down scheduler failed fall back to host recovery, exactly
-        like :meth:`recover_signers`."""
+        like :meth:`recover_signers`.  Both return a
+        :class:`WindowAnswers`: the list, with the window's ``cached``
+        and ``coalesced`` counts."""
         labels = _call_labels(priority, len(hashes))
         with tracing.DEFAULT.span("sched.submit", **labels):
             keys = _array_keys(hashes, sigs)

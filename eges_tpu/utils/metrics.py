@@ -33,6 +33,11 @@ METRIC_FAMILIES = frozenset({
     "chain.bad_blocks", "chain.blocks", "chain.fastsync_adoptions",
     "chain.geec_txns", "chain.height", "chain.insert",
     "chain.insert_seconds", "chain.txns",
+    # core/state.py recover_senders — a block's signed rows handed to
+    # the verifier, those the scheduler's cache answered, those that
+    # joined a pending row, and the blocks it refused
+    "chain.blocks_refused", "chain.sender_cached_rows",
+    "chain.sender_coalesced_rows", "chain.sender_rows",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -131,6 +136,18 @@ METRIC_FAMILIES = frozenset({
 METRIC_HELP = {
     "chain.bad_blocks": "Blocks rejected by validation on insert.",
     "chain.blocks": "Canonical blocks inserted into the chain.",
+    "chain.blocks_refused": (
+        "Blocks whose sender recovery raised StateError (a signature "
+        "that names no sender)."),
+    "chain.sender_cached_rows": (
+        "Rows of block sender recovery that the scheduler's recovery "
+        "cache answered."),
+    "chain.sender_coalesced_rows": (
+        "Rows of block sender recovery that joined a row already "
+        "pending in the scheduler."),
+    "chain.sender_rows": (
+        "Signed rows of blocks handed to the verifier by "
+        "recover_senders."),
     "chain.fastsync_adoptions": "Fast-sync snapshot adoptions.",
     "chain.geec_txns": "Geec control-plane transactions inserted.",
     "chain.height": "Current canonical chain height.",

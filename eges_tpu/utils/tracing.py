@@ -90,6 +90,15 @@ SPANS = {
     "consensus.verify_quorum": ((), "a quorum's signatures through the "
                                     "scheduler"),
     "chain.insert": ((), "execute, state root, index"),
+    "chain.recover_senders": ((), "a block's signed rows through the "
+                                  "verifier in one call (core/state.py): "
+                                  "behind the scheduler one window, part "
+                                  "cache, part in flight, part device; "
+                                  "attrs rows, cached, coalesced, refused; "
+                                  "counters chain.sender_rows, "
+                                  "chain.sender_cached_rows, "
+                                  "chain.sender_coalesced_rows, "
+                                  "chain.blocks_refused, one inc a call"),
     "rpc.handle": (("method",), "one HTTP request body dispatched (a batch "
                                 "counts once, by its first method)"),
 }
