@@ -304,7 +304,7 @@ class TxPool:
                 self.stats["rejected"] += 1
                 # invalid signature: the cheap-reject path an ingress
                 # flood rides — billed to the captured ingest origin
-                self._ledger_charge(t.hash, rejects=1)
+                self._ledger_charge(t.hash, ledger.current(), rejects=1)
                 continue
             self._admit(t, sender)
         if self._queue:
@@ -316,8 +316,9 @@ class TxPool:
         ``max_batch``-row slice makes ONE ``recover_signers_window``
         call over arrays gathered straight out of the columns — no
         per-row ``signature_parts``, no per-row entry tuples — and
-        ``Transaction`` objects materialize only for rows that admit.
-        Outcome order matches the scalar ``_flush`` row for row."""
+        ``Transaction`` objects materialize only for rows that admit,
+        a chunk's in one pass over its columns.  Outcome order matches
+        the scalar ``_flush`` row for row."""
         import numpy as np
 
         while self._queue:
@@ -347,14 +348,15 @@ class TxPool:
             self._window_chunks -= consumed_chunks
             self._queue_rows -= rows_n
             self.stats["batches"] += 1
-            # flat row map in arrival order; gather valid rows' arrays
-            flat: list = []  # (cols|txn, row_index|None) per output row
+            # each taken item's first output row, arrival order; gather
+            # the valid rows' arrays
+            bases: list = []
+            n_out = 0
             vh, vs, vpos = [], [], []
             for item in take:
+                bases.append(n_out)
                 if isinstance(item, _WindowChunk):
                     c, rs = item.cols, item.rows
-                    base = len(flat)
-                    flat.extend((c, i) for i in rs)
                     rs_arr = np.asarray(rs, dtype=np.int64)
                     mask = c.valid[rs_arr]
                     sel = rs_arr[mask]
@@ -362,10 +364,9 @@ class TxPool:
                         vh.append(c.sighash[sel])
                         vs.append(c.sig[sel])
                         vpos.extend(
-                            (base + np.nonzero(mask)[0]).tolist())
+                            (n_out + np.nonzero(mask)[0]).tolist())
+                    n_out += len(rs)
                 else:
-                    pos = len(flat)
-                    flat.append((item, None))
                     p = item.signature_parts()
                     if p is not None:
                         sig, h = p
@@ -373,8 +374,9 @@ class TxPool:
                                   .reshape(1, 32))
                         vs.append(np.frombuffer(sig, np.uint8)
                                   .reshape(1, 65))
-                        vpos.append(pos)
-            senders: list = [None] * len(flat)
+                        vpos.append(n_out)
+                    n_out += 1
+            senders: list = [None] * n_out
             if vpos:
                 from eges_tpu.crypto.verify_host import \
                     recover_signers_window
@@ -392,30 +394,42 @@ class TxPool:
             # per-row span via _admit
             wcm = wsp = None
             amb = ledger.current()  # stable for the whole slice
+            now = self.clock.now()  # one flush, one instant
             try:
-                for j, (obj, li) in enumerate(flat):
-                    sender = senders[j]
-                    if sender is None:
-                        self.stats["rejected"] += 1
-                        rej.append(obj.hash if li is None
-                                   else obj.hashes[li])
-                    elif li is None:
-                        self._admit(obj, sender)
-                    else:
-                        t = obj.txn(li)
-                        if wcm is None:
-                            ctx = self._ingest_ctx.get(t.hash) \
-                                or tracing.DEFAULT.current_context()
-                            wcm = tracing.DEFAULT.span(
-                                "txpool.admit_window", parent=ctx,
-                                owner=self.owner, rows=len(flat))
-                            wsp = wcm.__enter__()
-                        self._admit_traced(t, sender, wsp, batched=True,
-                                           amb=amb)
+                for item, base in zip(take, bases):
+                    if not isinstance(item, _WindowChunk):
+                        if senders[base] is None:
+                            self.stats["rejected"] += 1
+                            rej.append(item.hash)
+                        else:
+                            self._admit(item, senders[base])
+                        continue
+                    c, rs = item.cols, item.rows
+                    snd = senders[base:base + len(rs)]
+                    if None in snd:
+                        hashes = c.hashes
+                        rej.extend(hashes[i] for i, s in zip(rs, snd)
+                                   if s is None)
+                        rs = [i for i, s in zip(rs, snd) if s is not None]
+                        self.stats["rejected"] += len(snd) - len(rs)
+                        snd = [s for s in snd if s is not None]
+                        if not rs:
+                            continue
+                    if wcm is None:
+                        ctx = self._ingest_ctx.get(c.hashes[rs[0]]) \
+                            or tracing.DEFAULT.current_context()
+                        wcm = tracing.DEFAULT.span(
+                            "txpool.admit_window", parent=ctx,
+                            owner=self.owner, rows=n_out)
+                        wsp = wcm.__enter__()
+                    # the chunk's admitted rows' Transactions in one
+                    # pass over the columns, then the rows' admission
+                    self._admit_rows(c.txns(rs), snd, wsp, amb, now,
+                                     batched=True)
             finally:
                 if wcm is not None:
                     wcm.__exit__(None, None, None)
-                    # slice-deferred housekeeping (see _admit_traced)
+                    # slice-deferred housekeeping (see _admit_rows)
                     self._maybe_compact()
                     self._depth_gauge()
             if rej:
@@ -444,19 +458,14 @@ class TxPool:
             (led, origin), n = groups[key]
             led.charge(origin, **{k: v * n for k, v in counts.items()})
 
-    # sentinel: "caller did not pre-resolve the ambient ledger pair"
-    _NO_AMB = object()
-
-    def _ledger_charge(self, h: bytes, _amb=_NO_AMB, **counts) -> None:
+    def _ledger_charge(self, h: bytes, amb, **counts) -> None:
         """Charge a flush outcome to the origin captured at ingest (the
         flush runs on a clock callback with no ambient binding); falls
-        back to the ambient pair, no-op when neither exists.  ``_amb``
-        lets a window flush resolve :func:`ledger.current` once per
-        slice instead of per row — the ambient binding cannot change
-        mid-flush (one clock callback, one thread)."""
-        rec = self._ingest_origin.pop(h, None)
-        if rec is None:
-            rec = ledger.current() if _amb is self._NO_AMB else _amb
+        back to ``amb``, the ambient pair as the caller resolved it
+        (:func:`ledger.current` once per call or slice — the binding
+        cannot change mid-flush: one clock callback, one thread);
+        no-op when neither exists."""
+        rec = self._ingest_origin.pop(h, None) or amb
         if rec is not None:
             led, origin = rec
             led.charge(origin, **counts)
@@ -468,60 +477,85 @@ class TxPool:
     def _admit(self, t: Transaction, sender: bytes) -> None:
         # re-enter the txn's ingest trace: the flush that got us here ran
         # on a clock callback, outside any ambient span context
-        ctx = self._ingest_ctx.get(t.hash) \
+        h = t.hash
+        ctx = self._ingest_ctx.get(h) \
             or tracing.DEFAULT.current_context()
         with tracing.DEFAULT.span("txpool.admit", parent=ctx,
                                   owner=self.owner,
-                                  tx=t.hash.hex()[:16]) as sp:
-            self._admit_traced(t, sender, sp)
+                                  tx=h.hex()[:16]) as sp:
+            self._admit_rows((t,), (sender,), sp, ledger.current(),
+                             self.clock.now())
 
-    def _admit_traced(self, t: Transaction, sender: bytes, sp,
-                      batched: bool = False, amb=_NO_AMB) -> None:
-        """Admission body.  ``batched=True`` (the window flush) defers
-        the per-row housekeeping that is slice-equivalent: the depth
-        gauge and ``_order`` compaction run once after the slice, and
-        the shared window span skips per-row outcome attrs (on a
-        shared span they are last-write-wins noise; the per-row
-        outcomes live in ``stats`` and the ledger either way)."""
-        by_nonce = self.pending.setdefault(sender, {})
-        old = by_nonce.get(t.nonce)
-        if old is None and len(self._by_hash) >= self.max_pending:
-            # capacity only limits NEW slots: a price-bump replacement
-            # keeps the pool size constant and must stay possible even
-            # when full (ref: core/tx_pool.go admits replacements)
-            self.stats["rejected"] += 1
-            self._ledger_charge(t.hash, amb, rejects=1, sender=sender)
-            if not batched:
-                sp.set_attr("outcome", "rejected")
-            if not by_nonce:
-                del self.pending[sender]
-            return
-        if old is not None:
-            # price-bump replacement (ref: core/tx_pool.go:571+)
-            if t.gas_price * 100 < old.gas_price * (100 + self.PRICE_BUMP_PCT):
-                self.stats["duplicate"] += 1
-                self._ledger_charge(t.hash, amb, drops=1, sender=sender)
+    def _admit_rows(self, txns, senders, sp, amb, now: float,
+                    batched: bool = False) -> None:
+        """Admission body, rows in arrival order: one row under its own
+        span (the scalar path) or a window chunk's under the slice's.
+        What is the same for every row is read once: ``now`` (one
+        flush, one instant), the ambient ledger pair ``amb``, and
+        whether anybody is billed at all (no origin captured and no
+        ambient pair: nothing to pop, nothing to charge, for any row).
+        ``batched=True`` (the window flush) defers the per-row
+        housekeeping that is slice-equivalent: the depth gauge and
+        ``_order`` compaction run once after the slice, and the shared
+        window span skips per-row outcome attrs (on a shared span they
+        are last-write-wins noise; the per-row outcomes live in
+        ``stats`` and the ledger either way)."""
+        pending = self.pending
+        by_hash = self._by_hash
+        admit_t = self._admit_t
+        stats = self.stats
+        max_pending = self.max_pending
+        bump = 100 + self.PRICE_BUMP_PCT
+        billed = amb is not None or bool(self._ingest_origin)
+        charge = self._ledger_charge
+        hook = self.on_admitted
+        for t, sender in zip(txns, senders):
+            h, nonce = t.hash, t.nonce
+            by_nonce = pending.setdefault(sender, {})
+            old = by_nonce.get(nonce)
+            if old is None and len(by_hash) >= max_pending:
+                # capacity only limits NEW slots: a price-bump
+                # replacement keeps the pool size constant and must stay
+                # possible even when full (ref: core/tx_pool.go admits
+                # replacements)
+                stats["rejected"] += 1
+                if billed:
+                    charge(h, amb, rejects=1, sender=sender)
                 if not batched:
-                    sp.set_attr("outcome", "duplicate")
-                return
-            self._by_hash.pop(old.hash, None)
-            self._dead.add(old.hash)
-            self.stats["replaced"] += 1
-        by_nonce[t.nonce] = t
-        self._order.append((sender, t))
-        self._by_hash[t.hash] = (sender, t.nonce)
-        if len(self._admit_t) < self._INGEST_CTX_CAP:
-            self._admit_t[t.hash] = self.clock.now()
-        self.stats["admitted"] += 1
-        self._ledger_charge(t.hash, amb, admits=1, sender=sender)
-        if not batched:
-            self._maybe_compact()
-            self._depth_gauge()
-            sp.set_attr("outcome", "admitted")
-        if self.on_admitted is not None:
-            # still inside the admit span: a broadcast hook fired here
-            # injects this trace into the outbound gossip envelope
-            self.on_admitted(t, sender)
+                    sp.set_attr("outcome", "rejected")
+                if not by_nonce:
+                    del pending[sender]
+                continue
+            if old is not None:
+                # price-bump replacement (ref: core/tx_pool.go:571+)
+                if t.gas_price * 100 < old.gas_price * bump:
+                    stats["duplicate"] += 1
+                    if billed:
+                        charge(h, amb, drops=1, sender=sender)
+                    if not batched:
+                        sp.set_attr("outcome", "duplicate")
+                    continue
+                by_hash.pop(old.hash, None)
+                self._dead.add(old.hash)
+                stats["replaced"] += 1
+            by_nonce[nonce] = t
+            # _order is read through self: _maybe_compact rebinds it
+            self._order.append((sender, t))
+            by_hash[h] = (sender, nonce)
+            if len(admit_t) < self._INGEST_CTX_CAP:
+                admit_t[h] = now
+            stats["admitted"] += 1
+            if billed:
+                charge(h, amb, admits=1, sender=sender)
+            if not batched:
+                self._maybe_compact()
+                self._depth_gauge()
+                sp.set_attr("outcome", "admitted")
+            if hook is not None:
+                # still inside the admit span: a broadcast hook fired
+                # here injects this trace into the outbound gossip
+                # envelope
+                hook(t, sender)
 
     def _maybe_compact(self) -> None:
         """Compact ``_order`` when mostly tombstones — reachable from
@@ -563,7 +597,7 @@ class TxPool:
                     start = state.nonce(s)
                     stale = [t for n, t in run if n < start]
                     if stale:
-                        self._evict(stale)
+                        self._evict([t.hash for t in stale])
                         run = [(n, t) for n, t in run if n >= start]
                     spendable = state.balance(s)
                     picked = []
@@ -585,53 +619,86 @@ class TxPool:
                     break
             return out[:limit] if limit else out
 
-    def _evict(self, txns) -> None:
-        """O(evicted) eviction: the ``_by_hash`` index locates each txn's
-        (sender, nonce) slot directly, and ``_order`` compacts lazily via
-        a tombstone set only when mostly dead (round-2 verdict weak #8:
-        the old path rebuilt the whole order list per block)."""
-        for t in txns:
-            loc = self._by_hash.pop(t.hash, None)
+    def _evict(self, hashes) -> None:
+        """O(evicted) eviction by txn hash: the ``_by_hash`` index
+        locates each txn's (sender, nonce) slot directly, and ``_order``
+        compacts lazily via a tombstone set only when mostly dead
+        (round-2 verdict weak #8: the old path rebuilt the whole order
+        list per block)."""
+        pending = self.pending
+        by_hash = self._by_hash
+        dead = self._dead
+        ctx_pop = self._ingest_ctx.pop
+        ingest_pop = self._ingest_t.pop
+        admit_pop = self._admit_t.pop
+        origin_pop = self._ingest_origin.pop
+        for h in hashes:
+            loc = by_hash.pop(h, None)
             if loc is None:
                 continue
             sender, nonce = loc
-            by_nonce = self.pending.get(sender)
+            by_nonce = pending.get(sender)
             if by_nonce is not None:
                 cur = by_nonce.get(nonce)
-                if cur is not None and cur.hash == t.hash:
+                if cur is not None and cur.hash == h:
                     del by_nonce[nonce]
                     if not by_nonce:
-                        del self.pending[sender]
-            self._dead.add(t.hash)  # bounded-by: _maybe_compact clears when dead > live (called below)
-            self._ingest_ctx.pop(t.hash, None)
-            self._ingest_t.pop(t.hash, None)
-            self._admit_t.pop(t.hash, None)
-            self._ingest_origin.pop(t.hash, None)
+                        del pending[sender]
+            dead.add(h)  # bounded-by: _maybe_compact clears when dead > live (called below)
+            ctx_pop(h, None)
+            ingest_pop(h, None)
+            admit_pop(h, None)
+            origin_pop(h, None)
         self._maybe_compact()
         self._depth_gauge()
 
     def remove_included(self, txns, block: int | None = None) -> None:
-        """Drop txns included in a canonical block; closes each txn's
-        trace with a ``tx.commit`` span so ingest -> admit -> commit is
+        """Drop txns included in a canonical block; closes their traces
+        with ONE ``tx.commit`` record an ingest trace (a window's rows
+        share the context of its one ``txpool.ingest`` span; a txn that
+        came alone is a group of one), so ingest -> admit -> commit is
         one linked trace even across nodes."""
         with self._lock, tracing.DEFAULT.span(
                 "txpool.evict", owner=self.owner, txns=len(txns)):
-            for t in txns:
-                ctx = self._ingest_ctx.get(t.hash)
-                if ctx is not None:
+            hashes = [t.hash for t in txns]
+            # the block's txns by ingest context, first-seen order; a
+            # window's rows mostly stand together, so the group is
+            # looked up where the context changes
+            groups: dict = {}
+            ctx_of = self._ingest_ctx.get
+            last = grp = None
+            for h in hashes:
+                ctx = ctx_of(h)
+                if ctx is None:
+                    continue
+                if ctx is not last:
+                    last = ctx
+                    grp = groups.get(ctx)
+                    if grp is None:
+                        grp = groups[ctx] = []
+                grp.append(h)
+            if groups:
+                blk = {"block": block} if block is not None else {}
+                for ctx, hs in groups.items():
+                    # ``tx`` names the group's first txn, ``txs`` every
+                    # one: 16 hex digits each, back to back
                     tracing.DEFAULT.record_span(
                         "tx.commit", 0.0, parent=ctx, owner=self.owner,
-                        tx=t.hash.hex()[:16],
-                        **({"block": block} if block is not None else {}))
+                        tx=hs[0].hex()[:16], txns=len(hs),
+                        txs=b"".join([h[:8] for h in hs]).hex(), **blk)
+                from eges_tpu.utils import metrics
+                metrics.DEFAULT.counter("txpool.commit_rows").inc(
+                    sum(map(len, groups.values())))
+                metrics.DEFAULT.counter("txpool.commit_records").inc(
+                    len(groups))
             # commit-anatomy pool stage: the ingest->admission leg of
             # this block's critical path, on the node clock (virtual
             # under the simulator, so deterministic in sims).  Emitted
             # BEFORE eviction drops the per-txn timestamps.
             if self.event_journal is not None and txns:
-                ing = [self._ingest_t[t.hash] for t in txns
-                       if t.hash in self._ingest_t]
-                adm = [self._admit_t[t.hash] for t in txns
-                       if t.hash in self._admit_t]
+                ingest_t, admit_t = self._ingest_t, self._admit_t
+                ing = [ingest_t[h] for h in hashes if h in ingest_t]
+                adm = [admit_t[h] for h in hashes if h in admit_t]
                 if ing and adm:
                     self.event_journal.record(
                         "commit_anatomy", blk=block, stage="pool",
@@ -639,7 +706,7 @@ class TxPool:
                         t_first_ingest=round(min(ing), 6),
                         t_last_admit=round(max(adm), 6),
                         ingest_to_admit_s=round(max(adm) - min(ing), 6))
-            self._evict(txns)
+            self._evict(hashes)
             if self.event_journal is not None and txns:
                 self.event_journal.record("txns_included", blk=block,
                                           count=len(txns))

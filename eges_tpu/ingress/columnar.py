@@ -11,7 +11,8 @@ numpy arrays — ``sighash32`` / ``sig65`` / ``txhash`` / ``gas_price`` /
 path's staging buffers, so the window lands in the device staging pool
 (``verifier.recover_addresses`` / ``scheduler.submit_window``) without
 any per-row conversion.  ``Transaction`` object construction is
-deferred to admission time (:meth:`TxColumns.txn`): rejected rows —
+deferred to admission time (:meth:`TxColumns.txns`, one pass over the
+columns for a flushed slice's admitted rows): rejected rows —
 the flood case — never materialize an object at all, keeping the
 cheap-reject path cheap at wire rate (arXiv 1808.02252's DoS contract;
 arXiv 2112.02229's never-touch-a-scalar-path discipline).
@@ -56,6 +57,7 @@ FRAME_MAX_BYTES = 128 * 1024
 WINDOW_MAX_ROWS = 16384
 
 _SECP_MAX = 1 << 256
+_U64_MAX = (1 << 64) - 1  # where the nonce / gas_price columns clip
 
 
 class TxColumns:
@@ -93,37 +95,59 @@ class TxColumns:
         self._txns: list = [None] * n   # materialized / original txns
 
     def txn(self, i: int) -> Transaction:
-        """Materialize row ``i``'s ``Transaction`` — admission time
-        only; rejected rows never pay this."""
-        t = self._txns[i]
-        if t is None:
+        """Materialize row ``i``'s ``Transaction``: the one-row case
+        of :meth:`txns`."""
+        return self.txns((i,))[0]
+
+    def txns(self, rows) -> list[Transaction]:
+        """Materialize ``rows``' ``Transaction``s in ONE pass over the
+        columns — admission time only; rejected rows never pay this.
+        A row already materialized (or kept from ``columns_from_txns``,
+        which has no wire bytes at all) is returned as it stands."""
+        have = self._txns
+        need = [i for i in rows if have[i] is None]
+        if need:
             # direct field construction instead of from_rlp: the scan
             # already enforced every from_rlp guard (canonical uints,
             # r/s/v widths, `to` length), so int.from_bytes over the
             # raw payloads builds the identical object without a
-            # second decode pass
-            base, data = int(self._offsets[i]), self._data
-            it = [data[base + a:base + b]
-                  for a, b in self._spans[i].tolist()]
-            t = Transaction(
-                nonce=int.from_bytes(it[0], "big"),
-                gas_price=int.from_bytes(it[1], "big"),
-                gas_limit=int.from_bytes(it[2], "big"),
-                to=it[3] or None,
-                value=int.from_bytes(it[4], "big"),
-                payload=it[5],
-                is_geec=bool(int.from_bytes(it[6], "big")),
-                v=int.from_bytes(it[7], "big"),
-                r=int.from_bytes(it[8], "big"),
-                s=int.from_bytes(it[9], "big"))
-            h = self.hashes[i]
-            if h is not None:
-                # seed the memoized hash from the wire frame's keccak
-                # (canonical RLP: keccak256(frame) == keccak256(
-                # t.encode())) — admission never re-encodes the row
-                t._SENDER_CACHE["hash"] = h
-            self._txns[i] = t
-        return t
+            # second decode pass — and without the frozen dataclass's
+            # __init__ (eleven object.__setattr__ a row): the instance
+            # dict is set whole, the memoized hash seeded from the wire
+            # frame's keccak (canonical RLP: keccak256(frame) ==
+            # keccak256(t.encode())), so admission never re-encodes
+            idx = np.asarray(need, np.int64)
+            spans = (self._spans[idx].astype(np.int64)
+                     + self._offsets[idx].astype(np.int64)[:, None, None])
+            data, hashes = self._data, self.hashes
+            new, put, num = object.__new__, object.__setattr__, \
+                int.from_bytes
+            # the spans as 20 columns, so that a row is the loop's own
+            # names and allocates nothing
+            for (i, nonce, price, a0, b0, a1, b1, a2, b2, a3, b3, a4, b4,
+                 a5, b5, a6, b6, a7, b7, a8, b8, a9, b9) in zip(
+                    need, self.nonce[idx].tolist(),
+                    self.gas_price[idx].tolist(),
+                    *spans.reshape(len(need), 20).T.tolist()):
+                # the two uint64 columns clip: a wider field is re-read
+                if nonce == _U64_MAX:
+                    nonce = num(data[a0:b0], "big")
+                if price == _U64_MAX:
+                    price = num(data[a1:b1], "big")
+                t = new(Transaction)
+                put(t, "__dict__", {
+                    "nonce": nonce, "gas_price": price,
+                    "gas_limit": num(data[a2:b2], "big"),
+                    "to": data[a3:b3] or None,
+                    "value": num(data[a4:b4], "big"),
+                    "payload": data[a5:b5],
+                    "is_geec": num(data[a6:b6], "big") != 0,
+                    "v": num(data[a7:b7], "big"),
+                    "r": num(data[a8:b8], "big"),
+                    "s": num(data[a9:b9], "big"),
+                    "_SENDER_CACHE": {"hash": hashes[i]}})
+                have[i] = t  # bounded-by: self.n, the window's rows: a slot of a list sized once (an index past it raises)
+        return [have[i] for i in rows]
 
     def gather(self, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """(sighash32, sig65) sub-arrays for ``rows`` — contiguous
@@ -311,9 +335,8 @@ def _decode_frames(frames: list) -> TxColumns:
         # a payload ends where its encoding does
         cols._spans[i] = [(end - len(it), end)
                           for it, (_, end) in zip(items, spans)]
-        cols.nonce[i] = min(int.from_bytes(items[0], "big"), (1 << 64) - 1)
-        cols.gas_price[i] = min(int.from_bytes(items[1], "big"),
-                                (1 << 64) - 1)
+        cols.nonce[i] = min(int.from_bytes(items[0], "big"), _U64_MAX)
+        cols.gas_price[i] = min(int.from_bytes(items[1], "big"), _U64_MAX)
         # signature_parts()'s exact v/r/s rules, span-sliced
         v = int.from_bytes(items[7], "big")
         protected = v not in (27, 28) and v != 0
@@ -383,8 +406,8 @@ def columns_from_txns(txns) -> TxColumns:  # ingress-entry:bounded
         cols.hashes[i] = h
         cols.txhash[i] = np.frombuffer(h, np.uint8)
         cols._txns[i] = t
-        cols.nonce[i] = min(t.nonce, (1 << 64) - 1)
-        cols.gas_price[i] = min(t.gas_price, (1 << 64) - 1)
+        cols.nonce[i] = min(t.nonce, _U64_MAX)
+        cols.gas_price[i] = min(t.gas_price, _U64_MAX)
         parts = t.signature_parts()
         if parts is not None:
             sig, sighash = parts

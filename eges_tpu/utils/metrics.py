@@ -58,7 +58,8 @@ METRIC_FAMILIES = frozenset({
     # sim/faults.py — deterministic fault injection
     "sim.faults_injected",
     # core/txpool.py
-    "txpool.known_clears", "txpool.pending", "txpool.window_undecoded",
+    "txpool.commit_records", "txpool.commit_rows", "txpool.known_clears",
+    "txpool.pending", "txpool.window_undecoded",
     # crypto/ verifiers
     "verifier.batches", "verifier.compile_cache_hits",
     "verifier.compile_cache_misses", "verifier.d2h_seconds",
@@ -185,6 +186,12 @@ METRIC_HELP = {
     "span.self_seconds": (
         "A span's duration minus what its child spans on the same "
         "thread covered, in seconds."),
+    "txpool.commit_records": (
+        "tx.commit records written by remove_included: one an ingest "
+        "trace among a block's transactions."),
+    "txpool.commit_rows": (
+        "Transactions handed to remove_included whose ingest context "
+        "was known."),
     "txpool.known_clears": "Coarse clears of the known-txn dedup set.",
     "txpool.pending": "Transactions pending in the pool.",
     "txpool.window_undecoded": (

@@ -67,8 +67,11 @@ SPANS = {
     "txpool.admit_window": ((), "nonce/balance checks and insertion of "
                                 "one flushed slice"),
     "txpool.admit": ((), "the same for one scalar transaction"),
-    "txpool.evict": ((), "commit eviction, with its per-transaction "
-                         "tx.commit records"),
+    "txpool.evict": ((), "commit eviction, with one tx.commit record "
+                         "an ingest trace among the block's "
+                         "transactions (attrs tx, txns, txs, block; "
+                         "counters txpool.commit_rows, "
+                         "txpool.commit_records, one inc a call)"),
     "sched.submit": (("class", "size"), "row keys, then the window's "
                                         "entry (cache probe, dedup, "
                                         "slots) under one lock hold, up "
