@@ -52,6 +52,19 @@ class ChainGeecConfig:
     # set "signed_votes": false in genesis for reference-parity
     # trustedHW-style deployments.
     signed_votes: bool = True
+    # The ACK (and query) quorum as a fraction of the height's acceptors:
+    # need = ceil(fraction * acceptors), e.g. 0.66 -> 169 of 256.  None
+    # keeps upstream's majority, ceil((acceptors + 1) / 2).  Consensus-
+    # critical like signed_votes: a confirm's certificate is held to it
+    # on every node.  A quorum must outnumber half the acceptors, so the
+    # fraction lies in (0.5, 1].
+    validate_threshold: float | None = None
+
+    def __post_init__(self):
+        f = self.validate_threshold
+        if f is not None and not 0.5 < f <= 1.0:
+            raise ValueError(
+                f"validate_threshold {f!r} is no fraction in (0.5, 1]")
 
     @classmethod
     def from_json(cls, obj: dict) -> "ChainGeecConfig":
@@ -62,6 +75,7 @@ class ChainGeecConfig:
             get_logger("geec.config").warn(
                 "genesis thw section omits 'signed_votes'; defaulting to "
                 "true — pin it explicitly so every node generation agrees")
+        fraction = obj.get("validate_threshold")
         return cls(
             bootstrap=tuple(BootstrapNode.from_json(n)
                             for n in obj.get("bootstrap", [])),
@@ -71,10 +85,12 @@ class ChainGeecConfig:
             election_timeout_ms=float(obj.get("election_timeout", 100)),
             backoff_time_ms=float(obj.get("backoff_time", 0)),
             signed_votes=bool(obj.get("signed_votes", True)),
+            validate_threshold=(None if fraction is None
+                                else float(fraction)),
         )
 
     def to_json(self) -> dict:
-        return {
+        out = {
             "bootstrap": [n.to_json() for n in self.bootstrap],
             "reg_per_blk": self.max_reg_per_blk,
             "registration_timeout": self.reg_timeout_s,
@@ -83,6 +99,11 @@ class ChainGeecConfig:
             "backoff_time": self.backoff_time_ms,
             "signed_votes": self.signed_votes,
         }
+        if self.validate_threshold is not None:
+            # written only where set: a genesis of upstream's rule stays
+            # byte for byte what it was
+            out["validate_threshold"] = self.validate_threshold
+        return out
 
 
 @dataclass(frozen=True)

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import bisect
 from dataclasses import dataclass, field
+from fractions import Fraction
 
 
 @dataclass
@@ -56,9 +57,15 @@ class Membership:
     def __init__(self, n_candidates: int, n_acceptors: int, *,
                  initial_ttl: int = 50, bonus_ttl: int = 20,
                  renew_ttl_threshold: int = 20, max_ttl: int = 50,
-                 ttl_interval: int = 10):
+                 ttl_interval: int = 10,
+                 validate_fraction: float | None = None):
         self.n_candidates = n_candidates
         self.n_acceptors = n_acceptors
+        # the chain's ``validate_threshold`` (consensus/config.py), as
+        # the decimal it was written as: 0.66 is 33/50, not the float
+        # beside it, so 0.66 of 100 is 66 and not 67
+        self.validate_fraction = (None if validate_fraction is None
+                                  else Fraction(str(validate_fraction)))
         self.initial_ttl = initial_ttl
         self.bonus_ttl = bonus_ttl
         self.renew_ttl_threshold = renew_ttl_threshold
@@ -184,8 +191,14 @@ class Membership:
     # -- thresholds (ref: geec_state.go:651, election_go.go:66) -----------
 
     def validate_threshold(self) -> int:
-        """ceil((acceptors + 1) / 2) — proposer needs this many ACKs."""
+        """The ACKs a proposer needs, and the supporters a confirm's
+        certificate must carry: ``ceil(fraction * acceptors)`` where the
+        chain configures ``validate_threshold`` (0.66 of 256 is 169),
+        else upstream's majority ``ceil((acceptors + 1) / 2)``."""
         n = self.acceptor_count()
+        if self.validate_fraction is not None:
+            return -(-self.validate_fraction.numerator * n
+                     // self.validate_fraction.denominator)
         return -(-(n + 1) // 2)
 
     def election_threshold(self, n_committee: int) -> int:

@@ -43,6 +43,7 @@ from eges_tpu.consensus.config import (
     CONFIDENCE_THRESHOLD,
 )
 from eges_tpu.consensus.membership import Member, Membership, derive_seed
+from eges_tpu.consensus.quorum import QuorumTally, handle_direct
 from eges_tpu.consensus.working_block import (
     WorkingBlock, ELEC_CANDIDATE, ELEC_ELECTED, ELEC_VOTED,
     WB_CURRENT, WB_FUTURE, WB_PASSED,
@@ -132,9 +133,14 @@ class GeecNode:
             raise ValueError("signed_votes chain requires a 32-byte privkey")
 
         tp = ttl_params(node_cfg.total_nodes)
-        self.membership = Membership(node_cfg.n_candidates,
-                                     node_cfg.n_acceptors, **tp)
+        self.membership = Membership(
+            node_cfg.n_candidates, node_cfg.n_acceptors,
+            validate_fraction=chain_cfg.validate_threshold, **tp)
         self.membership.journal = self.journal
+        # the quorum arithmetic (consensus/quorum.py): signatures through
+        # the verifier, the ACK tally, a confirm's certificate
+        self.quorum = QuorumTally(self.membership, verifier,
+                                  signing=self._signing, now=clock.now)
         # genesis bootstrap membership (ref: geec_state.go:275-289)
         for bn in chain_cfg.bootstrap:
             self.membership.add(Member(addr=bn.account, ip=bn.ip, port=bn.port,
@@ -325,35 +331,11 @@ class GeecNode:
                                priority="consensus")[0] == author
 
     def _recover_entries(self, entries) -> list:
-        """Recover the signer of each ``(author, sighash, sig)`` entry in
-        ONE verifier batch (or one scheduler window, where the cache
-        strips already-seen votes before the device sees them); per-entry
-        result is the claimed author when the signature checks out, else
-        None.  With signing off every entry passes.  Election acks and
-        QC checks block consensus progress, so the rows enter the
-        scheduler's consensus priority class: they flush ahead of bulk
-        tx-ingest rows and their windows preempt bulk windows at lane
-        placement."""
-        if not self._signing:
-            return [a for a, _, _ in entries]
-        from eges_tpu.crypto.verify_host import recover_signers
-        rec = recover_signers([(h, s) for _, h, s in entries], self.verifier,
-                              priority="consensus")
-        return [a if r == a else None
-                for (a, _, _), r in zip(entries, rec)]
-
-    def _verify_quorum(self, entries) -> dict[bytes, bytes]:
-        """Quorum tally over possibly-multiple entries per author:
-        returns ``{author: verified_sig}`` for every author with at least
-        one valid entry (sig is ``b""`` when signing is off)."""
-        out: dict[bytes, bytes] = {}
-        with tracing.DEFAULT.span("consensus.verify_quorum",
-                                  rows=len(entries)):
-            for (a, _, s), r in zip(entries,
-                                    self._recover_entries(entries)):
-                if r is not None and a not in out:
-                    out[a] = s if self._signing else b""
-        return out
+        """The signer of each ``(author, sighash, sig)`` entry, or None:
+        one verifier call, behind a scheduler one consensus-class window
+        entry of which the cache answers what it has seen
+        (:meth:`QuorumTally.recover_entries`)."""
+        return self.quorum.recover_entries(entries)
 
     # ------------------------------------------------------------------
     # timers
@@ -501,38 +483,9 @@ class GeecNode:
             self._handle_txns(msg)
 
     def on_direct(self, data: bytes) -> None:  # ingress-entry
-        ctx, data = tracing.extract(data)
-        src = ledger.current_peer()
-        with self._lock, tracing.DEFAULT.activate(ctx), \
-                ledger.bind(self.ledger, f"peer:{src}" if src else "net"), \
-                tracing.DEFAULT.span("consensus.handle") as sp:
-            self._on_direct(data, sp)
-
-    def _on_direct(self, data: bytes, sp: tracing.Span) -> None:
-        if len(data) > self.INGRESS_MAX_BYTES:
-            # same decode budget as the gossip plane
-            from eges_tpu.utils.metrics import DEFAULT as metrics
-            metrics.counter("consensus.ingress_oversized").inc()
-            ledger.charge(drops=1)
-            self._log("oversized direct dropped", nbytes=len(data))
-            return
-        if not self._state_reply_fits(data):
-            return
-        try:
-            code, author, msg = M.unpack_direct(data)
-        except Exception as exc:
-            # malformed/unauthenticated datagram: drop, but leave a trace
-            self._log("malformed direct", nbytes=len(data), err=repr(exc))
-            return
-        sp.set_attr("kind", "vote" if code == M.UDP_ELECT
-                    and msg.code == M.MSG_VOTE
-                    else M.DIRECT_KINDS.get(code, "other"))
-        try:
-            self._dispatch_direct(code, msg, author)
-        except Exception as exc:
-            # same contract as the gossip plane: corrupted-but-unpackable
-            # payloads get rejected by the handler, not fatal
-            self._log("direct handler rejected", code=code, err=repr(exc))
+        handle_direct(data, self._dispatch_direct, lock=self._lock,
+                       book=self.ledger, max_bytes=self.INGRESS_MAX_BYTES,
+                       fits=self._state_reply_fits, log=self._log)
 
     def _state_reply_fits(self, data: bytes) -> bool:
         """Pre-decode byte cap for state-sync replies: a state page is
@@ -715,23 +668,28 @@ class GeecNode:
                                                      committee, retry + 1))
 
     def _on_elected(self) -> bool:
-        """Threshold of votes reached -> verify the vote signatures as one
-        device batch, then build + broadcast the proposal.  Returns False
-        (election continues) if pruning forged votes drops the count back
-        below the threshold."""
+        """Threshold of votes reached -> an attempt at the election's
+        quorum: every collected vote signature through the verifier as
+        one call (one scheduler window, of which the cache answers what
+        an earlier attempt recovered), then build + broadcast the
+        proposal.  Returns False (election continues) if pruning forged
+        votes drops the count back below the threshold; the vote that
+        brings it back there starts the next attempt."""
         wb = self.wb
         if self._phase != ELECTING:
             return False
         if self._signing:
             items = [(a, h, s) for a in wb.supporters
                      for (h, s) in wb.supporter_votes.get(a, ())]
-            valid = self._verify_quorum(items)
+            valid = self.quorum.attempt(wb, "election", items,
+                                        wb.election_threshold)
             for a in list(wb.supporters):
                 if a not in valid:
                     wb.supporters.discard(a)
                     wb.supporter_votes.pop(a, None)
             if len(wb.supporters) < wb.election_threshold:
                 return False
+            self.quorum.certified(wb, "election")
         from eges_tpu.utils.metrics import DEFAULT as metrics
         metrics.counter("consensus.elected").inc()
         wb.elect_state = ELEC_ELECTED
@@ -823,6 +781,7 @@ class GeecNode:
         self.wb.validate_replies.clear()
         self.wb.validate_cert = {}
         self.wb.validate_succeeded = False
+        self.wb.quorum_tries.pop("ack", None)
         self._ack_t = self.clock.now()
         self.journal.record("validate_request", blk=req.block_num,
                             version=req.version,
@@ -851,50 +810,21 @@ class GeecNode:
                                                      retry + 1))
 
     def _handle_validate_reply(self, reply: M.ValidateReply) -> None:
-        """Tally ACKs (ref: handleVerifyReplies geec_state.go:1184-1227).
-
-        Only replies from the seeded acceptor window for this height may
-        count toward the quorum (the reference gates acceptor identity via
-        IsValidator on the reply path, geec_state.go:439-521) — otherwise
-        a single peer could fabricate a validate quorum."""
+        """Tally one ACK (:meth:`QuorumTally.ack`, ref:
+        handleVerifyReplies geec_state.go:1184-1227): only an accepting
+        reply of the height's seeded acceptor window for THIS proposal
+        counts; in signed-vote mode the reply that brings the count to
+        the threshold starts an attempt (every collected signature
+        through the verifier, one scheduler window; forgeries pruned),
+        and each reply that brings a pruned count back there the next.
+        The reply that certifies the quorum moves the proposer on."""
         wb = self.wb
-        if reply.block_num != wb.blk_num:
-            return
-        seed = self.seed_for(reply.block_num)
-        if seed is None or not self.membership.is_acceptor(reply.author, seed):
-            return
-        # backfilled empty blocks ride the same certification gate as the
-        # sync plane — an unverified reply must not inject history
-        fills = (self._filter_certified(list(reply.fill_blocks))
-                 if self._signing else reply.fill_blocks)
-        for blk in fills:
-            self.chain.offer(blk)
-        if not reply.accepted:
-            return  # an explicit NACK never counts toward the quorum
-        if (self._proposal is not None
-                and reply.block_hash != self._proposal.hash):
-            return  # an ACK binds a specific block; not ours -> not ours
-        # up to 2 distinct stored replies per author (spoof-squat defense)
-        lst = wb.validate_replies.setdefault(reply.author, [])
-        if len(lst) < 2 and all(r.sig != reply.sig for r in lst):
-            lst.append(reply)
-        if (len(wb.validate_replies) >= wb.validate_threshold
-                and not wb.validate_succeeded and self._phase == VALIDATING):
-            if self._signing:
-                # the config-3 batch point: recover every collected ACK
-                # signature in ONE device call, prune forgeries, and only
-                # then trip the quorum.  The verified signatures become
-                # the confirm's quorum certificate.
-                items = [(r.author, r.signing_hash(), r.sig)
-                         for rl in wb.validate_replies.values() for r in rl]
-                cert = self._verify_quorum(items)
-                for a in list(wb.validate_replies):
-                    if a not in cert:
-                        del wb.validate_replies[a]
-                if len(wb.validate_replies) < wb.validate_threshold:
-                    return  # keep collecting; retry loop re-solicits
-                wb.validate_cert = cert
-            wb.validate_succeeded = True
+        if self.quorum.ack(
+                wb, reply, seed=self.seed_for(reply.block_num),
+                block_hash=(self._proposal.hash
+                            if self._proposal is not None else None),
+                collecting=self._phase == VALIDATING,
+                offer_fills=self._offer_fills):
             self._cancel_timer("validate")
             dt = self.clock.now() - self._ack_t
             self._breakdown("ack", dt, blk=wb.blk_num)
@@ -905,6 +835,15 @@ class GeecNode:
             supporters = tuple(wb.validate_replies.keys())
             self._set_timer("backoff", self.ccfg.backoff_time_ms / 1e3,
                             lambda: self._finish_seal(supporters))
+
+    def _offer_fills(self, fill_blocks) -> None:
+        """A reply's backfilled empty blocks ride the same certification
+        gate as the sync plane — an unverified reply must not inject
+        history."""
+        fills = (self._filter_certified(list(fill_blocks))
+                 if self._signing else fill_blocks)
+        for blk in fills:
+            self.chain.offer(blk)
 
     def _finish_seal(self, supporters: tuple[bytes, ...]) -> None:
         """Confirm + self-insert + broadcast (ref: Seal tail geec.go:356-368
@@ -1221,59 +1160,20 @@ class GeecNode:
             self._request_backfill(target)
 
     def _confirm_cert_entries(self, confirm: ConfirmBlockMsg):
-        """Reconstruct the per-supporter signing hashes of a confirm's
-        quorum certificate, or None if structurally invalid.
-
-        ``version == 0``: supporters signed ACKs (ValidateReply sighash,
-        which binds height + acceptor + the exact block hash).
-        ``version > 0``: supporters signed query replies for the
-        timeout-recovery outcome.  Receivers can therefore re-verify the
-        quorum with NO trust in the proposer — the upgrade over the
-        reference's trustedHW assumption (and over a single-member
-        signature, which one malicious member could mint alone)."""
-        sups, sigs = confirm.supporters, confirm.supporter_sigs
-        if (len(sups) != len(sigs) or len(set(sups)) != len(sups)
-                or len(sups) < self.membership.validate_threshold()):
-            return None
-        entries = []
-        for a, s in zip(sups, sigs):
-            if confirm.version == 0:
-                h = M.ValidateReply(block_num=confirm.block_number, author=a,
-                                    accepted=True,
-                                    block_hash=confirm.hash).signing_hash()
-            else:
-                h = M.QueryReply(
-                    block_num=confirm.block_number, author=a,
-                    version=confirm.version, empty=confirm.empty_block,
-                    block_hash=bytes(32) if confirm.empty_block
-                    else confirm.hash).signing_hash()
-            entries.append((a, h, s))
-        return entries
+        """The per-supporter ``(author, sighash, sig)`` entries of a
+        confirm's quorum certificate, or None if structurally invalid
+        (:meth:`QuorumTally.cert_entries`)."""
+        return self.quorum.cert_entries(confirm)
 
     def _confirm_ok(self, confirm: ConfirmBlockMsg) -> bool:
         """Signed-vote mode: a gossiped confirm is accepted only with a
         valid quorum certificate (>= validate_threshold verified
         supporter signatures; acceptor-window-checked when the seed for
-        that height is known) AND a member signature from its builder
-        (binds the confidence/supporter packaging to a member key).
-
-        The threshold is evaluated against membership as currently known.
-        A syncing node's membership starts at the genesis bootstrap list
-        and grows in step with the blocks it applies, so historical certs
-        meet the as-of-then threshold; the one rough edge is a live
-        confirm racing a threshold-raising membership change, which the
-        timeout/re-election ladder recovers from."""
-        entries = self._confirm_cert_entries(confirm)
-        if entries is None:
-            return False
-        valid = [a for a in self._recover_entries(entries) if a is not None]
-        need = self.membership.validate_threshold()
-        if len(valid) < need:
-            return False
-        seed = self.seed_for(confirm.block_number)
-        if seed is not None and sum(
-                1 for a in valid
-                if self.membership.is_acceptor(a, seed)) < need:
+        that height is known: :meth:`QuorumTally.cert_ok`) AND a member
+        signature from its builder (binds the confidence/supporter
+        packaging to a member key)."""
+        if not self.quorum.cert_ok(confirm,
+                                   self.seed_for(confirm.block_number)):
             return False
         if len(confirm.sig) != 65:
             return False
@@ -2467,6 +2367,7 @@ class GeecNode:
         wb.query_empty_count = 0
         wb.query_nonempty_count = 0
         wb.query_recv_majority = False
+        wb.quorum_tries.pop("query", None)
         self._phase = VALIDATING  # reuse phase slot for retry gating
         self._query_retry(blk_num, version, 0)
 
@@ -2498,12 +2399,14 @@ class GeecNode:
             if self._signing:
                 items = [(r.author, r.signing_hash(), r.sig)
                          for rl in wb.query_replies.values() for r in rl]
-                cert = self._verify_quorum(items)
+                cert = self.quorum.attempt(wb, "query", items,
+                                           wb.query_threshold)
                 for a in list(wb.query_replies):
                     if a not in cert:
                         del wb.query_replies[a]
                 if len(wb.query_replies) < wb.query_threshold:
                     return  # keep collecting; query retry re-solicits
+                self.quorum.certified(wb, "query")
                 wb.query_cert = cert
                 # the verified reply per author = the one whose sig the
                 # batch recovered

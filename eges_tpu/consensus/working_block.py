@@ -73,6 +73,10 @@ class WorkingBlock:
         self.query_nonempty_count = 0
         self.query_threshold = 1 << 62
         self.query_recv_majority = False
+        # signed-vote mode, per kind of quorum ("election", "ack",
+        # "query"): [attempts so far, when the count first stood at the
+        # threshold] (consensus/quorum.py); gone once the quorum stands
+        self.quorum_tries: dict[str, list] = {}
 
     def classify(self, blk_num: int) -> int:
         """Old / current / future for an incoming message's height
@@ -93,3 +97,4 @@ class WorkingBlock:
             self.elect_state = ELEC_CANDIDATE
             self.supporters.clear()
             self.supporter_votes.clear()
+            self.quorum_tries.clear()

@@ -44,6 +44,13 @@ METRIC_FAMILIES = frozenset({
     "consensus.geec_txn_dropped", "consensus.ingress_oversized",
     "consensus.phase_seconds", "consensus.reg_req_dropped",
     "consensus.sealed", "membership.min_ttl", "membership.size",
+    # consensus/quorum.py — a quorum's tally: attempts (every collected
+    # signature through the verifier), the signatures they handed over,
+    # the authors they pruned, the quorums certified, and the time from
+    # the count first standing at the threshold to the quorum certified
+    "consensus.quorum_attempts", "consensus.quorum_pruned",
+    "consensus.quorum_rows", "consensus.quorum_seconds",
+    "consensus.quorums",
     # ingress/columnar.py — frames handed to decode_window, and those
     # that went through the native window decoder
     "ingress.decode_native_rows", "ingress.decode_rows",
@@ -165,6 +172,15 @@ METRIC_HELP = {
     "consensus.reg_req_dropped": "Pending registrations evicted at "
                                  "REG_PENDING_MAX.",
     "consensus.phase_seconds": "Consensus phase duration in seconds.",
+    "consensus.quorum_attempts": "Attempts at a quorum: every collected "
+                                 "signature through the verifier.",
+    "consensus.quorum_pruned": "Authors an attempt dropped for want of a "
+                               "valid signature.",
+    "consensus.quorum_rows": "Signatures the attempts handed to the "
+                             "verifier.",
+    "consensus.quorum_seconds": "From the count of replies first standing "
+                                "at the threshold to the quorum certified.",
+    "consensus.quorums": "Quorums certified (election, ACK, query).",
     "consensus.sealed": "Blocks sealed by this node.",
     "membership.min_ttl": "Minimum TTL across registered members.",
     "membership.size": "Registered committee members.",

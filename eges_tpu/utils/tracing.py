@@ -90,8 +90,15 @@ SPANS = {
                           "futures set"),
     "consensus.handle": (("kind",), "one gossip or direct message handled, "
                                     "under the node's lock"),
-    "consensus.verify_quorum": ((), "a quorum's signatures through the "
-                                    "scheduler"),
+    "consensus.verify_quorum": ((), "one attempt at a quorum: every "
+                                    "collected signature through the "
+                                    "scheduler (consensus/quorum.py); "
+                                    "attrs rows, attempt (from 1), need; "
+                                    "counters consensus.quorum_attempts, "
+                                    "consensus.quorum_rows, "
+                                    "consensus.quorum_pruned, "
+                                    "consensus.quorums, one inc a call; "
+                                    "histogram consensus.quorum_seconds"),
     "chain.insert": ((), "execute, state root, index"),
     "chain.recover_senders": ((), "a block's signed rows through the "
                                   "verifier in one call (core/state.py): "
