@@ -27,7 +27,16 @@ JAX-free :class:`~eges_tpu.crypto.verify_host.NativeBatchVerifier`):
 * a flush that coalesced down to a single row is diverted to the host
   recovery path instead of the device: a padded 1-row device dispatch
   costs more than one native recover, and diverting keeps
-  ``verifier.singleton_batches`` at zero in steady state.
+  ``verifier.singleton_batches`` at zero in steady state.  A
+  consensus-class flush of a FEW rows joins it where its lane's target
+  would pad it to a bucket at least ``HOST_WINDOW_RATIO`` times its
+  rows (up to 8 rows under the kernel path's 256-row floor; never on a
+  16-row ladder, never on a target that reports no bucket: the native
+  verifiers): what such a window costs on the device is the round trip,
+  not the rows, and a quorum is waiting for it.  It is answered inline
+  on the dispatch thread in ONE native call for all of its rows
+  (:meth:`VerifierScheduler._host_served`).  Bulk windows keep the
+  one-row rule alone.
 
 **Mesh dispatch.** When the backing verifier exposes ``device_targets()``
 (:class:`~eges_tpu.crypto.verifier.MeshBatchVerifier`, or the host-model
@@ -220,6 +229,14 @@ def _class_of(priority: str) -> str:
 # be mixed.
 BURST_ROWS = 1000
 
+# A consensus-class window of a few rows is answered on the host, as a
+# one-row window is, when its lane's target would pad it to a bucket of
+# at least this many times its rows (``VerifierScheduler._host_served``):
+# up to 8 rows under the kernel path's 256-row floor, none on a 16-row
+# ladder.  A constant, not a policy knob; PERF.md has the crossover,
+# measured on the chip's host.
+HOST_WINDOW_RATIO = 32
+
 # A lane's window is a straggler, and is hedged onto a sibling lane, once
 # its age exceeds this many medians of the lane's recent window totals.
 HEDGE_FACTOR = 3.0
@@ -315,7 +332,8 @@ class _PendingWindow:
 
     __slots__ = ("batch", "keys", "reason", "t0", "rows", "results",
                  "staged", "probing", "diverted", "computed", "failure",
-                 "finished", "t_dispatch", "t_collect", "ticket", "flight")
+                 "finished", "t_dispatch", "t_collect", "ticket", "flight",
+                 "hosted")
 
 
 class _WindowTicket:
@@ -478,7 +496,10 @@ class VerifierScheduler:
         self._stats = {  # guarded-by: _lock
             "cache_hits": 0, "cache_misses": 0, "cache_served_rows": 0,
             "coalesced_rows": 0,
-            "batches": 0, "rows": 0, "bucket_rows": 0, "host_diverted": 0,
+            "batches": 0, "rows": 0, "bucket_rows": 0,
+            # windows answered on the host BY RULE (one row, or a small
+            # consensus window: _host_served), and their rows
+            "host_diverted": 0, "host_diverted_rows": 0,
             "kicks": 0, "flush_full": 0, "flush_deadline": 0,
             "flush_kick": 0, "flush_close": 0, "invalid": 0,
             "device_errors": 0, "breaker_trips": 0, "breaker_probes": 0,
@@ -966,26 +987,69 @@ class VerifierScheduler:
         while len(cache) > self.cache_size:
             cache.popitem(last=False)
 
-    def _host_recover(self, key: tuple):
-        """One host-path recovery (native C++ single recover when built,
-        pure-Python model otherwise) — the divert target for flushes
-        that coalesced down to a single row, and the post-close inline
-        path.  Counts into ``verifier.host_rows`` like every other host
-        fallback so the device-share metric stays honest."""
-        h, sig = key
+    def _host_recover_rows(self, keys) -> list:
+        """The host recovery path: the rows of ``keys`` in ONE native
+        call (``ec_recover_batch``: the rows in parallel, no GIL held)
+        when the C++ library is built, the pure-Python model a row
+        otherwise.  The divert target of a window served on the host by
+        rule (:meth:`_host_served`) or because its lane's device died,
+        and of a row after close.  Counts into ``verifier.host_rows``
+        like every other host fallback so the device-share metric stays
+        honest."""
         from eges_tpu.crypto.verify_host import _count_host_rows
-        _count_host_rows(1)
+        n = len(keys)
+        _count_host_rows(n)
         from eges_tpu.crypto import native
         if native.available():
             from eges_tpu.crypto.keccak import keccak256
-            pubs, okb = native.ec_recover_batch(h, sig, 1)
-            return keccak256(pubs[:64])[12:] if okb[0] else None
+            pubs, okb = native.ec_recover_batch(
+                b"".join([k[0] for k in keys]),
+                b"".join([k[1] for k in keys]), n)
+            return [keccak256(pubs[64 * i:64 * i + 64])[12:]
+                    if okb[i] else None for i in range(n)]
         from eges_tpu.crypto import secp256k1 as host
-        try:
-            return host.recover_address(h, sig)
-        # analysis: allow-swallow(invalid signature maps to a None result)
-        except Exception:
-            return None
+        out = []
+        for h, sig in keys:
+            try:
+                out.append(host.recover_address(h, sig))
+            # analysis: allow-swallow(invalid signature maps to a None result)
+            except Exception:
+                out.append(None)
+        return out
+
+    def _host_recover(self, key: tuple):
+        """One row through :meth:`_host_recover_rows`."""
+        return self._host_recover_rows((key,))[0]
+
+    def _target_pad(self, lane: _DeviceLane):
+        """The function by which ``lane``'s target pads a window's rows
+        to a bucket; None for a target that reports none (the native
+        verifiers, a test's stub)."""
+        return (getattr(lane.target, "_pad", None)
+                or getattr(self._verifier, "_pad", None))
+
+    def _host_served(self, lane: _DeviceLane, batch) -> bool:
+        """Whether the flushed window ``batch`` is answered on the host
+        by rule, healthy device or not.  A function of what the window
+        and its target are, nothing else: its rows, its class (any row
+        of it entered as ``consensus``) and the bucket ``lane``'s target
+        would pad it to.  One row: always (a padded 1-row dispatch costs
+        more than one native recover).  More: a consensus-class window
+        whose bucket is at least ``HOST_WINDOW_RATIO`` times its rows,
+        where the native library is there to recover them in one call
+        (the pure-Python model is milliseconds a row).  Bulk windows are
+        throughput's, and nobody's quorum waits on them: they keep the
+        device."""
+        rows = len(batch)
+        if rows == 1:
+            return True
+        pad = self._target_pad(lane)
+        if pad is None or rows * HOST_WINDOW_RATIO > pad(rows):
+            return False
+        if not any(row[2] == "consensus" for _k, row in batch):
+            return False
+        from eges_tpu.crypto import native
+        return native.available()
 
     def _dispatch_loop(self) -> None:
         """Wrapper keeping the strand-no-row invariant: if the flush
@@ -1055,7 +1119,8 @@ class VerifierScheduler:
                 batch = [(k, self._pending.pop(k)) for k in keys]
                 if not self._pending:
                     self._kick = False
-            if (len(self._lanes) > 1 or self._pipelined) and len(batch) > 1:
+            if ((len(self._lanes) > 1 or self._pipelined)
+                    and not self._host_served(self._lanes[0], batch)):
                 # mesh windows go to the per-device lanes; single-lane
                 # pipeline-capable targets ALSO route through the lane
                 # worker, whose begin/finish split overlaps consecutive
@@ -1064,8 +1129,10 @@ class VerifierScheduler:
                     self._place(batch, reason)
                 continue
             try:
-                # single-lane (or singleton) windows dispatch inline on
-                # this thread — the pre-mesh behavior, no lane workers
+                # single-lane windows, and those the host answers by
+                # rule (one row, a small consensus window), dispatch
+                # inline on this thread: the pre-mesh behavior, no lane
+                # worker, nothing waits behind a lane's window in flight
                 self._run_batch(self._lanes[0], batch, reason)
             # the batch's futures were already resolved or failed inside
             # _run_batch's finally; the loop survives to the next window
@@ -1223,7 +1290,7 @@ class VerifierScheduler:
                             and nxt_p.failure is None):
                         pending = nxt_p
                     else:
-                        # host-diverted / singleton / failed windows
+                        # host-served (by rule or divert) / failed windows
                         # have nothing on the device — finish them now
                         self._finish_lane_window(lane, nxt_p)
         except BaseException as exc:
@@ -1328,7 +1395,7 @@ class VerifierScheduler:
 
     def _begin_batch(self, lane: _DeviceLane, batch, reason: str,
                      ticket: "_WindowTicket | None" = None) -> _PendingWindow:
-        """Phase 1 of one window: singleton/breaker divert decisions,
+        """Phase 1 of one window: host-by-rule/breaker divert decisions,
         numpy fill, and the device dispatch.  On a pipeline-capable
         target the dispatch is split-phase (stage H2D + async commit,
         left in ``staged`` for ``_finish_batch`` to collect); otherwise
@@ -1351,16 +1418,22 @@ class VerifierScheduler:
         p.t_dispatch = None
         p.t_collect = None
         p.flight = None
+        p.hosted = False
         # analysis: allow-determinism(batch latency instrumentation; dt/waited_ms are volatile-stripped)
         p.t0 = time.monotonic()
         try:
-            if p.rows == 1:
-                # singleton divert: a padded 1-row device dispatch costs
-                # more than one native recover — keep the device for
-                # real batches and verifier.singleton_batches at zero
-                p.results[0] = self._host_recover(p.keys[0])
+            if self._host_served(lane, batch):
+                # host divert by rule: a padded 1-row device dispatch
+                # costs more than one native recover, and so does a few
+                # rows' round trip that a quorum waits for — keep the
+                # device for real batches and
+                # verifier.singleton_batches at zero.  The breaker is
+                # not consulted: nothing touches the device
+                p.hosted = True
+                p.results = self._host_recover_rows(p.keys)
                 with self._lock:
                     self._stats["host_diverted"] += 1
+                    self._stats["host_diverted_rows"] += p.rows
                     lane.stats["host_diverted"] += 1
                 p.computed = True
                 return p
@@ -1370,7 +1443,7 @@ class VerifierScheduler:
                 # — the whole window takes the host recover path so
                 # consensus keeps committing (other lanes are
                 # unaffected: the breaker is lane-scoped)
-                p.results = [self._host_recover(k) for k in p.keys]
+                p.results = self._host_recover_rows(p.keys)
                 p.diverted = True
                 with self._lock:
                     self._stats["breaker_diverted"] += p.rows
@@ -1406,7 +1479,7 @@ class VerifierScheduler:
             # lane's circuit breaker for the windows after it)
             except Exception:
                 self._breaker_trip(lane, p.probing)
-                p.results = [self._host_recover(k) for k in p.keys]
+                p.results = self._host_recover_rows(p.keys)
                 p.diverted = True
                 p.computed = True
         except BaseException as exc:
@@ -1437,7 +1510,7 @@ class VerifierScheduler:
             # failure would)
             except Exception:
                 self._breaker_trip(lane, p.probing)
-                p.results = [self._host_recover(k) for k in p.keys]
+                p.results = self._host_recover_rows(p.keys)
                 p.diverted = True
                 p.computed = True
             except BaseException as exc:
@@ -1541,11 +1614,16 @@ class VerifierScheduler:
             # for nothing — bill the waste to the device-efficiency
             # ledger at the padded size
             from eges_tpu.utils import devstats
-            pad = getattr(lane.target, "_pad", None) \
-                or getattr(self._verifier, "_pad", None) or bucket_round
             devstats.DEFAULT.observe_hedge_waste(
-                lane.index, p.rows, pad(p.rows) if p.rows > 1 else 1)
+                lane.index, p.rows, self._bucket_of(lane, p))
         return won
+
+    def _bucket_of(self, lane: _DeviceLane, p: _PendingWindow) -> int:
+        """The padded rows ``p`` cost: its target's bucket, or its own
+        rows where the host answered it by rule (it padded nothing)."""
+        if p.hosted:
+            return p.rows
+        return (self._target_pad(lane) or bucket_round)(p.rows)
 
     def _answer(self, p: _PendingWindow) -> int:
         """Every holder of the batch gets its row's value exactly once:
@@ -1588,9 +1666,7 @@ class VerifierScheduler:
         groups = Counter([(row[1], row[2]) for _, row in batch])
         # analysis: allow-determinism(batch latency instrumentation; waited_ms is volatile-stripped)
         done = time.monotonic()
-        pad = getattr(lane.target, "_pad", None) \
-            or getattr(self._verifier, "_pad", None) or bucket_round
-        bucket = pad(rows) if rows > 1 else 1  # diverted rows pad nothing
+        bucket = self._bucket_of(lane, p)
         oldest = min(t for t, _ in groups)
         waited = p.t0 - oldest
         tk = p.ticket
@@ -1682,11 +1758,11 @@ class VerifierScheduler:
                     itertools.repeat((p.t0 - t_submit) * 1e3, n))
         # per-origin window cost: each captured origin gets its row
         # count plus its row-share of the window's wall-clock interior,
-        # booked as host-ms when the rows were host-served (singleton
+        # booked as host-ms when the rows were host-served (by rule,
         # or breaker/straggler divert) and device-ms otherwise
         if origin_rows:
             win_ms = (done - p.t0) * 1e3
-            host_served = p.diverted or rows == 1
+            host_served = p.diverted or p.hosted
             for (led, origin), n in origin_rows.items():
                 ms = win_ms * (n / rows)
                 led.charge(origin, rows=n,
@@ -1723,13 +1799,13 @@ class VerifierScheduler:
         # device-efficiency ledger (utils/devstats.py): deterministic
         # count deltas only — the goodput numerator/denominator this
         # window contributed, journaled on the next devstats tick.
-        # Host-served windows (singleton or breaker/straggler divert)
+        # Host-served windows (by rule, or breaker/straggler divert)
         # padded no device bucket, so they land in the rescue column.
         from eges_tpu.utils import devstats
         devstats.DEFAULT.observe_window(
             lane.index, rows, bucket,
             cache_rows=cache_rows, dedup_rows=dedup_rows,
-            diverted=bool(p.diverted or rows == 1),
+            diverted=bool(p.diverted or p.hosted),
             hedged=flight["hedged"])
         journal = self.journal
         if journal is not None:

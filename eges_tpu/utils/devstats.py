@@ -302,7 +302,7 @@ class GoodputLedger:
                        diverted: bool = False,
                        hedged: bool = False) -> None:  # hot-path-entry
         """One recorded (winner) scheduler window.  Host-served windows
-        (singletons and breaker/straggler diverts) never padded a
+        (by rule, and breaker/straggler diverts) never padded a
         device bucket, so their rows stay out of the goodput
         denominator and land in the ``diverted_rows`` rescue column
         instead."""

@@ -10,6 +10,9 @@
 
 #include <cstdint>
 #include <cstring>
+#ifdef _OPENMP
+#include <omp.h>
+#endif
 
 typedef unsigned __int128 u128;
 
@@ -563,11 +566,22 @@ int geec_ec_pubkey(const uint8_t priv32[32], uint8_t pub64[64]) {
   return 0;
 }
 
-// Batched recover: n rows; ok[i] = 1 on success. Host-parallel loop.
+// Batched recover: n rows; ok[i] = 1 on success. Host-parallel loop, on
+// a team of no more threads than rows: a window of a few rows (the
+// scheduler's host-served consensus windows) wakes only the threads that
+// get a row.  A whole team woken for 3 rows leaves a dozen threads
+// spinning at the barrier on every core, and whichever thread the kernel
+// then displaces, the caller among them, waits a scheduler slice behind a
+// spinner (PERF.md, PR 37: 6 ms in one such window in nine).
 void geec_ec_recover_batch(const uint8_t* hashes /* n*32 */,
                            const uint8_t* sigs /* n*65 */, uint64_t n,
                            uint8_t* pubs /* n*64 */, uint8_t* ok /* n */) {
-#pragma omp parallel for schedule(static)
+  int team = 1;
+#ifdef _OPENMP
+  team = omp_get_max_threads();
+  if ((uint64_t)team > n) team = n ? (int)n : 1;
+#endif
+#pragma omp parallel for schedule(static) num_threads(team)
   for (uint64_t i = 0; i < n; i++)
     ok[i] = geec_ec_recover(hashes + 32 * i, sigs + 65 * i, pubs + 64 * i) == 0;
 }
