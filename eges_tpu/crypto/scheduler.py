@@ -333,7 +333,7 @@ class _PendingWindow:
     __slots__ = ("batch", "keys", "reason", "t0", "rows", "results",
                  "staged", "probing", "diverted", "computed", "failure",
                  "finished", "t_dispatch", "t_collect", "ticket", "flight",
-                 "hosted")
+                 "hosted", "t_flush")
 
 
 class _WindowTicket:
@@ -350,16 +350,19 @@ class _WindowTicket:
     and it skips ``_record_window``, so stats, journal events, flight
     entries and ledger charges all happen exactly once per window).
     Every field is guarded by the owning scheduler's ``self._lock``
-    except ``batch``/``reason``/``klass``/``rows``/``lane``, which are
-    immutable after construction.
+    except ``batch``/``reason``/``klass``/``rows``/``lane``/``t_flush``,
+    which are immutable after construction.
     """
 
-    __slots__ = ("batch", "reason", "klass", "rows", "lane",
+    __slots__ = ("batch", "reason", "klass", "rows", "lane", "t_flush",
                  "hedge_lane", "t_placed", "hedged", "winner")
 
-    def __init__(self, batch, reason: str, klass: str, lane: int):
+    def __init__(self, batch, reason: str, klass: str, lane: int,
+                 t_flush: float):
         self.batch = batch
         self.reason = reason
+        # when the dispatcher popped the window this chunk was cut from
+        self.t_flush = t_flush
         self.klass = klass           # "consensus" | "bulk"
         self.rows = len(batch)
         self.lane = lane             # primary placement lane index
@@ -921,11 +924,14 @@ class VerifierScheduler:
         ``thw_flight`` hands out at most — and evictions count into
         ``stats()["flight_dropped"]`` / ``verifier.flight_dropped``);
         ``limit`` keeps only the newest N.  Each entry is one window's
-        lifecycle: phase timestamps (``t_submit``/``t_begin``/
-        ``t_dispatch``/``t_collect``/``t_done``), phase durations
-        (``wait_ms``, ``stage_ms``, ``compute_ms``, ``resolve_ms``: from
-        the device's answer to the window's last future set, the
-        recording included) and lane/device attribution."""
+        lifecycle: phase timestamps (``t_submit``/``t_flush``/
+        ``t_begin``/``t_dispatch``/``t_collect``/``t_done``), phase
+        durations (``wait_ms`` and its two halves ``flush_ms``, the
+        oldest row's entry to the dispatcher's pop, and
+        ``lane_wait_ms``, the pop to the stage's begin; ``stage_ms``,
+        ``compute_ms``, ``resolve_ms``: from the device's answer to the
+        window's last future set, the recording included) and
+        lane/device attribution."""
         with self._lock:
             evs = self._newest_flights(limit) if limit and limit > 0 \
                 else self._flights
@@ -1119,6 +1125,11 @@ class VerifierScheduler:
                 batch = [(k, self._pending.pop(k)) for k in keys]
                 if not self._pending:
                     self._kick = False
+                # flight-recorder stamp: the window leaves ``_pending``;
+                # what it waited before is coalescing or this thread's
+                # wake-up, what it waits after is a lane's
+                # analysis: allow-determinism(flight recorder timestamps are wall-clock by design and never journaled)
+                t_flush = time.monotonic()
             if ((len(self._lanes) > 1 or self._pipelined)
                     and not self._host_served(self._lanes[0], batch)):
                 # mesh windows go to the per-device lanes; single-lane
@@ -1126,14 +1137,14 @@ class VerifierScheduler:
                 # worker, whose begin/finish split overlaps consecutive
                 # windows (inline dispatch can't — it must block)
                 with tracing.DEFAULT.span("sched.place", rows=len(batch)):
-                    self._place(batch, reason)
+                    self._place(batch, reason, t_flush)
                 continue
             try:
                 # single-lane windows, and those the host answers by
                 # rule (one row, a small consensus window), dispatch
                 # inline on this thread: the pre-mesh behavior, no lane
                 # worker, nothing waits behind a lane's window in flight
-                self._run_batch(self._lanes[0], batch, reason)
+                self._run_batch(self._lanes[0], batch, reason, t_flush)
             # the batch's futures were already resolved or failed inside
             # _run_batch's finally; the loop survives to the next window
             # analysis: allow-swallow(futures already resolved/failed in _run_batch finally)
@@ -1142,8 +1153,9 @@ class VerifierScheduler:
 
     # -- mesh placement ---------------------------------------------------
 
-    def _place(self, batch, reason: str) -> None:
-        """Place one flushed window onto the device lanes.
+    def _place(self, batch, reason: str, t_flush: float) -> None:
+        """Place one flushed window onto the device lanes (its chunks
+        share ``t_flush``, the moment the dispatcher popped it).
 
         A window at most ``chunk_cap = max(min_split, max_batch/lanes)``
         rows fills the single least-loaded lane; a larger one splits
@@ -1183,7 +1195,8 @@ class VerifierScheduler:
             if len(chunks) > 1:
                 self._stats["window_splits"] += 1
             for chunk, lane in zip(chunks, order):
-                tk = _WindowTicket(chunk, reason, klass, lane.index)
+                tk = _WindowTicket(chunk, reason, klass, lane.index,
+                                   t_flush)
                 if klass == "consensus":
                     lane.queue.appendleft(tk)
                 else:
@@ -1263,6 +1276,7 @@ class VerifierScheduler:
                                                   device=lane.index):
                             nxt_p = self._begin_batch(lane, nxt.batch,
                                                       nxt.reason,
+                                                      nxt.t_flush,
                                                       ticket=nxt)
                         if (pending is not None and nxt_p.staged is not None
                                 and nxt_p.failure is None):
@@ -1275,7 +1289,7 @@ class VerifierScheduler:
                     else:
                         try:
                             self._run_batch(lane, nxt.batch, nxt.reason,
-                                            ticket=nxt)
+                                            nxt.t_flush, ticket=nxt)
                         # analysis: allow-swallow(futures already resolved/failed in _run_batch finally; the lane survives to its next window)
                         except Exception:
                             pass
@@ -1381,6 +1395,7 @@ class VerifierScheduler:
     # -- window execution -------------------------------------------------
 
     def _run_batch(self, lane: _DeviceLane, batch, reason: str,
+                   t_flush: float,
                    ticket: "_WindowTicket | None" = None) -> None:
         """Dispatch one coalesced window (or mesh chunk) on ``lane``,
         OUTSIDE the scheduler lock (the device call is the long pole;
@@ -1390,13 +1405,15 @@ class VerifierScheduler:
         overlap — the pre-pipeline behavior."""
         with tracing.DEFAULT.span("sched.stage", rows=len(batch),
                                   device=lane.index):
-            p = self._begin_batch(lane, batch, reason, ticket)
+            p = self._begin_batch(lane, batch, reason, t_flush, ticket)
         self._finish_batch(lane, p)
 
     def _begin_batch(self, lane: _DeviceLane, batch, reason: str,
+                     t_flush: float,
                      ticket: "_WindowTicket | None" = None) -> _PendingWindow:
         """Phase 1 of one window: host-by-rule/breaker divert decisions,
-        numpy fill, and the device dispatch.  On a pipeline-capable
+        numpy fill, and the device dispatch.  ``t_flush`` is when the
+        dispatcher popped the window (a chunk's: its ticket's).  On a pipeline-capable
         target the dispatch is split-phase (stage H2D + async commit,
         left in ``staged`` for ``_finish_batch`` to collect); otherwise
         the device call runs to completion here.  NEVER raises — any
@@ -1421,6 +1438,7 @@ class VerifierScheduler:
         p.hosted = False
         # analysis: allow-determinism(batch latency instrumentation; dt/waited_ms are volatile-stripped)
         p.t0 = time.monotonic()
+        p.t_flush = t_flush
         try:
             if self._host_served(lane, batch):
                 # host divert by rule: a padded 1-row device dispatch
@@ -1681,10 +1699,17 @@ class VerifierScheduler:
             "reason": p.reason, "diverted": bool(p.diverted),
             "probing": bool(p.probing),
             "pipelined": p.staged is not None,
-            "t_submit": round(oldest, 6), "t_begin": round(p.t0, 6),
+            "t_submit": round(oldest, 6), "t_flush": round(p.t_flush, 6),
+            "t_begin": round(p.t0, 6),
             "t_dispatch": round(t_dispatch, 6),
             "t_collect": round(t_collect, 6), "t_done": round(done, 6),
             "wait_ms": round(waited * 1e3, 3),
+            # the wait's two halves: to the dispatcher's pop (coalescing
+            # up to the deadline or, for a kicked call, its wake-up),
+            # then to the stage's begin (sched.place, the lane's queue,
+            # the lane worker's wake-up)
+            "flush_ms": round((p.t_flush - oldest) * 1e3, 3),
+            "lane_wait_ms": round((p.t0 - p.t_flush) * 1e3, 3),
             "stage_ms": round((t_dispatch - p.t0) * 1e3, 3),
             "compute_ms": round((t_collect - t_dispatch) * 1e3, 3),
             # results, the ticket claim and the cache inserts so far;

@@ -59,9 +59,13 @@ METRIC_FAMILIES = frozenset({
     "net.gossip_bytes", "net.gossip_msgs", "net.peer_count",
     # node/service.py — how late the event loop's 20 ms tick fires
     "service.loop_lag_seconds",
-    # utils/tracing.py — every live span's duration and self time,
-    # labelled ``;name=<span>[,<label>=<value>]``
-    "span.seconds", "span.self_seconds",
+    # utils/tracing.py — every live span's duration, self time and
+    # self CPU time, labelled ``;name=<span>[,<label>=<value>]``
+    "span.seconds", "span.self_cpu_seconds", "span.self_seconds",
+    # utils/profiler.py read_cpu — the process's CPU time and its live
+    # Python threads' by role (``;role=<role>``), set when the DEFAULT
+    # registry is read
+    "process.cpu_seconds", "threads.cpu_seconds",
     # sim/faults.py — deterministic fault injection
     "sim.faults_injected",
     # core/txpool.py
@@ -198,7 +202,16 @@ METRIC_HELP = {
     "service.loop_lag_seconds": (
         "Lateness of the service event loop's 20 ms tick, in seconds."),
     "sim.faults_injected": "Scripted faults injected by the chaos harness.",
+    "process.cpu_seconds": (
+        "CPU time of the whole process, every native thread included, "
+        "read when the registry is read, in seconds."),
+    "threads.cpu_seconds": (
+        "CPU time of the live Python threads of one role, read when the "
+        "registry is read, in seconds."),
     "span.seconds": "Duration of a program span, by name, in seconds.",
+    "span.self_cpu_seconds": (
+        "CPU time a span's thread ran inside it, less its child spans' "
+        "on the same thread, in seconds."),
     "span.self_seconds": (
         "A span's duration minus what its child spans on the same "
         "thread covered, in seconds."),
@@ -464,6 +477,26 @@ class Histogram:
                                + (self._rng.random() < keep % 1.0)):
                     self._sample[self._rng.randrange(self.RESERVOIR)] = v
 
+    def _take(self, v: float, slot: int | None) -> int | None:
+        """One observation with the lock held by the caller
+        (:func:`observe_together`); ``slot`` is Algorithm R's draw where
+        a histogram observed in step has made it already.  Returns the
+        draw."""
+        self.count += 1
+        self.total += v
+        if v < self.min:
+            self.min = v
+        if v > self.max:
+            self.max = v
+        if len(self._sample) < self.RESERVOIR:
+            self._sample.append(v)
+            return slot
+        if slot is None:
+            slot = int(self._rng.random() * self.count)
+        if slot < self.RESERVOIR:
+            self._sample[slot] = v
+        return slot
+
     def percentile(self, q: float) -> float:
         with self._lock:
             vals = sorted(self._sample)
@@ -477,6 +510,27 @@ class Histogram:
     @property
     def mean(self) -> float:
         return self.total / self.count if self.count else 0.0
+
+
+def together(*hists: Histogram) -> tuple:
+    """Make histograms that are only ever observed together (a span's
+    duration and self time) share the first's lock, so
+    that :func:`observe_together` covers all in one hold and a reader of
+    any of them excludes the writer."""
+    for h in hists[1:]:
+        h._lock = hists[0]._lock
+    return hists
+
+
+def observe_together(hists: tuple, values: tuple) -> None:
+    """One observation each of histograms joined by :func:`together`:
+    one lock hold and, once the reservoirs are full, ONE draw for all
+    (their counts move in step, so Algorithm R's slot is the same, and
+    the reservoirs keep the same observations)."""
+    with hists[0]._lock:
+        slot = None
+        for h, v in zip(hists, values):
+            slot = h._take(v, slot)
 
 
 class Registry:
@@ -507,9 +561,22 @@ class Registry:
     def histogram(self, name: str) -> Histogram:
         return self._get(name, Histogram)
 
-    def snapshot(self) -> dict:
+    def _read(self) -> list:
+        """The metrics by name, for ``snapshot`` and
+        ``prometheus_text``.  The DEFAULT registry first reads the
+        process's CPU time and its threads' by role off the process
+        (``profiler.read_cpu``: gauges that are read then, not emitted
+        on anybody's path)."""
+        if self is DEFAULT:
+            # utils/profiler.py has the roles, and imports nothing of
+            # this module at import time
+            from eges_tpu.utils import profiler
+            profiler.read_cpu(self)
         with self._lock:
-            metrics = sorted(self._metrics.items())
+            return sorted(self._metrics.items())
+
+    def snapshot(self) -> dict:
+        metrics = self._read()
         out = {}
         for name, m in metrics:
             if isinstance(m, Counter):
@@ -590,8 +657,7 @@ def prometheus_text(registry: "Registry | None" = None) -> str:
     ``verifier.device_name``) become ``<name>_info{value="..."} 1``.
     """
     reg = registry if registry is not None else DEFAULT
-    with reg._lock:
-        metrics = sorted(reg._metrics.items())
+    metrics = reg._read()
 
     families: dict[str, list[tuple[str, dict, object]]] = {}
     for name, m in metrics:

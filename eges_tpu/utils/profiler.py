@@ -124,12 +124,48 @@ _ROLE_PREFIXES = (
 )
 
 
+# the closed vocabulary of roles (``main`` is also a real node's event
+# loop: ``node/__main__.py`` runs it on the main thread; the pool's
+# timer is the clock's, so the loop's again)
+ROLES = tuple(dict.fromkeys(role for _, role in _ROLE_PREFIXES)) + ("other",)
+
+
 def role_of(thread_name: str) -> str:
     """Map a thread name onto the role vocabulary (``other`` if none)."""
     for prefix, role in _ROLE_PREFIXES:
         if thread_name.startswith(prefix):
             return role
     return "other"
+
+
+def read_cpu(metrics) -> None:
+    """Set ``process.cpu_seconds`` (``time.process_time()``: every
+    thread of the process, the native verifier's OpenMP team and XLA's
+    among them) and ``threads.cpu_seconds;role=<role>`` (the CPU clocks
+    of the live Python threads, summed by :func:`role_of`) in the
+    registry ``metrics``.  Called when the DEFAULT registry is read; no
+    thread, no sampling, nothing on anybody's path.  The process's time
+    less the roles' sum is the CPU of threads Python does not know.
+
+    A role's gauge is the sum over the threads that are alive NOW (0
+    for a role with none): a thread that ended drops out of it (the
+    program's own threads live as long as the process; a caller's
+    short-lived ones, role ``other``, do not).  Where the platform has
+    no per-thread CPU clock (``time.pthread_getcpuclockid``) the roles'
+    gauges are absent."""
+    clock_of = getattr(time, "pthread_getcpuclockid", None)
+    if clock_of is not None:
+        by_role = dict.fromkeys(ROLES, 0.0)
+        for t in threading.enumerate():
+            try:
+                cpu = time.clock_gettime(clock_of(t.ident))
+            # analysis: allow-swallow(a thread that ended between enumerate and the read has no clock: it drops out of the sum)
+            except (OSError, TypeError):
+                continue
+            by_role[role_of(t.name)] += cpu
+        for role, cpu in by_role.items():
+            metrics.gauge(f"threads.cpu_seconds;role={role}").set(cpu)
+    metrics.gauge("process.cpu_seconds").set(time.process_time())
 
 
 def configured_hz() -> float:

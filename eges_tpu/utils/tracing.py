@@ -21,12 +21,32 @@ path (:data:`SPANS` names the sites).  Besides the ring entry, a live
 span (a) enters a ``jax.profiler.TraceAnnotation`` for its body, so that
 under a profiler session it lands in the ``.xplane.pb`` on the device
 trace's clock and an idle gap of the chip can be named by the layer that
-spent it; (b) observes ``span.seconds;name=<name>`` and
+spent it (the one link to that clock); (b) reads the tracer's wall clock
+on entry and on exit and observes ``span.seconds;name=<name>`` and
 ``span.self_seconds;name=<name>`` (duration minus what child spans on
 the same thread covered) in the metrics registry, which ``thw_metrics``
-exports; (c) tags the thread for the sampling profiler
-(``profiler.SPAN_PHASES``).  A ``TraceAnnotation`` belongs to a thread:
-a span goes around a synchronous section, never around an ``await``.
+exports; (c) on one span in :data:`CPU_EVERY` also reads the CPU clock
+of its thread (``time.thread_time()``) on entry and on exit and observes
+``span.self_cpu_seconds;name=<name>`` (what the thread RAN inside the
+span, minus its children's) with that weight, so the histogram's count
+times its mean estimates the CPU time of all; self wall less self CPU
+is what the thread spent NOT running: the GIL, a lock, the device, a
+sleep; (d) tags the thread for the sampling profiler
+(``profiler.SPAN_PHASES``).  A ``TraceAnnotation`` and a CPU clock
+belong to a thread: a span goes around a synchronous section, never
+around an ``await``.
+
+Why one in :data:`CPU_EVERY`: the thread's CPU clock is a system call
+on every platform, 0.3 us in a plain Linux process and 5.4-6.1 us on
+the sealed hosts the chips are served from, where it also moves in
+steps of 10 ms (PR 38's chip runs: two reads there cost more than the
+whole span did before, and one span's reading is 0 or 0.01: only the
+sum over many spans means anything).  A span that is outermost on its
+thread reads it by the toss of a seeded coin, whatever its name and
+whatever came before (spans come in periods, a flush every few
+ingests, and a count would keep step with them); a span inside one
+that reads it reads it too, so a parent's self CPU is always less its
+children's.
 """
 
 from __future__ import annotations
@@ -34,6 +54,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import random
 import sys
 import threading
 import time
@@ -54,10 +75,12 @@ _UNSET = object()
 # bounds).  One span per window, call or message, never per row
 # (``txpool.admit`` is the scalar path's exception, kept for the
 # per-transaction trace).  A label attribute's value becomes a label of
-# the span's histograms:
+# the span's three histograms (``span.seconds``, ``span.self_seconds``:
+# wall time; ``span.self_cpu_seconds``: what the thread ran), as in
 # ``span.seconds;name=sched.await,class=consensus,size=burst``; every
 # label has a closed vocabulary.  Other names stay allowed; they carry
-# no label.  PERF.md section 3 copies this table.
+# no label.  The ring entry has the span's whole wall and CPU time
+# (``duration_s``, ``cpu_s``).  PERF.md section 3 copies this table.
 SPANS = {
     "ingress.decode": ((), "a window of frames to columns: one native "
                            "call that holds no GIL"),
@@ -113,23 +136,24 @@ SPANS = {
                                 "counts once, by its first method)"),
 }
 
-# ids: one draw of entropy a process, a counter under it.  A trace id is
-# the process's random half over the counter, a span id the counter
-# alone (it started at a random value, so two nodes' spans under one
-# trace do not meet).
+# ids: one draw of entropy a process, a counter under it.  A span id is
+# the counter alone (it started at a random value, so two nodes' spans
+# under one trace do not meet); a span that begins a trace gives it the
+# process's random half over its own id: one draw of the counter a span.
 # analysis: allow-determinism(trace/span ids are observability-only, never journaled)
 _PROCESS_ID = os.urandom(8).hex()
 # analysis: allow-determinism(trace/span ids are observability-only, never journaled)
 _ids = itertools.count(int.from_bytes(os.urandom(8), "big"))
 
+_thread_time = time.thread_time
+_NO_LABELS = ((),)
 
-def _new_span_id() -> str:
-    return "%016x" % (next(_ids) & 0xFFFFFFFFFFFFFFFF)
-
-
-def _new_trace_id() -> str:
-    return _PROCESS_ID + _new_span_id()
-
+# a span that is outermost on its thread reads the thread's CPU clock
+# with probability 1 / CPU_EVERY (and every span inside it does), and
+# observes its CPU time with that weight; 1 reads it on every span
+CPU_EVERY = 8
+# analysis: allow-determinism(which spans read the CPU clock is observability-only, never journaled)
+_coin = random.Random(0x5eed).random
 
 _trace_annotation = None
 
@@ -159,28 +183,49 @@ class Span:
     """One timed operation.  Finished spans land in the tracer's ring
     buffer; unfinished ones are invisible to exporters.  As a context
     manager (what ``Tracer.span`` hands out) it is live for its body:
-    annotated, timed, and the current context unless it stands alone
-    (see :meth:`Tracer.span`)."""
+    annotated, timed on the wall clock and, one in :data:`CPU_EVERY`,
+    on its thread's CPU clock, and the current context unless it stands
+    alone (see :meth:`Tracer.span`).  ``cpu_s`` is the CPU time of the
+    whole body, None for a span that did not read the clock (the other
+    seven, ``record_span``)."""
 
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "start_s",
-                 "end_s", "attrs", "_tracer", "_child_s", "_lone", "_live")
+    __slots__ = ("name", "parent_id", "start_s", "end_s", "cpu_s", "attrs",
+                 "_tracer", "_id", "_trace", "_child_s", "_child_cpu",
+                 "_cpu0", "_lone", "_live")
 
-    def __init__(self, tracer: "Tracer", name: str, trace_id: str,
+    def __init__(self, tracer: "Tracer", name: str, trace_id: str | None,
                  parent_id: str | None, start_s: float, attrs: dict):
         self._tracer = tracer
         self.name = name
-        self.trace_id = trace_id
-        self.span_id = _new_span_id()
+        # the ids are written out when somebody reads them: most spans
+        # stand alone, and theirs are read by ``to_dict`` or never
+        self._id = next(_ids)
+        self._trace = trace_id
         self.parent_id = parent_id
         self.start_s = start_s
         self.end_s: float | None = None
+        self.cpu_s: float | None = None
         self.attrs = attrs
-        self._child_s = 0.0  # what child spans on this thread covered
+        # what child spans on this thread covered, wall and CPU
+        self._child_s = 0.0
+        self._child_cpu = 0.0
         self._lone = False
 
     def __enter__(self) -> "Span":
         tracer = self._tracer
-        tracer._stack().append(self)
+        stack = tracer._stack()
+        if stack:
+            timed = stack[-1]._cpu0 is not None  # as the span around it
+        else:
+            timed = _coin() * CPU_EVERY < 1.0
+        if timed:
+            # the slow read stays outside the wall interval, here and on
+            # exit: the span's duration is what it is without the clock
+            self._cpu0 = _thread_time()
+            self.start_s = tracer._clock()
+        else:
+            self._cpu0 = None
+        stack.append(self)
         ann = _annotation()
         if ann is not None:
             # whole-number attributes (a window's ``rows``, its lane's
@@ -191,11 +236,16 @@ class Span:
             ann.__enter__()
         self._live = (None if self._lone
                       else tracer._current.set(self.context()),
-                      profiler.tag_span(self.name), ann)
+                      profiler.tag_span(self.name), ann, stack)
         return self
 
     def __exit__(self, *exc) -> None:
-        token, ptok, ann = self._live
+        self.end()
+        cpu = None
+        if self._cpu0 is not None:
+            cpu = self.cpu_s = _thread_time() - self._cpu0
+        token, ptok, ann, stack = self._live
+        self._live = None
         if ann is not None:
             ann.__exit__(*exc)
         if ptok is not None:
@@ -203,13 +253,25 @@ class Span:
         tracer = self._tracer
         if token is not None:
             tracer._current.reset(token)
-        self.end()
-        stack = tracer._stack()
         stack.pop()  # self: ``with`` blocks of one thread end innermost first
-        dur = self.duration_s
+        dur = self.end_s - self.start_s
         if stack:
-            stack[-1]._child_s += dur
-        tracer._observe(self, dur, max(0.0, dur - self._child_s))
+            outer = stack[-1]
+            outer._child_s += dur
+            if cpu is not None:
+                outer._child_cpu += cpu
+        tracer._observe(self, dur, max(0.0, dur - self._child_s),
+                        None if cpu is None
+                        else max(0.0, cpu - self._child_cpu))
+
+    @property
+    def span_id(self) -> str:
+        return "%016x" % (self._id & 0xFFFFFFFFFFFFFFFF)
+
+    @property
+    def trace_id(self) -> str:
+        # no trace to join: the span begins one, under its own id
+        return self._trace or _PROCESS_ID + self.span_id
 
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
@@ -230,11 +292,14 @@ class Span:
         return self.end_s - self.start_s
 
     def to_dict(self) -> dict:
-        return {"name": self.name, "trace": self.trace_id,
-                "span": self.span_id, "parent": self.parent_id,
-                "start_s": round(self.start_s, 6),
-                "duration_s": round(self.duration_s, 6),
-                "attrs": dict(self.attrs)}
+        out = {"name": self.name, "trace": self.trace_id,
+               "span": self.span_id, "parent": self.parent_id,
+               "start_s": round(self.start_s, 6),
+               "duration_s": round(self.duration_s, 6),
+               "attrs": dict(self.attrs)}
+        if self.cpu_s is not None:
+            out["cpu_s"] = round(self.cpu_s, 6)
+        return out
 
 
 class Tracer:
@@ -257,35 +322,29 @@ class Tracer:
         self._lock = threading.Lock()
         # live spans of each thread, innermost last (self time)
         self._tls = threading.local()
-        # (name, label values...) -> the span's two histograms
-        self._hists: dict[tuple, tuple] = {}
-        self._finished: deque[dict] = deque(maxlen=capacity)
+        # span name, or (name, label values...) -> ((wall, self), cpu)
+        self._hists: dict = {}
+        self._finished: deque[Span] = deque(maxlen=capacity)
         self._current: ContextVar[SpanContext | None] = ContextVar(
             "geec_trace_ctx", default=None)
+        # spans that entered the ring (``stats()["started"]``: a span is
+        # counted where it ends, under the ring's one lock hold)
         self.started = 0
         self.dropped = 0
 
     # -- span lifecycle -------------------------------------------------
-    def start_span(self, name: str, parent=_UNSET, **attrs) -> Span:
-        """Open a span.  ``parent`` may be a SpanContext, None (force a
-        new root), or omitted (inherit the current context)."""
-        if parent is _UNSET:
-            parent = self._current.get()
-        if isinstance(parent, Span):
-            parent = parent.context()
+    def _open(self, name: str, parent, attrs: dict) -> Span:
         if parent is None:
-            trace_id, parent_id = _new_trace_id(), None
-        else:
-            trace_id, parent_id = parent.trace_id, parent.span_id
-        with self._lock:
-            self.started += 1
-        return Span(self, name, trace_id, parent_id, self._clock(), attrs)
+            return Span(self, name, None, None, self._clock(), attrs)
+        return Span(self, name, parent.trace_id, parent.span_id,
+                    self._clock(), attrs)
 
     def span(self, name: str, parent=_UNSET, root: bool = False,
              **attrs) -> Span:
         """A span to use as ``with tracer.span(...) as sp``: ended on
         exit, annotated in the profiler's trace and observed into
-        ``span.seconds`` / ``span.self_seconds``.
+        ``span.seconds`` / ``span.self_seconds`` /
+        ``span.self_cpu_seconds``.
 
         It joins the trace it runs under (or the ``parent`` given) and
         is the current context for its body.  Where no trace is current,
@@ -303,9 +362,12 @@ class Tracer:
         bridge that lets the continuous sampling profiler attribute
         CPU samples to ``pool_admit`` etc. without its own hooks on
         every ingest path (one dict probe per span when unmapped)."""
-        sp = self.start_span(name, parent, **attrs)
-        sp._lone = (parent is _UNSET and sp.parent_id is None
-                    and not root)
+        lone = False
+        if parent is _UNSET:
+            parent = self._current.get()
+            lone = parent is None and not root
+        sp = self._open(name, parent, attrs)
+        sp._lone = lone
         return sp
 
     def _stack(self) -> list:
@@ -315,35 +377,53 @@ class Tracer:
             self._tls.stack = []
             return self._tls.stack
 
-    def _observe(self, span: Span, dur: float, self_s: float) -> None:
-        labels = SPANS.get(span.name, ((),))[0]
-        key = (span.name, *[span.attrs.get(k) for k in labels])
-        pair = self._hists.get(key)
-        if pair is None:
-            tail = span.name + "".join(
+    def _observe(self, span: Span, dur: float, self_s: float,
+                 self_cpu: float | None) -> None:
+        """The live span's two wall-time observations, in one hold of
+        one lock and one reservoir draw (``metrics.observe_together``),
+        and its self CPU time where it read the clock, as CPU_EVERY
+        observations: it stands for that many spans."""
+        name = span.name
+        labels = SPANS.get(name, _NO_LABELS)[0]
+        key = (name, *[span.attrs.get(k) for k in labels]) if labels \
+            else name
+        hists = self._hists.get(key)
+        if hists is None:
+            tail = name + "".join(
                 f",{k}={v}" for k, v in zip(labels, key[1:])
                 if v is not None)
-            pair = self._hists[key] = (
-                self.metrics.histogram(f"span.seconds;name={tail}"),
-                self.metrics.histogram(f"span.self_seconds;name={tail}"))
-        pair[0].observe(dur)
-        pair[1].observe(self_s)
+            hists = self._hists[key] = (
+                metrics_mod.together(
+                    self.metrics.histogram(f"span.seconds;name={tail}"),
+                    self.metrics.histogram(
+                        f"span.self_seconds;name={tail}")),
+                self.metrics.histogram(
+                    f"span.self_cpu_seconds;name={tail}"))
+        metrics_mod.observe_together(hists[0], (dur, self_s))
+        if self_cpu is not None:
+            hists[1].observe(self_cpu, CPU_EVERY)
 
     def record_span(self, name: str, duration_s: float, parent=_UNSET,
                     **attrs) -> Span:
         """Record an already-measured duration as a finished span (used
-        by virtual-clock phases where wall time is meaningless)."""
-        sp = self.start_span(name, parent, **attrs)
+        by virtual-clock phases where wall time is meaningless; it has
+        no CPU time and observes no histogram)."""
+        if parent is _UNSET:
+            parent = self._current.get()
+        sp = self._open(name, parent, attrs)
         sp.start_s -= duration_s
         sp.end_s = sp.start_s + duration_s
         self._finish(sp)
         return sp
 
     def _finish(self, span: Span) -> None:
+        # the ring keeps the span itself: ``finished`` / ``dump`` make
+        # the dict, when somebody asks
         with self._lock:
+            self.started += 1
             if len(self._finished) == self._finished.maxlen:
                 self.dropped += 1
-            self._finished.append(span.to_dict())
+            self._finished.append(span)
 
     # -- context plumbing -----------------------------------------------
     def current_context(self) -> SpanContext | None:
@@ -369,10 +449,10 @@ class Tracer:
         with self._lock:
             spans = list(self._finished)
         if trace:
-            spans = [s for s in spans if s["trace"] == trace]
+            spans = [s for s in spans if s.trace_id == trace]
         if limit and limit > 0:
             spans = spans[-limit:]
-        return spans
+        return [s.to_dict() for s in spans]
 
     def clear(self) -> None:
         with self._lock:
@@ -390,7 +470,7 @@ class Tracer:
             return 0
         with open(path, "a", encoding="utf-8") as fh:
             for s in spans:
-                fh.write(json.dumps(s, sort_keys=True) + "\n")
+                fh.write(json.dumps(s.to_dict(), sort_keys=True) + "\n")
         return len(spans)
 
     def stats(self) -> dict:

@@ -528,7 +528,7 @@ def test_a_mixed_batch_answers_every_holder_exactly_once(how):
         sched.failure_hook = hook
     if how == "died":
         with pytest.raises(_Dead):
-            sched._run_batch(sched._lanes[0], batch, "kick")
+            sched._run_batch(sched._lanes[0], batch, "kick", time.monotonic())
         dead = [isinstance(v, _Dead) for v in win_a.result(0)]
         assert dead == [True, True, False, True]
         assert all(isinstance(v, _Dead) for v in win_b.result(0))
@@ -539,7 +539,7 @@ def test_a_mixed_batch_answers_every_holder_exactly_once(how):
         assert st["batches"] == st["rows"] == st["resolve_holds"] == 0
         assert st["cached_entries"] == 0 and sched.flights() == []
     else:
-        sched._run_batch(sched._lanes[0], batch, "kick")
+        sched._run_batch(sched._lanes[0], batch, "kick", time.monotonic())
         assert win_a.result(0) == [m[0], m[1], won, None]
         assert win_b.result(0) == [m[3], m[1], m[4]]
         assert [f.result(0) for f in futs[:2]] == [m[5], m[1]]

@@ -1,9 +1,12 @@
 """Program spans: the one timing primitive of the served path.
 
-A live ``Tracer.span`` observes ``span.seconds`` / ``span.self_seconds``,
+A live ``Tracer.span`` observes ``span.seconds`` / ``span.self_seconds``
+and, from its thread's CPU clock, ``span.self_cpu_seconds`` (PR 38),
 lands in the profiler's trace beside the device operations, and roots no
-trace of its own unless it is a transaction's ingest; the scheduler's
-flight recorder carries ``resolve_ms`` and keeps a whole run; the 1 s
+trace of its own unless it is a transaction's ingest; the registry's
+snapshot reads the process's CPU and its threads' by role; the
+scheduler's flight recorder carries ``resolve_ms``, splits ``wait_ms``
+at the dispatcher's pop and keeps a whole run; the 1 s
 election re-send and the validate retry say whose message was missing;
 the trace armer leaves the profiler's Python tracer off.
 """
@@ -81,6 +84,210 @@ def test_self_time_is_kept_per_thread():
     # a span on another thread is nobody's child here
     assert reg.snapshot()["span.self_seconds;name=txpool.flush"][
         "mean"] == pytest.approx(0.020)
+
+
+# -- the thread's CPU clock beside the wall clock (PR 38) -------------------
+
+def _spin(seconds: float) -> None:
+    """Keep this thread on a core for ``seconds`` of ITS CPU time."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        sum(range(200))
+
+
+@pytest.fixture
+def every_span_reads_the_clock(monkeypatch):
+    monkeypatch.setattr(tracing, "CPU_EVERY", 1)
+
+
+def test_a_busy_span_ran_and_a_sleeping_span_waited(
+        every_span_reads_the_clock):
+    t = tracing.Tracer(metrics=metrics_mod.Registry())
+    with t.span("sched.stage"):
+        _spin(0.05)
+    with t.span("sched.collect"):
+        time.sleep(0.05)
+    busy, asleep = t.finished()
+    assert busy["name"] == "sched.stage"
+    # it ran nearly all of its wall time (another process may take the
+    # core for a moment: the wall time is then the longer)
+    assert busy["cpu_s"] >= 0.05 - 1e-4
+    assert busy["cpu_s"] <= busy["duration_s"] + 1e-4
+    if busy["duration_s"] < 0.06:  # nobody took the core
+        assert busy["cpu_s"] == pytest.approx(busy["duration_s"], rel=0.2)
+    assert asleep["duration_s"] >= 0.05 and asleep["cpu_s"] < 0.005
+    snap = t.metrics.snapshot()
+    assert snap["span.self_cpu_seconds;name=sched.stage"]["mean"] == \
+        pytest.approx(busy["cpu_s"], abs=2e-6)
+    assert snap["span.self_cpu_seconds;name=sched.collect"]["mean"] < 0.005
+    # wall less CPU is the wait
+    assert snap["span.self_seconds;name=sched.collect"]["mean"] - snap[
+        "span.self_cpu_seconds;name=sched.collect"]["mean"] > 0.045
+
+
+def test_a_childs_cpu_leaves_its_parents_self_cpu_per_thread(
+        every_span_reads_the_clock):
+    import threading
+
+    t = tracing.Tracer(metrics=metrics_mod.Registry())
+
+    def other():
+        with t.span("sched.stage"):
+            _spin(0.03)
+
+    with t.span("txpool.flush"):
+        _spin(0.02)
+        with t.span("sched.await", **{"class": "bulk", "size": "call"}):
+            _spin(0.04)
+        # a span on another thread is nobody's child here, and its CPU
+        # is on its own thread's clock
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(10)
+        assert not th.is_alive()
+    snap = t.metrics.snapshot()
+
+    def cpu(name):
+        return snap[f"span.self_cpu_seconds;name={name}"]["mean"]
+
+    assert 0.02 <= cpu("txpool.flush") < 0.03
+    # labelled spans get labelled histograms, as the wall-time ones do
+    assert 0.04 <= cpu("sched.await,class=bulk,size=call") < 0.05
+    assert 0.03 <= cpu("sched.stage") < 0.04
+    # the ring entry keeps the WHOLE CPU time: the child's inside it
+    whole = {s["name"]: s["cpu_s"] for s in t.finished()}
+    assert 0.06 <= whole["txpool.flush"] < 0.08
+    assert set(snap) == {
+        f"span.{fam};name={n}"
+        for fam in ("seconds", "self_seconds", "self_cpu_seconds")
+        for n in ("txpool.flush", "sched.stage",
+                  "sched.await,class=bulk,size=call")}
+    # the three histograms of a span that read the clock move in step
+    assert {snap[n]["count"] for n in snap} == {1}
+
+
+def test_one_span_in_cpu_every_reads_the_clock_and_counts_for_all():
+    """The thread's CPU clock is a system call (5.4-6.1 us on the chip's
+    host, where it moves in steps of 10 ms): a span that is outermost
+    on its thread reads it by the toss of a coin, one in CPU_EVERY, and
+    every span inside it does; the histogram takes each reading
+    CPU_EVERY times, so its count times its mean estimates them all."""
+    every = tracing.CPU_EVERY
+    assert every == 8
+    n = 4000
+    clock, reg = _Clock(), metrics_mod.Registry()
+    t = tracing.Tracer(clock=clock, capacity=2 * n, metrics=reg)
+    for _ in range(n):
+        with t.span("txpool.flush"):
+            clock.t += 0.25
+            with t.span("sched.await", **{"class": "bulk", "size": "call"}):
+                clock.t += 0.25
+    done = t.finished()
+    inner, outer = done[0::2], done[1::2]
+    assert {s["name"] for s in outer} == {"txpool.flush"}
+    read = ["cpu_s" in s for s in outer]
+    # one in eight, give or take five standard deviations
+    assert n / every - 105 <= sum(read) <= n / every + 105
+    # a span inside one that reads the clock reads it, and no other
+    assert ["cpu_s" in s for s in inner] == read
+    # not by count: the gaps between readings are not all alike
+    at = [i for i, r in enumerate(read) if r]
+    assert len({b - a for a, b in zip(at, at[1:])}) > 8
+    snap = reg.snapshot()
+    for tail in ("txpool.flush", "sched.await,class=bulk,size=call"):
+        cpu = snap[f"span.self_cpu_seconds;name={tail}"]
+        assert snap[f"span.seconds;name={tail}"]["count"] == n
+        assert abs(cpu["count"] - n) <= 105 * every
+        assert cpu["count"] % every == 0
+    # the reading leaves the wall time alone: on a clock moved by hand
+    # a span that read the CPU clock is as long as one that did not
+    assert {s["duration_s"] for s in outer} == {0.5}
+    assert {s["duration_s"] for s in inner} == {0.25}
+    flush = snap["span.self_seconds;name=txpool.flush"]
+    assert (flush["min"], flush["max"]) == (0.25, 0.25)
+
+
+def test_a_recorded_span_has_no_cpu_and_observes_nothing():
+    t, clock, reg = _tracer()
+    clock.t = 5.0
+    sp = t.record_span("consensus.election", 0.25)
+    assert sp.cpu_s is None and sp.duration_s == pytest.approx(0.25)
+    (entry,) = t.finished()
+    assert "cpu_s" not in entry and entry["duration_s"] == 0.25
+    assert reg.snapshot() == {}
+    assert t.stats()["started"] == 1
+
+
+def test_the_ring_hands_out_dicts_made_when_asked(
+        tmp_path, every_span_reads_the_clock):
+    import json
+
+    t, clock, _reg = _tracer()
+    with t.span("sched.stage", rows=3) as sp:
+        clock.t += 0.5
+    sp.set_attr("late", 1)  # the ring keeps the span, not a copy
+    (entry,) = t.finished()
+    assert entry == {"name": "sched.stage", "trace": sp.trace_id,
+                     "span": sp.span_id, "parent": None, "start_s": 0.0,
+                     "duration_s": 0.5, "cpu_s": entry["cpu_s"],
+                     "attrs": {"rows": 3, "late": 1}}
+    assert t.finished(trace=sp.trace_id) == [entry]
+    assert t.finished(trace="0" * 32) == []
+    path = str(tmp_path / "spans.jsonl")
+    assert t.dump(path) == 1 and t.finished() == []
+    assert json.loads(open(path).read()) == entry
+    assert t.stats() == {"started": 1, "buffered": 0, "dropped": 0,
+                         "capacity": 4096}
+
+
+def test_the_snapshot_reads_the_process_cpu_and_the_roles():
+    """``process.cpu_seconds`` and ``threads.cpu_seconds;role=*`` are set
+    when the DEFAULT registry is read, by no thread of their own: a lane
+    worker that computes makes its role's gauge grow."""
+    import threading
+
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+    from eges_tpu.utils.metrics import prometheus_text
+
+    if not hasattr(time, "pthread_getcpuclockid"):
+        pytest.skip("no per-thread CPU clock on this platform")
+    go, done = threading.Event(), threading.Event()
+
+    def lane():
+        while go.wait(10) and not done.is_set():
+            _spin(0.05)
+            go.clear()
+
+    th = threading.Thread(target=lane, name="verifier-lane-7", daemon=True)
+    th.start()
+    try:
+        threads_before = threading.active_count()
+        before = metrics.snapshot()
+        go.set()
+        while go.is_set():
+            time.sleep(0.005)
+        after = metrics.snapshot()
+        assert threading.active_count() <= threads_before  # none started
+    finally:
+        done.set()
+        go.set()
+        th.join(10)
+    grew = (after["threads.cpu_seconds;role=lane"]
+            - before["threads.cpu_seconds;role=lane"])
+    assert 0.05 <= grew < 0.2
+    roles = {n.rpartition("=")[2]: v for n, v in after.items()
+             if n.startswith("threads.cpu_seconds;role=")}
+    assert set(roles) == set(profiler.ROLES) and "other" in roles
+    assert roles["main"] > 0.0 and roles["hedge"] >= 0.0
+    # every thread of the process, those Python does not know included
+    assert after["process.cpu_seconds"] >= sum(roles.values())
+    assert after["process.cpu_seconds"] - before["process.cpu_seconds"] \
+        >= grew
+    text = prometheus_text()
+    assert 'threads_cpu_seconds{role="lane"}' in text
+    assert "\nprocess_cpu_seconds " in text
+    # a registry of one's own reads nothing off the process
+    assert metrics_mod.Registry().snapshot() == {}
 
 
 def test_a_span_joins_a_trace_and_roots_one_only_when_told():
@@ -230,6 +437,53 @@ def test_flights_carry_resolve_ms_and_a_run_of_300_windows_drops_none():
     assert {"sched.submit", "sched.await", "sched.stage",
             "sched.resolve"} <= names
     assert "verifier.sched_dispatch" not in names
+
+
+@pytest.mark.parametrize("target", ["inline", "pipelined", "four_lanes"])
+def test_a_windows_wait_is_split_where_the_dispatcher_takes_it(target):
+    """``flush_ms`` (the oldest row's entry to the dispatcher's pop) and
+    ``lane_wait_ms`` (the pop to the stage's begin) add up to
+    ``wait_ms`` in every flight of a run of 300 windows, host-served
+    ones included; the chunks of one window share its ``t_flush``."""
+    from eges_tpu.crypto import secp256k1 as host
+    from eges_tpu.crypto.scheduler import VerifierScheduler
+    from eges_tpu.crypto.verify_host import (
+        NativeBatchVerifier, NativeMeshVerifier, PipelinedNativeVerifier,
+    )
+
+    verifier = {"inline": NativeBatchVerifier,
+                "pipelined": PipelinedNativeVerifier,
+                "four_lanes": lambda: NativeMeshVerifier(4)}[target]()
+    sig = host.ecdsa_sign(b"\x13" * 32, b"\x07" * 32)
+    sched = VerifierScheduler(verifier, max_batch=32, min_split=4,
+                              hedge=False)
+    try:
+        for k in range(100):
+            # fresh rows every time; one row (the host's by rule), two,
+            # then a full window, which four lanes split into 4 x 8
+            for n, base in ((1, 20000), (2, 30000), (32, 40000)):
+                rows = [((base + 64 * k + i).to_bytes(4, "big") * 8, sig)
+                        for i in range(n)]
+                assert len(sched.recover_signers(rows)) == n
+        flights = sched.flights()
+        st = sched.stats()
+    finally:
+        sched.close()
+        close = getattr(verifier, "close", None)
+        if close:
+            close()
+    assert st["host_diverted"] == 100 and st["flight_dropped"] == 0
+    assert len(flights) == (600 if target == "four_lanes" else 300)
+    for f in flights:
+        assert f["flush_ms"] >= 0.0 and f["lane_wait_ms"] >= 0.0
+        assert f["flush_ms"] + f["lane_wait_ms"] == pytest.approx(
+            f["wait_ms"], abs=0.002)
+        assert f["t_submit"] <= f["t_flush"] <= f["t_begin"]
+    by_flush: dict = {}
+    for f in flights:
+        by_flush.setdefault(f["t_flush"], []).append(f["rows"])
+    want = [[1], [2], [8, 8, 8, 8] if target == "four_lanes" else [32]]
+    assert sorted(by_flush.values()) == sorted(want * 100)
 
 
 def test_a_mesh_window_is_placed_under_a_span_and_its_lane_is_no_label(
