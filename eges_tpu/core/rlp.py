@@ -21,6 +21,15 @@ class RLPError(ValueError):
     pass
 
 
+class _Decoded(list):
+    """A list as :func:`decode` found it, with the span of its own
+    encoding: ``source[start:end]`` is the list's header and payload as
+    they stood on the wire.  A plain list in every other way (``==``,
+    ``isinstance``, unpacking); :func:`encoding_of` reads the span."""
+
+    __slots__ = ("source", "start", "end")
+
+
 def encode_uint(x: int) -> bytes:
     """Int -> minimal big-endian bytes (0 -> b'')."""
     if x < 0:
@@ -93,7 +102,7 @@ def _decode_at(data: bytes, pos: int):
         end = pos + 1 + n
         if end > len(data):
             raise RLPError("truncated list")
-        return _decode_list(data, pos + 1, end), end
+        return _decode_list(data, pos, pos + 1, end), end
     # long list
     ln = b0 - 0xF7
     if pos + 1 + ln > len(data):
@@ -107,11 +116,14 @@ def _decode_at(data: bytes, pos: int):
     end = pos + 1 + ln + n
     if end > len(data):
         raise RLPError("truncated list")
-    return _decode_list(data, pos + 1 + ln, end), end
+    return _decode_list(data, pos, pos + 1 + ln, end), end
 
 
-def _decode_list(data: bytes, pos: int, end: int) -> list:
-    out = []
+def _decode_list(data: bytes, start: int, pos: int, end: int) -> list:
+    """The list whose header stands at ``start`` and whose payload is
+    ``data[pos:end]``."""
+    out = _Decoded()
+    out.source, out.start, out.end = data, start, end
     while pos < end:
         item, pos = _decode_at(data, pos)
         out.append(item)
@@ -126,6 +138,18 @@ def decode(data: bytes):
     if end != len(data):
         raise RLPError("trailing bytes")
     return item
+
+
+def encoding_of(item) -> bytes | None:
+    """The bytes a list was decoded FROM: for a list that :func:`decode`
+    returned (or one nested in it), a copy of its own encoding, header
+    included; None for any other value.  Decoding is strict, so those
+    bytes are the one canonical encoding of what the list held WHEN IT
+    WAS DECODED: a caller that changes such a list in place must not
+    ask."""
+    if isinstance(item, _Decoded):
+        return item.source[item.start:item.end]
+    return None
 
 
 def peek_first_uint(data: bytes) -> int | None:

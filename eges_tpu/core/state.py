@@ -396,15 +396,17 @@ def recover_senders(txns, verifier) -> list:
     device, coalesced with whatever else is pending (class ``bulk``).
 
     Span ``chain.recover_senders`` bounds the body (attrs ``rows``: rows
-    handed to the verifier, ``cached``, ``coalesced``, ``refused``);
-    counters ``chain.sender_rows``, ``chain.sender_cached_rows``,
-    ``chain.sender_coalesced_rows`` and ``chain.blocks_refused`` take
-    one ``inc(n)`` a call."""
+    handed to the verifier, ``native``: those of them whose signature
+    and signing hash the one native pass over the body filled in,
+    ``cached``, ``coalesced``, ``refused``); counters
+    ``chain.sender_rows``, ``chain.sender_native_rows``,
+    ``chain.sender_cached_rows``, ``chain.sender_coalesced_rows`` and
+    ``chain.blocks_refused`` take one ``inc(n)`` a call."""
     from eges_tpu.utils import tracing
     from eges_tpu.utils.metrics import DEFAULT as metrics
 
-    with tracing.DEFAULT.span("chain.recover_senders", rows=0, cached=0,
-                              coalesced=0, refused=0) as sp:
+    with tracing.DEFAULT.span("chain.recover_senders", rows=0, native=0,
+                              cached=0, coalesced=0, refused=0) as sp:
         try:
             return _recover_senders(txns, verifier, sp)
         except StateError:
@@ -413,37 +415,84 @@ def recover_senders(txns, verifier) -> list:
             raise
 
 
+def _signature_rows(signed: list) -> tuple[np.ndarray, np.ndarray, int]:
+    """``(sigs n x 65, sighashes n x 32, rows the native pass filled)``
+    for a block's signed transactions, in steps a block: the rows that
+    carry their wire encoding (``Transaction.from_rlp`` keeps it) go
+    through the native window decoder (``native/ingress.cpp``, what a
+    gossip window goes through) in ONE call, which rules on v/r/s as
+    ``signature_parts`` does, takes both Keccak digests of a row and
+    holds no GIL; each such row's transaction hash is left in its memo,
+    where the pool's eviction finds it.  A row without wire bytes, a
+    library without the decoder, or a row the pass does not rule valid
+    takes ``signature_parts()``, and a None there raises StateError
+    before any row reaches a verifier."""
+    from eges_tpu.crypto import native
+
+    n = len(signed)
+    sigs = np.zeros((n, 65), np.uint8)
+    hashes = np.zeros((n, 32), np.uint8)
+    memos = [t._SENDER_CACHE for t in signed]
+    wired = [k for k, m in enumerate(memos) if "wire" in m]
+    rest = range(n)
+    if wired and native.has_decode_window():
+        frames = [memos[k]["wire"] for k in wired]
+        m = len(frames)
+        offsets = np.zeros((m + 1,), np.uint64)
+        np.cumsum([len(f) for f in frames], dtype=np.uint64,
+                  out=offsets[1:])
+        decoded, valid = np.zeros((m,), bool), np.zeros((m,), bool)
+        txhash = np.zeros((m, 32), np.uint8)
+        sighash = np.zeros((m, 32), np.uint8)
+        sig = np.zeros((m, 65), np.uint8)
+        native.decode_txn_window(
+            b"".join(frames), offsets, decoded=decoded, valid=valid,
+            txhash=txhash, sighash=sighash, sig=sig,
+            nonce=np.zeros((m,), np.uint64),
+            gas_price=np.zeros((m,), np.uint64),
+            spans=np.zeros((m, 10, 2), np.uint32))
+        # canonical RLP: keccak256(wire) == keccak256(t.encode())
+        flat = txhash.tobytes()
+        for j in np.flatnonzero(decoded).tolist():
+            memos[wired[j]].setdefault("hash", flat[32 * j:32 * j + 32])
+        took = np.flatnonzero(valid)
+        at = np.asarray(wired, np.int64)[took]
+        sigs[at], hashes[at] = sig[took], sighash[took]
+        left = np.ones((n,), bool)
+        left[at] = False
+        rest = np.flatnonzero(left).tolist()
+    for k in rest:
+        parts = signed[k].signature_parts()
+        if parts is None:
+            raise StateError("malformed transaction signature")
+        sigs[k] = np.frombuffer(parts[0], np.uint8)
+        hashes[k] = np.frombuffer(parts[1], np.uint8)
+    return sigs, hashes, n - len(rest)
+
+
 def _recover_senders(txns, verifier, sp) -> list:
     """The body of :func:`recover_senders`, under its span ``sp``."""
     from eges_tpu.utils.metrics import DEFAULT as metrics
 
     senders: list = [None] * len(txns)
-    rows = []
-    for i, t in enumerate(txns):
-        if t.is_geec or (t.v == 0 and t.r == 0 and t.s == 0):
-            continue
-        parts = t.signature_parts()
-        if parts is None:
-            raise StateError("malformed transaction signature")
-        rows.append((i, parts))
+    rows = [i for i, t in enumerate(txns)
+            if not (t.is_geec or (t.v == 0 and t.r == 0 and t.s == 0))]
     if not rows:
         return senders
+    sigs, hashes, native_rows = _signature_rows([txns[i] for i in rows])
     sp.set_attr("rows", len(rows))
+    sp.set_attr("native", native_rows)
     metrics.counter("chain.sender_rows").inc(len(rows))
+    metrics.counter("chain.sender_native_rows").inc(native_rows)
     if verifier is None:
         from eges_tpu.crypto.verify_host import _count_host_rows
         _count_host_rows(len(rows))
-        for i, _ in rows:
+        for i in rows:
             try:
                 senders[i] = txns[i].sender()
             except ValueError:
                 raise StateError("unrecoverable transaction signature")
         return senders
-    sigs = np.zeros((len(rows), 65), np.uint8)
-    hashes = np.zeros((len(rows), 32), np.uint8)
-    for k, (_, (sig, h)) in enumerate(rows):
-        sigs[k] = np.frombuffer(sig, np.uint8)
-        hashes[k] = np.frombuffer(h, np.uint8)
     answer = verifier.recover_addresses(sigs, hashes)
     addrs, ok = answer
     # a scheduler says what answered the window; a plain verifier
@@ -456,10 +505,11 @@ def _recover_senders(txns, verifier, sp) -> list:
         metrics.counter("chain.sender_cached_rows").inc(cached)
     if coalesced:
         metrics.counter("chain.sender_coalesced_rows").inc(coalesced)
-    for k, (i, _) in enumerate(rows):
-        if not ok[k]:
-            raise StateError("unrecoverable transaction signature")
-        senders[i] = bytes(addrs[k])
+    if not np.all(ok):
+        raise StateError("unrecoverable transaction signature")
+    flat = np.ascontiguousarray(addrs, np.uint8).tobytes()
+    for k, i in enumerate(rows):
+        senders[i] = flat[20 * k:20 * k + 20]
     return senders
 
 

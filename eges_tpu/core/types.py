@@ -170,13 +170,21 @@ class Transaction:
             raise rlp.RLPError("signature scalar wider than 256 bits")
         if len(v) > 8:
             raise rlp.RLPError("v wider than 64 bits")
-        return cls(
+        txn = cls(
             nonce=rlp.decode_uint(nonce), gas_price=rlp.decode_uint(price),
             gas_limit=rlp.decode_uint(gas), to=_addr(to) if to else None,
             value=rlp.decode_uint(value), payload=bytes(payload),
             is_geec=bool(rlp.decode_uint(is_geec)), v=rlp.decode_uint(v),
             r=rlp.decode_uint(r), s=rlp.decode_uint(s),
         )
+        # a transaction off the wire keeps its encoding, for the block
+        # path's one native pass (core/state.py recover_senders).  It
+        # lives in the memo, which replace() and signed() start anew:
+        # a changed transaction never carries the old bytes
+        wire = rlp.encoding_of(item)
+        if wire is not None:
+            txn._SENDER_CACHE["wire"] = wire
+        return txn
 
     def encode(self) -> bytes:
         return rlp.encode(self.to_rlp())

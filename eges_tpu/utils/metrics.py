@@ -34,10 +34,12 @@ METRIC_FAMILIES = frozenset({
     "chain.geec_txns", "chain.height", "chain.insert",
     "chain.insert_seconds", "chain.txns",
     # core/state.py recover_senders — a block's signed rows handed to
-    # the verifier, those the scheduler's cache answered, those that
-    # joined a pending row, and the blocks it refused
+    # the verifier, those the one native pass over the body filled in,
+    # those the scheduler's cache answered, those that joined a pending
+    # row, and the blocks it refused
     "chain.blocks_refused", "chain.sender_cached_rows",
-    "chain.sender_coalesced_rows", "chain.sender_rows",
+    "chain.sender_coalesced_rows", "chain.sender_native_rows",
+    "chain.sender_rows",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -157,6 +159,9 @@ METRIC_HELP = {
     "chain.sender_coalesced_rows": (
         "Rows of block sender recovery that joined a row already "
         "pending in the scheduler."),
+    "chain.sender_native_rows": (
+        "Rows of block sender recovery whose signature and signing "
+        "hash the native pass over the body's wire bytes filled in."),
     "chain.sender_rows": (
         "Signed rows of blocks handed to the verifier by "
         "recover_senders."),

@@ -127,8 +127,12 @@ SPANS = {
                                   "verifier in one call (core/state.py): "
                                   "behind the scheduler one window, part "
                                   "cache, part in flight, part device; "
-                                  "attrs rows, cached, coalesced, refused; "
+                                  "attrs rows, native (rows whose "
+                                  "signature and signing hash the one "
+                                  "native pass over the body's wire bytes "
+                                  "filled in), cached, coalesced, refused; "
                                   "counters chain.sender_rows, "
+                                  "chain.sender_native_rows, "
                                   "chain.sender_cached_rows, "
                                   "chain.sender_coalesced_rows, "
                                   "chain.blocks_refused, one inc a call"),
