@@ -190,6 +190,13 @@ class _WindowRows:
     def result(self, timeout: float | None = None) -> list:
         return self._fut.result(timeout)
 
+    def add_done_callback(self, fn) -> None:
+        """``fn(window)`` once every row has its value, on the thread
+        that set the last one (a lane worker, the dispatcher, or the
+        caller's own where the window completed as it entered): ``fn``
+        hands the window on and returns; it never blocks."""
+        self._fut.add_done_callback(lambda _fut: fn(self))
+
 
 class WindowAnswers(list):
     """A synchronous window call's answers, one a row, with what the
@@ -281,6 +288,37 @@ def _row_results(addrs, ok) -> list:
     ab = np.asarray(addrs, np.uint8).tobytes()
     return [ab[i * 20:i * 20 + 20] if good else None
             for i, good in enumerate(np.asarray(ok).tolist())]
+
+
+def host_recover_rows(keys) -> list:
+    """The host recovery path: the rows of ``keys`` (``(sighash32,
+    sig65)`` pairs) in ONE native call (``ec_recover_batch``: the rows
+    in parallel, no GIL held) when the C++ library is built, the
+    pure-Python model a row otherwise; one 20-byte address or None a
+    row.  What a scheduler answers by when the device cannot, and what
+    a sidecar's client answers by when the sidecar cannot.  Counts into
+    ``verifier.host_rows`` like every other host fallback so the
+    device-share metric stays honest."""
+    from eges_tpu.crypto.verify_host import _count_host_rows
+    n = len(keys)
+    _count_host_rows(n)
+    from eges_tpu.crypto import native
+    if native.available():
+        from eges_tpu.crypto.keccak import keccak256
+        pubs, okb = native.ec_recover_batch(
+            b"".join([k[0] for k in keys]),
+            b"".join([k[1] for k in keys]), n)
+        return [keccak256(pubs[64 * i:64 * i + 64])[12:]
+                if okb[i] else None for i in range(n)]
+    from eges_tpu.crypto import secp256k1 as host
+    out = []
+    for h, sig in keys:
+        try:
+            out.append(host.recover_address(h, sig))
+        # analysis: allow-swallow(invalid signature maps to a None result)
+        except Exception:
+            out.append(None)
+    return out
 
 
 class _DeviceLane:
@@ -764,13 +802,23 @@ class VerifierScheduler:
 
     def submit_window(self, hashes: np.ndarray, sigs: np.ndarray,
                       priority: str = "bulk") -> _WindowRows:
-        """Window-granular :meth:`submit`: a whole columnar ingest
-        window — ``hashes`` (n,32) / ``sigs`` (n,65) uint8 rows — enters
-        through :meth:`_enter_window` and returns ONE
-        :class:`_WindowRows` instead of N row futures.  The asynchronous
-        form of the window entry; :meth:`recover_window` is its
-        synchronous facade."""
-        return self._enter_window(_array_keys(hashes, sigs), priority)
+        """Window-granular :meth:`submit`: a whole columnar window
+        (``hashes`` (n,32) / ``sigs`` (n,65) uint8 rows) enters through
+        :meth:`_enter_window` under the ``sched.submit`` span and comes
+        back as ONE :class:`_WindowRows` instead of N row futures; the
+        caller does not wait.  The verify sidecar's server
+        (``crypto/sidecar.py``) is its caller: a connection's reader
+        enters each request here with the client's priority, kicks, and
+        goes on to the next frame, so several windows of several node
+        processes are in flight at once and each answer goes back when
+        its window resolves (:meth:`_WindowRows.add_done_callback`).
+        :meth:`recover_window` is the synchronous facade an in-process
+        caller takes.  A row that a torn-down scheduler failed stays an
+        exception VALUE in ``results``: the sidecar's client recovers it
+        on its own host, as :meth:`_await_window` does here."""
+        with tracing.DEFAULT.span("sched.submit",
+                                  **_call_labels(priority, len(hashes))):
+            return self._enter_window(_array_keys(hashes, sigs), priority)
 
     def recover_window(self, hashes: np.ndarray, sigs: np.ndarray,
                        *, priority: str = "bulk") -> list:
@@ -994,34 +1042,10 @@ class VerifierScheduler:
             cache.popitem(last=False)
 
     def _host_recover_rows(self, keys) -> list:
-        """The host recovery path: the rows of ``keys`` in ONE native
-        call (``ec_recover_batch``: the rows in parallel, no GIL held)
-        when the C++ library is built, the pure-Python model a row
-        otherwise.  The divert target of a window served on the host by
-        rule (:meth:`_host_served`) or because its lane's device died,
-        and of a row after close.  Counts into ``verifier.host_rows``
-        like every other host fallback so the device-share metric stays
-        honest."""
-        from eges_tpu.crypto.verify_host import _count_host_rows
-        n = len(keys)
-        _count_host_rows(n)
-        from eges_tpu.crypto import native
-        if native.available():
-            from eges_tpu.crypto.keccak import keccak256
-            pubs, okb = native.ec_recover_batch(
-                b"".join([k[0] for k in keys]),
-                b"".join([k[1] for k in keys]), n)
-            return [keccak256(pubs[64 * i:64 * i + 64])[12:]
-                    if okb[i] else None for i in range(n)]
-        from eges_tpu.crypto import secp256k1 as host
-        out = []
-        for h, sig in keys:
-            try:
-                out.append(host.recover_address(h, sig))
-            # analysis: allow-swallow(invalid signature maps to a None result)
-            except Exception:
-                out.append(None)
-        return out
+        """:func:`host_recover_rows`: the divert target of a window
+        served on the host by rule (:meth:`_host_served`) or because its
+        lane's device died, and of a row after close."""
+        return host_recover_rows(keys)
 
     def _host_recover(self, key: tuple):
         """One row through :meth:`_host_recover_rows`."""
@@ -1967,8 +1991,12 @@ def scheduler_for(verifier, **kwargs) -> VerifierScheduler | None:
     component holding the same device facade — all sim-cluster nodes,
     the chain, the txpool — shares one coalescing window and one
     recovery cache (and, for mesh verifiers, one set of device lanes),
-    and the pair is garbage-collected together.  ``None`` (host-fallback
-    mode) passes through: those nodes keep the per-entry host path.
+    and the pair is garbage-collected together.  That is the sharing
+    inside ONE process; node processes of one host share them the second
+    way, through the verify sidecar (``crypto/sidecar.py``): the
+    sidecar's process holds this scheduler and each node a
+    ``SidecarClient`` in its place.  ``None`` (host-fallback mode)
+    passes through: those nodes keep the per-entry host path.
     """
     if verifier is None:
         return None

@@ -66,9 +66,15 @@ def build_parser() -> argparse.ArgumentParser:
                    help="batch-verify signatures on the JAX device "
                         "(--no-tpuVerify to run host-only)")
     p.add_argument("--verifier", default="", choices=["", "jax", "native",
-                                                      "none"],
+                                                      "sidecar", "none"],
                    help="verifier backend override: jax device batches "
-                        "(default), native C++ batches, or none")
+                        "(default), native C++ batches, sidecar (the "
+                        "host's verify sidecar at --sidecar, which holds "
+                        "the chip for every node of the host), or none")
+    p.add_argument("--sidecar", default="",
+                   help="socket path of the verify sidecar (python -m "
+                        "eges_tpu.crypto.sidecar --socket PATH); with "
+                        "--verifier sidecar")
     p.add_argument("--rpcPort", type=int, default=0,
                    help="JSON-RPC HTTP port (0 = disabled)")
     p.add_argument("--collector", default="",
@@ -126,7 +132,7 @@ def main(argv=None) -> None:
                                if a),
         bootnodes=parse_peers(args.bootnodes),
         nat=args.nat,
-        verifier_mode=args.verifier,
+        verifier_mode=args.verifier, sidecar_path=args.sidecar,
         collector_addr=args.collector,
         telemetry_interval_s=args.telemetryInterval)
 

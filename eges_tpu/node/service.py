@@ -40,7 +40,11 @@ class ServiceConfig:
     verifier_mode: str = ""            # "" -> "jax" if use_tpu_verifier
     #                                    else "none"; "native" = C++ batch
     #                                    verifier (no JAX import — for
-    #                                    hosts without an accelerator)
+    #                                    hosts without an accelerator);
+    #                                    "sidecar" = a client of the
+    #                                    host's verify sidecar (no JAX
+    #                                    import either)
+    sidecar_path: str = ""             # "sidecar": the sidecar's socket
     rpc_port: int = 0                  # 0 = RPC disabled
     net_secret_hex: str = ""           # gossip-plane auth secret; ""
     #                                    derives one from the genesis hash
@@ -222,46 +226,15 @@ class NodeService:
             if isinstance(genesis_doc.get("timestamp"), str)
             else int(genesis_doc.get("timestamp", 0)))
 
-        mode = cfg.verifier_mode or ("jax" if cfg.use_tpu_verifier
-                                     else "none")
-        verifier = None
-        self._verifier_platform = None
-        if mode == "jax":
-            # share compiled verifier graphs across node processes and
-            # restarts (the recover graph is the expensive compile); a
-            # broken cache logs + counts verifier.compile_cache_errors
-            # and the node runs uncached
-            from eges_tpu.crypto.aotstore import enable_persistent_cache
-            enable_persistent_cache()
-            # default_verifier refuses a platform nobody asked for: a
-            # node that wanted the chip never verifies on the CPU
-            # backend in silence
-            from eges_tpu.crypto.verifier import default_verifier
-            verifier = default_verifier()
-            self._verifier_platform = verifier.device_kind.partition(":")[0]
-            self.log.geec("verifier device", device=verifier.device_kind)
-        elif mode == "native":
-            from eges_tpu.crypto.verify_host import NativeBatchVerifier
-            verifier = NativeBatchVerifier()
-        self._verifier_mode = mode
-        # the coalescing scheduler + sender-recovery cache fronts the
-        # device for every consumer below (chain body validation, the
-        # consensus node's vote paths, the txpool flush): concurrent RPC
-        # submissions and consensus checks merge into one device batch
-        # per micro-window, and commit-time re-verification of gossiped
-        # signatures becomes a cache hit
-        self._raw_verifier = verifier
-        if verifier is not None:
-            from eges_tpu.crypto.scheduler import scheduler_for
-            verifier = scheduler_for(verifier)
-            # a mesh verifier (default_verifier over >1 visible device)
-            # turns the scheduler into the mesh dispatcher: one window
-            # lane per device.  Surface the topology in the service log
-            # so an operator can see the fan-out without scraping stats.
-            lanes = verifier.stats()["lanes"]
-            if lanes > 1:
-                self.log.geec("verifier mesh dispatch enabled",
-                              devices=lanes)
+        # the verify path (device facade behind the coalescing scheduler,
+        # or a client of the host's verify sidecar) is built where the
+        # sidecar's own entry point builds it: crypto/verify_path.py
+        from eges_tpu.crypto import verify_path
+        self._verify_path = verify_path.build(
+            cfg.verifier_mode or ("jax" if cfg.use_tpu_verifier
+                                  else "none"),
+            sidecar_path=cfg.sidecar_path, log=self.log.geec)
+        verifier = self._verify_path.verifier
 
         os.makedirs(cfg.datadir, exist_ok=True)
         store = FileStore(os.path.join(cfg.datadir, "chaindata"))
@@ -434,50 +407,17 @@ class NodeService:
         from eges_tpu.utils import devstats as devstats_mod
         devstats_mod.DEFAULT.rebase()
         devstats_mod.DEFAULT.trace.dir = self.cfg.datadir
-        if self._verifier_mode == "jax" and self._raw_verifier is not None:
-            # warm the recover graphs NOW: a cold bucket costs about
-            # two minutes of Python tracing plus a quarter of a minute
-            # of compiling on the kernel path, and letting that happen
-            # lazily inside a consensus message handler wedges the
-            # event loop mid-election (diagnosed via the SIGUSR1 dump).
-            # The warm goes through the AOT artifact store: a node
-            # restarted on a machine that compiled before deserializes
-            # the stored executable instead of re-tracing (and a
-            # first-ever compile leaves an artifact behind for the next
-            # process).  On the chip EVERY bucket the scheduler can pad
-            # a window to is warmed before the node serves; on the CPU
-            # backend (asked for by name — tests, dev rigs) a big-graph
-            # compile per bucket would outlast the run, so only the
-            # smallest warms here and the next few on a background
-            # thread, as before.
-            import time as _t
-
-            from eges_tpu.crypto.aotstore import default_store
-            from eges_tpu.utils.metrics import DEFAULT as metrics
-
-            store = default_store()
-            on_chip = self._verifier_platform == "tpu"
-            cap = self.chain.verifier.max_batch
-            every = tuple(16 << i for i in range(16) if 16 << i <= cap)
-            t0 = _t.monotonic()
-            info = self._raw_verifier.aot_prewarm(
-                buckets=every if on_chip else (16,), store=store)
-            cold = round(_t.monotonic() - t0, 3)
-            metrics.gauge("verifier.cold_start_seconds").set(cold)
-            self.log.geec("verifier warmup", dt=cold,
-                          buckets=info["buckets"],
-                          aot_loads=info["aot_loads"],
-                          aot_compiles=info["aot_compiles"])
+        from eges_tpu.crypto import verify_path
+        info = verify_path.warm(self._verify_path, log=self.log.geec)
+        if info is not None:
             self.node.journal.record(
                 "verifier_aot_load", buckets=info["buckets"],
                 aot_loads=info["aot_loads"],
                 aot_compiles=info["aot_compiles"],
                 load_s=round(info["load_s"], 3),
                 compile_s=round(info["compile_s"], 3),
-                cold_start_s=cold, device_kind=info["device_kind"])
-            if not on_chip:
-                self._raw_verifier.aot_prewarm(buckets=(32, 64, 128),
-                                               store=store, background=True)
+                cold_start_s=info["cold_start_s"],
+                device_kind=info["device_kind"])
         await self.direct.start()
         await self.gossip.start()
         if self.discovery is not None:

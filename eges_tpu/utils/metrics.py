@@ -115,6 +115,17 @@ METRIC_FAMILIES = frozenset({
     # cancelled before execution, losers that ran to waste
     "verifier.hedge_cancelled", "verifier.hedge_wasted",
     "verifier.hedge_wins", "verifier.hedges",
+    # crypto/sidecar.py — the verify sidecar's socket, in the sidecar's
+    # process (what it served) and in a client's (what it asked): the
+    # windows and their rows, the bytes of their frames, rows a client
+    # answered on its own host because the sidecar could not, frames
+    # that were none, reads held back while a connection's windows in
+    # flight stood at their bound, connected clients, and a window's
+    # time inside the sidecar from its entry to its last answer
+    "sidecar.backpressure_waits", "sidecar.bytes_in",
+    "sidecar.bytes_out", "sidecar.clients", "sidecar.fallback_rows",
+    "sidecar.rows", "sidecar.served_seconds", "sidecar.torn_frames",
+    "sidecar.windows",
     # consensus/node.py — snapshot state sync: durable checkpoints,
     # O(tail) restarts, byzantine-tolerant live sync, and the billed,
     # bounded snapshot-serving plane
@@ -271,6 +282,22 @@ METRIC_HELP = {
     "verifier.aot_load_seconds": "AOT artifact deserialize seconds.",
     "verifier.aot_loads": "AOT executables loaded from the artifact store.",
     "verifier.aot_saves": "AOT executables serialized to the artifact store.",
+    "sidecar.backpressure_waits": (
+        "Reads a sidecar connection held back while its windows in "
+        "flight stood at their bound."),
+    "sidecar.bytes_in": "Bytes of sidecar frames received.",
+    "sidecar.bytes_out": "Bytes of sidecar frames sent.",
+    "sidecar.clients": "Node processes connected to the verify sidecar.",
+    "sidecar.fallback_rows": (
+        "Rows a sidecar client recovered on its own host because the "
+        "sidecar gave no answer."),
+    "sidecar.rows": "Signature rows carried by sidecar windows.",
+    "sidecar.served_seconds": (
+        "A window's time inside the sidecar, from its entry into the "
+        "scheduler to its last answer."),
+    "sidecar.torn_frames": (
+        "Connections the sidecar ended for a frame that was none."),
+    "sidecar.windows": "Windows answered through the verify sidecar.",
     "verifier.cold_start_seconds":
         "Service cold start: verifier ready after process start.",
     "verifier.compile_cache_errors": "Persistent compile-cache failures.",

@@ -111,6 +111,18 @@ SPANS = {
                           "under which the device should be busy"),
     "sched.resolve": ((), "results to bytes, cache put, the recording, "
                           "futures set"),
+    "sidecar.call": (("class",), "a node's synchronous call through the "
+                                 "host's verify sidecar "
+                                 "(crypto/sidecar.py SidecarClient): the "
+                                 "frames sent, the wait, the rows no "
+                                 "sidecar answered recovered on this "
+                                 "host; attr rows"),
+    "sidecar.recv": ((), "the sidecar's reader, one request frame: its "
+                         "body off the socket (the wait for a frame to "
+                         "begin is outside); attr rows"),
+    "sidecar.reply": ((), "the sidecar's writer, one resolved window: "
+                          "the answers to bytes and onto the socket; "
+                          "attr rows"),
     "consensus.handle": (("kind",), "one gossip or direct message handled, "
                                     "under the node's lock"),
     "consensus.verify_quorum": ((), "one attempt at a quorum: every "
