@@ -113,7 +113,7 @@ from concurrent.futures import Future
 import numpy as np
 
 from eges_tpu.crypto.bucketing import bucket_round, lane_chunk_cap
-from eges_tpu.utils import ledger, tracing
+from eges_tpu.utils import heap, ledger, tracing
 
 # sentinel distinguishing "cached None" (a signature that verifiably
 # fails recovery) from "not cached"
@@ -1085,7 +1085,13 @@ class VerifierScheduler:
         """Wrapper keeping the strand-no-row invariant: if the flush
         loop itself dies on an unexpected error, every queued row is
         failed with that error instead of hanging its caller forever
-        (``_ensure_thread`` restarts a thread on the next entry)."""
+        (``_ensure_thread`` restarts a thread on the next entry).
+
+        The dispatcher starts at the first row that has to be computed:
+        after the imports, the warm-up and whatever the caller built,
+        before any window.  That is where the process settles its heap
+        (``utils/heap.py``: once a process, no lock of ours held)."""
+        heap.settle()
         try:
             self._dispatch_forever()
         except BaseException as exc:

@@ -27,6 +27,8 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass
 
+from eges_tpu.utils import heap
+
 
 @dataclass
 class VerifyPath:
@@ -57,6 +59,9 @@ def build(mode: str, *, sidecar_path: str = "", log=_quiet,
         client = SidecarClient(sidecar_path)
         log("verify sidecar", path=sidecar_path,
             connected=client.stats()["connected"])
+        # this process has no scheduler whose dispatcher would: the
+        # client in hand is where its verify path first serves
+        heap.settle()
         return VerifyPath(mode, verifier=client)
     raw, platform = None, None
     if mode == "jax":

@@ -99,6 +99,9 @@ class DebugController:
         counts = gc.get_count()
         out = {
             "gc_counts": list(counts),
+            # utils/heap.py settle(): what no collection walks any more
+            "gc_frozen": gc.get_freeze_count(),
+            "gc_thresholds": list(gc.get_threshold()),
             "gc_objects": len(gc.get_objects()),
             "threads": threading.active_count(),
         }

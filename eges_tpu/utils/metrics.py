@@ -68,6 +68,10 @@ METRIC_FAMILIES = frozenset({
     # Python threads' by role (``;role=<role>``), set when the DEFAULT
     # registry is read
     "process.cpu_seconds", "threads.cpu_seconds",
+    # utils/heap.py settle — what the process's ONE collect-and-freeze
+    # moved into the collector's permanent generation as its verify
+    # path first served, and what that took
+    "process.gc_frozen_objects", "process.gc_settle_seconds",
     # sim/faults.py — deterministic fault injection
     "sim.faults_injected",
     # core/txpool.py
@@ -221,6 +225,12 @@ METRIC_HELP = {
     "process.cpu_seconds": (
         "CPU time of the whole process, every native thread included, "
         "read when the registry is read, in seconds."),
+    "process.gc_frozen_objects": (
+        "Objects in the collector's permanent generation since the "
+        "process settled its heap: what no later collection walks."),
+    "process.gc_settle_seconds": (
+        "What the one collection and freeze took as the process's "
+        "verify path first served, in seconds."),
     "threads.cpu_seconds": (
         "CPU time of the live Python threads of one role, read when the "
         "registry is read, in seconds."),

@@ -110,6 +110,10 @@ def test_gas_price_oracle_and_debug():
     assert "thread" in stacks
     stats = rpc.dispatch("debug_stats", [])
     assert stats["threads"] >= 1
+    # the collector as the node left it (utils/heap.py): what is frozen,
+    # and the thresholds nobody touches
+    assert stats["gc_frozen"] >= 0
+    assert stats["gc_thresholds"] == [700, 10, 10]
 
 
 def test_get_transaction_by_hash_and_chain_id():
