@@ -261,7 +261,9 @@ class QuorumTally:
         entries = self.cert_entries(confirm)
         if entries is None:
             return False
-        valid = [a for a in self.recover_entries(entries) if a is not None]
+        with tracing.DEFAULT.span("consensus.cert_ok", rows=len(entries)):
+            valid = [a for a in self.recover_entries(entries)
+                     if a is not None]
         need = self.membership.validate_threshold()
         if len(valid) < need:
             return False

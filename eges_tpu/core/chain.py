@@ -464,8 +464,11 @@ class BlockChain:
         if block.uncles:
             raise ChainError("uncles not allowed")  # geec.go:215-219
         from eges_tpu.core.trie import derive_sha, EMPTY_ROOT
-        want = (derive_sha([t.encode() for t in block.transactions])
-                if block.transactions else EMPTY_ROOT)
+        from eges_tpu.utils import tracing
+        with tracing.DEFAULT.span("chain.verify_body",
+                                  txns=len(block.transactions)):
+            want = (derive_sha([t.encode() for t in block.transactions])
+                    if block.transactions else EMPTY_ROOT)
         if block.header.tx_hash != want:
             raise ChainError("transaction root mismatch")
 
@@ -476,6 +479,8 @@ class BlockChain:
         from eges_tpu.core.state import (
             StateError, process_block, receipts_root, recover_senders,
         )
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+        metrics.counter("chain.executions").inc()
         try:
             senders = recover_senders(block.transactions, self.verifier)
             state, receipts, gas = process_block(parent_state, block,
@@ -620,27 +625,40 @@ class BlockChain:
         ACKing (the reference acceptor ACKs unconditionally,
         geec_state.go:545).  Falls back to body+signature checks when the
         parent state is unknown (we are behind)."""
-        with self._lock:
-            try:
-                self._verify_body(block)
-            except ChainError:
-                return False
-            parent_state = self._states.get(block.header.parent_hash)
-            if parent_state is None:
-                # parent unknown: we are behind — signature checks only
-                from eges_tpu.crypto.verify_host import batch_verify_txns
-                return batch_verify_txns(block.transactions, self.verifier)
-            # parent known: the proposal must extend OUR head, or the
-            # insert path would reject what we ACKed ("non-sequential
-            # insert") and the quorum round is wasted on a stale parent
-            if (block.header.parent_hash != self._head.hash
-                    or block.header.number != self._head.number + 1):
-                return False
-            try:
-                self._process(block, parent_state)
-            except ChainError:
-                return False
-            return True
+        from eges_tpu.utils import tracing
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+
+        with self._lock, tracing.DEFAULT.span(
+                "chain.validate_candidate", number=block.number,
+                txns=len(block.transactions), ok=0) as sp:
+            ok = self._candidate_ok(block)
+            sp.set_attr("ok", int(ok))
+        metrics.counter("chain.validated_blocks" if ok
+                        else "chain.refused_candidates").inc()
+        return ok
+
+    def _candidate_ok(self, block: Block) -> bool:
+        """The body of :meth:`validate_candidate`, under its span."""
+        try:
+            self._verify_body(block)
+        except ChainError:
+            return False
+        parent_state = self._states.get(block.header.parent_hash)
+        if parent_state is None:
+            # parent unknown: we are behind — signature checks only
+            from eges_tpu.crypto.verify_host import batch_verify_txns
+            return batch_verify_txns(block.transactions, self.verifier)
+        # parent known: the proposal must extend OUR head, or the
+        # insert path would reject what we ACKed ("non-sequential
+        # insert") and the quorum round is wasted on a stale parent
+        if (block.header.parent_hash != self._head.hash
+                or block.header.number != self._head.number + 1):
+            return False
+        try:
+            self._process(block, parent_state)
+        except ChainError:
+            return False
+        return True
 
     # -- insert funnel ----------------------------------------------------
 

@@ -357,18 +357,25 @@ class StateDB:
         """Secure-trie state root over geth-shaped account RLP;
         incremental — only accounts dirtied since the last call rehash."""
         if self._root_cache is None:
-            t = self._trie
-            # sorted: the rehash order must not depend on set hash order
-            # (byte-identical trie node churn under the chaos contract)
-            for addr in sorted(self._dirty):
-                a = self.account(addr)
-                if a == Account():
-                    t = t.delete(addr)
-                else:
-                    t = t.update(addr, rlp.encode(a.to_rlp()))
-            self._trie = t
-            self._dirty = set()
-            self._root_cache = t.root()
+            from eges_tpu.utils import tracing
+            from eges_tpu.utils.metrics import DEFAULT as metrics
+
+            with tracing.DEFAULT.span("state.root",
+                                      dirty=len(self._dirty)):
+                t = self._trie
+                # sorted: the rehash order must not depend on set hash
+                # order (byte-identical trie node churn under the chaos
+                # contract)
+                for addr in sorted(self._dirty):
+                    a = self.account(addr)
+                    if a == Account():
+                        t = t.delete(addr)
+                    else:
+                        t = t.update(addr, rlp.encode(a.to_rlp()))
+                metrics.counter("state.root_accounts").inc(len(self._dirty))
+                self._trie = t
+                self._dirty = set()
+                self._root_cache = t.root()
         return self._root_cache
 
     def __len__(self) -> int:
@@ -618,25 +625,32 @@ def process_block(parent_state: StateDB, block, senders,
     """
     if not block.transactions:
         return parent_state, (), 0  # share the snapshot: nothing changed
+    from eges_tpu.utils import tracing
+
     state = parent_state.copy()
     receipts = []
     gas = 0
     coinbase = block.header.coinbase
     ctx = block_ctx(block.header)
-    for t, sender in zip(block.transactions, senders):
-        if sender is None:
-            raise StateError("rooted transaction without a sender")
-        r = apply_txn(state, t, sender, coinbase, gas, ctx=ctx,
-                      verifier=verifier)
-        gas = r.cumulative_gas_used
-        receipts.append(r)
+    with tracing.DEFAULT.span("chain.execute",
+                              txns=len(block.transactions)):
+        for t, sender in zip(block.transactions, senders):
+            if sender is None:
+                raise StateError("rooted transaction without a sender")
+            r = apply_txn(state, t, sender, coinbase, gas, ctx=ctx,
+                          verifier=verifier)
+            gas = r.cumulative_gas_used
+            receipts.append(r)
     return state, tuple(receipts), gas
 
 
 def receipts_root(receipts) -> bytes:
     if not receipts:
         return EMPTY_ROOT
-    return derive_sha([r.encode() for r in receipts])
+    from eges_tpu.utils import tracing
+
+    with tracing.DEFAULT.span("chain.receipts_root", txns=len(receipts)):
+        return derive_sha([r.encode() for r in receipts])
 
 
 def receipts_bloom(receipts) -> bytes:

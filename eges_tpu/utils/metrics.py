@@ -40,6 +40,11 @@ METRIC_FAMILIES = frozenset({
     "chain.blocks_refused", "chain.sender_cached_rows",
     "chain.sender_coalesced_rows", "chain.sender_native_rows",
     "chain.sender_rows",
+    # the block path (PR 44): an acceptor's validations by outcome, the
+    # executions of a block's transactions (one a _process), the accounts
+    # StateDB.root() hashed into the trie
+    "chain.executions", "chain.refused_candidates",
+    "chain.validated_blocks", "state.root_accounts",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -165,6 +170,15 @@ METRIC_FAMILIES = frozenset({
 METRIC_HELP = {
     "chain.bad_blocks": "Blocks rejected by validation on insert.",
     "chain.blocks": "Canonical blocks inserted into the chain.",
+    "chain.executions": (
+        "Executions of a block's transactions (one a _process: an "
+        "acceptor's validation, then the insert)."),
+    "chain.refused_candidates": (
+        "Proposed blocks validate_candidate refused (no ACK)."),
+    "chain.validated_blocks": (
+        "Proposed blocks validate_candidate took (an ACK follows)."),
+    "state.root_accounts": (
+        "Dirty accounts StateDB.root() put into the secure trie."),
     "chain.blocks_refused": (
         "Blocks whose sender recovery raised StateError (a signature "
         "that names no sender)."),

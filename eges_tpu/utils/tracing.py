@@ -134,6 +134,29 @@ SPANS = {
                                     "consensus.quorum_pruned, "
                                     "consensus.quorums, one inc a call; "
                                     "histogram consensus.quorum_seconds"),
+    "consensus.cert_ok": ((), "a confirm's certificate: its supporters' "
+                              "signatures through the scheduler as one "
+                              "consensus-class call (consensus/quorum.py "
+                              "cert_ok); attr rows"),
+    "chain.validate_candidate": ((), "an acceptor's whole check of a "
+                                     "proposed block before its ACK "
+                                     "(core/chain.py): body, senders, "
+                                     "execution, the commitments; attrs "
+                                     "number, txns, ok; counters "
+                                     "chain.validated_blocks, "
+                                     "chain.refused_candidates"),
+    "chain.verify_body": ((), "the transaction root of a block's body "
+                              "(derive_sha over its encodings); attr txns"),
+    "chain.execute": ((), "the apply_txn loop of process_block "
+                          "(core/state.py); attr txns; counter "
+                          "chain.executions, one inc a _process: the "
+                          "validation's and the insert's"),
+    "state.root": ((), "StateDB.root() where it is not cached: the dirty "
+                       "accounts into the secure trie, then the nodes' "
+                       "hashes; attr dirty; counter state.root_accounts, "
+                       "one inc(dirty) a call"),
+    "chain.receipts_root": ((), "derive_sha over a block's receipts "
+                                "(core/state.py receipts_root); attr txns"),
     "chain.insert": ((), "execute, state root, index"),
     "chain.recover_senders": ((), "a block's signed rows through the "
                                   "verifier in one call (core/state.py): "
