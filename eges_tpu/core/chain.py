@@ -322,6 +322,11 @@ class BlockChain:
         # "late conflicting offer displaces the good block" can stall the
         # funnel — insertion tries every candidate when the height opens
         self._future: dict[int, list[Block]] = {}
+        # block hash -> (transactions, state, receipts) of a candidate
+        # that passed validate_candidate's FULL check on the current head,
+        # kept for the insert of that very block (see _insert); at most
+        # _MAX_CANDIDATES, oldest out, all dropped when the head moves
+        self._validated: dict[bytes, tuple] = {}
         self.bad_blocks = 0
         # owning GeecNode attaches its event journal (utils/journal.py)
         self.journal = None
@@ -624,7 +629,9 @@ class BlockChain:
         commitments — the checks the insert path will make, run before
         ACKing (the reference acceptor ACKs unconditionally,
         geec_state.go:545).  Falls back to body+signature checks when the
-        parent state is unknown (we are behind)."""
+        parent state is unknown (we are behind).  A candidate that passed
+        every check leaves its state and receipts for :meth:`_insert`, so
+        the block is executed once."""
         from eges_tpu.utils import tracing
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
@@ -638,7 +645,9 @@ class BlockChain:
         return ok
 
     def _candidate_ok(self, block: Block) -> bool:
-        """The body of :meth:`validate_candidate`, under its span."""
+        """The body of :meth:`validate_candidate`, under its span.  Only
+        the full check's success keeps an entry in ``_validated``: nothing
+        for a refused candidate, nothing on the signatures-only branch."""
         try:
             self._verify_body(block)
         except ChainError:
@@ -655,9 +664,15 @@ class BlockChain:
                 or block.header.number != self._head.number + 1):
             return False
         try:
-            self._process(block, parent_state)
+            state, receipts, _ = self._process(block, parent_state)
         except ChainError:
             return False
+        kept = self._validated
+        h = block.hash
+        kept.pop(h, None)  # validated again: the newest
+        if len(kept) >= self._MAX_CANDIDATES:
+            del kept[next(iter(kept))]
+        kept[h] = (block.transactions, state, receipts)
         return True
 
     # -- insert funnel ----------------------------------------------------
@@ -741,7 +756,7 @@ class BlockChain:
                 prev = b
             # rewind + replay (the bloom index rewinds too; each insert
             # re-adds its height with the replacement bloom)
-            self._head = anchor
+            self._move_head(anchor)
             self.bloom_index.truncate(first.number)
             for b in blocks:
                 try:
@@ -753,22 +768,54 @@ class BlockChain:
             self._future.clear()
             return True
 
+    def _move_head(self, block: Block) -> None:
+        """Every move of the head after start-up: a validated candidate
+        is only ever good for a child of the head it was validated on."""
+        self._head = block
+        self._validated.clear()
+
+    def _validated_outcome(self, block: Block):
+        """``(state, receipts)`` of this very block's validation on the
+        current head, or None.  Identity of the body, not the hash alone:
+        a block's hash covers its header, and ``_verify_body`` is what
+        ties a body to the header."""
+        kept = self._validated.get(block.hash)
+        if kept is None or block.uncles:
+            return None
+        transactions, state, receipts = kept
+        if transactions is not block.transactions:
+            return None
+        return state, receipts
+
     def _insert(self, block: Block) -> None:
+        """Verify and store ``block`` as the new head.  Where
+        :meth:`validate_candidate` already ran the full check on this very
+        body (the IDENTICAL ``transactions`` tuple, what ``with_confirm``
+        hands back) on this head, its state and receipts are taken and the
+        body is neither rooted nor executed again; any other block meets
+        ``_verify_body`` and ``_process`` here."""
         from eges_tpu.utils import tracing
         from eges_tpu.utils.metrics import DEFAULT as metrics
 
         with tracing.DEFAULT.span("chain.insert", number=block.number,
-                                  txns=len(block.transactions)) as sp:
+                                  txns=len(block.transactions),
+                                  reused=0) as sp:
             self._verify_header(block.header)
-            self._verify_body(block)
-            parent_state = self._states.get(block.header.parent_hash)
-            if parent_state is None:
-                # cannot happen in-order
-                raise ChainError("no state for parent")
-            state, receipts, _ = self._process(block, parent_state)
+            kept = self._validated_outcome(block)
+            if kept is not None:
+                state, receipts = kept
+                sp.set_attr("reused", 1)
+                metrics.counter("chain.insert_reused").inc()
+            else:
+                self._verify_body(block)
+                parent_state = self._states.get(block.header.parent_hash)
+                if parent_state is None:
+                    # cannot happen in-order
+                    raise ChainError("no state for parent")
+                state, receipts, _ = self._process(block, parent_state)
             self.store.put_block(block)
             self.store.set_head(block.hash)
-            self._head = block
+            self._move_head(block)
             self._remember_state(block.hash, block.number, state, receipts)
             self._index_txns(block, receipts)
             self.bloom_index.add(block.number, block.header.bloom)
@@ -803,7 +850,7 @@ class BlockChain:
                 raise ChainError("pivot not ahead of head")
             self.store.put_block(block)
             self.store.set_head(block.hash)
-            self._head = block
+            self._move_head(block)
             self._remember_state(block.hash, block.number, state, ())
             self._index_txns(block)
             self.bloom_index.add(block.number, block.header.bloom)

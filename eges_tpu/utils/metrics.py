@@ -41,10 +41,12 @@ METRIC_FAMILIES = frozenset({
     "chain.sender_coalesced_rows", "chain.sender_native_rows",
     "chain.sender_rows",
     # the block path (PR 44): an acceptor's validations by outcome, the
-    # executions of a block's transactions (one a _process), the accounts
-    # StateDB.root() hashed into the trie
-    "chain.executions", "chain.refused_candidates",
-    "chain.validated_blocks", "state.root_accounts",
+    # executions of a block's transactions (one a _process), the inserts
+    # that took the state and receipts of the block's own validation
+    # (PR 45), the accounts StateDB.root() hashed into the trie
+    "chain.executions", "chain.insert_reused",
+    "chain.refused_candidates", "chain.validated_blocks",
+    "state.root_accounts",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -172,7 +174,11 @@ METRIC_HELP = {
     "chain.blocks": "Canonical blocks inserted into the chain.",
     "chain.executions": (
         "Executions of a block's transactions (one a _process: an "
-        "acceptor's validation, then the insert)."),
+        "acceptor's validation, or the insert of a block this node did "
+        "not validate on this head)."),
+    "chain.insert_reused": (
+        "Inserts that took the state and receipts of the block's own "
+        "validate_candidate instead of executing it again."),
     "chain.refused_candidates": (
         "Proposed blocks validate_candidate refused (no ACK)."),
     "chain.validated_blocks": (

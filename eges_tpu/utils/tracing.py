@@ -144,20 +144,27 @@ SPANS = {
                                      "execution, the commitments; attrs "
                                      "number, txns, ok; counters "
                                      "chain.validated_blocks, "
-                                     "chain.refused_candidates"),
+                                     "chain.refused_candidates; a pass "
+                                     "keeps its state and receipts for "
+                                     "chain.insert"),
     "chain.verify_body": ((), "the transaction root of a block's body "
                               "(derive_sha over its encodings); attr txns"),
     "chain.execute": ((), "the apply_txn loop of process_block "
                           "(core/state.py); attr txns; counter "
-                          "chain.executions, one inc a _process: the "
-                          "validation's and the insert's"),
+                          "chain.executions, one inc a _process: a "
+                          "validation's, or the insert's of a block "
+                          "that brings no validation of its own"),
     "state.root": ((), "StateDB.root() where it is not cached: the dirty "
                        "accounts into the secure trie, then the nodes' "
                        "hashes; attr dirty; counter state.root_accounts, "
                        "one inc(dirty) a call"),
     "chain.receipts_root": ((), "derive_sha over a block's receipts "
                                 "(core/state.py receipts_root); attr txns"),
-    "chain.insert": ((), "execute, state root, index"),
+    "chain.insert": ((), "execute, state root, index; attrs number, "
+                         "txns, reused (1: the state and receipts are "
+                         "chain.validate_candidate's for this very body "
+                         "on this head, nothing executed again); counter "
+                         "chain.insert_reused"),
     "chain.recover_senders": ((), "a block's signed rows through the "
                                   "verifier in one call (core/state.py): "
                                   "behind the scheduler one window, part "

@@ -182,31 +182,34 @@ def test_the_plain_copy_of_the_windows_is_the_memberships(seed, size, n):
     assert ref_members.majority(n, size) == ms.validate_threshold()
 
 
+def _sim_journal(seed: int) -> bytes:
+    """One short three-node cluster run's journal, as chaos compares it."""
+    from eges_tpu.sim.cluster import SimCluster
+    from harness.chaos import canonical_dump
+
+    cluster = SimCluster(3, txn_per_block=4, seed=seed,
+                         verifier=NativeBatchVerifier())
+    cluster.start()
+    cluster.run(600.0, stop_condition=lambda: cluster.min_height() >= 5)
+    for sn in cluster.nodes:
+        sn.node.stop()
+    assert cluster.min_height() >= 5
+    cluster.verifier.close()
+    return canonical_dump(cluster.journals())
+
+
 def test_the_new_spans_leave_a_sims_journal_byte_for_byte():
     """A span is not an event: one short cluster run's journal is the same
     with the block path's new spans and counters taken out."""
-    from eges_tpu.sim.cluster import SimCluster
     from eges_tpu.utils import tracing
     from eges_tpu.utils.metrics import DEFAULT as metrics
-    from harness.chaos import canonical_dump
 
     new = {"chain.validate_candidate", "chain.verify_body", "chain.execute",
            "state.root", "chain.receipts_root", "consensus.cert_ok"}
     assert new <= set(tracing.SPANS)
 
-    def run() -> bytes:
-        cluster = SimCluster(3, txn_per_block=4, seed=44,
-                             verifier=NativeBatchVerifier())
-        cluster.start()
-        cluster.run(600.0, stop_condition=lambda: cluster.min_height() >= 5)
-        for sn in cluster.nodes:
-            sn.node.stop()
-        assert cluster.min_height() >= 5
-        cluster.verifier.close()
-        return canonical_dump(cluster.journals())
-
     before = metrics.counter("chain.executions").value
-    with_spans = run()
+    with_spans = _sim_journal(44)
     assert metrics.counter("chain.executions").value > before
     real = tracing.Tracer.span
 
@@ -217,7 +220,31 @@ def test_the_new_spans_leave_a_sims_journal_byte_for_byte():
 
     tracing.Tracer.span, saved = span, real
     try:
-        without = run()
+        without = _sim_journal(44)
     finally:
         tracing.Tracer.span = saved
     assert with_spans == without
+
+
+def test_the_reused_validation_leaves_a_sims_journal_byte_for_byte(
+        monkeypatch):
+    """An acceptor's insert that takes its own validation's state is the
+    same insert: one short cluster run's journal is the same with the
+    lookup made to find nothing, and the run WITH it did reuse."""
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    def run() -> tuple[bytes, int, int]:
+        reused = metrics.counter("chain.insert_reused").value
+        executed = metrics.counter("chain.executions").value
+        return (_sim_journal(45),
+                metrics.counter("chain.insert_reused").value - reused,
+                metrics.counter("chain.executions").value - executed)
+
+    with_reuse, reused, executed = run()
+    assert reused > 0
+    monkeypatch.setattr(BlockChain, "_validated_outcome",
+                        lambda self, block: None)
+    without, none, executed_twice = run()
+    assert with_reuse == without
+    # the same run, and every reused insert was one execution the less
+    assert none == 0 and executed_twice == executed + reused
