@@ -35,7 +35,7 @@ def ensure_built() -> str:
     native = os.path.dirname(lib)
     srcs = [os.path.join(native, f) for f in (
         "secp256k1.cpp", "keccak.cpp", "election.cpp", "ingress.cpp",
-        "Makefile")]
+        "trie.cpp", "Makefile")]
     if os.path.exists(lib) and all(
             os.path.getmtime(lib) >= os.path.getmtime(s) for s in srcs):
         return lib
@@ -74,6 +74,17 @@ def _load():
             [ctypes.c_char_p, ctypes.c_void_p, ctypes.c_uint64]
             + [ctypes.c_void_p] * 8)
         lib.geec_decode_txn_window.restype = ctypes.c_int
+    except AttributeError:
+        pass
+    try:  # trie roots (native/trie.cpp); absent in old builds
+        lib.geec_derive_sha.argtypes = [
+            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
+        lib.geec_derive_sha.restype = ctypes.c_int
+        lib.geec_trie_hash_nodes.argtypes = [
+            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.c_char_p, ctypes.c_char_p]
+        lib.geec_trie_hash_nodes.restype = ctypes.c_int
     except AttributeError:
         pass
     try:  # election component (native/election.cpp); absent in old builds
@@ -142,6 +153,51 @@ def decode_txn_window(data: bytes, offsets, **columns) -> None:
         ptrs.append(col.ctypes.data)
     if lib.geec_decode_txn_window(data, offsets.ctypes.data, n, *ptrs):
         raise MemoryError("native window decoder found no scratch memory")
+
+
+def has_trie() -> bool:
+    lib = _load()
+    return lib is not None and hasattr(lib, "geec_trie_hash_nodes")
+
+
+def derive_sha(items) -> tuple[bytes, int]:
+    """Root of the trie that holds ``items[i]`` under the key
+    ``rlp(i)``, and the number of nodes it took: built, encoded and
+    hashed in ONE library call that holds no GIL (``native/trie.cpp``).
+    Raises AttributeError on a library built before the entry existed."""
+    import numpy as np
+
+    lib = _load()
+    n = len(items)
+    # the spans are made here from the items themselves, so they are
+    # what the library trusts them to be
+    offsets = np.zeros((n + 1,), np.uint64)
+    np.cumsum(np.fromiter(map(len, items), np.uint64, n), out=offsets[1:])
+    root = ctypes.create_string_buffer(32)
+    nodes = ctypes.c_uint64()
+    if lib.geec_derive_sha(b"".join(items), offsets.ctypes.data, n, root,
+                           ctypes.byref(nodes)):
+        raise MemoryError("native derive_sha found no scratch memory")
+    return root.raw, nodes.value
+
+
+def trie_hash_nodes(records: bytes, n: int) -> tuple[bytes, bytes]:
+    """``n`` trie nodes, flattened children first into ``records`` (the
+    form ``native/trie.cpp geec_trie_hash_nodes`` documents and
+    ``core/trie.py`` writes), encoded and hashed in ONE library call
+    that holds no GIL.  Returns node ``i``'s reference in
+    ``refs[33 * i:][:lens[i]]``: the node's own encoding where that is
+    under 32 bytes, else ``0xa0`` and its hash.  The library checks
+    every length against what is left of ``records``."""
+    lib = _load()
+    refs = ctypes.create_string_buffer(33 * n)
+    lens = ctypes.create_string_buffer(n)
+    rc = lib.geec_trie_hash_nodes(records, len(records), n, refs, lens)
+    if rc == -1:
+        raise ValueError("node records are not of the library's form")
+    if rc:
+        raise MemoryError("native trie hasher found no scratch memory")
+    return refs.raw, lens.raw
 
 
 def ec_recover(msg_hash: bytes, sig: bytes) -> bytes:
@@ -220,6 +276,41 @@ def self_check() -> None:
     assert ec_recover(msg, sig) == ps.privkey_to_pubkey(priv)
     assert ec_verify(msg, sig[:64], ps.privkey_to_pubkey(priv))
     _check_decode_window()
+    _check_trie()
+
+
+# roots the benchmark's plain reference gives (perfbench/ref/state.py
+# derive_sha / trie_root; tests/test_native.py computes them again)
+EMPTY_TRIE_ROOT = bytes.fromhex(
+    "56e81f171bcc55a6ff8345e692c0f86e5b48e01b996cadc001622fb5e363b421")
+DERIVE_SHA_ITEMS = [bytes([i % 251]) * (i % 40 + 1) for i in range(200)]
+DERIVE_SHA_ROOT = bytes.fromhex(
+    "09f3ef3772261d6fa788bf21d351a88d96c9ca902badf5a0931af21df2bb16cd")
+# the trie of 0x0123 -> "v" and 0x0145 -> bytes(range(40)), children
+# first: a leaf short enough to be embedded, a leaf that is hashed, the
+# branch over both at nibble 2, the extension (0, 1) above it
+TRIE_NODE_RECORDS = (
+    b"\x00" + (1).to_bytes(4, "little") + (1).to_bytes(4, "little")
+    + b"\x03" + b"v"
+    + b"\x00" + (1).to_bytes(4, "little") + (40).to_bytes(4, "little")
+    + b"\x05" + bytes(range(40))
+    + b"\x02" + b"\x80" * 2 + b"\x00" + (0).to_bytes(4, "little")
+    + b"\x80" + b"\x00" + (1).to_bytes(4, "little") + b"\x80" * 11
+    + (0).to_bytes(4, "little")
+    + b"\x01" + (2).to_bytes(4, "little") + b"\x00\x01"
+    + b"\x00" + (2).to_bytes(4, "little"))
+TRIE_NODES_ROOT = bytes.fromhex(
+    "2da65d865a48d4e29a9d050e1f962d087aa06e920436f481529c55c20bf9b6d3")
+
+
+def _check_trie() -> None:
+    """The two entry points of ``native/trie.cpp`` on fixed vectors."""
+    assert derive_sha([]) == (EMPTY_TRIE_ROOT, 0)
+    assert derive_sha(DERIVE_SHA_ITEMS)[0] == DERIVE_SHA_ROOT
+    refs, lens = trie_hash_nodes(TRIE_NODE_RECORDS, 4)
+    assert list(lens) == [3, 33, 33, 33], "embedded leaf, three hashes"
+    assert refs[:3] == b"\xc2\x33v" and refs[99] == 0xa0
+    assert refs[100:132] == TRIE_NODES_ROOT
 
 
 def _check_decode_window() -> None:

@@ -47,6 +47,10 @@ METRIC_FAMILIES = frozenset({
     "chain.executions", "chain.insert_reused",
     "chain.refused_candidates", "chain.validated_blocks",
     "state.root_accounts",
+    # core/trie.py — the nodes a root encoded and hashed (derive_sha and
+    # IncrementalTrie.root, one inc a root), and those of them that
+    # went through the library's one call (native/trie.cpp)
+    "trie.native_nodes", "trie.nodes",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -185,6 +189,13 @@ METRIC_HELP = {
         "Proposed blocks validate_candidate took (an ACK follows)."),
     "state.root_accounts": (
         "Dirty accounts StateDB.root() put into the secure trie."),
+    "trie.nodes": (
+        "Trie nodes encoded and hashed for a root (derive_sha and "
+        "IncrementalTrie.root; a node that has its reference is never "
+        "counted again)."),
+    "trie.native_nodes": (
+        "Trie nodes the native library encoded and hashed, one call a "
+        "root (trie.nodes less these took the Python rung)."),
     "chain.blocks_refused": (
         "Blocks whose sender recovery raised StateError (a signature "
         "that names no sender)."),
