@@ -201,14 +201,6 @@ class GeecNode:
         self.geec_txn_sink = None  # app-layer callback for confirmed geec txns
         self.txpool = None  # optional TxPool; proposals drain it
         #                     (property: attaching one wires the journal)
-        # columnar ingest hook (ROADMAP item 5): an injectable
-        # txns -> TxColumns extractor (eges_tpu.ingress.columns_of).
-        # Injected rather than imported — consensus sits below the
-        # ingress package in the layer map — by whatever wires the node
-        # (sim/cluster.py, the node runner).  When set, multi-txn
-        # gossip bundles admit window-granular via add_remotes_window;
-        # singletons keep the legacy per-tx path.
-        self.columnarize = None
 
         # deferred messages for future working blocks (Wait() analogue);
         # deque for the same O(1) oldest-first shedding as above
@@ -1228,12 +1220,7 @@ class GeecNode:
             # network-wide fan-out amplification (the reference relays
             # only pool-accepted txns, eth/handler.go:742-759)
             self._ensure_pool_relay()
-            if self.columnarize is not None and len(fresh) > 1:
-                # wire-speed path: one columnar extraction + one
-                # window-granular admission for the whole bundle
-                self.txpool.add_remotes_window(self.columnarize(fresh))
-            else:
-                self.txpool.add_remotes(fresh)
+            self.txpool.add_remotes(fresh)
         else:
             # pool-less follower: relay with dedup so txns still
             # propagate through it (marked seen either way)

@@ -443,28 +443,14 @@ def _signature_rows(signed: list) -> tuple[np.ndarray, np.ndarray, int]:
     wired = [k for k, m in enumerate(memos) if "wire" in m]
     rest = range(n)
     if wired and native.has_decode_window():
-        frames = [memos[k]["wire"] for k in wired]
-        m = len(frames)
-        offsets = np.zeros((m + 1,), np.uint64)
-        np.cumsum([len(f) for f in frames], dtype=np.uint64,
-                  out=offsets[1:])
-        decoded, valid = np.zeros((m,), bool), np.zeros((m,), bool)
-        txhash = np.zeros((m, 32), np.uint8)
-        sighash = np.zeros((m, 32), np.uint8)
-        sig = np.zeros((m, 65), np.uint8)
-        native.decode_txn_window(
-            b"".join(frames), offsets, decoded=decoded, valid=valid,
-            txhash=txhash, sighash=sighash, sig=sig,
-            nonce=np.zeros((m,), np.uint64),
-            gas_price=np.zeros((m,), np.uint64),
-            spans=np.zeros((m, 10, 2), np.uint32))
+        _, _, c = native.decode_txn_frames([memos[k]["wire"] for k in wired])
         # canonical RLP: keccak256(wire) == keccak256(t.encode())
-        flat = txhash.tobytes()
-        for j in np.flatnonzero(decoded).tolist():
+        flat = c["txhash"].tobytes()
+        for j in np.flatnonzero(c["decoded"]).tolist():
             memos[wired[j]].setdefault("hash", flat[32 * j:32 * j + 32])
-        took = np.flatnonzero(valid)
+        took = np.flatnonzero(c["valid"])
         at = np.asarray(wired, np.int64)[took]
-        sigs[at], hashes[at] = sig[took], sighash[took]
+        sigs[at], hashes[at] = c["sig"][took], c["sighash"][took]
         left = np.ones((n,), bool)
         left[at] = False
         rest = np.flatnonzero(left).tolist()

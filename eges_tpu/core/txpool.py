@@ -1,4 +1,4 @@
-"""Transaction pool with a device-batched verification window.
+"""Transaction pool with a verification window, one device batch each.
 
 Role parity with the reference's ``core/tx_pool.go`` for the Geec
 capability set: remote txns are validated (signature -> sender) before
@@ -18,19 +18,19 @@ from __future__ import annotations
 
 import threading
 
+import numpy as np
+
+from eges_tpu.core.txcolumns import (WINDOW_MAX_ROWS, TxColumns,
+                                     columns_from_txns)
 from eges_tpu.core.types import Transaction
-from eges_tpu.utils import ledger
-from eges_tpu.utils import tracing
+from eges_tpu.utils import ledger, metrics, tracing
 
 
 class _WindowChunk:
-    """A columnar window's fresh rows queued for the verify flush.
-
-    Rides the same ``_queue`` as scalar ``Transaction`` entries so
-    mixed arrivals (windows from gossip, singletons from RPC) flush in
-    strict arrival order; ``rows`` indexes the still-live rows of the
-    shared ``TxColumns`` and shrinks in place when a flush slice splits
-    the chunk at a ``max_batch`` boundary."""
+    """A window's fresh rows queued for the verify flush: what the
+    ``_queue`` holds, in arrival order.  ``rows`` indexes the still-live
+    rows of the window's ``TxColumns`` and shrinks in place when a flush
+    slice splits the chunk at a ``max_batch`` boundary."""
 
     __slots__ = ("cols", "rows")
 
@@ -71,14 +71,11 @@ class TxPool:
         self._by_hash: dict[bytes, tuple[bytes, int]] = {}  # guarded-by: _lock
         self._dead: set[bytes] = set()  # guarded-by: _lock
         self._known: set[bytes] = set()  # guarded-by: _lock
-        # verify queue: scalar Transactions interleaved with columnar
-        # _WindowChunk entries in strict arrival order (mixed arrivals
-        # must flush exactly like an all-scalar stream); _queue_rows is
-        # the ROW count (a chunk is many rows), the unit max_batch and
-        # the flush trigger are denominated in
-        self._queue: list = []  # guarded-by: _lock
+        # verify queue: _WindowChunk entries in arrival order;
+        # _queue_rows is the ROW count (a chunk is many rows), the unit
+        # max_batch and the flush trigger are denominated in
+        self._queue: list[_WindowChunk] = []  # guarded-by: _lock
         self._queue_rows = 0  # guarded-by: _lock
-        self._window_chunks = 0  # guarded-by: _lock
         self._timer = None
         self.stats = {"admitted": 0, "rejected": 0, "duplicate": 0,  # guarded-by: _lock
                       "batches": 0, "replaced": 0}
@@ -110,64 +107,23 @@ class TxPool:
         self._depth_gauge()  # register txpool.pending at 0
 
     def _depth_gauge(self) -> None:
-        from eges_tpu.utils import metrics
-
         metrics.DEFAULT.gauge("txpool.pending").set(len(self._by_hash))
 
     # -- ingest -----------------------------------------------------------
 
     def add_remotes(self, txns) -> None:  # thread-entry (RPC via add_locals); ingress-entry:bounded
-        """Queue remote txns for batched admission
-        (ref: TxPool.AddRemotes core/tx_pool.go:551)."""
-        fresh = 0
-        with self._lock, \
-                tracing.DEFAULT.span("txpool.ingest", root=True,
-                                     owner=self.owner) as sp:
-            ctx = sp.context()
-            for t in txns:
-                h = t.hash
-                if h in self._known:
-                    self.stats["duplicate"] += 1
-                    # ambient charge: a re-delivered txn is pure waste
-                    # billed to whoever delivered THIS copy
-                    ledger.charge(drops=1)
-                    continue
-                if len(self._known) >= self._KNOWN_CAP:
-                    # coarse clear at the cap (geth's maxKnownTxs
-                    # idiom): briefly losing dedup history is cheaper
-                    # than letting a hash flood grow the set forever
-                    self._known.clear()
-                    from eges_tpu.utils import metrics
-                    metrics.DEFAULT.counter("txpool.known_clears").inc()
-                self._known.add(h)
-                self._queue.append(t)
-                self._queue_rows += 1
-                # one capacity probe covers all three bookkeeping maps:
-                # they fill together here and the thread-hygiene counter
-                # reconciliation assumes a uniform cap across them
-                if len(self._ingest_ctx) < self._INGEST_CTX_CAP:
-                    self._ingest_ctx[h] = ctx
-                    self._ingest_t[h] = self.clock.now()
-                    rec = ledger.current()
-                    if rec is not None:
-                        self._ingest_origin[h] = rec
-                fresh += 1
-            sp.set_attr("fresh", fresh)
-            if self._queue_rows >= self.max_batch:
-                self._flush()
-            elif self._queue and self._timer is None:
-                self._timer = self.clock.call_later(self.window_ms / 1e3,
-                                                    self._on_window)
+        """Queue remote txns for admission
+        (ref: TxPool.AddRemotes core/tx_pool.go:551): the window of
+        their columns, one a chunk of at most ``WINDOW_MAX_ROWS``."""
+        txns = list(txns)
+        for w in range(0, len(txns), WINDOW_MAX_ROWS):
+            self.add_remotes_window(
+                columns_from_txns(txns[w:w + WINDOW_MAX_ROWS]))
 
-    def add_remotes_window(self, cols) -> None:  # thread-entry (gossip relay); ingress-entry:bounded
-        """Columnar window admission: ONE lock hold and ONE tracing span
+    def add_remotes_window(self, cols: TxColumns) -> None:  # thread-entry (gossip relay); ingress-entry:bounded
+        """The one way into the pool: ONE lock hold and ONE tracing span
         for the whole window, dedup against ``_known`` via set ops, and
-        per-window (not per-tx) bookkeeping — the batched sibling of
-        :meth:`add_remotes` with row-for-row identical admission
-        outcomes, journal events and ledger billing (the differential
-        test's contract).  ``cols`` is an ``ingress.columnar.TxColumns``
-        duck type: this layer consumes the arrays, it never imports the
-        decoder (core stays below ingress in the layer map)."""
+        per-window (not per-tx) bookkeeping."""
         with self._lock, \
                 tracing.DEFAULT.span("txpool.ingest", root=True,
                                      owner=self.owner) as sp:
@@ -176,10 +132,8 @@ class TxPool:
             n_undec = cols.n - int(cols.decoded.sum())
             if n_undec:
                 # no identity survives a failed decode: billed to the
-                # deliverer as pure waste, dropped pre-queue (the legacy
-                # path never sees such rows — its codec drops them)
+                # deliverer as pure waste, dropped pre-queue
                 ledger.charge(drops=n_undec)
-                from eges_tpu.utils import metrics
                 metrics.DEFAULT.counter("txpool.window_undecoded").inc(
                     n_undec)
             hs = hashes if not n_undec else \
@@ -206,19 +160,24 @@ class TxPool:
                     fresh_rows = self._dedup_rows_slow(hashes)
                     dup = len(hs) - len(fresh_rows)
             else:
-                # cap boundary: replicate the per-row coarse-clear
-                # semantics exactly (a clear mid-window re-admits
-                # earlier duplicates, same as the scalar path would)
+                # cap boundary: the coarse clear falls where it falls
+                # in the window (a clear mid-window re-admits earlier
+                # duplicates)
                 fresh_rows = self._dedup_rows_slow(hashes)
                 dup = len(hs) - len(fresh_rows)
             if dup:
                 self.stats["duplicate"] += dup
-                # ambient charge, aggregated: N same-origin unit drops
-                # at one timestamp equal one summed drop charge
+                # ambient charge, aggregated: a re-delivered txn is pure
+                # waste billed to whoever delivered THIS copy, and N
+                # same-origin unit drops at one timestamp equal one
+                # summed drop charge
                 ledger.charge(drops=dup)
             if fresh_rows:
                 now = self.clock.now()
                 rec = ledger.current()
+                # one capacity probe covers all three bookkeeping maps:
+                # they fill together here and the thread-hygiene counter
+                # reconciliation assumes a uniform cap across them
                 room = self._INGEST_CTX_CAP - len(self._ingest_ctx)
                 book = fresh_rows[:room] if room < len(fresh_rows) \
                     else fresh_rows
@@ -229,7 +188,6 @@ class TxPool:
                         self._ingest_origin.update(
                             (hashes[i], rec) for i in book)
                 self._queue.append(_WindowChunk(cols, fresh_rows))
-                self._window_chunks += 1
                 self._queue_rows += len(fresh_rows)
             sp.set_attr("fresh", len(fresh_rows) if fresh_rows else 0)
             if self._queue_rows >= self.max_batch:
@@ -239,9 +197,11 @@ class TxPool:
                                                     self._on_window)
 
     def _dedup_rows_slow(self, hashes) -> list[int]:
-        """Per-row dedup replica of the scalar loop — the path taken
-        when the window carries intra-window duplicates or could trip
-        the ``_KNOWN_CAP`` coarse clear mid-window."""
+        """Dedup a row at a time — the path taken when the window
+        carries intra-window duplicates or could trip the ``_KNOWN_CAP``
+        coarse clear mid-window (geth's maxKnownTxs idiom: briefly
+        losing dedup history is cheaper than letting a hash flood grow
+        the set forever)."""
         fresh_rows = []
         known = self._known
         for i, h in enumerate(hashes):
@@ -249,7 +209,6 @@ class TxPool:
                 continue
             if len(known) >= self._KNOWN_CAP:
                 known.clear()
-                from eges_tpu.utils import metrics
                 metrics.DEFAULT.counter("txpool.known_clears").inc()
             known.add(h)
             fresh_rows.append(i)
@@ -264,176 +223,113 @@ class TxPool:
         """Hand everything queued to the verifier and admit what comes
         back: one ``txpool.flush`` span, whose self time is the
         gathering of rows (the wait is ``sched.await`` inside it, the
-        admission ``txpool.admit_window`` / ``txpool.admit``)."""
-        if not self._queue:
-            self._flush_queue()  # nothing to hand over: the timer goes
-            return
-        with tracing.DEFAULT.span("txpool.flush", owner=self.owner,
-                                  rows=self._queue_rows):
-            self._flush_queue()
-
-    def _flush_queue(self) -> None:
+        admission ``txpool.admit_window``)."""
         if self._timer is not None:
             self._timer.cancel()
             self._timer = None
-        if self._window_chunks:
-            self._flush_mixed()
+        if not self._queue:
             return
-        batch, self._queue = self._queue[: self.max_batch], \
-            self._queue[self.max_batch:]
-        if not batch:
-            return
-        self._queue_rows -= len(batch)
+        with tracing.DEFAULT.span("txpool.flush", owner=self.owner,
+                                  rows=self._queue_rows):
+            while self._queue:
+                self._flush_slice()
+
+    def _flush_slice(self) -> None:
+        """The queue's first ``max_batch`` rows: ONE
+        ``recover_signers_window`` call over arrays gathered straight
+        out of the chunks' columns — no per-row ``signature_parts``, no
+        per-row entry tuples — and ``Transaction`` objects materialize
+        only for rows that admit, a chunk's in one pass over its
+        columns.  Outcomes fall in arrival order."""
+        take: list[_WindowChunk] = []
+        rows_n = 0
+        qi = 0
+        while qi < len(self._queue) and rows_n < self.max_batch:
+            item = self._queue[qi]
+            need = self.max_batch - rows_n
+            if len(item.rows) <= need:
+                take.append(item)
+                rows_n += len(item.rows)
+                qi += 1
+            else:  # split: head flushes now, tail stays queued
+                take.append(_WindowChunk(item.cols, item.rows[:need]))
+                item.rows = item.rows[need:]
+                rows_n += need
+        self._queue = self._queue[qi:]
+        self._queue_rows -= rows_n
         self.stats["batches"] += 1
-        parts = [t.signature_parts() for t in batch]
-        senders: list[bytes | None] = [None] * len(batch)
-        rows = [(i, p) for i, p in enumerate(parts) if p is not None]
-        if rows:
-            # one shared recovery path for all three verifier shapes:
-            # a VerifierScheduler (window coalescing across callers +
-            # the sender cache, so a re-gossiped txn costs a lookup),
-            # a plain batch verifier (one device batch), or None (the
-            # per-entry host fallback, signature_nocgo.go role)
-            from eges_tpu.crypto.verify_host import recover_signers
-            rec = recover_signers([(h, sig) for _, (sig, h) in rows],
-                                  self.verifier)
-            for (i, _), sender in zip(rows, rec):
-                senders[i] = sender
-        for t, sender in zip(batch, senders):
-            if sender is None:
-                self.stats["rejected"] += 1
-                # invalid signature: the cheap-reject path an ingress
-                # flood rides — billed to the captured ingest origin
-                self._ledger_charge(t.hash, ledger.current(), rejects=1)
-                continue
-            self._admit(t, sender)
-        if self._queue:
-            self._flush_queue()
-
-    def _flush_mixed(self) -> None:
-        """Row-granular flush for a queue holding columnar window
-        chunks (possibly interleaved with scalar txns): each
-        ``max_batch``-row slice makes ONE ``recover_signers_window``
-        call over arrays gathered straight out of the columns — no
-        per-row ``signature_parts``, no per-row entry tuples — and
-        ``Transaction`` objects materialize only for rows that admit,
-        a chunk's in one pass over its columns.  Outcome order matches
-        the scalar ``_flush`` row for row."""
-        import numpy as np
-
-        while self._queue:
-            take: list = []
-            rows_n = 0
-            qi = 0
-            consumed_chunks = 0
-            while qi < len(self._queue) and rows_n < self.max_batch:
-                item = self._queue[qi]
-                if isinstance(item, _WindowChunk):
-                    need = self.max_batch - rows_n
-                    if len(item.rows) <= need:
-                        take.append(item)
-                        rows_n += len(item.rows)
-                        consumed_chunks += 1
-                        qi += 1
-                    else:  # split: head flushes now, tail stays queued
-                        take.append(_WindowChunk(item.cols,
-                                                 item.rows[:need]))
-                        item.rows = item.rows[need:]
-                        rows_n += need
-                else:
-                    take.append(item)
-                    rows_n += 1
-                    qi += 1
-            self._queue = self._queue[qi:]
-            self._window_chunks -= consumed_chunks
-            self._queue_rows -= rows_n
-            self.stats["batches"] += 1
-            # each taken item's first output row, arrival order; gather
-            # the valid rows' arrays
-            bases: list = []
-            n_out = 0
-            vh, vs, vpos = [], [], []
-            for item in take:
-                bases.append(n_out)
-                if isinstance(item, _WindowChunk):
-                    c, rs = item.cols, item.rows
-                    rs_arr = np.asarray(rs, dtype=np.int64)
-                    mask = c.valid[rs_arr]
-                    sel = rs_arr[mask]
-                    if sel.size:
-                        vh.append(c.sighash[sel])
-                        vs.append(c.sig[sel])
-                        vpos.extend(
-                            (n_out + np.nonzero(mask)[0]).tolist())
-                    n_out += len(rs)
-                else:
-                    p = item.signature_parts()
-                    if p is not None:
-                        sig, h = p
-                        vh.append(np.frombuffer(h, np.uint8)
-                                  .reshape(1, 32))
-                        vs.append(np.frombuffer(sig, np.uint8)
-                                  .reshape(1, 65))
-                        vpos.append(n_out)
-                    n_out += 1
-            senders: list = [None] * n_out
-            if vpos:
-                from eges_tpu.crypto.verify_host import \
-                    recover_signers_window
-                rec = recover_signers_window(
-                    vh[0] if len(vh) == 1 else np.concatenate(vh),
-                    vs[0] if len(vs) == 1 else np.concatenate(vs),
-                    self.verifier)
-                for pos, sender in zip(vpos, rec):
-                    senders[pos] = sender
-            rej: list = []
-            # ONE admit span for the whole slice's window rows (spans
-            # are ring-buffer telemetry, never journaled — admission
-            # outcomes, billing and relay order stay per-row identical
-            # to the scalar path); scalar interlopers keep their own
-            # per-row span via _admit
-            wcm = wsp = None
-            amb = ledger.current()  # stable for the whole slice
-            now = self.clock.now()  # one flush, one instant
-            try:
-                for item, base in zip(take, bases):
-                    if not isinstance(item, _WindowChunk):
-                        if senders[base] is None:
-                            self.stats["rejected"] += 1
-                            rej.append(item.hash)
-                        else:
-                            self._admit(item, senders[base])
+        # each taken chunk's first output row, arrival order; gather the
+        # valid rows' arrays
+        bases: list = []
+        n_out = 0
+        vh, vs, vpos = [], [], []
+        for item in take:
+            bases.append(n_out)
+            c, rs = item.cols.signed(), item.rows
+            rs_arr = np.asarray(rs, dtype=np.int64)
+            mask = c.valid[rs_arr]
+            sel = rs_arr[mask]
+            if sel.size:
+                vh.append(c.sighash[sel])
+                vs.append(c.sig[sel])
+                vpos.extend((n_out + np.nonzero(mask)[0]).tolist())
+            n_out += len(rs)
+        senders: list = [None] * n_out
+        if vpos:
+            # one recovery path for all three verifier shapes: a
+            # VerifierScheduler (window coalescing across callers + the
+            # sender cache, so a re-gossiped txn costs a lookup), a plain
+            # batch verifier (one device batch), or None (the host
+            # fallback, signature_nocgo.go role)
+            from eges_tpu.crypto.verify_host import recover_signers_window
+            rec = recover_signers_window(
+                vh[0] if len(vh) == 1 else np.concatenate(vh),
+                vs[0] if len(vs) == 1 else np.concatenate(vs),
+                self.verifier)
+            for pos, sender in zip(vpos, rec):
+                senders[pos] = sender
+        # invalid signatures: the cheap-reject path an ingress flood
+        # rides — billed to the captured ingest origins, once a slice
+        rej: list = []
+        # ONE admit span for the whole slice (spans are ring-buffer
+        # telemetry, never journaled), in the ingest trace of its first
+        # row that admits: the flush that got us here ran on a clock
+        # callback, outside any ambient span context
+        wcm = None
+        amb = ledger.current()  # stable for the whole slice
+        now = self.clock.now()  # one flush, one instant
+        try:
+            for item, base in zip(take, bases):
+                c, rs = item.cols, item.rows
+                snd = senders[base:base + len(rs)]
+                if None in snd:
+                    hashes = c.hashes
+                    rej.extend(hashes[i] for i, s in zip(rs, snd)
+                               if s is None)
+                    rs = [i for i, s in zip(rs, snd) if s is not None]
+                    self.stats["rejected"] += len(snd) - len(rs)
+                    snd = [s for s in snd if s is not None]
+                    if not rs:
                         continue
-                    c, rs = item.cols, item.rows
-                    snd = senders[base:base + len(rs)]
-                    if None in snd:
-                        hashes = c.hashes
-                        rej.extend(hashes[i] for i, s in zip(rs, snd)
-                                   if s is None)
-                        rs = [i for i, s in zip(rs, snd) if s is not None]
-                        self.stats["rejected"] += len(snd) - len(rs)
-                        snd = [s for s in snd if s is not None]
-                        if not rs:
-                            continue
-                    if wcm is None:
-                        ctx = self._ingest_ctx.get(c.hashes[rs[0]]) \
-                            or tracing.DEFAULT.current_context()
-                        wcm = tracing.DEFAULT.span(
-                            "txpool.admit_window", parent=ctx,
-                            owner=self.owner, rows=n_out)
-                        wsp = wcm.__enter__()
-                    # the chunk's admitted rows' Transactions in one
-                    # pass over the columns, then the rows' admission
-                    self._admit_rows(c.txns(rs), snd, wsp, amb, now,
-                                     batched=True)
-            finally:
-                if wcm is not None:
-                    wcm.__exit__(None, None, None)
-                    # slice-deferred housekeeping (see _admit_rows)
-                    self._maybe_compact()
-                    self._depth_gauge()
-            if rej:
-                self._ledger_charge_many(rej, rejects=1)
+                if wcm is None:
+                    ctx = self._ingest_ctx.get(c.hashes[rs[0]]) \
+                        or tracing.DEFAULT.current_context()
+                    wcm = tracing.DEFAULT.span(
+                        "txpool.admit_window", parent=ctx,
+                        owner=self.owner, rows=n_out)
+                    wcm.__enter__()
+                # the chunk's admitted rows' Transactions in one pass
+                # over the columns, then the rows' admission
+                self._admit_rows(c.txns(rs), snd, amb, now)
+        finally:
+            if wcm is not None:
+                wcm.__exit__(None, None, None)
+                # once a slice, not a row: the depth gauge and the
+                # compaction of ``_order``
+                self._maybe_compact()
+                self._depth_gauge()
+        if rej:
+            self._ledger_charge_many(rej, rejects=1)
 
     def _ledger_charge_many(self, hashes, **counts) -> None:
         """Aggregated flush billing: ONE ``charge()`` per (ledger,
@@ -474,32 +370,14 @@ class TxPool:
     # price (ref: core/tx_pool.go PriceBump default 10)
     PRICE_BUMP_PCT = 10
 
-    def _admit(self, t: Transaction, sender: bytes) -> None:
-        # re-enter the txn's ingest trace: the flush that got us here ran
-        # on a clock callback, outside any ambient span context
-        h = t.hash
-        ctx = self._ingest_ctx.get(h) \
-            or tracing.DEFAULT.current_context()
-        with tracing.DEFAULT.span("txpool.admit", parent=ctx,
-                                  owner=self.owner,
-                                  tx=h.hex()[:16]) as sp:
-            self._admit_rows((t,), (sender,), sp, ledger.current(),
-                             self.clock.now())
-
-    def _admit_rows(self, txns, senders, sp, amb, now: float,
-                    batched: bool = False) -> None:
-        """Admission body, rows in arrival order: one row under its own
-        span (the scalar path) or a window chunk's under the slice's.
-        What is the same for every row is read once: ``now`` (one
-        flush, one instant), the ambient ledger pair ``amb``, and
-        whether anybody is billed at all (no origin captured and no
-        ambient pair: nothing to pop, nothing to charge, for any row).
-        ``batched=True`` (the window flush) defers the per-row
-        housekeeping that is slice-equivalent: the depth gauge and
-        ``_order`` compaction run once after the slice, and the shared
-        window span skips per-row outcome attrs (on a shared span they
-        are last-write-wins noise; the per-row outcomes live in
-        ``stats`` and the ledger either way)."""
+    def _admit_rows(self, txns, senders, amb, now: float) -> None:
+        """Admission of a flushed chunk's rows, in arrival order, under
+        the slice's ``txpool.admit_window``.  What is the same for every
+        row is read once: ``now`` (one flush, one instant), the ambient
+        ledger pair ``amb``, and whether anybody is billed at all (no
+        origin captured and no ambient pair: nothing to pop, nothing to
+        charge, for any row).  The outcomes live in ``stats`` and the
+        ledger."""
         pending = self.pending
         by_hash = self._by_hash
         admit_t = self._admit_t
@@ -521,8 +399,6 @@ class TxPool:
                 stats["rejected"] += 1
                 if billed:
                     charge(h, amb, rejects=1, sender=sender)
-                if not batched:
-                    sp.set_attr("outcome", "rejected")
                 if not by_nonce:
                     del pending[sender]
                 continue
@@ -532,8 +408,6 @@ class TxPool:
                     stats["duplicate"] += 1
                     if billed:
                         charge(h, amb, drops=1, sender=sender)
-                    if not batched:
-                        sp.set_attr("outcome", "duplicate")
                     continue
                 by_hash.pop(old.hash, None)
                 self._dead.add(old.hash)
@@ -547,10 +421,6 @@ class TxPool:
             stats["admitted"] += 1
             if billed:
                 charge(h, amb, admits=1, sender=sender)
-            if not batched:
-                self._maybe_compact()
-                self._depth_gauge()
-                sp.set_attr("outcome", "admitted")
             if hook is not None:
                 # still inside the admit span: a broadcast hook fired
                 # here injects this trace into the outbound gossip
@@ -686,7 +556,6 @@ class TxPool:
                         "tx.commit", 0.0, parent=ctx, owner=self.owner,
                         tx=hs[0].hex()[:16], txns=len(hs),
                         txs=b"".join([h[:8] for h in hs]).hex(), **blk)
-                from eges_tpu.utils import metrics
                 metrics.DEFAULT.counter("txpool.commit_rows").inc(
                     sum(map(len, groups.values())))
                 metrics.DEFAULT.counter("txpool.commit_records").inc(

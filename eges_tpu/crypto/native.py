@@ -111,12 +111,12 @@ def keccak256(data: bytes) -> bytes:
     return out.raw
 
 
-# (argument, dtype, bytes a row) of geec_decode_txn_window's outputs, in
-# the call's order
-_WINDOW_COLUMNS = (("decoded", "bool", 1), ("valid", "bool", 1),
-                   ("txhash", "uint8", 32), ("sighash", "uint8", 32),
-                   ("sig", "uint8", 65), ("nonce", "uint64", 8),
-                   ("gas_price", "uint64", 8), ("spans", "uint32", 80))
+# (argument, dtype, shape of a row) of geec_decode_txn_window's outputs,
+# in the call's order
+_WINDOW_COLUMNS = (("decoded", "bool", ()), ("valid", "bool", ()),
+                   ("txhash", "uint8", (32,)), ("sighash", "uint8", (32,)),
+                   ("sig", "uint8", (65,)), ("nonce", "uint64", ()),
+                   ("gas_price", "uint64", ()), ("spans", "uint32", (10, 2)))
 
 
 def has_decode_window() -> bool:
@@ -143,16 +143,53 @@ def decode_txn_window(data: bytes, offsets, **columns) -> None:
             or (offsets[1:] < offsets[:-1]).any()):
         raise ValueError("offsets do not span the window's bytes")
     ptrs = []
-    for name, dtype, width in _WINDOW_COLUMNS:
+    for name, dtype, row in _WINDOW_COLUMNS:
         col = columns[name]
-        if (col.dtype != dtype or col.nbytes != n * width
+        if (col.dtype != dtype or col.shape != (n, *row)
                 or not col.flags.c_contiguous
                 or not col.flags.writeable):
-            raise ValueError(f"column {name!r} is not n x {width} bytes "
-                             f"of {dtype}")
+            raise ValueError(f"column {name!r} is not {(n, *row)} of "
+                             f"{dtype}")
         ptrs.append(col.ctypes.data)
     if lib.geec_decode_txn_window(data, offsets.ctypes.data, n, *ptrs):
         raise MemoryError("native window decoder found no scratch memory")
+
+
+def window_columns(n: int) -> dict:
+    """The zeroed arrays :data:`_WINDOW_COLUMNS` names, for ``n`` rows:
+    what :func:`decode_txn_window` fills and ``core.txcolumns.TxColumns``
+    holds."""
+    import numpy as np
+
+    return {name: np.zeros((n, *row), dtype)
+            for name, dtype, row in _WINDOW_COLUMNS}
+
+
+def pack_txn_frames(frames) -> tuple:
+    """``(data, offsets, columns)`` of a window's frames: the frames back
+    to back (one join; an empty frame an empty span, a dead row), where
+    each begins and ends (one cumsum) and the zeroed columns.  What the
+    library call takes, made from the frames themselves, so the spans
+    are what it trusts them to be."""
+    import numpy as np
+
+    n = len(frames)
+    data = b"".join(frames)
+    offsets = np.zeros((n + 1,), np.uint64)
+    np.cumsum(np.fromiter(map(len, frames), np.uint64, n), out=offsets[1:])
+    if int(offsets[-1]) != len(data):
+        raise ValueError("a frame's len() is not its size in bytes")
+    return data, offsets, window_columns(n)
+
+
+def decode_txn_frames(frames) -> tuple:
+    """:func:`pack_txn_frames`, with the columns filled by ONE
+    :func:`decode_txn_window` call: the one way a window of frames
+    reaches the library (``ingress.columnar.decode_window`` behind its
+    byte gate, ``core.state`` for a block body's wire bytes)."""
+    data, offsets, columns = pack_txn_frames(frames)
+    decode_txn_window(data, offsets, **columns)
+    return data, offsets, columns
 
 
 def has_trie() -> bool:
@@ -318,8 +355,6 @@ def _check_decode_window() -> None:
     malformed, against answers the golden Keccak gives (this module
     sits below the decoder's Python oracle, which the tier-1
     differential test holds it to case by case)."""
-    import numpy as np
-
     from eges_tpu.crypto import keccak as pk
 
     # nonce 9, gas price 2**70, gas 21000, to 00..13, value 7, "chk"
@@ -340,25 +375,21 @@ def _check_decode_window() -> None:
               b"\xff" * 9 + good, good[:3] + b"\xbf" + good[4:]]
     pre = [b"\xeb" + body + b"\x4d\x80\x80", b"\xe8" + body]
     n = len(frames)
-    offsets = np.zeros((n + 1,), np.uint64)
-    np.cumsum([len(f) for f in frames], dtype=np.uint64, out=offsets[1:])
-    cols = {name: np.zeros((n, width // np.dtype(dtype).itemsize), dtype)
-            for name, dtype, width in _WINDOW_COLUMNS}
-    decode_txn_window(b"".join(frames), offsets, **cols)
-    assert cols["decoded"].ravel().tolist() == [True] * 4 + [False] * 6
-    assert cols["valid"].ravel().tolist() == [True] * 2 + [False] * 8
+    _, _, cols = decode_txn_frames(frames)
+    assert cols["decoded"].tolist() == [True] * 4 + [False] * 6
+    assert cols["valid"].tolist() == [True] * 2 + [False] * 8
     for i in range(n):
         ok = i < 4
         assert bytes(cols["txhash"][i]) == (
             pk.keccak256(frames[i]) if ok else bytes(32)), "txhash"
-        assert cols["nonce"][i, 0] == (9 if ok else 0)
-        assert cols["gas_price"][i, 0] == (2**64 - 1 if ok else 0)
+        assert cols["nonce"][i] == (9 if ok else 0)
+        assert cols["gas_price"][i] == (2**64 - 1 if ok else 0)
         assert bytes(cols["sig"][i]) == (
             r + s + b"\x01" if i < 2 else bytes(65)), "sig"
         assert bytes(cols["sighash"][i]) == (
             pk.keccak256(pre[i]) if i < 2 else bytes(32)), "sighash"
     # the payload spans of row 0: the ten fields back out of the frame
-    fields = [good[a:b] for a, b in cols["spans"][0].reshape(10, 2).tolist()]
+    fields = [good[a:b] for a, b in cols["spans"][0].tolist()]
     assert fields == [b"\x09", bytes.fromhex("400000000000000000"),
                       b"\x52\x08", bytes(range(20)), b"\x07", b"chk", b"",
                       b"\xbe", r, s], "spans"

@@ -16,16 +16,15 @@ rule ``perimeter-breach``) requires that
   the wrappers here.
 
 This package is deliberately import-weightless: no eager imports, the
-wrappers take the owning object as an argument (the columnar decoder
-in :mod:`eges_tpu.ingress.columnar` loads lazily through its own
-wrappers below).  ROADMAP item 5's wire-speed ingest rebuild now lives
-here: ``columnar.decode_window`` turns a whole gossip window of txn
-frames into numpy-backed columns (sighash32 / sig65 / txhash /
-gas_price / nonce) with O(1) Python-level transitions per window,
-``TxPool.add_remotes_window`` admits it with set-op dedup and
-per-window bookkeeping, and ``VerifierScheduler.submit_window`` takes
-the rows in one lock hold — the legacy per-tx path stays for
-singletons and as the differential-test oracle.
+wrappers take the owning object as an argument (the window decoder in
+:mod:`eges_tpu.ingress.columnar` loads lazily through its own wrapper
+below).  ``columnar.decode_window`` turns a whole gossip window of txn
+frames into the pool's columns (``core.txcolumns.TxColumns``: sighash32
+/ sig65 / txhash / gas_price / nonce) with O(1) Python-level
+transitions per window, ``TxPool.add_remotes_window`` admits it with
+set-op dedup and per-window bookkeeping, and
+``VerifierScheduler.submit_window`` takes the rows in one lock hold.
+``TxPool.add_remotes`` is the same window over ``Transaction`` objects.
 """
 
 from __future__ import annotations
@@ -46,9 +45,9 @@ INGRESS_ENTRIES = frozenset({
     "_fire_gossip", "_fire_direct",
     # core/txpool.py — the admission seam (validated, capped batches)
     "add_remotes", "add_locals", "add_remotes_window",
-    # ingress/columnar.py — the wire-speed columnar decoders (frames
+    # ingress/columnar.py — the wire-speed window decoder (frames
     # are transport-length-capped; oversized rows die pre-decode)
-    "decode_window", "columns_from_txns",
+    "decode_window",
 })
 
 
@@ -104,11 +103,11 @@ def admit_locals(pool, txns) -> None:
     pool.add_locals(txns)
 
 
-# -- wire-speed columnar ingest (ROADMAP item 5) -------------------------
+# -- wire-speed columnar ingest ------------------------------------------
 
 def decode_txn_window(frames):
     """Decode a whole window of raw txn frames into columnar arrays
-    (``ingress.columnar.TxColumns``) in one native call that holds no
+    (``core.txcolumns.TxColumns``) in one native call that holds no
     GIL: one canonical scan + both keccaks per frame, sighash preimages
     sliced straight out of the frame bytes, ``Transaction``
     construction deferred to admission time."""
@@ -117,18 +116,9 @@ def decode_txn_window(frames):
     return decode_window(frames)
 
 
-def columns_of(txns):
-    """Columns for already-decoded ``Transaction`` objects — the gossip
-    relay path, where the codec decoded the bundle but admission should
-    still run window-granular."""
-    from eges_tpu.ingress.columnar import columns_from_txns
-
-    return columns_from_txns(txns)
-
-
 def admit_remotes_window(pool, cols) -> None:
     """Admit one decoded columnar window into a txpool: one lock hold,
-    set-op dedup, one batched verify call per ``max_batch`` rows —
-    byte-identical admission outcomes to :func:`admit_remotes` over the
-    same rows."""
+    set-op dedup, one batched verify call per ``max_batch`` rows
+    (what :func:`admit_remotes` does with the columns of its
+    transactions)."""
     pool.add_remotes_window(cols)

@@ -1,24 +1,15 @@
-"""Columnar txn ingest — the wire-speed front half (ROADMAP item 5).
+"""Columnar txn ingest: a gossip window's frames to the pool's columns.
 
-The back half of the pipeline is batched to the hilt (one device call
-per verify window); before this module, every row still paid per-tx
-Python on the way in: a per-datagram RLP decode into a ``Transaction``
-object, a per-tx ``signature_parts()`` re-encode, a per-tx cache probe
-and ``Future`` in the scheduler, per-tx dict bookkeeping in the pool.
-Here a whole gossip window of txn frames is decoded ONCE into columnar
-numpy arrays — ``sighash32`` / ``sig65`` / ``txhash`` / ``gas_price`` /
-``nonce`` columns plus validity masks — shaped exactly like the verify
-path's staging buffers, so the window lands in the device staging pool
-(``verifier.recover_addresses`` / ``scheduler.submit_window``) without
-any per-row conversion.  ``Transaction`` object construction is
-deferred to admission time (:meth:`TxColumns.txns`, one pass over the
-columns for a flushed slice's admitted rows): rejected rows —
-the flood case — never materialize an object at all, keeping the
-cheap-reject path cheap at wire rate (arXiv 1808.02252's DoS contract;
-arXiv 2112.02229's never-touch-a-scalar-path discipline).
+A whole window of raw txn frames is decoded ONCE into
+``core.txcolumns.TxColumns`` (the format lives there, below the pool;
+its names are re-exported here) instead of a per-datagram RLP decode
+into a ``Transaction`` and a per-tx ``signature_parts()`` re-encode.
+This module is what is ingress about that: the per-frame byte gate, the
+one native call behind it (:func:`decode_window`), and the same decoder
+in Python, a frame at a time, that the native one is held to.
 
 Byte-identity contract: for every frame the per-row results here equal
-the legacy scalar path exactly —
+what ``Transaction.decode(frame)`` gives —
 
 * ``txhash`` is ``keccak256(frame)``.  ``core/rlp.py`` rejects every
   non-canonical encoding, so a frame that decodes at all re-encodes to
@@ -31,9 +22,8 @@ the legacy scalar path exactly —
   ``Transaction.signature_parts()`` (mask-don't-raise), and the
   ``decoded`` mask the same width guards as ``Transaction.from_rlp``.
 
-The tier-1 differential test (tests/test_columnar_ingest.py) holds the
-two paths byte-identical end to end: admissions, stats, ledger
-billing, journal dumps.
+``tests/test_columnar_ingest.py`` holds the decoders to each other and
+to ``Transaction.decode`` case by case.
 """
 
 from __future__ import annotations
@@ -41,6 +31,8 @@ from __future__ import annotations
 import numpy as np
 
 from eges_tpu.core import rlp
+from eges_tpu.core.txcolumns import (U64_MAX, WINDOW_MAX_ROWS,  # noqa: F401
+                                     TxColumns, columns_from_txns)
 from eges_tpu.core.types import Transaction
 from eges_tpu.crypto import native
 from eges_tpu.crypto.keccak import keccak256
@@ -52,108 +44,7 @@ from eges_tpu.utils import metrics, tracing
 # message; this is the per-row second fence for direct window callers).
 FRAME_MAX_BYTES = 128 * 1024
 
-# Hard row cap per window — the largest window the scheduler's staging
-# pool is sized for; decode callers chunk above it.
-WINDOW_MAX_ROWS = 16384
-
 _SECP_MAX = 1 << 256
-_U64_MAX = (1 << 64) - 1  # where the nonce / gas_price columns clip
-
-
-class TxColumns:
-    """One decoded gossip window in columnar form.
-
-    Arrays are row-aligned: row ``i`` of every column describes frame
-    (or txn) ``i`` of the input.  ``decoded[i]`` is False when the
-    frame failed the size gate or canonical decode (no identity — the
-    row is untouchable); ``valid[i]`` is False when the row decoded
-    but its v/r/s cannot form a wire signature (the cheap-reject rows
-    the pool bills without ever building a ``Transaction``).
-    """
-
-    __slots__ = ("n", "sighash", "sig", "txhash", "gas_price", "nonce",
-                 "decoded", "valid", "hashes", "_data", "_offsets",
-                 "_spans", "_txns")
-
-    def __init__(self, n: int):
-        self.n = n
-        self.sighash = np.zeros((n, 32), np.uint8)
-        self.sig = np.zeros((n, 65), np.uint8)
-        self.txhash = np.zeros((n, 32), np.uint8)
-        self.gas_price = np.zeros((n,), np.uint64)
-        self.nonce = np.zeros((n,), np.uint64)
-        self.decoded = np.zeros((n,), bool)
-        self.valid = np.zeros((n,), bool)
-        # python-object mirror of ``txhash`` for set-based dedup (the
-        # pool's ``_known`` difference is one C-level set op over these)
-        self.hashes: list[bytes | None] = [None] * n
-        # decode path only (_pack): the window's frames packed back to
-        # back (frame i at _offsets[i].._offsets[i+1], a dead frame an
-        # empty span) and each decoded row's ten payload spans (start,
-        # end), relative to its frame — all txn() needs of the wire
-        self._data = self._offsets = self._spans = None
-        self._txns: list = [None] * n   # materialized / original txns
-
-    def txn(self, i: int) -> Transaction:
-        """Materialize row ``i``'s ``Transaction``: the one-row case
-        of :meth:`txns`."""
-        return self.txns((i,))[0]
-
-    def txns(self, rows) -> list[Transaction]:
-        """Materialize ``rows``' ``Transaction``s in ONE pass over the
-        columns — admission time only; rejected rows never pay this.
-        A row already materialized (or kept from ``columns_from_txns``,
-        which has no wire bytes at all) is returned as it stands."""
-        have = self._txns
-        need = [i for i in rows if have[i] is None]
-        if need:
-            # direct field construction instead of from_rlp: the scan
-            # already enforced every from_rlp guard (canonical uints,
-            # r/s/v widths, `to` length), so int.from_bytes over the
-            # raw payloads builds the identical object without a
-            # second decode pass — and without the frozen dataclass's
-            # __init__ (eleven object.__setattr__ a row): the instance
-            # dict is set whole, the memoized hash seeded from the wire
-            # frame's keccak (canonical RLP: keccak256(frame) ==
-            # keccak256(t.encode())), so admission never re-encodes
-            idx = np.asarray(need, np.int64)
-            spans = (self._spans[idx].astype(np.int64)
-                     + self._offsets[idx].astype(np.int64)[:, None, None])
-            data, hashes = self._data, self.hashes
-            new, put, num = object.__new__, object.__setattr__, \
-                int.from_bytes
-            # the spans as 20 columns, so that a row is the loop's own
-            # names and allocates nothing
-            for (i, nonce, price, a0, b0, a1, b1, a2, b2, a3, b3, a4, b4,
-                 a5, b5, a6, b6, a7, b7, a8, b8, a9, b9) in zip(
-                    need, self.nonce[idx].tolist(),
-                    self.gas_price[idx].tolist(),
-                    *spans.reshape(len(need), 20).T.tolist()):
-                # the two uint64 columns clip: a wider field is re-read
-                if nonce == _U64_MAX:
-                    nonce = num(data[a0:b0], "big")
-                if price == _U64_MAX:
-                    price = num(data[a1:b1], "big")
-                t = new(Transaction)
-                put(t, "__dict__", {
-                    "nonce": nonce, "gas_price": price,
-                    "gas_limit": num(data[a2:b2], "big"),
-                    "to": data[a3:b3] or None,
-                    "value": num(data[a4:b4], "big"),
-                    "payload": data[a5:b5],
-                    "is_geec": num(data[a6:b6], "big") != 0,
-                    "v": num(data[a7:b7], "big"),
-                    "r": num(data[a8:b8], "big"),
-                    "s": num(data[a9:b9], "big"),
-                    "_SENDER_CACHE": {"hash": hashes[i]}})
-                have[i] = t  # bounded-by: self.n, the window's rows: a slot of a list sized once (an index past it raises)
-        return [have[i] for i in rows]
-
-    def gather(self, rows: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-        """(sighash32, sig65) sub-arrays for ``rows`` — contiguous
-        uint8 blocks that drop straight into the verifier's staging
-        buffers (one fancy-index copy, zero per-row conversion)."""
-        return self.sighash[rows], self.sig[rows]
 
 
 def _scan_txn_frame(frame: bytes) -> tuple[list, list]:
@@ -253,22 +144,11 @@ def _list_header(n: int) -> bytes:
     return bytes([0xC0 + 55 + len(lb)]) + lb
 
 
-def _pack(frames: list) -> TxColumns:
-    """A window's columns, empty but for its bytes: the per-frame byte
-    gate BEFORE any copy (an empty or oversized frame contributes an
-    empty span, so it dies without a parse or a hash), then one join
-    and the offsets from one cumsum."""
-    n = len(frames)
-    cols = TxColumns(n)
-    kept = [f if 0 < len(f) <= FRAME_MAX_BYTES else b"" for f in frames]
-    cols._data = b"".join(kept)  # bounded-by: WINDOW_MAX_ROWS * FRAME_MAX_BYTES (gate above, row cap in decode_window)
-    cols._offsets = np.zeros((n + 1,), np.uint64)
-    np.cumsum([len(f) for f in kept], dtype=np.uint64,
-              out=cols._offsets[1:])
-    cols._spans = np.zeros((n, 10, 2), np.uint32)
-    if int(cols._offsets[-1]) != len(cols._data):
-        raise ValueError("a frame's len() is not its size in bytes")
-    return cols
+def _gated(frames: list) -> list:
+    """The per-frame byte gate, BEFORE any copy: an empty or oversized
+    frame contributes an empty span, so it dies without a parse or a
+    hash."""
+    return [f if 0 < len(f) <= FRAME_MAX_BYTES else b"" for f in frames]
 
 
 def decode_window(frames) -> TxColumns:  # ingress-entry:bounded
@@ -304,11 +184,7 @@ def decode_window(frames) -> TxColumns:  # ingress-entry:bounded
 def _decode_native(frames: list) -> TxColumns:
     """:func:`decode_window`'s one library call over a window it has
     capped."""
-    cols = _pack(frames)
-    native.decode_txn_window(
-        cols._data, cols._offsets, decoded=cols.decoded, valid=cols.valid,
-        txhash=cols.txhash, sighash=cols.sighash, sig=cols.sig,
-        nonce=cols.nonce, gas_price=cols.gas_price, spans=cols._spans)
+    cols = TxColumns(len(frames), *native.decode_txn_frames(_gated(frames)))
     th = cols.txhash.tobytes()
     cols.hashes = [th[32 * i:32 * i + 32] if ok else None
                    for i, ok in enumerate(cols.decoded.tolist())]
@@ -319,9 +195,9 @@ def _decode_frames(frames: list) -> TxColumns:
     """The same window in Python, a frame at a time: the oracle the
     native decoder is held to (tests/test_columnar_ingest.py), and the
     fallback for a checkout whose library lacks it."""
-    cols = _pack(frames)
+    cols = TxColumns(len(frames), *native.pack_txn_frames(_gated(frames)))
     data, offsets = cols._data, cols._offsets.tolist()
-    for i in range(cols.n):
+    for i in range(cols.n):  # bounded-by: WINDOW_MAX_ROWS (row cap in decode_window)
         frame = data[offsets[i]:offsets[i + 1]]
         if not frame:
             continue  # oversized/empty: dead before any parse
@@ -335,8 +211,8 @@ def _decode_frames(frames: list) -> TxColumns:
         # a payload ends where its encoding does
         cols._spans[i] = [(end - len(it), end)
                           for it, (_, end) in zip(items, spans)]
-        cols.nonce[i] = min(int.from_bytes(items[0], "big"), _U64_MAX)
-        cols.gas_price[i] = min(int.from_bytes(items[1], "big"), _U64_MAX)
+        cols.nonce[i] = min(int.from_bytes(items[0], "big"), U64_MAX)
+        cols.gas_price[i] = min(int.from_bytes(items[1], "big"), U64_MAX)
         # signature_parts()'s exact v/r/s rules, span-sliced
         v = int.from_bytes(items[7], "big")
         protected = v not in (27, 28) and v != 0
@@ -388,30 +264,3 @@ def _dispatch_decode():
 
 
 _DECODE = _dispatch_decode()
-
-
-def columns_from_txns(txns) -> TxColumns:  # ingress-entry:bounded
-    """Columns for already-decoded ``Transaction`` objects (the gossip
-    path hands the pool decoded txns): extraction only — the original
-    objects are kept and returned by :meth:`TxColumns.txn`, so
-    admission admits the exact objects the legacy path would."""
-    txns = list(txns)
-    if len(txns) > WINDOW_MAX_ROWS:
-        raise ValueError("window exceeds %d rows — chunk the caller"
-                         % WINDOW_MAX_ROWS)
-    cols = TxColumns(len(txns))
-    for i, t in enumerate(txns):
-        h = t.hash
-        cols.decoded[i] = True
-        cols.hashes[i] = h
-        cols.txhash[i] = np.frombuffer(h, np.uint8)
-        cols._txns[i] = t
-        cols.nonce[i] = min(t.nonce, _U64_MAX)
-        cols.gas_price[i] = min(t.gas_price, _U64_MAX)
-        parts = t.signature_parts()
-        if parts is not None:
-            sig, sighash = parts
-            cols.sig[i] = np.frombuffer(sig, np.uint8)
-            cols.sighash[i] = np.frombuffer(sighash, np.uint8)
-            cols.valid[i] = True
-    return cols

@@ -15,7 +15,7 @@ from eges_tpu.consensus.config import BootstrapNode, ChainGeecConfig, NodeConfig
 from eges_tpu.consensus.node import GeecNode
 from eges_tpu.core.chain import BlockChain, make_genesis
 from eges_tpu.crypto import secp256k1 as secp
-from eges_tpu.ingress import columns_of, direct_sink, gossip_sink
+from eges_tpu.ingress import direct_sink, gossip_sink
 from eges_tpu.sim.simnet import SimClock, SimNet, SkewedClock
 
 
@@ -41,7 +41,7 @@ class SimCluster:
                  alloc: dict | None = None, txpool: bool = False,
                  fast_sync: set | None = None, defer: set | None = None,
                  mesh_devices: int | None = None,
-                 columnar: bool = True, checkpoint_every: int = 0):
+                 checkpoint_every: int = 0):
         self.clock = SimClock()
         self.net = SimNet(self.clock, seed=seed, drop_rate=drop_rate)
         self.nodes: list[SimNode] = []
@@ -86,7 +86,6 @@ class SimCluster:
         self._genesis = genesis
         self._mine = mine
         self._txpool = txpool
-        self._columnar = columnar
         self._alloc = alloc
         # crashed nodes' journal history, preserved across the rebuild
         # so the observatory sees one continuous per-node stream
@@ -138,13 +137,6 @@ class SimCluster:
             if txpool:
                 from eges_tpu.core.txpool import TxPool
                 node.txpool = TxPool(node_clock, verifier=verifier)
-                if columnar:
-                    # the wire-speed ingest hook: relayed txn bundles go
-                    # through the columnar admission seam.  Injected here
-                    # (sim is L4) so the node (L2) never imports ingress
-                    # (L3).  columnar=False keeps the per-tx legacy path
-                    # — the differential test's oracle.
-                    node.columnarize = columns_of
             if i not in self._deferred:
                 # deferred nodes (late joiners) stay OFF the network —
                 # no transport join, no gossip — until start_deferred()
@@ -214,8 +206,6 @@ class SimCluster:
         if self._txpool:
             from eges_tpu.core.txpool import TxPool
             node.txpool = TxPool(sn.clock, verifier=self.verifier)
-            if self._columnar:
-                node.columnarize = columns_of
         node.transport = self.net.join(sn.name, ncfg.consensus_ip,
                                        ncfg.consensus_port,
                                        gossip_sink(node),

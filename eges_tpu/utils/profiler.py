@@ -18,8 +18,8 @@ sample with
 * the **pipeline phase**, a per-thread tag maintained by the
   ``phase()`` context manager and — the bridge to the span tracer —
   set automatically for the duration of any ``Tracer.span`` whose name
-  appears in :data:`SPAN_PHASES` (``txpool.ingest``/``txpool.admit``
-  -> ``pool_admit``, ``sched.stage``/``sched.collect`` ->
+  appears in :data:`SPAN_PHASES` (``txpool.ingest``/
+  ``txpool.admit_window`` -> ``pool_admit``, ``sched.stage``/``sched.collect`` ->
   ``verify_stage``/``verify_collect``).  The phase vocabulary is the
   anatomy plane's ``PHASE_ORDER`` plus the verify-window interior
   (``verify_stage``/``verify_collect``) so profile
@@ -93,7 +93,6 @@ PROFILE_PHASES = frozenset({
 # collect ``sched.collect``.
 SPAN_PHASES = {
     "txpool.ingest": "pool_admit",
-    "txpool.admit": "pool_admit",
     "txpool.admit_window": "pool_admit",
     "sched.stage": "verify_stage",
     "sched.collect": "verify_collect",

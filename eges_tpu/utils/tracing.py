@@ -72,11 +72,10 @@ _HEADER_LEN = len(MAGIC) + 16 + 8
 _UNSET = object()
 
 # The served path's spans: name -> (label attributes, what the span
-# bounds).  One span per window, call or message, never per row
-# (``txpool.admit`` is the scalar path's exception, kept for the
-# per-transaction trace).  A label attribute's value becomes a label of
-# the span's three histograms (``span.seconds``, ``span.self_seconds``:
-# wall time; ``span.self_cpu_seconds``: what the thread ran), as in
+# bounds).  One span per window, call or message, never per row.  A
+# label attribute's value becomes a label of the span's three
+# histograms (``span.seconds``, ``span.self_seconds``: wall time;
+# ``span.self_cpu_seconds``: what the thread ran), as in
 # ``span.seconds;name=sched.await,class=consensus,size=burst``; every
 # label has a closed vocabulary.  Other names stay allowed; they carry
 # no label.  The ring entry has the span's whole wall and CPU time
@@ -89,7 +88,6 @@ SPANS = {
     "txpool.flush": ((), "hand the queue to the verifier, wait, admit"),
     "txpool.admit_window": ((), "nonce/balance checks and insertion of "
                                 "one flushed slice"),
-    "txpool.admit": ((), "the same for one scalar transaction"),
     "txpool.evict": ((), "commit eviction, with one tx.commit record "
                          "an ingest trace among the block's "
                          "transactions (attrs tx, txns, txs, block; "

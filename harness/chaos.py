@@ -835,8 +835,8 @@ def _scn_oversized_payload_flood(seed: int, fast: bool) -> dict:
         cluster.net.deliver_gossip("flooder", junk)
         # a burst of unique far-future queries: each one is a deferral
         base = 100_000 + wave[0] * 16
-        # a 16-row invalid-signature txn window: rides the columnar
-        # ingest (window dedup + batched verify) straight into the
+        # a 16-row invalid-signature txn window: rides the pool's ingest
+        # (window dedup + batched verify) straight into the
         # whole-window reject, billed per row to this flooder
         bad = tuple(Transaction(nonce=base + i, gas_price=1,
                                 gas_limit=21000, to=bytes(20), value=0,
@@ -879,7 +879,7 @@ def _scn_oversized_payload_flood(seed: int, fast: bool) -> dict:
                       "defer_queues_capped": all(
                           len(sn.node._deferred) <= sn.node.DEFER_MAX
                           for sn in cluster.nodes),
-                      # the columnar ingest queue never holds more than
+                      # the pool's ingest queue never holds more than
                       # one un-flushed window's worth of rows: the
                       # max_batch threshold flushes anything beyond it
                       "pool_ingest_queues_bounded": all(
@@ -901,7 +901,7 @@ def _scn_oversized_payload_flood(seed: int, fast: bool) -> dict:
     checks = {
         "flooder_billed_drops": flooder.get("drops", 0.0) > 0,
         "flooder_billed_deferred": flooder.get("deferred", 0.0) > 0,
-        # the invalid-signature windows reject on the columnar path and
+        # the invalid-signature windows reject in the pool's flush and
         # bill back to their deliverer
         "flooder_billed_rejects": flooder.get("rejects", 0.0) > 0,
         "flooder_top_offender": all(

@@ -1,17 +1,20 @@
 """Wire-speed columnar ingest tests for tier-1.
 
 Covers: the vectorized window decoder (``eges_tpu/ingress/columnar.py``)
-against the scalar ``Transaction.decode`` oracle — per-field columns,
-malformed/non-canonical frame rejection, the native window decoder
-against its Python oracle case by case, the fallback without it — the
-columnar pool admission path
-(``TxPool.add_remotes_window``) against the legacy scalar path over the
-same stream (identical stats, admission order and ledger billing), the
-scheduler's window submit, the invalid-signature flood reject path
-(billed to the flooder WITHOUT falling back to per-entry scalar
-recovery), and the headline differential: two same-seed 4-node sims —
-one columnar, one legacy — produce byte-identical canonical journal
-dumps.
+against ``Transaction.decode`` — per-field columns, malformed and
+non-canonical frame rejection, the native window decoder against its
+Python oracle case by case, the fallback without it, the one packing
+helper under both the window decoder and a block body's
+``core.state._signature_rows`` — and the pool's one way in: a window of
+frames (``TxPool.add_remotes_window``) against the same transactions as
+objects (``TxPool.add_remotes``, the window of their columns) over the
+same stream (identical stats, admission order and ledger billing), in
+chunks of 1, 13 and 45; ``add_remotes`` over more than a window's rows
+and over a repeated transaction; the scheduler's window submit; the
+invalid-signature flood (billed to the flooder, no recovery a row);
+and two same-seed 4-node sims, one fed 12-transaction gossip bundles
+and one the same stream a transaction a message: equal pool stats,
+billing and ``commit_anatomy`` pool stages on every node.
 """
 
 import dataclasses
@@ -90,6 +93,9 @@ def test_decode_window_matches_scalar_decode_column_for_column():
 
     ref = columnar.columns_from_txns(
         [Transaction.decode(f) for f in frames[:40]])
+    # an object window's signature columns wait for the flush
+    assert not ref.valid.any() and not ref.sig.any()
+    assert ref.signed() is ref and ref.signed().valid.any()
     got = decode_txn_window(frames)
 
     assert got.n == len(frames)
@@ -325,7 +331,7 @@ def test_native_window_decoder_matches_the_oracle(kind):
                 keccak256(frame)
             # one field is lossy: any non-zero `is_geec` reads True and
             # re-encodes as 1, so only such a frame is not its own
-            # re-encoding (the scalar path then hashes another string)
+            # re-encoding (``Transaction.decode`` then hashes another string)
             assert (ref.hash == t.hash) == (ref.encode() == frame)
             assert ref.encode() == frame or rlp.decode(frame)[6] != b"\x01"
             parts = ref.signature_parts()
@@ -345,7 +351,7 @@ def test_native_window_decoder_matches_the_oracle(kind):
             items = rlp.decode(frame)
         except RLPError:
             continue
-        # a list where a field should be is the scalar path's blind
+        # a list where a field should be is the scalar decoder's blind
         # spot (an empty one reads as the integer 0, any other trips a
         # TypeError); the window decoders refuse both
         if isinstance(items, list) and any(isinstance(x, list)
@@ -394,17 +400,13 @@ def test_native_wrapper_refuses_columns_that_do_not_fit():
     frames = [_tx().encode()] * 3
 
     def call(**swap):
-        cols = columnar._pack(frames)
-        kw = dict(decoded=cols.decoded, valid=cols.valid,
-                  txhash=cols.txhash, sighash=cols.sighash, sig=cols.sig,
-                  nonce=cols.nonce, gas_price=cols.gas_price,
-                  spans=cols._spans)
-        offsets = swap.pop("offsets", cols._offsets)
+        data, offsets, kw = native.pack_txn_frames(frames)
+        offsets = swap.pop("offsets", offsets)
         kw.update(swap)
-        native.decode_txn_window(cols._data, offsets, **kw)
-        return cols
+        native.decode_txn_window(data, offsets, **kw)
+        return kw
 
-    assert call().decoded.all()
+    assert call()["decoded"].all()
     bad = [dict(sig=np.zeros((3, 64), np.uint8)),
            dict(txhash=np.zeros((2, 32), np.uint8)),
            dict(nonce=np.zeros((3,), np.int32)),
@@ -451,9 +453,43 @@ def test_two_threads_decoding_at_once_each_get_their_own_window():
     assert wrong == []
 
 
-# -- pool admission: columnar vs legacy over the same stream --------------
+# -- the one packing helper under both of its callers ---------------------
 
-def _run_pool(frames: list[bytes], *, use_columnar: bool, chunk: int = 13):
+def test_block_body_and_window_decoder_fill_the_same_columns(monkeypatch):
+    """``core.state._signature_rows`` (a block body's wire bytes) and
+    ``decode_window`` (a gossip window) reach the library through ONE
+    helper, ``native.decode_txn_frames``: the same frames give the same
+    signatures, signing hashes and transaction hashes."""
+    from eges_tpu.core.state import _signature_rows
+    from eges_tpu.crypto import native
+
+    signed = [t for t in _mixed_stream(30) if t.signature_parts()]
+    frames = [t.encode() for t in signed]
+    body = [Transaction.decode(f) for f in frames]   # keeps the wire bytes
+    assert all("wire" in t._SENDER_CACHE and "hash" not in t._SENDER_CACHE
+               for t in body)
+
+    calls = []
+    real = native.decode_txn_frames
+
+    def counted(fs):
+        calls.append(len(fs))
+        return real(fs)
+
+    monkeypatch.setattr(native, "decode_txn_frames", counted)
+    cols = decode_txn_window(frames)
+    sigs, sighashes, filled = _signature_rows(body)
+    assert calls == [len(frames)] * 2
+    assert filled == len(frames) and cols.valid.all()
+    assert np.array_equal(sigs, cols.sig)
+    assert np.array_equal(sighashes, cols.sighash)
+    # the pass left each row's transaction hash in its memo
+    assert [t._SENDER_CACHE["hash"] for t in body] == cols.hashes
+
+
+# -- pool admission: frames by window, Transactions by add_remotes --------
+
+def _run_pool(frames: list[bytes], *, as_frames: bool, chunk: int):
     from eges_tpu.crypto.verify_host import NativeBatchVerifier
 
     led = LG.IngressLedger(lambda: 100.0)
@@ -462,7 +498,7 @@ def _run_pool(frames: list[bytes], *, use_columnar: bool, chunk: int = 13):
     with LG.bind(led, "peer:src"):
         for w in range(0, len(frames), chunk):
             part = frames[w:w + chunk]
-            if use_columnar:
+            if as_frames:
                 admit_remotes_window(pool, decode_txn_window(part))
             else:
                 admit_remotes(pool, [Transaction.decode(f) for f in part])
@@ -472,42 +508,104 @@ def _run_pool(frames: list[bytes], *, use_columnar: bool, chunk: int = 13):
     return dict(pool.stats), order, led.snapshot()
 
 
-def test_columnar_pool_admission_identical_to_legacy():
+@pytest.mark.parametrize("chunk", [1, 13, 45])
+def test_frames_by_window_and_transactions_by_add_remotes_admit_alike(chunk):
     stream = _mixed_stream(45)
     frames = [t.encode() for t in stream] + \
         [t.encode() for t in stream[:7]]  # re-delivered duplicates
 
-    sc, oc, lc = _run_pool(frames, use_columnar=True)
-    sl, ol, ll = _run_pool(frames, use_columnar=False)
+    sc, oc, lc = _run_pool(frames, as_frames=True, chunk=chunk)
+    sl, ol, ll = _run_pool(frames, as_frames=False, chunk=chunk)
     assert sc == sl
     assert oc == ol                    # same rows, same arrival order
     assert lc == ll                    # billing to the cent
     # non-vacuous: every outcome class fired
     assert sc["admitted"] and sc["rejected"] and sc["duplicate"] \
         and sc["replaced"]
+    # and the arrival's size changes nothing but the number of flushes
+    s13 = _run_pool(frames, as_frames=False, chunk=13)
+    assert {k: v for k, v in sl.items() if k != "batches"} == \
+        {k: v for k, v in s13[0].items() if k != "batches"}
+    assert ol == s13[1]
 
 
-def test_invalid_sig_flood_billed_without_scalar_fallback(monkeypatch):
+def test_add_remotes_over_a_window_s_rows_chunks_and_admits_them_all(
+        monkeypatch):
+    """More transactions than one window may hold: ``add_remotes`` makes
+    a window a chunk of ``WINDOW_MAX_ROWS`` and every row is admitted."""
+    from eges_tpu.core import txpool as txpool_mod
+    from eges_tpu.crypto.verify_host import NativeBatchVerifier
+
+    monkeypatch.setattr(txpool_mod, "WINDOW_MAX_ROWS", 8)
+    txns = [Transaction(nonce=i, gas_price=1, gas_limit=21000,
+                        to=bytes(20), value=i).signed(PRIV_A)
+            for i in range(21)]
+    windows = []
+    pool = TxPool(_WallClock(), verifier=NativeBatchVerifier(),
+                  max_batch=64)
+    real = pool.add_remotes_window
+
+    def counted(cols):
+        windows.append(cols.n)
+        real(cols)
+
+    pool.add_remotes_window = counted
+    pool.add_remotes(iter(txns))       # any iterable, as before
+    pool._on_window()
+    assert windows == [8, 8, 5]
+    assert pool.stats["admitted"] == 21 and pool.stats["batches"] == 1
+    assert [t.hash for _, t in pool._order] == [t.hash for t in txns]
+    # what the chunking is for: a window over the real cap is refused
+    with pytest.raises(ValueError):
+        columnar.columns_from_txns(
+            txns[:1] * (columnar.WINDOW_MAX_ROWS + 1))
+
+
+def test_a_repeated_transaction_in_one_list_counts_one_duplicate():
+    from eges_tpu.crypto.verify_host import NativeBatchVerifier
+
+    a, b = [Transaction(nonce=i, gas_price=1, gas_limit=21000,
+                        to=bytes(20), value=i).signed(PRIV_A)
+            for i in range(2)]
+    led = LG.IngressLedger(lambda: 100.0)
+    pool = TxPool(_WallClock(), verifier=NativeBatchVerifier())
+    with LG.bind(led, "peer:src"):
+        pool.add_remotes([a, b, a])
+        pool._on_window()
+    assert pool.stats == {"admitted": 2, "rejected": 0, "duplicate": 1,
+                          "batches": 1, "replaced": 0}
+    (origin,) = led.snapshot()["origins"]
+    assert (origin["admits"], origin["drops"]) == (2.0, 1.0)
+
+
+@pytest.mark.parametrize("as_frames", [True, False],
+                         ids=["window", "add_remotes"])
+def test_invalid_sig_flood_billed_without_scalar_fallback(monkeypatch,
+                                                          as_frames):
     """A whole-window invalid-signature flood rides the batched reject
-    path end to end: the per-entry scalar recovery helper must never
-    run (it is monkeypatched to a tripwire), and every reject bills the
-    flooder's ledger origin."""
+    path end to end, as frames and as ``Transaction``s alike: the
+    per-entry recovery helper must never run (it is monkeypatched to a
+    tripwire), and every reject bills the flooder's ledger origin."""
     from eges_tpu.crypto import verify_host
 
     def _tripwire(entries, verifier, priority="bulk"):
-        raise AssertionError("scalar recover_signers used on the "
-                             "columnar flood path")
+        raise AssertionError("recover_signers used on the pool's "
+                             "flood path")
 
     monkeypatch.setattr(verify_host, "recover_signers", _tripwire)
 
     n = 32
-    frames = [Transaction(nonce=i, gas_price=1, gas_limit=21000,
-                          to=bytes(20), value=0, v=27, r=0, s=1).encode()
-              for i in range(n)]
+    flood = [Transaction(nonce=i, gas_price=1, gas_limit=21000,
+                         to=bytes(20), value=0, v=27, r=0, s=1)
+             for i in range(n)]
     led = LG.IngressLedger(lambda: 100.0)
     pool = TxPool(_WallClock(), verifier=None, max_batch=16)
     with LG.bind(led, "peer:flooder"):
-        admit_remotes_window(pool, decode_txn_window(frames))
+        if as_frames:
+            admit_remotes_window(pool, decode_txn_window(
+                [t.encode() for t in flood]))
+        else:
+            admit_remotes(pool, flood)
         pool._on_window()
     assert pool.stats["rejected"] == n and pool.stats["admitted"] == 0
     snap = led.snapshot()
@@ -539,41 +637,44 @@ def test_scheduler_submit_window_recovers_against_host_oracle():
         assert rec[k] == K.keccak256(pub)[-20:]
 
 
-# -- the headline differential: columnar sim == legacy sim ----------------
+# -- a 4-node sim fed bundles against one fed singletons ------------------
 
-def _gossip_cluster(use_columnar: bool):
+def _gossip_cluster(bundle: int):
     """4-node txpool sim with an injected flooder peer bursting the
     mixed stream (valid + invalid sigs + a duplicate tail) as gossip
-    windows — the exact ingress surface the tentpole rewired."""
+    messages of ``bundle`` transactions each."""
     import eges_tpu.consensus.messages as M
     from eges_tpu.crypto import secp256k1 as secp
     from eges_tpu.sim.cluster import SimCluster
 
     # fund the flood senders so admitted txns become EXECUTABLE and
     # blocks include them — that's what emits the commit_anatomy
-    # stage="pool" events the differential compares
+    # stage="pool" events the comparison reads
     alloc = {secp.pubkey_to_address(secp.privkey_to_pubkey(p)): 10 ** 18
              for p in (PRIV_A, PRIV_B)}
     cluster = SimCluster(4, seed=0, txn_per_block=4, txpool=True,
-                         columnar=use_columnar, alloc=alloc)
+                         alloc=alloc)
     cluster.net.join("flooder", "10.0.0.99", 9999,
                      lambda d: None, lambda d: None)
+    # no jitter: what differs between the two sims is the size of the
+    # flooder's messages, not the order the wire leaves them in
+    cluster.net.jitter_s = 0.0
     stream = _mixed_stream(30)
     stream += stream[:5]
     fired = [False]
 
     def burst():
         fired[0] = True
-        for w in range(0, len(stream), 12):
+        for w in range(0, len(stream), bundle):
             cluster.net.deliver_gossip("flooder", M.pack_gossip(
-                M.GOSSIP_TXNS, M.TxnsMsg(txns=tuple(stream[w:w + 12]))))
+                M.GOSSIP_TXNS, M.TxnsMsg(txns=tuple(stream[w:w + bundle]))))
 
     cluster.clock.call_later(0.01, burst)
     return cluster, fired
 
 
-def _run_differential(use_columnar: bool):
-    cluster, fired = _gossip_cluster(use_columnar)
+def _run_differential(bundle: int):
+    cluster, fired = _gossip_cluster(bundle)
     cluster.start()
     cluster.run(600.0, stop_condition=lambda: fired[0]
                 and cluster.min_height() >= 6)
@@ -583,33 +684,33 @@ def _run_differential(use_columnar: bool):
     return cluster.journals(), stats, cluster.heights()
 
 
-def test_differential_columnar_sim_byte_identical_to_legacy_sim():
+def test_sim_fed_bundles_and_sim_fed_singletons_admit_alike():
+    """However the stream is cut into messages, every row is a row of a
+    window: the two sims' pools, ledgers and journals are the same."""
     from harness.chaos import canonical_dump
 
-    jc, sc, hc = _run_differential(True)
-    jl, sl, hl = _run_differential(False)
+    jb, sb, hb = _run_differential(12)
+    js, ss, hs = _run_differential(1)
 
-    assert hc == hl and min(hc) >= 6
-    assert sc == sl
-    # non-vacuous: the flood admitted AND rejected on some node
-    assert any(s["admitted"] for s in sc.values())
-    assert any(s["rejected"] for s in sc.values())
-    # the repo's own determinism criterion: canonical journal dumps
+    assert hb == hs and min(hb) >= 6
+    assert sb == ss                    # pool stats on every node
+    # non-vacuous: the flood admitted, rejected and replaced on every node
+    assert all(s["admitted"] and s["rejected"] and s["replaced"]
+               for s in sb.values())
+    # commit anatomy pool stages (ingest->admit legs on the virtual
+    # clock) are present and equal, node for node
+    for name in jb:
+        stages = [[e for e in j[name] if e.get("type") == "commit_anatomy"
+                   and e.get("stage") == "pool"] for j in (jb, js)]
+        assert stages[0] == stages[1]
+        assert stages[0] or name not in sb
+    # billing straight off the journal stream, node for node
+    led = [json.dumps({n: [{k: v for k, v in e.items() if k != "costs"}
+                           for e in evs if e.get("type") == "ingress_ledger"]
+                       for n, evs in j.items()}, sort_keys=True)
+           for j in (jb, js)]
+    assert led[0] == led[1] and "peer:flooder" in led[0]
+    # and the repo's own determinism criterion: canonical journal dumps
     # (volatile wall-clock fields stripped, everything protocol kept)
-    # must match BYTE FOR BYTE across the two ingest pipelines
-    assert canonical_dump(jc) == canonical_dump(jl)
-    # commit anatomy pool stages in particular (ingest->admit legs on
-    # the virtual clock) are present and equal
-    pool_stages = [
-        [e for e in evs if e.get("type") == "commit_anatomy"
-         and e.get("stage") == "pool"]
-        for evs in (sum(jc.values(), []), sum(jl.values(), []))]
-    assert pool_stages[0] and pool_stages[0] == pool_stages[1]
-    # billing parity straight off the journal stream
-    led = [
-        json.dumps([{k: v for k, v in e.items() if k != "costs"}
-                    for evs in j.values() for e in evs
-                    if e.get("type") == "ingress_ledger"],
-                   sort_keys=True)
-        for j in (jc, jl)]
-    assert led[0] == led[1]
+    # match BYTE FOR BYTE
+    assert canonical_dump(jb) == canonical_dump(js)

@@ -11,8 +11,8 @@ slice or a committed block at a time:
   trace (a window's rows share one), not one a transaction, and counts
   both in ``txpool.commit_rows`` / ``txpool.commit_records``.
 
-The admission outcomes themselves (stats, order, billing, journals)
-are ``tests/test_columnar_ingest.py``'s differential.
+The admission outcomes themselves (stats, order, billing) are held in
+``tests/test_columnar_ingest.py``.
 """
 
 import contextlib
@@ -263,7 +263,7 @@ def test_rows_of_two_windows_interleaved_in_a_block_still_group(pool):
     assert recs[1]["attrs"]["tx"] == txns[4].hash.hex()[:16]
 
 
-def test_scalar_arrivals_keep_a_record_each_with_its_own_tx(pool):
+def test_single_arrivals_keep_a_record_each_with_its_own_tx(pool):
     txns = [Transaction.decode(f) for f in _signed_frames(6)]
     rows0, recs0 = _counters()
     for t in txns:
@@ -278,11 +278,13 @@ def test_scalar_arrivals_keep_a_record_each_with_its_own_tx(pool):
     assert len({r["trace"] for r in recs}) == 6
     assert all(r["attrs"]["txns"] == 1 and r["attrs"]["block"] == 3
                and r["attrs"]["txs"] == r["attrs"]["tx"] for r in recs)
-    # each closes the trace its own ingest and admit spans are in
-    for r in recs:
+    # each closes the trace its own ingest span began; the slice's ONE
+    # admit span is in the trace of its first transaction
+    for k, r in enumerate(recs):
         names = {s["name"] for s in tracing.DEFAULT.finished(
             trace=r["trace"])}
-        assert {"txpool.ingest", "txpool.admit", "tx.commit"} <= names
+        assert {"txpool.ingest", "tx.commit"} <= names
+        assert ("txpool.admit_window" in names) == (k == 0)
     assert _counters() == (rows0 + 6, recs0 + 6)
 
 
@@ -304,9 +306,10 @@ def test_a_transaction_the_pool_never_saw_leaves_no_record(pool):
     assert len(pool) == 0
 
 
-def test_a_window_and_a_scalar_in_one_slice_admit_in_arrival_order(pool):
-    """The window flush admits a chunk's rows in one call and a scalar
-    interloper by itself: the hook must still see arrival order."""
+def test_a_window_and_a_single_in_one_slice_admit_in_arrival_order(pool):
+    """The flush admits a slice chunk by chunk, a window of frames and
+    a one-transaction ``add_remotes`` alike: the hook must see arrival
+    order."""
     seen = []
     pool.on_admitted = lambda t, sender: seen.append(t.nonce)
     pool.add_remotes_window(columnar.decode_window(_signed_frames(3)))
@@ -349,9 +352,9 @@ def _run_to_capacity(frames: list, *, window: bool, bound: bool):
                          ids=["billed", "nobody_to_bill"])
 def test_a_full_pool_refuses_new_slots_alike_a_row_and_a_chunk(bound):
     """Capacity limits NEW slots only, a price bump must still replace
-    in a full pool, and a bid too low is a duplicate: the chunk's
-    admission and the scalar one agree on outcomes, order, hook calls
-    and billing, with somebody to bill and with nobody."""
+    in a full pool, and a bid too low is a duplicate: frames by window
+    and ``Transaction``s by ``add_remotes`` agree on outcomes, order,
+    hook calls and billing, with somebody to bill and with nobody."""
     other = bytes(range(3, 35))
     txs = [_tx(nonce=k, gas_price=10, payload=b"a%d" % k).signed(PRIV)
            for k in range(5)]

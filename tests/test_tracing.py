@@ -287,7 +287,9 @@ def test_get_logger_relevel_and_single_handler(tmp_path):
 
 def test_one_trace_links_txn_across_cluster():
     """A txn submitted at node0 must produce txpool.ingest ->
-    txpool.admit -> tx.commit spans sharing ONE trace id, with commit
+    txpool.admit_window -> tx.commit spans sharing ONE trace id (it is
+    its slice's first transaction, so the slice's one admit span is in
+    its trace), with commit
     spans from at least two distinct nodes (the wire header carried the
     context across the simnet hop)."""
     from eges_tpu.core.state import INTRINSIC_GAS
@@ -328,7 +330,7 @@ def test_one_trace_links_txn_across_cluster():
     linked = [s for s in spans if s["trace"] == trace_id]
     names = {s["name"] for s in linked}
     assert "txpool.ingest" in names
-    assert "txpool.admit" in names
+    assert "txpool.admit_window" in names
     # commit spans carry the including block number
     assert all(isinstance(s["attrs"].get("block"), int) for s in commits)
 
