@@ -74,6 +74,9 @@ class GeecNode:
     :meth:`on_direct` for inbound traffic; the chain calls
     :meth:`_on_new_block` via its listener hook.  ``clock`` provides
     ``now()`` and ``call_later(delay_s, fn) -> cancelable handle``.
+    ``rand_source`` is the trusted random source (Geec's THW): it provides
+    ``my_rand(blk_num)`` and ``trust_rand(blk_num)``; the default is
+    :class:`working_block.CoinbaseRand`, the PRNG seeded by the coinbase.
     """
 
     # Ingress hardening caps: every attacker-fed byte path or container
@@ -90,7 +93,8 @@ class GeecNode:
 
     def __init__(self, chain: BlockChain, clock, transport,
                  node_cfg: NodeConfig, chain_cfg: ChainGeecConfig, *,
-                 mine: bool = True, verifier=None, log=None):
+                 mine: bool = True, verifier=None, log=None,
+                 rand_source=None):
         self.chain = chain
         self.clock = clock
         self.transport = transport
@@ -157,7 +161,7 @@ class GeecNode:
         # lock (see the txpool setter) — one lock domain, no ordering
         # hazards between pool window flushes and RPC submissions.
         self._lock = threading.RLock()
-        self.wb = WorkingBlock(self.coinbase)
+        self.wb = WorkingBlock(self.coinbase, rand_source)
         self.trust_rands: dict[int, int] = {0: 0}
         self.pending_blocks: dict[int, Block] = {}
         self.max_confirmed_block = 0
@@ -341,9 +345,16 @@ class GeecNode:
             # RPC entry points; re-entrancy keeps nested arming from
             # already-locked regions cheap
             with self._lock:
-                fn()
+                # on a clock whose timers are threads, a timer that
+                # fired while a handler held the lock waits here, and
+                # cancelling it (or arming its name anew) meanwhile
+                # cannot reach it: it must not run.  A stale election
+                # re-send would else arm itself again and, a height
+                # later, abort that height's proposal
+                if self._timers.get(name) is handle:
+                    fn()
 
-        self._timers[name] = self.clock.call_later(delay_s, fire)
+        handle = self._timers[name] = self.clock.call_later(delay_s, fire)
 
     def _cancel_timer(self, name: str) -> None:
         h = self._timers.pop(name, None)
@@ -704,7 +715,22 @@ class GeecNode:
 
     def _build_proposal(self, blk_num: int) -> Block:
         """Assemble header+body (ref: Prepare geec.go:228-264 + Seal's txn
-        attachment geec.go:319-339 + Finalize geec.go:268-279)."""
+        attachment geec.go:319-339 + Finalize geec.go:268-279).  A block
+        carries ``txn_per_block`` transactions IN ALL: the unsigned fakes
+        fill what the UDP transactions and the signed transactions the
+        preview kept leave (upstream pads beside a full pool too, which at
+        4000 a block puts the request over every acceptor's decode
+        budget)."""
+        with tracing.DEFAULT.span("consensus.build_proposal",
+                                  number=blk_num, txns=0, fakes=0) as sp:
+            block = self._assemble_proposal(blk_num)
+            sp.set_attr("txns", len(block.transactions))
+            sp.set_attr("fakes", len(block.fake_txns))
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+        metrics.counter("consensus.proposals_built").inc()
+        return block
+
+    def _assemble_proposal(self, blk_num: int) -> Block:
         parent = self.chain.head()
         regs = tuple(self.pending_regs[a] for a in
                      sorted(self.pending_regs)[: self.ccfg.max_reg_per_blk])
@@ -714,8 +740,6 @@ class GeecNode:
         # remember the drained txns so an aborted proposal re-queues them
         # instead of silently dropping UDP-ingested transactions
         self._proposal_geec_txns = list(geec_txns)
-        fakes = tuple(fake_txn(self.cfg.txn_size, seq=i)
-                      for i in range(self.cfg.txn_per_block - n))
         # signed txns execute: dry-run them on the head state for the
         # header's state/receipt/gas commitments (L3; worker.go:463-467)
         txs = (tuple(self.txpool.pending_txns(
@@ -737,13 +761,18 @@ class GeecNode:
             from eges_tpu.core.trie import EMPTY_ROOT
             root, receipt_hash, gas_used = (parent.header.root, EMPTY_ROOT, 0)
             bloom = bytes(256)
+        # the padding rides beside the rooted body: no header field and
+        # no journal line depends on it
+        fakes = tuple(fake_txn(self.cfg.txn_size, seq=i) for i in range(
+            max(0, self.cfg.txn_per_block - n - len(txs))))
         header = Header(
             parent_hash=parent.hash, number=blk_num,
             coinbase=self.coinbase, difficulty=difficulty,
             time=blk_time,
             root=root, receipt_hash=receipt_hash, gas_used=gas_used,
             bloom=bloom, regs=regs,
-            trust_rand=self.wb._rng.getrandbits(64),  # seed for NEXT block
+            # seed for NEXT block
+            trust_rand=self.wb.rand_source.trust_rand(blk_num),
         )
         return new_block(header, txs=txs, geec_txns=geec_txns,
                          fake_txns=fakes)
@@ -756,18 +785,23 @@ class GeecNode:
         self.journal.record("proposal_built", blk=blk_num, version=version,
                             txns=len(self._proposal.transactions),
                             geec_txns=len(self._proposal.geec_txns))
-        req = M.ValidateRequest(
-            block_num=blk_num, author=self.coinbase, block=self._proposal,
-            ip=self.cfg.consensus_ip, port=self.cfg.consensus_port,
-            retry=0, version=version,
-            empty_list=tuple(self.empty_block_list),
-        )
-        req = dataclasses.replace(req, sig=self._sign(req.signing_hash()))
-        self._ask_for_ack(req)
+        # the request signed, the whole block packed, the gossip
+        with tracing.DEFAULT.span("consensus.request", bytes=0) as sp:
+            req = M.ValidateRequest(
+                block_num=blk_num, author=self.coinbase,
+                block=self._proposal,
+                ip=self.cfg.consensus_ip, port=self.cfg.consensus_port,
+                retry=0, version=version,
+                empty_list=tuple(self.empty_block_list),
+            )
+            req = dataclasses.replace(req,
+                                      sig=self._sign(req.signing_hash()))
+            sp.set_attr("bytes", self._ask_for_ack(req))
 
-    def _ask_for_ack(self, req: M.ValidateRequest) -> None:
+    def _ask_for_ack(self, req: M.ValidateRequest) -> int:
         """(ref: AskForAck geec.go:373-419 — gossip the full block, retry
-        on validate_timeout with bumped retry counter)"""
+        on validate_timeout with bumped retry counter).  Returns the
+        bytes of the request as gossiped."""
         self._phase = VALIDATING
         self._validate_req = req
         self.wb.validate_replies.clear()
@@ -778,11 +812,11 @@ class GeecNode:
         self.journal.record("validate_request", blk=req.block_num,
                             version=req.version,
                             threshold=self.wb.validate_threshold)
-        self._validate_retry(req.block_num, req.version, 0)
+        return self._validate_retry(req.block_num, req.version, 0)
 
-    def _validate_retry(self, blk_num: int, version: int, retry: int) -> None:
+    def _validate_retry(self, blk_num: int, version: int, retry: int) -> int:
         if blk_num != self.wb.blk_num or self._phase != VALIDATING:
-            return
+            return 0
         if retry > 0:
             # whose ACK has not come, of the height's acceptor window
             seed = self.seed_for(blk_num)
@@ -796,10 +830,14 @@ class GeecNode:
                                    if seed is not None else ())
                          if m.addr not in replies])
         req = dataclasses.replace(self._validate_req, retry=retry)
-        self.transport.gossip(M.pack_gossip(M.GOSSIP_VALIDATE_REQ, req))
+        data = M.pack_gossip(M.GOSSIP_VALIDATE_REQ, req)
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+        metrics.counter("consensus.request_bytes").inc(len(data))
+        self.transport.gossip(data)
         self._set_timer("validate", self.ccfg.validate_timeout_ms / 1e3,
                         lambda: self._validate_retry(blk_num, version,
                                                      retry + 1))
+        return len(data)
 
     def _handle_validate_reply(self, reply: M.ValidateReply) -> None:
         """Tally one ACK (:meth:`QuorumTally.ack`, ref:
@@ -844,6 +882,11 @@ class GeecNode:
         if block is None or block.number != self.wb.blk_num:
             self._abort_proposal()
             return
+        with tracing.DEFAULT.span("consensus.seal", number=block.number,
+                                  supporters=len(supporters)):
+            self._seal(block, supporters)
+
+    def _seal(self, block: Block, supporters: tuple[bytes, ...]) -> None:
         parent = self.chain.head()
         parent_conf = parent.confirm.confidence if parent.confirm else 0
         confirm = ConfirmBlockMsg(

@@ -7,7 +7,9 @@ working height catches up, geec_wb.go:118) becomes *deferral* — the node
 queues messages addressed to future heights and replays them on
 :meth:`advance` (the ``Move``/``Cond.Broadcast`` analogue, geec_wb.go:84).
 
-``my_rand`` is drawn from a per-node deterministic PRNG seeded by the
+``my_rand`` comes from the node's trusted random source (Geec's THW),
+which a node is GIVEN as it is given a clock and a transport.  The default
+is :class:`CoinbaseRand`, a per-node deterministic PRNG seeded by the
 coinbase (geec_wb.go:66-68), so election tie-breaks are reproducible in
 the simulator.
 """
@@ -27,10 +29,30 @@ WB_CURRENT = 0x01
 WB_FUTURE = 0x02  # caller must defer (reference blocks instead)
 
 
-class WorkingBlock:
+class CoinbaseRand:
+    """The default trusted random source: ONE PRNG seeded by the coinbase,
+    a draw for each ``my_rand`` (one a :meth:`WorkingBlock.advance`) and a
+    draw for each header's ``trust_rand`` (one a build), in the order they
+    are asked for.  Any object with these two methods may stand in its
+    place (``GeecNode(rand_source=...)``)."""
+
     def __init__(self, coinbase: bytes):
-        self.coinbase = coinbase
         self._rng = random.Random(int.from_bytes(coinbase[-8:], "big"))
+
+    def my_rand(self, blk_num: int) -> int:
+        """The election tie-break of height ``blk_num``."""
+        return self._rng.getrandbits(64)
+
+    def trust_rand(self, blk_num: int) -> int:
+        """What block ``blk_num``'s header carries: the committee seed
+        of height ``blk_num + 1``."""
+        return self._rng.getrandbits(64)
+
+
+class WorkingBlock:
+    def __init__(self, coinbase: bytes, rand_source=None):
+        self.coinbase = coinbase
+        self.rand_source = rand_source or CoinbaseRand(coinbase)
         self.blk_num = 0
         self.advance(1)
 
@@ -48,7 +70,7 @@ class WorkingBlock:
         # — multiple entries so a spoofed garbage-sig vote can neither
         # squat the slot nor overwrite the genuine one
         self.supporter_votes: dict[bytes, list[tuple[bytes, bytes]]] = {}
-        self.my_rand = self._rng.getrandbits(64)
+        self.my_rand = self.rand_source.my_rand(blk_num)
         self.delegator: bytes = self.coinbase
         self.delegator_ip: str = ""
         self.delegator_port: int = 0

@@ -597,7 +597,13 @@ class BlockChain:
         from eges_tpu.core.state import (
             StateError, apply_txn, receipts_root, recover_senders,
         )
-        with self._lock:
+        from eges_tpu.utils import tracing
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+        with self._lock, tracing.DEFAULT.span(
+                "chain.execute_preview", txns=len(txs), kept=0) as sp:
+            # a preview is an execution of the block: the counter a
+            # _process increments counts it too
+            metrics.counter("chain.executions").inc()
             state = self.head_state().copy()
             try:
                 senders = recover_senders(txs, self.verifier)
@@ -619,6 +625,10 @@ class BlockChain:
                 gas = r.cumulative_gas_used
                 receipts.append(r)
                 kept.append(t)
+            sp.set_attr("kept", len(kept))
+            if len(kept) < len(txs):
+                metrics.counter("chain.preview_dropped").inc(
+                    len(txs) - len(kept))
             from eges_tpu.core.state import receipts_bloom
             return (kept, state.root(), receipts_root(receipts), gas,
                     receipts_bloom(receipts))

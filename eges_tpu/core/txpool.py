@@ -403,8 +403,15 @@ class TxPool:
                     del pending[sender]
                 continue
             if old is not None:
-                # price-bump replacement (ref: core/tx_pool.go:571+)
-                if t.gas_price * 100 < old.gas_price * bump:
+                # price-bump replacement (ref: core/tx_pool.go:571+).
+                # The SAME transaction delivered again (a copy that
+                # gossip brings after the dedup history's coarse clear)
+                # is a duplicate whatever its price: as a replacement
+                # it would tombstone the hash its own new entry bears,
+                # and the next compaction of ``_order`` would take the
+                # sender out of a proposer's sight
+                if old.hash == h or \
+                        t.gas_price * 100 < old.gas_price * bump:
                     stats["duplicate"] += 1
                     if billed:
                         charge(h, amb, drops=1, sender=sender)
@@ -452,7 +459,9 @@ class TxPool:
         reference pool (pending vs queued, core/tx_pool.go): a sender
         with a nonce gap or empty purse no longer starves other senders
         out of the per-block limit."""
-        with self._lock:
+        with self._lock, tracing.DEFAULT.span(
+                "txpool.pending", limit=limit or 0, picked=0,
+                senders=0) as sp:
             seen: set[bytes] = set()
             out: list[Transaction] = []
             for s, _ in list(self._order):
@@ -487,7 +496,11 @@ class TxPool:
                     out.extend(t for _, t in run)
                 if limit and len(out) >= limit:
                     break
-            return out[:limit] if limit else out
+            if limit:
+                out = out[:limit]
+            sp.set_attr("picked", len(out))
+            sp.set_attr("senders", len(seen))
+            return out
 
     def _evict(self, hashes) -> None:
         """O(evicted) eviction by txn hash: the ``_by_hash`` index

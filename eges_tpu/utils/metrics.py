@@ -47,6 +47,9 @@ METRIC_FAMILIES = frozenset({
     "chain.executions", "chain.insert_reused",
     "chain.refused_candidates", "chain.validated_blocks",
     "state.root_accounts",
+    # the proposer's half (PR 48): the transactions a preview dropped
+    # because they could not execute
+    "chain.preview_dropped",
     # core/trie.py — the nodes a root encoded and hashed (derive_sha and
     # IncrementalTrie.root, one inc a root), and those of them that
     # went through the library's one call (native/trie.cpp)
@@ -57,6 +60,9 @@ METRIC_FAMILIES = frozenset({
     "consensus.geec_txn_dropped", "consensus.ingress_oversized",
     "consensus.phase_seconds", "consensus.reg_req_dropped",
     "consensus.sealed", "membership.min_ttl", "membership.size",
+    # the proposer's half (PR 48): proposals built, and the bytes of the
+    # validate requests gossiped (retries too)
+    "consensus.proposals_built", "consensus.request_bytes",
     # consensus/quorum.py — a quorum's tally: attempts (every collected
     # signature through the verifier), the signatures they handed over,
     # the authors they pruned, the quorums certified, and the time from
@@ -179,10 +185,13 @@ METRIC_HELP = {
     "chain.executions": (
         "Executions of a block's transactions (one a _process: an "
         "acceptor's validation, or the insert of a block this node did "
-        "not validate on this head)."),
+        "not validate on this head; one a proposer's execute_preview)."),
     "chain.insert_reused": (
         "Inserts that took the state and receipts of the block's own "
         "validate_candidate instead of executing it again."),
+    "chain.preview_dropped": (
+        "Transactions a proposer's execute_preview left out of its "
+        "block because they could not execute."),
     "chain.refused_candidates": (
         "Proposed blocks validate_candidate refused (no ACK)."),
     "chain.validated_blocks": (
@@ -236,6 +245,10 @@ METRIC_HELP = {
     "consensus.quorum_seconds": "From the count of replies first standing "
                                 "at the threshold to the quorum certified.",
     "consensus.quorums": "Quorums certified (election, ACK, query).",
+    "consensus.proposals_built": "Proposals this node built as the "
+                                 "elected proposer.",
+    "consensus.request_bytes": "Bytes of the validate requests gossiped "
+                               "(the whole block in each; retries too).",
     "consensus.sealed": "Blocks sealed by this node.",
     "membership.min_ttl": "Minimum TTL across registered members.",
     "membership.size": "Registered committee members.",

@@ -136,6 +136,35 @@ SPANS = {
                               "signatures through the scheduler as one "
                               "consensus-class call (consensus/quorum.py "
                               "cert_ok); attr rows"),
+    "consensus.build_proposal": ((), "the elected proposer's build of "
+                                     "its block (consensus/node.py "
+                                     "_build_proposal): the pool's "
+                                     "pending run, the preview, the "
+                                     "padding, the header and the body's "
+                                     "root; attrs number, txns, fakes; "
+                                     "counter consensus.proposals_built"),
+    "consensus.request": ((), "the validate request signed, the whole "
+                              "block packed and gossiped "
+                              "(_build_and_validate, _ask_for_ack); attr "
+                              "bytes; counter consensus.request_bytes, "
+                              "one inc(bytes) a gossiped request"),
+    "consensus.seal": ((), "a certified proposal sealed (_finish_seal): "
+                           "the confirm with its supporters' signatures "
+                           "signed, chain.offer and its insert, the "
+                           "listeners, the confirm's gossip; attrs "
+                           "number, supporters"),
+    "txpool.pending": ((), "the executable run a proposer drains "
+                           "(core/txpool.py pending_txns): every sender's "
+                           "pending transactions sorted by nonce, cut at "
+                           "the state's nonce, a gap or the balance; "
+                           "attrs limit, picked, senders"),
+    "chain.execute_preview": ((), "a proposer's dry run of its block on "
+                                  "the head state (core/chain.py): the "
+                                  "senders, the apply_txn loop, the state "
+                                  "root, the receipts' root, the bloom; "
+                                  "attrs txns, kept; counters "
+                                  "chain.executions (one inc a preview), "
+                                  "chain.preview_dropped"),
     "chain.validate_candidate": ((), "an acceptor's whole check of a "
                                      "proposed block before its ACK "
                                      "(core/chain.py): body, senders, "
@@ -151,7 +180,8 @@ SPANS = {
                           "(core/state.py); attr txns; counter "
                           "chain.executions, one inc a _process: a "
                           "validation's, or the insert's of a block "
-                          "that brings no validation of its own"),
+                          "that brings no validation of its own (a "
+                          "proposer's preview counts there too)"),
     "state.root": ((), "StateDB.root() where it is not cached: the dirty "
                        "accounts into the secure trie, then the nodes' "
                        "hashes; attr dirty; counter state.root_accounts, "

@@ -97,9 +97,11 @@ def test_geec_txns_flow_through_blocks():
         c.nodes[0].node.on_geec_txn(b"txn-%d" % i)
     c.run(240, stop_condition=lambda: len(delivered) >= 6)
     assert any(p == b"txn-0" for p in delivered)
-    # every block carries exactly txn_per_block geec+fake txns
     blk = c.nodes[0].chain.get_block_by_number(2)
-    assert len(blk.geec_txns) + len(blk.fake_txns) == 4
+    # every block carries exactly txn_per_block transactions IN ALL: the
+    # fakes fill what the geec and the signed transactions leave
+    assert (len(blk.geec_txns) + len(blk.fake_txns)
+            + len(blk.transactions)) == 4
 
 
 def test_registration_joins_new_node():
