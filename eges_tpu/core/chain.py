@@ -22,6 +22,7 @@ import os
 import struct
 import threading
 from dataclasses import dataclass
+from typing import NamedTuple
 
 from eges_tpu.core import rlp
 from eges_tpu.core.types import (
@@ -287,6 +288,19 @@ def make_genesis(extra: bytes = b"geec-genesis", time: int = 0,
                             parent_hash=ZERO_HASH, trust_rand=0, root=root))
 
 
+class _Preview(NamedTuple):
+    """What ``BlockChain.execute_preview`` ran on and with, and what came
+    of it: all that ``process_block`` would take from a header and give."""
+
+    head: bytes            # hash of the head it ran on
+    coinbase: bytes
+    ctx: object            # the BlockCtx it executed with
+    transactions: tuple    # the kept ones, the tuple it returned
+    state: object
+    receipts: tuple
+    commitments: tuple     # root, receipts' root, gas used, bloom
+
+
 class BlockChain:
     """Ordered canonical chain with an insert funnel.
 
@@ -327,6 +341,10 @@ class BlockChain:
         # kept for the insert of that very block (see _insert); at most
         # _MAX_CANDIDATES, oldest out, all dropped when the head moves
         self._validated: dict[bytes, tuple] = {}
+        # the newest execute_preview's outcome, kept for the insert of the
+        # block built from it (see _kept_outcome); ONE slot, overwritten by
+        # the next preview, dropped when the head moves
+        self._previewed: _Preview | None = None
         self.bad_blocks = 0
         # owning GeecNode attaches its event journal (utils/journal.py)
         self.journal = None
@@ -585,14 +603,17 @@ class BlockChain:
                         ctx=None) -> tuple:
         """Proposer-side dry run on top of the head state: greedily apply
         ``txs``, dropping any that cannot execute, and return
-        ``(kept_txs, root, receipt_root, gas_used)`` for the new header
-        (the role of the worker's commitTransactions loop,
+        ``(kept_txs, root, receipt_root, gas_used, bloom)`` for the new
+        header (the role of the worker's commitTransactions loop,
         ref: miner/worker.go:463-467).  ``coinbase`` is the PROPOSED
         block's fee recipient and ``ctx`` MUST carry the exact
         time/difficulty/number the sealed header will — validation
         re-executes with ``block_ctx(header)``, so any divergence (a
         contract reading TIMESTAMP, say) makes the committed state root
-        unreproducible."""
+        unreproducible.  ``kept_txs`` is a TUPLE, and the outcome is kept
+        under it for :meth:`_insert`: a block built from that very tuple
+        (``new_block`` and ``with_confirm`` hand it on) on this head under
+        this coinbase and ctx is not executed again."""
         from eges_tpu.core.evm import BlockCtx
         from eges_tpu.core.state import (
             StateError, apply_txn, receipts_root, recover_senders,
@@ -630,8 +651,14 @@ class BlockChain:
                 metrics.counter("chain.preview_dropped").inc(
                     len(txs) - len(kept))
             from eges_tpu.core.state import receipts_bloom
-            return (kept, state.root(), receipts_root(receipts), gas,
-                    receipts_bloom(receipts))
+            kept, receipts = tuple(kept), tuple(receipts)
+            commitments = (state.root(), receipts_root(receipts), gas,
+                           receipts_bloom(receipts))
+            # () is every empty body's tuple: identity says nothing there
+            self._previewed = _Preview(
+                self._head.hash, coinbase, ctx, kept, state, receipts,
+                commitments) if kept else None
+            return (kept, *commitments)
 
     def validate_candidate(self, block: Block) -> bool:
         """Full acceptor-side validation of a proposed block WITHOUT
@@ -780,29 +807,53 @@ class BlockChain:
 
     def _move_head(self, block: Block) -> None:
         """Every move of the head after start-up: a validated candidate
-        is only ever good for a child of the head it was validated on."""
+        or a preview is only ever good for a child of the head it was
+        executed on."""
         self._head = block
         self._validated.clear()
+        self._previewed = None
 
-    def _validated_outcome(self, block: Block):
-        """``(state, receipts)`` of this very block's validation on the
-        current head, or None.  Identity of the body, not the hash alone:
-        a block's hash covers its header, and ``_verify_body`` is what
-        ties a body to the header."""
+    def _kept_outcome(self, block: Block):
+        """``(state, receipts, counter)`` of this very block's execution
+        on the current head, or None: its validation's, else the preview's
+        it was built from.  Identity of the body, not the hash alone: a
+        block's hash covers its header, and ``_verify_body`` is what ties
+        a body to the header.  A validation ran it over this header; a
+        preview knew no header, so it runs here (and raises as it does on
+        the full path), and the header has to say what the preview
+        executed with (``process_block`` takes its coinbase and
+        ``block_ctx`` of it) and computed: a commitment that differs
+        leaves the block to ``_process``, which refuses it.  ``counter``
+        counts the inserts that took the one or the other."""
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+        if block.uncles:
+            return None
         kept = self._validated.get(block.hash)
-        if kept is None or block.uncles:
+        if kept is not None and kept[0] is block.transactions:
+            return kept[1], kept[2], metrics.counter("chain.insert_reused")
+        pre, h = self._previewed, block.header
+        if pre is None:
             return None
-        transactions, state, receipts = kept
-        if transactions is not block.transactions:
+        from eges_tpu.core.state import block_ctx
+        if (pre.transactions is not block.transactions
+                or h.parent_hash != pre.head or h.coinbase != pre.coinbase
+                or block_ctx(h) != pre.ctx
+                or (h.root, h.receipt_hash, h.gas_used, h.bloom)
+                != pre.commitments):
             return None
-        return state, receipts
+        self._verify_body(block)
+        return (pre.state, pre.receipts,
+                metrics.counter("chain.insert_previewed"))
 
     def _insert(self, block: Block) -> None:
         """Verify and store ``block`` as the new head.  Where
         :meth:`validate_candidate` already ran the full check on this very
         body (the IDENTICAL ``transactions`` tuple, what ``with_confirm``
         hands back) on this head, its state and receipts are taken and the
-        body is neither rooted nor executed again; any other block meets
+        body is neither rooted nor executed again; where the block was
+        built from :meth:`execute_preview`'s tuple and commitments on this
+        head, the preview's are taken and the body is rooted but not
+        executed again (:meth:`_kept_outcome`); any other block meets
         ``_verify_body`` and ``_process`` here."""
         from eges_tpu.utils import tracing
         from eges_tpu.utils.metrics import DEFAULT as metrics
@@ -811,11 +862,11 @@ class BlockChain:
                                   txns=len(block.transactions),
                                   reused=0) as sp:
             self._verify_header(block.header)
-            kept = self._validated_outcome(block)
+            kept = self._kept_outcome(block)
             if kept is not None:
-                state, receipts = kept
+                state, receipts, counter = kept
                 sp.set_attr("reused", 1)
-                metrics.counter("chain.insert_reused").inc()
+                counter.inc()
             else:
                 self._verify_body(block)
                 parent_state = self._states.get(block.header.parent_hash)

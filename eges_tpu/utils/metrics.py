@@ -43,8 +43,9 @@ METRIC_FAMILIES = frozenset({
     # the block path (PR 44): an acceptor's validations by outcome, the
     # executions of a block's transactions (one a _process), the inserts
     # that took the state and receipts of the block's own validation
-    # (PR 45), the accounts StateDB.root() hashed into the trie
-    "chain.executions", "chain.insert_reused",
+    # (PR 45) or of the preview it was built from (PR 49), the accounts
+    # StateDB.root() hashed into the trie
+    "chain.executions", "chain.insert_previewed", "chain.insert_reused",
     "chain.refused_candidates", "chain.validated_blocks",
     "state.root_accounts",
     # the proposer's half (PR 48): the transactions a preview dropped
@@ -184,8 +185,12 @@ METRIC_HELP = {
     "chain.blocks": "Canonical blocks inserted into the chain.",
     "chain.executions": (
         "Executions of a block's transactions (one a _process: an "
-        "acceptor's validation, or the insert of a block this node did "
-        "not validate on this head; one a proposer's execute_preview)."),
+        "acceptor's validation, or the insert of a block this node "
+        "neither validated nor previewed on this head; one a proposer's "
+        "execute_preview, whose outcome is kept for that block's insert)."),
+    "chain.insert_previewed": (
+        "Inserts that took the state and receipts of the execute_preview "
+        "the block was built from instead of executing it again."),
     "chain.insert_reused": (
         "Inserts that took the state and receipts of the block's own "
         "validate_candidate instead of executing it again."),

@@ -164,7 +164,8 @@ SPANS = {
                                   "root, the receipts' root, the bloom; "
                                   "attrs txns, kept; counters "
                                   "chain.executions (one inc a preview), "
-                                  "chain.preview_dropped"),
+                                  "chain.preview_dropped; its state and "
+                                  "receipts are kept for chain.insert"),
     "chain.validate_candidate": ((), "an acceptor's whole check of a "
                                      "proposed block before its ACK "
                                      "(core/chain.py): body, senders, "
@@ -180,8 +181,9 @@ SPANS = {
                           "(core/state.py); attr txns; counter "
                           "chain.executions, one inc a _process: a "
                           "validation's, or the insert's of a block "
-                          "that brings no validation of its own (a "
-                          "proposer's preview counts there too)"),
+                          "that brings neither a validation nor a "
+                          "preview of its own (a proposer's preview "
+                          "counts there too)"),
     "state.root": ((), "StateDB.root() where it is not cached: the dirty "
                        "accounts into the secure trie, then the nodes' "
                        "hashes; attr dirty; counter state.root_accounts, "
@@ -190,9 +192,10 @@ SPANS = {
                                 "(core/state.py receipts_root); attr txns"),
     "chain.insert": ((), "execute, state root, index; attrs number, "
                          "txns, reused (1: the state and receipts are "
-                         "chain.validate_candidate's for this very body "
-                         "on this head, nothing executed again); counter "
-                         "chain.insert_reused"),
+                         "chain.validate_candidate's or "
+                         "chain.execute_preview's for this very body on "
+                         "this head, nothing executed again); counters "
+                         "chain.insert_reused, chain.insert_previewed"),
     "chain.recover_senders": ((), "a block's signed rows through the "
                                   "verifier in one call (core/state.py): "
                                   "behind the scheduler one window, part "

@@ -228,23 +228,26 @@ def test_the_new_spans_leave_a_sims_journal_byte_for_byte():
 
 def test_the_reused_validation_leaves_a_sims_journal_byte_for_byte(
         monkeypatch):
-    """An acceptor's insert that takes its own validation's state is the
-    same insert: one short cluster run's journal is the same with the
+    """An insert that takes its own validation's state (an acceptor's; a
+    preview's too, where this sim's proposers had transfers to preview) is
+    the same insert: one short cluster run's journal is the same with the
     lookup made to find nothing, and the run WITH it did reuse."""
     from eges_tpu.utils.metrics import DEFAULT as metrics
 
-    def run() -> tuple[bytes, int, int]:
-        reused = metrics.counter("chain.insert_reused").value
-        executed = metrics.counter("chain.executions").value
-        return (_sim_journal(45),
-                metrics.counter("chain.insert_reused").value - reused,
-                metrics.counter("chain.executions").value - executed)
+    names = ("chain.insert_reused", "chain.insert_previewed",
+             "chain.executions")
 
-    with_reuse, reused, executed = run()
+    def run() -> tuple:
+        before = [metrics.counter(n).value for n in names]
+        return (_sim_journal(45), *(metrics.counter(n).value - b
+                                    for n, b in zip(names, before)))
+
+    with_reuse, reused, previewed, executed = run()
     assert reused > 0
-    monkeypatch.setattr(BlockChain, "_validated_outcome",
+    monkeypatch.setattr(BlockChain, "_kept_outcome",
                         lambda self, block: None)
-    without, none, executed_twice = run()
+    without, none, nor_this, executed_twice = run()
     assert with_reuse == without
-    # the same run, and every reused insert was one execution the less
-    assert none == 0 and executed_twice == executed + reused
+    # the same run, and every kept outcome taken was one execution the less
+    assert none == nor_this == 0
+    assert executed_twice == executed + reused + previewed

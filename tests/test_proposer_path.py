@@ -118,15 +118,27 @@ class Rig:
         self.sched.close()
 
 
+def _executed() -> tuple[int, int]:
+    from eges_tpu.utils.metrics import DEFAULT as metrics
+
+    return (metrics.counter("chain.executions").value,
+            metrics.counter("chain.insert_previewed").value)
+
+
 @pytest.mark.parametrize("verifier", ["native", "jax"])
 def test_six_heights_built_certified_sealed_and_held_to_the_reference(
         verifier):
     rig = Rig(DEPLOY, 2**31 + 29, verifier)
     try:
+        before = _executed()
         rig.seal(6)
         feed, tally = rig.feed, rig.tally
         assert [n for n, _h, _head, _t in tally.inserted][:6] == \
             [1, 2, 3, 4, 5, 6]
+        # a height is executed ONCE, by its preview: the seal's insert
+        # takes that state (the run closes with the height in hand sealed)
+        heights = len(tally.inserted)
+        assert _executed() == tuple(n + heights for n in before)
         got = rig.judge()
         assert {k: got[k] for k in ZERO} == dict.fromkeys(ZERO, 0)
         assert got["roots_compared"] >= 6
@@ -182,6 +194,76 @@ def test_a_control_fails_the_check_that_is_its_own(control, check):
         assert [k for k in ZERO if got[k]] == [check]
     finally:
         rig.close()
+
+
+def test_an_aborted_proposals_preview_is_not_anothers_block():
+    """The node builds height 1 (its preview is kept), then ANOTHER
+    proposer's block of that height, the same transfers under another
+    coinbase, is inserted: it is verified and executed in full, and the
+    proposal is aborted."""
+    import dataclasses
+
+    from eges_tpu.consensus import messages as M
+    from eges_tpu.core.evm import BlockCtx
+    from eges_tpu.core.types import ConfirmBlockMsg, new_block
+    from eges_tpu.ingress import admit_remotes_window, decode_txn_window
+    from tests.test_validated_insert import last_insert_span
+
+    rig = Rig(DEPLOY, 2**31 + 29)
+    try:
+        feed, node, chain, run = rig.feed, rig.node, rig.chain, rig.run
+        handed = 0
+        for idx in feed.windows(0):
+            admit_remotes_window(rig.pool, decode_txn_window(
+                [feed.frames[k] for k in idx]))
+            handed += len(idx)
+        assert _wait(lambda: sum(rig.pool.stats[k] for k in (
+            "admitted", "rejected", "duplicate")) >= handed)
+        before = _executed()
+        node.start()
+        assert _wait(lambda: len(rig.transport.direct) >= len(feed.votes[0]))
+        for dg, _kind, _a in feed.votes[0]:
+            node.on_direct(dg)
+        at = run._sent(rig.transport.gossiped, 0,
+                       lambda g: bp._code(g[1]) == bp.VALIDATE_REQ)
+        assert at >= 0 and _executed() == (before[0] + 1, before[1])
+        mine = M.unpack_gossip(rig.transport.gossiped[at][1])[1].block
+        assert chain._previewed is not None and chain.height() == 0
+        # the other proposer's block, from a chain of its own
+        other = bytes([0xD7]) * 20
+        twin = BlockChain(verifier=rig.sched, alloc={
+            a: feed.balance for a in feed.addrs})
+        h = mine.header
+        kept, root, rroot, gas, bloom = twin.execute_preview(
+            list(mine.transactions), other, ctx=BlockCtx(
+                coinbase=other, number=1, time=h.time,
+                difficulty=h.difficulty))
+        assert len(kept) == 32
+        theirs = new_block(dataclasses.replace(
+            h, coinbase=other, root=root, receipt_hash=rroot, gas_used=gas,
+            bloom=bloom), txs=kept)
+        theirs = theirs.with_confirm(ConfirmBlockMsg(
+            block_number=1, hash=theirs.hash, confidence=1000))
+        assert chain.offer(theirs) == [theirs]
+        assert _executed() == (before[0] + 3, before[1])  # twin's, the full
+        assert last_insert_span()["reused"] == 0
+        assert chain._previewed is None
+        assert chain.head_state().root() == root and chain.height() == 1
+        assert any(e["type"] == "proposal_aborted" and e["blk"] == 1
+                   for e in node.journal.events())
+    finally:
+        rig.close()
+
+
+def _wait(cond, seconds: float = 30.0) -> bool:
+    import time
+
+    deadline = time.monotonic() + seconds
+    while not cond():
+        if time.monotonic() > deadline:
+            return False
+        time.sleep(0.002)
+    return True
 
 
 def test_pad_upstream_puts_fakes_beside_a_full_block():

@@ -40,16 +40,23 @@ def mk_chain() -> BlockChain:
                       verifier=NativeBatchVerifier())
 
 
-def block_on(chain: BlockChain, txs, **header) -> Block:
-    """A sound block of ``txs`` on the chain's head."""
-    kept, root, rroot, gas, bloom = chain.execute_preview(list(txs), COINBASE)
-    assert len(kept) == len(txs)
+def block_from(chain: BlockChain, preview, **header) -> Block:
+    """The block a proposer builds on the chain's head from what its
+    ``execute_preview`` returned."""
+    kept, root, rroot, gas, bloom = preview
     parent = chain.head()
     fields = dict(parent_hash=parent.hash, number=parent.number + 1,
                   coinbase=COINBASE, time=parent.header.time + 1, root=root,
                   receipt_hash=rroot, gas_used=gas, bloom=bloom, trust_rand=1)
     fields.update(header)
     return new_block(Header(**fields), txs=kept)
+
+
+def block_on(chain: BlockChain, txs, **header) -> Block:
+    """A sound block of ``txs`` on the chain's head."""
+    preview = chain.execute_preview(list(txs), COINBASE)
+    assert len(preview[0]) == len(txs)
+    return block_from(chain, preview, **header)
 
 
 def confirm_of(block: Block) -> ConfirmBlockMsg:
