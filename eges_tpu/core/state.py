@@ -23,6 +23,7 @@ format-compatible.
 
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass, field, replace
 
 import numpy as np
@@ -43,12 +44,13 @@ class ContractStorage:
     """Persistent contract-storage handle (the dirty-storage role of
     ref: core/state/state_object.go, redesigned): slot->value lives in a
     structure-sharing :class:`~eges_tpu.core.trie.SecureIncrementalTrie`,
-    so a transaction's write-set costs O(writes x trie depth), the
-    storage root re-hashes only the touched path (node encodings memoize
-    on shared immutable nodes), and every state snapshot holds the same
-    tree — the round-3 verdict's "tuple rebuild is quadratic for a
-    5k-slot contract" fix, with the same incremental treatment the
-    account trie already got."""
+    so a transaction's write-set is ONE batch that costs O(writes x trie
+    depth), the storage root re-hashes only the touched path (a node
+    keeps its reference for its life, in the library's node store where
+    that is built in), and every state snapshot holds the same tree —
+    the round-3 verdict's "tuple rebuild is quadratic for a 5k-slot
+    contract" fix, with the same incremental treatment the account trie
+    already got."""
 
     __slots__ = ("_trie", "_root")
 
@@ -62,11 +64,11 @@ class ContractStorage:
         return rlp.decode_uint(rlp.decode(raw)) if raw else 0
 
     def with_writes(self, writes: dict) -> "ContractStorage":
-        t = self._trie
-        for slot, value in writes.items():
-            key = slot.to_bytes(32, "big")
-            t = t.update(key, rlp.encode(value)) if value else t.delete(key)
-        return ContractStorage(t)
+        # one batch: a slot written 0 is deleted (an empty value)
+        return ContractStorage(self._trie.update_many(
+            [slot.to_bytes(32, "big") for slot in writes],
+            [rlp.encode(value) if value else b""
+             for value in writes.values()]))
 
     def root(self) -> bytes:
         if self._root is None:
@@ -180,22 +182,25 @@ class StateDB:
     the account map — a snapshot is an overlay whose reads fall through
     to its parent, and the state root is maintained by a persistent
     :class:`~eges_tpu.core.trie.SecureIncrementalTrie` (structure-shared
-    across snapshots), so per-block cost is O(touched accounts x trie
-    depth) in both time and memory, not O(total accounts).  The
+    across snapshots; a handle on the library's node store where that is
+    built in, so a snapshot's trie is no Python object the collector
+    walks), so per-block cost is O(touched accounts x trie depth) in
+    both time and memory, not O(total accounts).  The
     journaled-revert machinery of the reference (core/state/journal.go)
     collapses to "throw the overlay away" under the single insert funnel.
     """
 
     __slots__ = ("_origin", "_base", "_local", "_trie", "_dirty",
                  "_root_cache",
-                 "_codes")
+                 "_codes", "__weakref__")
 
     # flatten overlay chains deeper than this so reads stay O(1)-ish
     _MAX_DEPTH = 48
 
     def __init__(self, accounts: dict[bytes, Account] | None = None):
         self._base: StateDB | None = None
-        self._origin: StateDB | None = None  # pre-flatten parent (absorb)
+        # weak reference to the pre-flatten parent (absorb), or None
+        self._origin = None
         # addr -> Account (live) | None (deleted/empty)
         self._local: dict[bytes, Account | None] = dict(accounts or {})
         from eges_tpu.core.trie import SecureIncrementalTrie
@@ -224,7 +229,9 @@ class StateDB:
             #  * our own parent link is consumed by the flatten, but the
             #    EVM will still absorb() us into that parent when the
             #    frame commits — record it in ``_origin`` so absorb can
-            #    verify lineage.
+            #    verify lineage.  WEAKLY: a strong link would chain every
+            #    flattened snapshot to its parent back to genesis, and no
+            #    height the chain prunes would ever give its trie back.
             chain = []
             s = self
             while s is not None:
@@ -234,7 +241,7 @@ class StateDB:
             for s in reversed(chain):       # oldest first, newest wins
                 merged.update(s._local)
             self._local = merged
-            self._origin = self._base
+            self._origin = weakref.ref(self._base)
             self._base = None
         child = StateDB.__new__(StateDB)
         child._base = self
@@ -345,7 +352,7 @@ class StateDB:
         # parent link in _origin instead; its _local then holds the
         # complete merged view, which merges just as correctly
         assert child._base is self \
-            or getattr(child, "_origin", None) is self, \
+            or (child._origin is not None and child._origin() is self), \
             "absorb requires a direct child"
         for addr, acct in child._local.items():
             self._local[addr] = acct
@@ -355,24 +362,30 @@ class StateDB:
 
     def root(self) -> bytes:
         """Secure-trie state root over geth-shaped account RLP;
-        incremental — only accounts dirtied since the last call rehash."""
+        incremental — only accounts dirtied since the last call rehash,
+        handed to the trie as ONE batch (one library call where its
+        node store holds the trie: keys hashed, paths copied, new nodes
+        encoded and hashed there, the root hash back from the same
+        call).  Final when it returns."""
         if self._root_cache is None:
             from eges_tpu.utils import tracing
             from eges_tpu.utils.metrics import DEFAULT as metrics
 
             with tracing.DEFAULT.span("state.root",
                                       dirty=len(self._dirty)):
-                t = self._trie
                 # sorted: the rehash order must not depend on set hash
                 # order (byte-identical trie node churn under the chaos
                 # contract)
-                for addr in sorted(self._dirty):
+                addrs = sorted(self._dirty)
+                empty = Account()
+                rlps = []
+                for addr in addrs:
                     a = self.account(addr)
-                    if a == Account():
-                        t = t.delete(addr)
-                    else:
-                        t = t.update(addr, rlp.encode(a.to_rlp()))
-                metrics.counter("state.root_accounts").inc(len(self._dirty))
+                    # an emptied account leaves the trie: an empty value
+                    rlps.append(b"" if a == empty
+                                else rlp.encode(a.to_rlp()))
+                t = self._trie.update_many(addrs, rlps)
+                metrics.counter("state.root_accounts").inc(len(addrs))
                 self._trie = t
                 self._dirty = set()
                 self._root_cache = t.root()

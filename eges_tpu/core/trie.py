@@ -12,19 +12,22 @@ snapshot is a root pointer and a block's root costs its dirty paths.
 
 A root is the block path's largest cost (three of them a height, 4000
 items and 5,000 dirty accounts each at the 1024-validator operating
-point), and nearly all of it is ENCODING nodes, not hashing them.  So a
-root is one library call where ``native/trie.cpp`` is built in:
-``derive_sha`` builds its trie there, and ``IncrementalTrie.root``
-hands over the nodes that have no reference yet, flattened, and keeps
-each node's reference (its hash, or its encoding where that is under 32
-bytes) on the node for the node's life.  The Python code below is the
-golden model of both and runs where the library lacks the entry points;
-the counters ``trie.nodes`` / ``trie.native_nodes`` say which ran.
+point), and nearly all of it is walking, copying and ENCODING nodes,
+not hashing them.  So a root is one library call where
+``native/trie.cpp`` is built in: ``derive_sha`` builds its trie there
+and forgets it, and the persistent trie's NODES LIVE THERE, in a
+reference-counted store: ``update_many`` hands a batch of keys over
+(hashed there, where the trie is secure), the library copies the paths,
+encodes and hashes the nodes it made and answers with the new root's id
+and hash, and an :class:`IncrementalTrie` is that id, given back when
+the handle dies.  No trie node is a Python object then.  The Python
+nodes below are the golden model of both and run where the library
+lacks the entry points (one test, :func:`native.has_trie`; a handle
+stays on the rung that made it); the counters ``trie.nodes`` /
+``trie.native_nodes`` / ``trie.native_updates`` say which ran.
 """
 
 from __future__ import annotations
-
-import struct
 
 from eges_tpu.core import rlp
 from eges_tpu.crypto import native
@@ -264,11 +267,13 @@ def verify_secure_proof(root: bytes, key: bytes, proof: list[bytes]):
 # is O(dirty keys x depth), round-2 verdict item 10)
 # ---------------------------------------------------------------------------
 
-# A node's ``_ref`` is its REFERENCE, what a parent's encoding holds for
-# it: the node's own encoding where that is under 32 bytes, else 0xa0 and
-# its Keccak-256 (the RLP of the hash).  None until a root() reaches the
-# node; then kept for the node's life, which nothing mutates, so no
-# parent and no later height encodes or hashes the node again.
+# The golden model's nodes (the library's store keeps the same shapes
+# and the same memo, native/trie.cpp).  A node's ``_ref`` is its
+# REFERENCE, what a parent's encoding holds for it: the node's own
+# encoding where that is under 32 bytes, else 0xa0 and its Keccak-256
+# (the RLP of the hash).  None until a root() reaches the node; then
+# kept for the node's life, which nothing mutates, so no parent and no
+# later height encodes or hashes the node again.
 
 class _Leaf:
     __slots__ = ("path", "value", "_ref")
@@ -333,43 +338,11 @@ def _refer_py(order: list) -> None:
         node._ref = enc if len(enc) < 32 else b"\xa0" + keccak256(enc)
 
 
-_LEAF = struct.Struct("<BII")  # kind 0, path nibbles, value bytes
-_EXT = struct.Struct("<BI")    # kind 1, path nibbles
-_U32 = struct.Struct("<I")
-
-
-def _refer_native(order: list) -> None:
-    """The same references from ONE library call: the nodes flattened
-    into the records ``native/trie.cpp geec_trie_hash_nodes`` documents,
-    a child either by the reference it has or by its place in
-    ``order``."""
-    place = {id(node): b"\x00" + _U32.pack(i)
-             for i, node in enumerate(order)}
-    recs = []
-    for node in order:
-        if type(node) is _Leaf:
-            recs += (_LEAF.pack(0, len(node.path), len(node.value)),
-                     bytes(node.path), node.value)
-        elif type(node) is _Ext:
-            c = node.child
-            recs += (_EXT.pack(1, len(node.path)), bytes(node.path),
-                     c._ref or place[id(c)])
-        else:
-            recs.append(b"\x02")
-            recs += [b"\x80" if c is None else c._ref or place[id(c)]
-                     for c in node.children]
-            recs += (_U32.pack(len(node.value)), node.value)
-    refs, lens = native.trie_hash_nodes(b"".join(recs), len(order))
-    for node, at, n in zip(order, range(0, len(refs), 33), lens):
-        node._ref = refs[at:at + n]
-
-
 def _refer(root) -> None:
     """Give every node under ``root`` that has none its reference."""
     order = _unreferenced(root)
-    by_library = native.has_trie()
-    (_refer_native if by_library else _refer_py)(order)
-    _count(len(order), by_library)
+    _refer_py(order)
+    _count(len(order), False)
 
 
 def _insert(node, nibs: tuple[int, ...], value: bytes):
@@ -489,60 +462,99 @@ def _get(node, nibs: tuple[int, ...]):
     return None
 
 
+def _packed(nibs) -> bytes:
+    return bytes((nibs[i] << 4) | nibs[i + 1]
+                 for i in range(0, len(nibs), 2))
+
+
+def _walk(node, path):
+    """``(nibble path, value)`` over every leaf under ``node``."""
+    if node is None:
+        return
+    if isinstance(node, _Leaf):
+        yield path + node.path, node.value
+    elif isinstance(node, _Ext):
+        yield from _walk(node.child, path + node.path)
+    else:  # _Branch
+        if node.value:
+            yield path, node.value
+        for i, ch in enumerate(node.children):
+            if ch is not None:
+                yield from _walk(ch, path + (i,))
+
+
 class IncrementalTrie:
-    """Immutable MPT handle: ``update``/``delete`` return NEW handles that
-    share structure with the old one, so chain snapshots are cheap and a
-    block's root costs O(dirty keys x depth) hashing (a node's reference
-    stays on the shared immutable node: ``root()`` encodes and hashes
-    only the nodes made since, in one call)."""
+    """Immutable MPT handle: ``update``/``delete``/``update_many``
+    return NEW handles that share structure with the old one, so chain
+    snapshots are cheap and a block's root costs O(dirty keys x depth).
 
-    __slots__ = ("_root",)
+    On the library's rung the handle is a root id of the node store
+    (``_id``; given back when the handle dies, on whichever thread) and
+    the root hash its batch came back with: ``update_many`` is ONE call
+    that walks, copies, encodes and hashes, ``get`` and ``items`` one
+    read.  On the golden rung it is the Python root node (``_root``) and
+    ``root()`` encodes and hashes the nodes made since.  An empty handle
+    is neither; its first update takes the rung the loaded library
+    allows, and every handle derived from it stays there."""
 
-    def __init__(self, _root=None):
+    __slots__ = ("_root", "_id", "_hash")
+
+    def __init__(self, _root=None, _id: int = 0, _hash=None):
         self._root = _root
+        self._id = _id
+        self._hash = _hash
+
+    def __del__(self):
+        if self._id:
+            native.trie_release(self._id)
 
     @classmethod
     def from_pairs(cls, pairs: dict[bytes, bytes]) -> "IncrementalTrie":
-        t = cls()
-        for k, v in pairs.items():
-            t = t.update(k, v)
-        return t
+        return cls().update_many(list(pairs), list(pairs.values()))
+
+    def update_many(self, keys, values,
+                    secure: bool = False) -> "IncrementalTrie":
+        """``keys[i] -> values[i]`` in the order given, an empty value a
+        delete (of an absent key, a no-op); the keys hashed first where
+        ``secure``."""
+        if self._id or (self._root is None and native.has_trie()):
+            new_id, root, nodes = native.trie_update_many(
+                self._id, keys, values, secure)
+            _count(nodes, True)
+            metrics.counter("trie.native_updates").inc(len(keys))
+            return IncrementalTrie(_id=new_id, _hash=root)
+        node = self._root
+        for key, value in zip(keys, values, strict=True):
+            nibs = tuple(_nibbles(keccak256(key) if secure else key))
+            node = _insert(node, nibs, value) if value \
+                else _delete(node, nibs)
+        return IncrementalTrie(node)
 
     def update(self, key: bytes, value: bytes) -> "IncrementalTrie":
-        if not value:
-            return self.delete(key)
-        return IncrementalTrie(
-            _insert(self._root, tuple(_nibbles(key)), value))
+        return self.update_many((key,), (value,))
 
     def delete(self, key: bytes) -> "IncrementalTrie":
-        return IncrementalTrie(_delete(self._root, tuple(_nibbles(key))))
+        return self.update_many((key,), (b"",))
 
-    def get(self, key: bytes):
-        return _get(self._root, tuple(_nibbles(key)))
+    def get(self, key: bytes, secure: bool = False):
+        if self._id:
+            return native.trie_get(self._id, key, secure)
+        return _get(self._root,
+                    tuple(_nibbles(keccak256(key) if secure else key)))
 
     def items(self):
-        """Yield ``(key, value)`` over every leaf, keys re-packed from
-        nibble paths.  This is the state-sync SERVING walk (ref role:
-        trie.Iterator in eth/downloader/statesync.go's source side); on
-        a secure trie the keys that come back are the hashed ones."""
-        def walk(node, path):
-            if node is None:
-                return
-            if isinstance(node, _Leaf):
-                yield path + node.path, node.value
-            elif isinstance(node, _Ext):
-                yield from walk(node.child, path + node.path)
-            else:  # _Branch
-                if node.value:
-                    yield path, node.value
-                for i, ch in enumerate(node.children):
-                    if ch is not None:
-                        yield from walk(ch, path + (i,))
-        for nibs, val in walk(self._root, ()):
-            yield (bytes((nibs[i] << 4) | nibs[i + 1]
-                         for i in range(0, len(nibs), 2)), val)
+        """``(key, value)`` over every leaf in key order, keys re-packed
+        from nibble paths.  This is the state-sync SERVING walk (ref
+        role: trie.Iterator in eth/downloader/statesync.go's source
+        side); on a secure trie the keys that come back are the hashed
+        ones."""
+        if self._id:
+            return iter(native.trie_items(self._id))
+        return ((_packed(nibs), val) for nibs, val in _walk(self._root, ()))
 
     def root(self) -> bytes:
+        if self._id:
+            return self._hash
         node = self._root
         if node is None:
             return EMPTY_ROOT
@@ -554,21 +566,25 @@ class IncrementalTrie:
 
 
 class SecureIncrementalTrie:
-    """Secure-keyed wrapper (keys pre-hashed, ref: trie/secure_trie.go)."""
+    """Secure-keyed wrapper (keys pre-hashed, ref: trie/secure_trie.go;
+    by the library where its store holds the trie)."""
 
     __slots__ = ("_t",)
 
     def __init__(self, _t: IncrementalTrie | None = None):
         self._t = _t if _t is not None else IncrementalTrie()
 
+    def update_many(self, keys, values) -> "SecureIncrementalTrie":
+        return SecureIncrementalTrie(self._t.update_many(keys, values, True))
+
     def update(self, key: bytes, value: bytes) -> "SecureIncrementalTrie":
-        return SecureIncrementalTrie(self._t.update(keccak256(key), value))
+        return self.update_many((key,), (value,))
 
     def delete(self, key: bytes) -> "SecureIncrementalTrie":
-        return SecureIncrementalTrie(self._t.delete(keccak256(key)))
+        return self.update_many((key,), (b"",))
 
     def get(self, key: bytes):
-        return self._t.get(keccak256(key))
+        return self._t.get(key, True)
 
     def items(self):
         """(hashed_key, value) pairs — see IncrementalTrie.items."""

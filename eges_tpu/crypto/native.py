@@ -9,7 +9,9 @@ stays authoritative for tests.  Build with ``make -C native``.
 
 from __future__ import annotations
 
+import array
 import ctypes
+import itertools
 import os
 
 _LIB = None
@@ -81,10 +83,24 @@ def _load():
             ctypes.c_char_p, ctypes.c_void_p, ctypes.c_uint64,
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
         lib.geec_derive_sha.restype = ctypes.c_int
-        lib.geec_trie_hash_nodes.argtypes = [
-            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64,
-            ctypes.c_char_p, ctypes.c_char_p]
-        lib.geec_trie_hash_nodes.restype = ctypes.c_int
+        u64, out64 = ctypes.c_uint64, ctypes.POINTER(ctypes.c_uint64)
+        lib.geec_trie_update_many.argtypes = [
+            u64, ctypes.c_char_p, ctypes.c_void_p, u64,
+            ctypes.c_char_p, ctypes.c_void_p, u64, u64, ctypes.c_int,
+            out64, ctypes.c_char_p, out64]
+        lib.geec_trie_update_many.restype = ctypes.c_int
+        lib.geec_trie_release.argtypes = [u64]
+        lib.geec_trie_release.restype = ctypes.c_int
+        lib.geec_trie_get.argtypes = [
+            u64, ctypes.c_char_p, u64, ctypes.c_int, ctypes.c_char_p,
+            u64, out64]
+        lib.geec_trie_get.restype = ctypes.c_int
+        lib.geec_trie_items.argtypes = [
+            u64, ctypes.c_char_p, u64, ctypes.c_void_p, ctypes.c_char_p,
+            u64, ctypes.c_void_p, u64, out64, out64, out64]
+        lib.geec_trie_items.restype = ctypes.c_int
+        lib.geec_trie_store_nodes.argtypes = []
+        lib.geec_trie_store_nodes.restype = u64
     except AttributeError:
         pass
     try:  # election component (native/election.cpp); absent in old builds
@@ -193,8 +209,10 @@ def decode_txn_frames(frames) -> tuple:
 
 
 def has_trie() -> bool:
+    """Whether the loaded library has ``native/trie.cpp``'s entry points
+    (the node store's among them: they came last)."""
     lib = _load()
-    return lib is not None and hasattr(lib, "geec_trie_hash_nodes")
+    return lib is not None and hasattr(lib, "geec_trie_update_many")
 
 
 def derive_sha(items) -> tuple[bytes, int]:
@@ -218,23 +236,100 @@ def derive_sha(items) -> tuple[bytes, int]:
     return root.raw, nodes.value
 
 
-def trie_hash_nodes(records: bytes, n: int) -> tuple[bytes, bytes]:
-    """``n`` trie nodes, flattened children first into ``records`` (the
-    form ``native/trie.cpp geec_trie_hash_nodes`` documents and
-    ``core/trie.py`` writes), encoded and hashed in ONE library call
-    that holds no GIL.  Returns node ``i``'s reference in
-    ``refs[33 * i:][:lens[i]]``: the node's own encoding where that is
-    under 32 bytes, else ``0xa0`` and its hash.  The library checks
-    every length against what is left of ``records``."""
-    lib = _load()
-    refs = ctypes.create_string_buffer(33 * n)
-    lens = ctypes.create_string_buffer(n)
-    rc = lib.geec_trie_hash_nodes(records, len(records), n, refs, lens)
+def _spans(items) -> tuple:
+    """``(data, offsets)``: the items back to back and where each begins
+    and ends (n+1 uint64), made here from the items themselves, so the
+    spans are what the library takes them to be (the store checks them
+    all the same).  An ``array``: a batch of one, a contract's few
+    slots, pays a microsecond for it."""
+    return b"".join(items), array.array(
+        "Q", itertools.accumulate(map(len, items), initial=0))
+
+
+def _trie_rc(rc: int) -> None:
     if rc == -1:
-        raise ValueError("node records are not of the library's form")
+        raise ValueError("not a live root of the trie store, or spans "
+                         "that do not fit their buffers")
     if rc:
-        raise MemoryError("native trie hasher found no scratch memory")
-    return refs.raw, lens.raw
+        raise MemoryError("native trie store found no memory")
+
+
+def trie_update_many(root: int, keys, values,
+                     secure: bool) -> tuple[int, bytes, int]:
+    """``keys[i] -> values[i]`` put, in the order given, into the trie
+    the store holds under ``root`` (0: the empty trie): a key hashed
+    first where ``secure``, inserted where its value has bytes, deleted
+    where it is empty; then every node made is encoded and hashed.  ONE
+    library call that holds no GIL.  ``root`` stands as it was; returns
+    the new trie's id (0 where it is empty; else to be given back once
+    through :func:`trie_release`), its root hash and the number of nodes
+    encoded.  ValueError for a root that is not live."""
+    lib = _load()
+    if len(keys) != len(values):
+        raise ValueError("as many values as keys")
+    kdata, koff = _spans(keys)
+    vdata, voff = _spans(values)
+    new_root, nodes = ctypes.c_uint64(), ctypes.c_uint64()
+    root_hash = ctypes.create_string_buffer(32)
+    _trie_rc(lib.geec_trie_update_many(
+        root, kdata, koff.buffer_info()[0], len(kdata), vdata,
+        voff.buffer_info()[0], len(vdata), len(keys), bool(secure),
+        ctypes.byref(new_root), root_hash, ctypes.byref(nodes)))
+    return new_root.value, root_hash.raw, nodes.value
+
+
+def trie_release(root: int) -> None:
+    """Give a root id back to the store, once: the nodes nothing else
+    holds are freed.  Any thread may."""
+    _trie_rc(_load().geec_trie_release(root))
+
+
+def trie_get(root: int, key: bytes, secure: bool):
+    """The value under ``key`` (hashed first where ``secure``) in the
+    trie under ``root``, or None."""
+    lib = _load()
+    n = ctypes.c_uint64()
+    cap = 128  # an account's RLP fits; a longer value comes again
+    while True:
+        out = ctypes.create_string_buffer(cap)
+        _trie_rc(lib.geec_trie_get(root, key, len(key), bool(secure), out,
+                                   cap, ctypes.byref(n)))
+        if n.value <= cap:
+            return out.raw[:n.value] or None
+        cap = n.value
+
+
+def trie_items(root: int) -> list:
+    """Every ``(key, value)`` of the trie under ``root`` in key order
+    (the keys as the trie holds them: hashed, in a secure trie): one
+    call that sizes the buffers and one that fills them."""
+    lib = _load()
+    n, nk, nv = ctypes.c_uint64(), ctypes.c_uint64(), ctypes.c_uint64()
+    sizes = (ctypes.byref(n), ctypes.byref(nk), ctypes.byref(nv))
+    _trie_rc(lib.geec_trie_items(root, None, 0, None, None, 0, None, 0,
+                                 *sizes))
+    count = n.value
+    keys = ctypes.create_string_buffer(nk.value)
+    vals = ctypes.create_string_buffer(nv.value)
+    koff = array.array("Q", bytes(8 * (count + 1)))
+    voff = array.array("Q", bytes(8 * (count + 1)))
+    _trie_rc(lib.geec_trie_items(
+        root, keys, nk.value, koff.buffer_info()[0], vals, nv.value,
+        voff.buffer_info()[0], count, *sizes))
+    kraw, vraw = keys.raw, vals.raw
+    return [(kraw[ka:kb], vraw[va:vb]) for ka, kb, va, vb in zip(
+        koff, koff[1:], voff, voff[1:])]
+
+
+def read_trie_store(metrics) -> None:
+    """Set the gauge ``trie.store_nodes``, the store's live nodes, in
+    the registry ``metrics``.  Called when the DEFAULT registry is read,
+    as ``profiler.read_cpu`` is: a handle is released wherever it dies
+    (the collector's thread among them), so nothing on that path writes
+    a metric.  Absent where the library has no store."""
+    if has_trie():
+        metrics.gauge("trie.store_nodes").set(
+            _load().geec_trie_store_nodes())
 
 
 def ec_recover(msg_hash: bytes, sig: bytes) -> bytes:
@@ -323,31 +418,39 @@ EMPTY_TRIE_ROOT = bytes.fromhex(
 DERIVE_SHA_ITEMS = [bytes([i % 251]) * (i % 40 + 1) for i in range(200)]
 DERIVE_SHA_ROOT = bytes.fromhex(
     "09f3ef3772261d6fa788bf21d351a88d96c9ca902badf5a0931af21df2bb16cd")
-# the trie of 0x0123 -> "v" and 0x0145 -> bytes(range(40)), children
-# first: a leaf short enough to be embedded, a leaf that is hashed, the
-# branch over both at nibble 2, the extension (0, 1) above it
-TRIE_NODE_RECORDS = (
-    b"\x00" + (1).to_bytes(4, "little") + (1).to_bytes(4, "little")
-    + b"\x03" + b"v"
-    + b"\x00" + (1).to_bytes(4, "little") + (40).to_bytes(4, "little")
-    + b"\x05" + bytes(range(40))
-    + b"\x02" + b"\x80" * 2 + b"\x00" + (0).to_bytes(4, "little")
-    + b"\x80" + b"\x00" + (1).to_bytes(4, "little") + b"\x80" * 11
-    + (0).to_bytes(4, "little")
-    + b"\x01" + (2).to_bytes(4, "little") + b"\x00\x01"
-    + b"\x00" + (2).to_bytes(4, "little"))
+# 0x0123 -> "v" and 0x0145 -> bytes(range(40)): a leaf short enough to
+# be embedded, a leaf that is hashed, the branch over both at nibble 2,
+# the extension (0, 1) above it
+TRIE_PAIRS = ((bytes.fromhex("0123"), b"v"),
+              (bytes.fromhex("0145"), bytes(range(40))))
 TRIE_NODES_ROOT = bytes.fromhex(
     "2da65d865a48d4e29a9d050e1f962d087aa06e920436f481529c55c20bf9b6d3")
 
 
 def _check_trie() -> None:
-    """The two entry points of ``native/trie.cpp`` on fixed vectors."""
+    """The entry points of ``native/trie.cpp`` on fixed vectors: the
+    whole-built trie, and the store through a batch, a delete that
+    merges what it leaves, reads, and the release that frees."""
     assert derive_sha([]) == (EMPTY_TRIE_ROOT, 0)
     assert derive_sha(DERIVE_SHA_ITEMS)[0] == DERIVE_SHA_ROOT
-    refs, lens = trie_hash_nodes(TRIE_NODE_RECORDS, 4)
-    assert list(lens) == [3, 33, 33, 33], "embedded leaf, three hashes"
-    assert refs[:3] == b"\xc2\x33v" and refs[99] == 0xa0
-    assert refs[100:132] == TRIE_NODES_ROOT
+    lib = _load()
+    before = lib.geec_trie_store_nodes()
+    keys, values = zip(*TRIE_PAIRS)
+    root, root_hash, nodes = trie_update_many(0, keys, values, False)
+    assert (root_hash, nodes) == (TRIE_NODES_ROOT, 4)
+    assert trie_items(root) == list(TRIE_PAIRS)
+    assert trie_get(root, keys[1], False) == values[1]
+    assert trie_get(root, b"\x01", False) is None
+    # without the second key the first is one leaf, hashed as the root
+    one, one_hash, nodes = trie_update_many(root, keys[1:], [b""], False)
+    assert nodes == 1 and trie_items(one) == [TRIE_PAIRS[0]]
+    assert one_hash == keccak256(b"\xc5\x83\x20" + keys[0] + values[0])
+    assert trie_update_many(one, keys[:1], [b""], False)[:2] == (
+        0, EMPTY_TRIE_ROOT)
+    assert lib.geec_trie_store_nodes() == before + 5
+    trie_release(root)
+    trie_release(one)
+    assert lib.geec_trie_store_nodes() == before, "the store leaked"
 
 
 def _check_decode_window() -> None:

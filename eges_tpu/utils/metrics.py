@@ -52,9 +52,12 @@ METRIC_FAMILIES = frozenset({
     # because they could not execute
     "chain.preview_dropped",
     # core/trie.py — the nodes a root encoded and hashed (derive_sha and
-    # IncrementalTrie.root, one inc a root), and those of them that
-    # went through the library's one call (native/trie.cpp)
-    "trie.native_nodes", "trie.nodes",
+    # the persistent trie, one inc a root), those of them the library
+    # did (native/trie.cpp), the keys its node store took in batches,
+    # and the store's live nodes (read off the library when the
+    # registry is read)
+    "trie.native_nodes", "trie.native_updates", "trie.nodes",
+    "trie.store_nodes",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -210,6 +213,13 @@ METRIC_HELP = {
     "trie.native_nodes": (
         "Trie nodes the native library encoded and hashed, one call a "
         "root (trie.nodes less these took the Python rung)."),
+    "trie.native_updates": (
+        "Keys the library's node store took, one batch a call (over "
+        "state.root_accounts plus storage writes: how often the store "
+        "engages)."),
+    "trie.store_nodes": (
+        "Live nodes of the library's trie node store (falls when a "
+        "fork or a pruned height's state is dropped)."),
     "chain.blocks_refused": (
         "Blocks whose sender recovery raised StateError (a signature "
         "that names no sender)."),
@@ -657,12 +667,16 @@ class Registry:
         ``prometheus_text``.  The DEFAULT registry first reads the
         process's CPU time and its threads' by role off the process
         (``profiler.read_cpu``: gauges that are read then, not emitted
-        on anybody's path)."""
+        on anybody's path) and the trie node store's live nodes off the
+        library (``native.read_trie_store``)."""
         if self is DEFAULT:
             # utils/profiler.py has the roles, and imports nothing of
-            # this module at import time
+            # this module at import time; crypto/native.py imports
+            # nothing of this package
+            from eges_tpu.crypto import native
             from eges_tpu.utils import profiler
             profiler.read_cpu(self)
+            native.read_trie_store(self)
         with self._lock:
             return sorted(self._metrics.items())
 

@@ -13,6 +13,11 @@ from eges_tpu.core.state import (
 )
 from eges_tpu.core.types import Transaction
 from eges_tpu.crypto.keccak import keccak256
+from tests.test_trie_native import trie_rung  # noqa: F401 (a fixture)
+
+# every case on both rungs of the persistent trie: the library's node
+# store and the Python nodes (tests/test_trie_native.py old_library)
+pytestmark = pytest.mark.usefixtures("trie_rung")
 
 A = b"\xaa" * 20
 B = b"\xbb" * 20

@@ -60,7 +60,7 @@ def test_batch_recover():
 
 
 def test_trie_entry_points_on_the_plain_references_roots():
-    """``native/trie.cpp``'s two entry points on fixed vectors: the empty
+    """``native/trie.cpp``'s entry points on fixed vectors: the empty
     root, and roots that the benchmark's plain reference
     (``perfbench/ref/state.py``, which ``tests/test_state_reference.py``
     holds the whole program to) gives for the same items and pairs."""
@@ -72,25 +72,94 @@ def test_trie_entry_points_on_the_plain_references_roots():
     assert ref.derive_sha(native.DERIVE_SHA_ITEMS) == native.DERIVE_SHA_ROOT
     root, nodes = native.derive_sha(native.DERIVE_SHA_ITEMS)
     assert root == native.DERIVE_SHA_ROOT and nodes > 200
-    pairs = [(bytes.fromhex("0123"), b"v"),
-             (bytes.fromhex("0145"), bytes(range(40)))]
-    assert ref.trie_root(pairs) == native.TRIE_NODES_ROOT
-    refs, lens = native.trie_hash_nodes(native.TRIE_NODE_RECORDS, 4)
-    assert list(lens) == [3, 33, 33, 33]
-    assert refs[33 * 3 + 1:] == native.TRIE_NODES_ROOT
+    assert ref.trie_root(list(native.TRIE_PAIRS)) == native.TRIE_NODES_ROOT
+    keys, values = zip(*native.TRIE_PAIRS)
+    held, root, nodes = native.trie_update_many(0, keys, values, False)
+    assert (root, nodes) == (native.TRIE_NODES_ROOT, 4)
+    native.trie_release(held)
+    assert native.trie_update_many(0, [], [], True) == (0, ref.EMPTY_ROOT, 0)
     native.self_check()
 
 
-@pytest.mark.parametrize("records, n", [
-    (native.TRIE_NODE_RECORDS[:-1], 4),            # cut short
-    (native.TRIE_NODE_RECORDS + b"\x00", 4),       # slack after the last
-    (native.TRIE_NODE_RECORDS, 3),                 # fewer nodes than records
-    (b"\x03", 1),                                  # no such kind
-    (b"\x01" + bytes(4) + b"\x00" + bytes(4), 1),  # a child not before it
-    (b"\x01" + bytes(4) + b"\x80", 1),             # an extension over nothing
-    (b"\x00" + (1).to_bytes(4, "little") + bytes(4) + b"\x10", 1),  # nibble 16
-    (b"\x00" + b"\xff" * 8, 1),                    # lengths past the end
-])
-def test_trie_hash_nodes_refuses_malformed_records(records, n):
-    with pytest.raises(ValueError):
-        native.trie_hash_nodes(records, n)
+def _raw_update(root, keys: bytes, key_off, values: bytes, val_off, n=None):
+    """``geec_trie_update_many`` with spans the caller made up (the
+    binding makes them from the items themselves)."""
+    import ctypes
+
+    import numpy as np
+
+    koff = np.asarray(key_off, np.uint64)
+    voff = np.asarray(val_off, np.uint64)
+    out = ctypes.c_uint64(), ctypes.create_string_buffer(32), \
+        ctypes.c_uint64()
+    native._trie_rc(native._load().geec_trie_update_many(
+        root, keys, koff.ctypes.data, len(keys), values, voff.ctypes.data,
+        len(values), len(koff) - 1 if n is None else n, False,
+        ctypes.byref(out[0]), out[1], ctypes.byref(out[2])))
+    return out[0].value
+
+
+def _released(live):
+    root = native.trie_update_many(live, [b"gone"], [b"soon"], False)[0]
+    native.trie_release(root)
+    return root
+
+
+@pytest.mark.parametrize("call", [
+    # a root id that was never issued
+    lambda live: native.trie_update_many(live ^ 1 << 40, [b"k"], [b"v"], True),
+    lambda live: native.trie_get((7 << 32) | 0xFFFFFF, b"k", False),
+    lambda live: native.trie_items(live + 1),
+    lambda live: native.trie_release(0),
+    # one that was released already, through each entry
+    lambda live: native.trie_update_many(_released(live), [b"k"], [b"v"],
+                                         False),
+    lambda live: native.trie_get(_released(live), b"k", True),
+    lambda live: native.trie_items(_released(live)),
+    lambda live: native.trie_release(_released(live)),
+    # offsets past the buffers, not from 0, not ascending
+    lambda live: _raw_update(live, b"key", [0, 4], b"v", [0, 1]),
+    lambda live: _raw_update(live, b"key", [0, 3], b"v", [0, 2**40]),
+    lambda live: _raw_update(live, b"key", [1, 3], b"v", [0, 1]),
+    lambda live: _raw_update(live, b"keys", [0, 3, 2, 4], b"abc",
+                             [0, 1, 2, 3]),
+    # lengths that disagree: buffers longer than their spans, fewer
+    # values than keys
+    lambda live: _raw_update(live, b"key", [0, 2], b"v", [0, 1]),
+    lambda live: _raw_update(live, b"key", [0, 3], b"value", [0, 1]),
+    lambda live: native.trie_update_many(live, [b"k", b"l"], [b"v"], False),
+], ids=["update-unissued", "get-unissued", "items-unissued", "release-empty",
+        "update-released", "get-released", "items-released",
+        "release-released", "keys-past-the-end", "values-past-the-end",
+        "keys-not-from-0", "keys-descending", "keys-slack", "values-slack",
+        "fewer-values"])
+def test_trie_store_refuses_what_is_not_of_its_form(call):
+    """ValueError, and the store as it was: its live nodes, and a live
+    root's hash, reads and items."""
+    keys, values = zip(*native.TRIE_PAIRS)
+    live = native.trie_update_many(0, keys, values, False)[0]
+    try:
+        nodes = native._load().geec_trie_store_nodes()
+        with pytest.raises(ValueError):
+            call(live)
+        assert native._load().geec_trie_store_nodes() == nodes
+        assert native.trie_items(live) == list(native.TRIE_PAIRS)
+        assert native.trie_get(live, keys[0], False) == values[0]
+        same, root, made = native.trie_update_many(live, [], [], False)
+        assert (root, made) == (native.TRIE_NODES_ROOT, 0)
+        native.trie_release(same)
+    finally:
+        native.trie_release(live)
+
+
+def test_the_empty_key_is_a_key_of_the_plain_trie():
+    """Not refused: the golden model holds it as the root branch's
+    value (its keys need not be prefix-free), so the store does."""
+    from eges_tpu.core.trie import trie_root
+
+    pairs = [(b"", b"at the root"), (b"\x01", b"one"), (b"\x10", b"two")]
+    held, root, _ = native.trie_update_many(0, *zip(*pairs), False)
+    assert root == trie_root(dict(pairs))
+    assert native.trie_get(held, b"", False) == b"at the root"
+    assert native.trie_items(held) == pairs
+    native.trie_release(held)

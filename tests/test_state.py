@@ -18,6 +18,11 @@ from eges_tpu.core.types import Header, Transaction, new_block
 from eges_tpu.crypto import secp256k1 as secp
 from eges_tpu.sim.cluster import SimCluster
 from eges_tpu.sim.simnet import SimClock
+from tests.test_trie_native import trie_rung  # noqa: F401 (a fixture)
+
+# every case on both rungs of the persistent trie: the library's node
+# store and the Python nodes (tests/test_trie_native.py old_library)
+pytestmark = pytest.mark.usefixtures("trie_rung")
 
 PRIV_A = bytes([0x11]) * 32
 PRIV_B = bytes([0x22]) * 32

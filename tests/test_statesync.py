@@ -14,6 +14,11 @@ from eges_tpu.core.state import StateDB
 from eges_tpu.core.types import Header, Transaction, new_block
 from eges_tpu.crypto import secp256k1 as secp
 from eges_tpu.sim.cluster import SimCluster
+from tests.test_trie_native import trie_rung  # noqa: F401 (a fixture)
+
+# every case on both rungs of the persistent trie: the library's node
+# store and the Python nodes (tests/test_trie_native.py old_library)
+pytestmark = pytest.mark.usefixtures("trie_rung")
 
 PRIV = bytes([3]) * 32
 ADDR = secp.pubkey_to_address(secp.privkey_to_pubkey(PRIV))
