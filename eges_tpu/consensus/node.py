@@ -751,10 +751,14 @@ class GeecNode:
         # must see the same values, or the state root won't reproduce)
         difficulty = 100
         blk_time = max(int(self.clock.now()), parent.header.time + 1)
+        # the block gas limit is the chain's: its parent's, from the
+        # genesis on (upstream: core.CalcGasLimit from the parent)
+        gas_limit = parent.header.gas_limit
         if txs:
-            from eges_tpu.core.evm import BlockCtx
-            ctx = BlockCtx(coinbase=self.coinbase, number=blk_num,
-                           time=blk_time, difficulty=difficulty)
+            from eges_tpu.core.state import block_ctx
+            ctx = block_ctx(Header(
+                coinbase=self.coinbase, number=blk_num, time=blk_time,
+                difficulty=difficulty, gas_limit=gas_limit))
             txs, root, receipt_hash, gas_used, bloom = \
                 self.chain.execute_preview(txs, self.coinbase, ctx=ctx)
         else:
@@ -768,7 +772,7 @@ class GeecNode:
         header = Header(
             parent_hash=parent.hash, number=blk_num,
             coinbase=self.coinbase, difficulty=difficulty,
-            time=blk_time,
+            time=blk_time, gas_limit=gas_limit,
             root=root, receipt_hash=receipt_hash, gas_used=gas_used,
             bloom=bloom, regs=regs,
             # seed for NEXT block

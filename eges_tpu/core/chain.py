@@ -277,15 +277,26 @@ class FileStore(MemoryStore):
 
 
 def make_genesis(extra: bytes = b"geec-genesis", time: int = 0,
-                 alloc: dict[bytes, int] | None = None) -> Block:
+                 alloc: dict | None = None, gas_limit: int = 0) -> Block:
     """Genesis block; the ``"thw"`` consensus config lives in the genesis
     JSON beside it (ref: core/genesis.go SetupGenesisBlock +
-    params/config.go:124).  ``alloc`` (address -> balance) sets the
-    genesis state root (ref: GenesisAlloc, core/genesis.go:228)."""
+    params/config.go:124).  ``alloc`` (address -> balance, or -> balance,
+    nonce, code and storage: ``StateDB.from_alloc``) sets the genesis
+    state root (ref: GenesisAlloc, core/genesis.go:228).  ``gas_limit``
+    is upstream's ``gasLimit``: every block built on this chain carries
+    its parent's, so it is the whole chain's; 0, what a genesis that
+    says nothing has, reads as ``state.BLOCK_GAS_LIMIT``."""
     from eges_tpu.core.state import StateDB
-    root = StateDB.from_alloc(alloc or {}).root()
+    return _genesis_over(StateDB.from_alloc(alloc or {}).root(), extra,
+                         time, gas_limit)
+
+
+def _genesis_over(root: bytes, extra: bytes = b"geec-genesis",
+                  time: int = 0, gas_limit: int = 0) -> Block:
+    """The genesis block over a genesis state's root."""
     return new_block(Header(number=0, time=time, extra=extra,
-                            parent_hash=ZERO_HASH, trust_rand=0, root=root))
+                            parent_hash=ZERO_HASH, trust_rand=0, root=root,
+                            gas_limit=gas_limit))
 
 
 class _Preview(NamedTuple):
@@ -320,7 +331,8 @@ class BlockChain:
     _STATE_KEEP = 1024
 
     def __init__(self, store=None, genesis: Block | None = None,
-                 verifier=None, listeners=(), alloc=None, engine=None):
+                 verifier=None, listeners=(), alloc=None, engine=None,
+                 gas_limit: int = 0):
         from eges_tpu.core.state import StateDB
 
         self.store = store if store is not None else MemoryStore()
@@ -365,10 +377,15 @@ class BlockChain:
         from eges_tpu.core.bloomindex import BloomIndex
         self.bloom_index = BloomIndex()
 
+        # the genesis state, built once: its root goes into a genesis
+        # made here and is held against one that is given or stored
+        gstate = StateDB.from_alloc(self.alloc)
         head_hash = self.store.get_head()
         if head_hash is None:
-            self.genesis = genesis if genesis is not None else make_genesis(
-                alloc=self.alloc)
+            # (``alloc`` and ``gas_limit`` are upstream's genesis.json's:
+            # what ``make_genesis`` takes, the state built once)
+            self.genesis = genesis if genesis is not None \
+                else _genesis_over(gstate.root(), gas_limit=gas_limit)
             self.store.put_block(self.genesis)
             self.store.set_head(self.genesis.hash)
             self._head = self.genesis
@@ -379,7 +396,6 @@ class BlockChain:
 
         if self.genesis is None:
             raise ChainError("store has a head but no genesis block")
-        gstate = StateDB.from_alloc(self.alloc)
         if self.genesis.header.root != gstate.root():
             raise ChainError("genesis state root does not match alloc")
         self._remember_state(self.genesis.hash, 0, gstate, ())
@@ -614,14 +630,15 @@ class BlockChain:
         under it for :meth:`_insert`: a block built from that very tuple
         (``new_block`` and ``with_confirm`` hand it on) on this head under
         this coinbase and ctx is not executed again."""
-        from eges_tpu.core.evm import BlockCtx
         from eges_tpu.core.state import (
-            StateError, apply_txn, receipts_root, recover_senders,
+            StateError, apply_txn, block_ctx, receipts_root,
+            recover_senders,
         )
         from eges_tpu.utils import tracing
         from eges_tpu.utils.metrics import DEFAULT as metrics
         with self._lock, tracing.DEFAULT.span(
-                "chain.execute_preview", txns=len(txs), kept=0) as sp:
+                "chain.execute_preview", txns=len(txs), kept=0,
+                evm_calls=0, reverted=0) as sp:
             # a preview is an execution of the block: the counter a
             # _process increments counts it too
             metrics.counter("chain.executions").inc()
@@ -632,9 +649,10 @@ class BlockChain:
                 senders = [None] * len(txs)
             kept, receipts, gas = [], [], 0
             if ctx is None:
-                ctx = BlockCtx(coinbase=coinbase,
-                               number=self._head.number + 1,
-                               time=self._head.header.time + 1)
+                ctx = block_ctx(Header(
+                    coinbase=coinbase, number=self._head.number + 1,
+                    time=self._head.header.time + 1, difficulty=1,
+                    gas_limit=self._head.header.gas_limit))
             for t, sender in zip(txs, senders):
                 if sender is None:
                     continue
@@ -647,6 +665,7 @@ class BlockChain:
                 receipts.append(r)
                 kept.append(t)
             sp.set_attr("kept", len(kept))
+            ctx.tally.flush(sp)
             if len(kept) < len(txs):
                 metrics.counter("chain.preview_dropped").inc(
                     len(txs) - len(kept))
@@ -935,4 +954,5 @@ class BlockChain:
             coinbase=EMPTY_ADDR,
             root=parent.header.root,
             difficulty=1,
+            gas_limit=parent.header.gas_limit,
         ))

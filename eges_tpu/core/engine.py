@@ -129,6 +129,7 @@ class DevEngine(Engine):
             list(txs), coinbase)
         header = Header(parent_hash=parent.hash, number=parent.number + 1,
                         coinbase=coinbase, time=parent.header.time + 1,
+                        gas_limit=parent.header.gas_limit,
                         root=root, receipt_hash=receipt_hash, gas_used=gas,
                         bloom=bloom)
         block = self.seal(chain, new_block(header, txs=kept))
@@ -305,18 +306,19 @@ class PowEngine(Engine):
         re-executes with block_ctx(header) — a contract reading
         TIMESTAMP/DIFFICULTY must see the same values or the committed
         root is unreproducible), seal, offer."""
-        from eges_tpu.core.evm import BlockCtx
+        from eges_tpu.core.state import block_ctx
 
         parent = chain.head()
         time = parent.header.time + self.TARGET_BLOCK_S
         difficulty = self.calc_difficulty(parent.header, time)
-        ctx = BlockCtx(coinbase=coinbase, number=parent.number + 1,
-                       time=time, difficulty=difficulty)
+        ctx = block_ctx(Header(
+            coinbase=coinbase, number=parent.number + 1, time=time,
+            difficulty=difficulty, gas_limit=parent.header.gas_limit))
         kept, root, receipt_hash, gas, bloom = chain.execute_preview(
             list(txs), coinbase, ctx=ctx)
         header = Header(parent_hash=parent.hash, number=parent.number + 1,
                         coinbase=coinbase, time=time, difficulty=difficulty,
-                        root=root, receipt_hash=receipt_hash, gas_used=gas,
+                        gas_limit=parent.header.gas_limit, root=root, receipt_hash=receipt_hash, gas_used=gas,
                         bloom=bloom)
         block = self.seal(chain, new_block(header, txs=kept))
         if not chain.offer(block):

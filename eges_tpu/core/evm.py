@@ -40,7 +40,7 @@ from __future__ import annotations
 import hashlib
 from dataclasses import dataclass, field
 
-from eges_tpu.core.state import StateError
+from eges_tpu.core.state import BLOCK_GAS_LIMIT, StateError
 from eges_tpu.crypto.keccak import keccak256
 
 U256 = 1 << 256
@@ -98,6 +98,55 @@ R_SELFDESTRUCT = 24_000
 
 
 @dataclass
+class Tally:
+    """What the transactions that ran the EVM under one block context
+    did, summed a transaction at a time (``core/state.py apply_txn``)
+    and added to the registry's ``evm.*`` counters ONCE a block
+    (:meth:`flush`, by ``chain.execute`` and ``chain.execute_preview``):
+    4000 calls a block would pay eight counters' locks each."""
+
+    calls: int = 0
+    reverts: int = 0
+    ops: int = 0
+    sloads: int = 0
+    sstores: int = 0
+    slot_deletes: int = 0
+    gas_used: int = 0
+    gas_refunded: int = 0
+
+    def add(self, evm: "EVM", res: "ExecResult", gas_used: int,
+            refunded: int) -> None:
+        self.calls += 1
+        self.reverts += res.reverted
+        self.ops += evm.ops
+        self.sloads += evm.sloads
+        self.sstores += evm.sstores
+        self.slot_deletes += evm.clears
+        self.gas_used += gas_used
+        self.gas_refunded += refunded
+
+    def flush(self, span=None) -> None:
+        """Into the counters (and ``span``'s attrs ``evm_calls`` and
+        ``reverted``), then back to zero."""
+        if not self.calls:
+            return
+        from eges_tpu.utils.metrics import DEFAULT as metrics
+
+        if span is not None:
+            span.set_attr("evm_calls", self.calls)
+            span.set_attr("reverted", self.reverts)
+        metrics.counter("evm.calls").inc(self.calls)
+        metrics.counter("evm.reverts").inc(self.reverts)
+        metrics.counter("evm.ops").inc(self.ops)
+        metrics.counter("evm.sloads").inc(self.sloads)
+        metrics.counter("evm.sstores").inc(self.sstores)
+        metrics.counter("evm.slot_deletes").inc(self.slot_deletes)
+        metrics.counter("evm.gas_used").inc(self.gas_used)
+        metrics.counter("evm.gas_refunded").inc(self.gas_refunded)
+        self.__init__()
+
+
+@dataclass
 class BlockCtx:
     """Execution environment of the enclosing block (ref: vm.Context)."""
 
@@ -105,8 +154,9 @@ class BlockCtx:
     number: int = 0
     time: int = 0
     difficulty: int = 1
-    gas_limit: int = 30_000_000
+    gas_limit: int = BLOCK_GAS_LIMIT
     blockhash: object = None  # callable number -> 32 bytes, or None
+    tally: Tally = field(default_factory=Tally)
 
 
 @dataclass
@@ -152,6 +202,7 @@ class _Task:
     frame_state: object    # overlay this frame runs on
     log_mark: int
     refund_mark: int
+    clear_mark: int
     suicide_mark: frozenset
     gas: int               # gas handed to the frame
     to: bytes              # account that receives the storage write-set
@@ -194,6 +245,12 @@ class EVM:
         # revert via the per-task marks, like the reference's journal
         self.refund = 0
         self.suicides: set[bytes] = set()
+        # slots written 0 over a value (each earned R_SCLEAR), rolled
+        # back with the refund; then the work done whatever became of
+        # it: opcodes run, SLOADs and SSTOREs among them (a frame adds
+        # its own when it ends)
+        self.clears = 0
+        self.ops = self.sloads = self.sstores = 0
 
     # -- precompiles (ref: core/vm/contracts.go) ------------------------
 
@@ -511,7 +568,8 @@ class EVM:
         self.state = frame_state
         return _Task(kind, self._run(frame, depth), frame, depth, snapshot,
                      frame_state, len(self.logs), self.refund,
-                     frozenset(self.suicides), gas, storage_addr)
+                     self.clears, frozenset(self.suicides), gas,
+                     storage_addr)
 
     def _begin_create(self, args: tuple, depth: int):
         from eges_tpu.core.state import contract_address
@@ -538,7 +596,8 @@ class EVM:
         self.state = frame_state
         return _Task("create", self._run(frame, depth), frame, depth,
                      snapshot, frame_state, len(self.logs), self.refund,
-                     frozenset(self.suicides), gas, new_addr, new_addr)
+                     self.clears, frozenset(self.suicides), gas, new_addr,
+                     new_addr)
 
     def _finish_ok(self, task: "_Task", out: bytes) -> ExecResult:
         f = task.frame
@@ -563,6 +622,7 @@ class EVM:
     def _finish_revert(self, task: "_Task", r: Revert) -> ExecResult:
         del self.logs[task.log_mark:]
         self.refund = task.refund_mark
+        self.clears = task.clear_mark
         self.suicides = set(task.suicide_mark)
         gas_left = getattr(r, "gas_left", 0)
         if self.tracer is not None:
@@ -576,6 +636,7 @@ class EVM:
     def _finish_err(self, task: "_Task", e: Exception) -> ExecResult:
         del self.logs[task.log_mark:]
         self.refund = task.refund_mark
+        self.clears = task.clear_mark
         self.suicides = set(task.suicide_mark)
         if self.tracer is not None:
             self.tracer.on_fault(task.depth, 0, str(e) or "evm error")
@@ -595,6 +656,8 @@ class EVM:
     def _run(self, f: _Frame, depth: int) -> bytes:
         jumpdests = None  # computed lazily on first JUMP
         code = f.code
+        # counted in locals, added to the EVM's when the frame is left
+        ops = sloads = sstores = 0
 
         def use(n: int) -> None:
             if f.gas < n:
@@ -633,362 +696,371 @@ class EVM:
         def sgn(x: int) -> int:
             return x - U256 if x >> 255 else x
 
-        while True:
-            if f.pc >= len(code):
-                return b""
-            op = code[f.pc]
-            if self.tracer is not None:
-                self.tracer.on_step(f.pc, op, f.gas, depth, f.stack)
-            f.pc += 1
+        try:
+            while True:
+                if f.pc >= len(code):
+                    return b""
+                op = code[f.pc]
+                ops += 1
+                if self.tracer is not None:
+                    self.tracer.on_step(f.pc, op, f.gas, depth, f.stack)
+                f.pc += 1
 
-            # PUSH1..PUSH32
-            if 0x60 <= op <= 0x7F:
-                n = op - 0x5F
-                use(G_VERYLOW)
-                push(int.from_bytes(code[f.pc : f.pc + n], "big"))
-                f.pc += n
-                continue
-            # DUP1..DUP16
-            if 0x80 <= op <= 0x8F:
-                use(G_VERYLOW)
-                i = op - 0x7F
-                if len(f.stack) < i:
-                    raise EvmError("stack underflow")
-                push(f.stack[-i])
-                continue
-            # SWAP1..SWAP16
-            if 0x90 <= op <= 0x9F:
-                use(G_VERYLOW)
-                i = op - 0x8F
-                if len(f.stack) < i + 1:
-                    raise EvmError("stack underflow")
-                f.stack[-1], f.stack[-i - 1] = f.stack[-i - 1], f.stack[-1]
-                continue
+                # PUSH1..PUSH32
+                if 0x60 <= op <= 0x7F:
+                    n = op - 0x5F
+                    use(G_VERYLOW)
+                    push(int.from_bytes(code[f.pc : f.pc + n], "big"))
+                    f.pc += n
+                    continue
+                # DUP1..DUP16
+                if 0x80 <= op <= 0x8F:
+                    use(G_VERYLOW)
+                    i = op - 0x7F
+                    if len(f.stack) < i:
+                        raise EvmError("stack underflow")
+                    push(f.stack[-i])
+                    continue
+                # SWAP1..SWAP16
+                if 0x90 <= op <= 0x9F:
+                    use(G_VERYLOW)
+                    i = op - 0x8F
+                    if len(f.stack) < i + 1:
+                        raise EvmError("stack underflow")
+                    f.stack[-1], f.stack[-i - 1] = f.stack[-i - 1], f.stack[-1]
+                    continue
 
-            if op == 0x00:  # STOP
-                return b""
-            elif op == 0x01:  # ADD
-                use(G_VERYLOW); push(pop() + pop())
-            elif op == 0x02:  # MUL
-                use(G_LOW); push(pop() * pop())
-            elif op == 0x03:  # SUB
-                use(G_VERYLOW); a, b = pop(), pop(); push(a - b)
-            elif op == 0x04:  # DIV
-                use(G_LOW); a, b = pop(), pop(); push(a // b if b else 0)
-            elif op == 0x05:  # SDIV
-                use(G_LOW); a, b = sgn(pop()), sgn(pop())
-                push(0 if b == 0 else abs(a) // abs(b) * (1 if a * b >= 0 else -1))
-            elif op == 0x06:  # MOD
-                use(G_LOW); a, b = pop(), pop(); push(a % b if b else 0)
-            elif op == 0x07:  # SMOD
-                use(G_LOW); a, b = sgn(pop()), sgn(pop())
-                push(0 if b == 0 else (abs(a) % abs(b)) * (1 if a >= 0 else -1))
-            elif op == 0x08:  # ADDMOD
-                use(G_MID); a, b, m = pop(), pop(), pop()
-                push((a + b) % m if m else 0)
-            elif op == 0x09:  # MULMOD
-                use(G_MID); a, b, m = pop(), pop(), pop()
-                push((a * b) % m if m else 0)
-            elif op == 0x0A:  # EXP
-                a, e = pop(), pop()
-                use(G_EXP + G_EXP_BYTE * ((e.bit_length() + 7) // 8))
-                push(pow(a, e, U256))
-            elif op == 0x0B:  # SIGNEXTEND
-                use(G_LOW); k, x = pop(), pop()
-                if k < 31:
-                    bit = 8 * (k + 1) - 1
-                    if x >> bit & 1:
-                        x |= MAXU ^ ((1 << (bit + 1)) - 1)
+                if op == 0x00:  # STOP
+                    return b""
+                elif op == 0x01:  # ADD
+                    use(G_VERYLOW); push(pop() + pop())
+                elif op == 0x02:  # MUL
+                    use(G_LOW); push(pop() * pop())
+                elif op == 0x03:  # SUB
+                    use(G_VERYLOW); a, b = pop(), pop(); push(a - b)
+                elif op == 0x04:  # DIV
+                    use(G_LOW); a, b = pop(), pop(); push(a // b if b else 0)
+                elif op == 0x05:  # SDIV
+                    use(G_LOW); a, b = sgn(pop()), sgn(pop())
+                    push(0 if b == 0 else abs(a) // abs(b) * (1 if a * b >= 0 else -1))
+                elif op == 0x06:  # MOD
+                    use(G_LOW); a, b = pop(), pop(); push(a % b if b else 0)
+                elif op == 0x07:  # SMOD
+                    use(G_LOW); a, b = sgn(pop()), sgn(pop())
+                    push(0 if b == 0 else (abs(a) % abs(b)) * (1 if a >= 0 else -1))
+                elif op == 0x08:  # ADDMOD
+                    use(G_MID); a, b, m = pop(), pop(), pop()
+                    push((a + b) % m if m else 0)
+                elif op == 0x09:  # MULMOD
+                    use(G_MID); a, b, m = pop(), pop(), pop()
+                    push((a * b) % m if m else 0)
+                elif op == 0x0A:  # EXP
+                    a, e = pop(), pop()
+                    use(G_EXP + G_EXP_BYTE * ((e.bit_length() + 7) // 8))
+                    push(pow(a, e, U256))
+                elif op == 0x0B:  # SIGNEXTEND
+                    use(G_LOW); k, x = pop(), pop()
+                    if k < 31:
+                        bit = 8 * (k + 1) - 1
+                        if x >> bit & 1:
+                            x |= MAXU ^ ((1 << (bit + 1)) - 1)
+                        else:
+                            x &= (1 << (bit + 1)) - 1
+                    push(x)
+                elif op == 0x10:  # LT
+                    use(G_VERYLOW); push(1 if pop() < pop() else 0)
+                elif op == 0x11:  # GT
+                    use(G_VERYLOW); push(1 if pop() > pop() else 0)
+                elif op == 0x12:  # SLT
+                    use(G_VERYLOW); push(1 if sgn(pop()) < sgn(pop()) else 0)
+                elif op == 0x13:  # SGT
+                    use(G_VERYLOW); push(1 if sgn(pop()) > sgn(pop()) else 0)
+                elif op == 0x14:  # EQ
+                    use(G_VERYLOW); push(1 if pop() == pop() else 0)
+                elif op == 0x15:  # ISZERO
+                    use(G_VERYLOW); push(1 if pop() == 0 else 0)
+                elif op == 0x16:  # AND
+                    use(G_VERYLOW); push(pop() & pop())
+                elif op == 0x17:  # OR
+                    use(G_VERYLOW); push(pop() | pop())
+                elif op == 0x18:  # XOR
+                    use(G_VERYLOW); push(pop() ^ pop())
+                elif op == 0x19:  # NOT
+                    use(G_VERYLOW); push(MAXU ^ pop())
+                elif op == 0x1A:  # BYTE
+                    use(G_VERYLOW); i, x = pop(), pop()
+                    push((x >> (8 * (31 - i))) & 0xFF if i < 32 else 0)
+                elif op == 0x1B:  # SHL
+                    use(G_VERYLOW); s, x = pop(), pop()
+                    push(x << s if s < 256 else 0)
+                elif op == 0x1C:  # SHR
+                    use(G_VERYLOW); s, x = pop(), pop()
+                    push(x >> s if s < 256 else 0)
+                elif op == 0x1D:  # SAR
+                    use(G_VERYLOW); s, x = pop(), sgn(pop())
+                    push((x >> s if s < 256 else (0 if x >= 0 else MAXU)))
+                elif op == 0x20:  # SHA3
+                    off, n = pop(), pop()
+                    use(G_SHA3 + G_SHA3_WORD * _words(n))
+                    push(int.from_bytes(keccak256(mload(off, n)), "big"))
+                elif op == 0x30:  # ADDRESS
+                    use(G_BASE); push(int.from_bytes(f.addr, "big"))
+                elif op == 0x31:  # BALANCE
+                    use(G_BALANCE)
+                    push(self.state.balance(pop().to_bytes(32, "big")[12:]))
+                elif op == 0x32:  # ORIGIN
+                    use(G_BASE); push(int.from_bytes(f.origin, "big"))
+                elif op == 0x33:  # CALLER
+                    use(G_BASE); push(int.from_bytes(f.caller, "big"))
+                elif op == 0x34:  # CALLVALUE
+                    use(G_BASE); push(f.value)
+                elif op == 0x35:  # CALLDATALOAD
+                    use(G_VERYLOW); off = pop()
+                    push(int.from_bytes(f.data[off : off + 32].ljust(32, b"\0"),
+                                        "big") if off < len(f.data) else 0)
+                elif op == 0x36:  # CALLDATASIZE
+                    use(G_BASE); push(len(f.data))
+                elif op == 0x37:  # CALLDATACOPY
+                    dst, src, n = pop(), pop(), pop()
+                    use(G_VERYLOW + G_COPY_WORD * _words(n))
+                    chunk = f.data[src : src + n] if src < len(f.data) else b""
+                    mstore(dst, chunk.ljust(n, b"\0"))
+                elif op == 0x38:  # CODESIZE
+                    use(G_BASE); push(len(code))
+                elif op == 0x39:  # CODECOPY
+                    dst, src, n = pop(), pop(), pop()
+                    use(G_VERYLOW + G_COPY_WORD * _words(n))
+                    chunk = code[src : src + n] if src < len(code) else b""
+                    mstore(dst, chunk.ljust(n, b"\0"))
+                elif op == 0x3A:  # GASPRICE
+                    use(G_BASE); push(0)
+                elif op == 0x3B:  # EXTCODESIZE
+                    use(G_EXTCODE)
+                    push(len(self.state.code(pop().to_bytes(32, "big")[12:])))
+                elif op == 0x3C:  # EXTCODECOPY
+                    addr = pop().to_bytes(32, "big")[12:]
+                    dst, src, n = pop(), pop(), pop()
+                    use(G_EXTCODE + G_COPY_WORD * _words(n))
+                    c = self.state.code(addr)
+                    chunk = c[src : src + n] if src < len(c) else b""
+                    mstore(dst, chunk.ljust(n, b"\0"))
+                elif op == 0x3D:  # RETURNDATASIZE
+                    use(G_BASE); push(len(f.ret))
+                elif op == 0x3E:  # RETURNDATACOPY
+                    dst, src, n = pop(), pop(), pop()
+                    use(G_VERYLOW + G_COPY_WORD * _words(n))
+                    if src + n > len(f.ret):
+                        raise EvmError("returndata out of bounds")
+                    mstore(dst, f.ret[src : src + n])
+                elif op == 0x40:  # BLOCKHASH
+                    use(G_HIGH + 10); n = pop()
+                    bh = self.ctx.blockhash
+                    # only the previous 256 ancestors — never the block
+                    # being executed, whose hash is not yet sealed
+                    # (ref core/vm/instructions.go opBlockhash: distance
+                    # 1..256, else zero)
+                    push(int.from_bytes(bh(n), "big")
+                         if bh is not None and 1 <= self.ctx.number - n <= 256
+                         else 0)
+                elif op == 0x41:  # COINBASE
+                    use(G_BASE); push(int.from_bytes(self.ctx.coinbase, "big"))
+                elif op == 0x42:  # TIMESTAMP
+                    use(G_BASE); push(self.ctx.time)
+                elif op == 0x43:  # NUMBER
+                    use(G_BASE); push(self.ctx.number)
+                elif op == 0x44:  # DIFFICULTY
+                    use(G_BASE); push(self.ctx.difficulty)
+                elif op == 0x45:  # GASLIMIT
+                    use(G_BASE); push(self.ctx.gas_limit)
+                elif op == 0x50:  # POP
+                    use(G_BASE); pop()
+                elif op == 0x51:  # MLOAD
+                    use(G_VERYLOW); off = pop()
+                    push(int.from_bytes(mload(off, 32), "big"))
+                elif op == 0x52:  # MSTORE
+                    use(G_VERYLOW); off, v = pop(), pop()
+                    mstore(off, v.to_bytes(32, "big"))
+                elif op == 0x53:  # MSTORE8
+                    use(G_VERYLOW); off, v = pop(), pop()
+                    mstore(off, bytes([v & 0xFF]))
+                elif op == 0x54:  # SLOAD
+                    use(G_SLOAD); slot = pop(); sloads += 1
+                    v = f.swrites.get(slot)
+                    push(v if v is not None
+                         else self.state.storage_at(f.addr, slot))
+                elif op == 0x55:  # SSTORE
+                    if f.static:
+                        raise EvmError("static sstore")
+                    slot, v = pop(), pop()
+                    sstores += 1
+                    cur = f.swrites.get(slot)
+                    if cur is None:
+                        cur = self.state.storage_at(f.addr, slot)
+                    # pre-Constantinople rules (gas_table.go:117 gasSStore):
+                    # 0->nonzero SET, else RESET; nonzero->0 earns the
+                    # 15 000 clear refund
+                    if cur == 0 and v != 0:
+                        use(G_SSTORE_SET)
                     else:
-                        x &= (1 << (bit + 1)) - 1
-                push(x)
-            elif op == 0x10:  # LT
-                use(G_VERYLOW); push(1 if pop() < pop() else 0)
-            elif op == 0x11:  # GT
-                use(G_VERYLOW); push(1 if pop() > pop() else 0)
-            elif op == 0x12:  # SLT
-                use(G_VERYLOW); push(1 if sgn(pop()) < sgn(pop()) else 0)
-            elif op == 0x13:  # SGT
-                use(G_VERYLOW); push(1 if sgn(pop()) > sgn(pop()) else 0)
-            elif op == 0x14:  # EQ
-                use(G_VERYLOW); push(1 if pop() == pop() else 0)
-            elif op == 0x15:  # ISZERO
-                use(G_VERYLOW); push(1 if pop() == 0 else 0)
-            elif op == 0x16:  # AND
-                use(G_VERYLOW); push(pop() & pop())
-            elif op == 0x17:  # OR
-                use(G_VERYLOW); push(pop() | pop())
-            elif op == 0x18:  # XOR
-                use(G_VERYLOW); push(pop() ^ pop())
-            elif op == 0x19:  # NOT
-                use(G_VERYLOW); push(MAXU ^ pop())
-            elif op == 0x1A:  # BYTE
-                use(G_VERYLOW); i, x = pop(), pop()
-                push((x >> (8 * (31 - i))) & 0xFF if i < 32 else 0)
-            elif op == 0x1B:  # SHL
-                use(G_VERYLOW); s, x = pop(), pop()
-                push(x << s if s < 256 else 0)
-            elif op == 0x1C:  # SHR
-                use(G_VERYLOW); s, x = pop(), pop()
-                push(x >> s if s < 256 else 0)
-            elif op == 0x1D:  # SAR
-                use(G_VERYLOW); s, x = pop(), sgn(pop())
-                push((x >> s if s < 256 else (0 if x >= 0 else MAXU)))
-            elif op == 0x20:  # SHA3
-                off, n = pop(), pop()
-                use(G_SHA3 + G_SHA3_WORD * _words(n))
-                push(int.from_bytes(keccak256(mload(off, n)), "big"))
-            elif op == 0x30:  # ADDRESS
-                use(G_BASE); push(int.from_bytes(f.addr, "big"))
-            elif op == 0x31:  # BALANCE
-                use(G_BALANCE)
-                push(self.state.balance(pop().to_bytes(32, "big")[12:]))
-            elif op == 0x32:  # ORIGIN
-                use(G_BASE); push(int.from_bytes(f.origin, "big"))
-            elif op == 0x33:  # CALLER
-                use(G_BASE); push(int.from_bytes(f.caller, "big"))
-            elif op == 0x34:  # CALLVALUE
-                use(G_BASE); push(f.value)
-            elif op == 0x35:  # CALLDATALOAD
-                use(G_VERYLOW); off = pop()
-                push(int.from_bytes(f.data[off : off + 32].ljust(32, b"\0"),
-                                    "big") if off < len(f.data) else 0)
-            elif op == 0x36:  # CALLDATASIZE
-                use(G_BASE); push(len(f.data))
-            elif op == 0x37:  # CALLDATACOPY
-                dst, src, n = pop(), pop(), pop()
-                use(G_VERYLOW + G_COPY_WORD * _words(n))
-                chunk = f.data[src : src + n] if src < len(f.data) else b""
-                mstore(dst, chunk.ljust(n, b"\0"))
-            elif op == 0x38:  # CODESIZE
-                use(G_BASE); push(len(code))
-            elif op == 0x39:  # CODECOPY
-                dst, src, n = pop(), pop(), pop()
-                use(G_VERYLOW + G_COPY_WORD * _words(n))
-                chunk = code[src : src + n] if src < len(code) else b""
-                mstore(dst, chunk.ljust(n, b"\0"))
-            elif op == 0x3A:  # GASPRICE
-                use(G_BASE); push(0)
-            elif op == 0x3B:  # EXTCODESIZE
-                use(G_EXTCODE)
-                push(len(self.state.code(pop().to_bytes(32, "big")[12:])))
-            elif op == 0x3C:  # EXTCODECOPY
-                addr = pop().to_bytes(32, "big")[12:]
-                dst, src, n = pop(), pop(), pop()
-                use(G_EXTCODE + G_COPY_WORD * _words(n))
-                c = self.state.code(addr)
-                chunk = c[src : src + n] if src < len(c) else b""
-                mstore(dst, chunk.ljust(n, b"\0"))
-            elif op == 0x3D:  # RETURNDATASIZE
-                use(G_BASE); push(len(f.ret))
-            elif op == 0x3E:  # RETURNDATACOPY
-                dst, src, n = pop(), pop(), pop()
-                use(G_VERYLOW + G_COPY_WORD * _words(n))
-                if src + n > len(f.ret):
-                    raise EvmError("returndata out of bounds")
-                mstore(dst, f.ret[src : src + n])
-            elif op == 0x40:  # BLOCKHASH
-                use(G_HIGH + 10); n = pop()
-                bh = self.ctx.blockhash
-                # only the previous 256 ancestors — never the block
-                # being executed, whose hash is not yet sealed
-                # (ref core/vm/instructions.go opBlockhash: distance
-                # 1..256, else zero)
-                push(int.from_bytes(bh(n), "big")
-                     if bh is not None and 1 <= self.ctx.number - n <= 256
-                     else 0)
-            elif op == 0x41:  # COINBASE
-                use(G_BASE); push(int.from_bytes(self.ctx.coinbase, "big"))
-            elif op == 0x42:  # TIMESTAMP
-                use(G_BASE); push(self.ctx.time)
-            elif op == 0x43:  # NUMBER
-                use(G_BASE); push(self.ctx.number)
-            elif op == 0x44:  # DIFFICULTY
-                use(G_BASE); push(self.ctx.difficulty)
-            elif op == 0x45:  # GASLIMIT
-                use(G_BASE); push(self.ctx.gas_limit)
-            elif op == 0x50:  # POP
-                use(G_BASE); pop()
-            elif op == 0x51:  # MLOAD
-                use(G_VERYLOW); off = pop()
-                push(int.from_bytes(mload(off, 32), "big"))
-            elif op == 0x52:  # MSTORE
-                use(G_VERYLOW); off, v = pop(), pop()
-                mstore(off, v.to_bytes(32, "big"))
-            elif op == 0x53:  # MSTORE8
-                use(G_VERYLOW); off, v = pop(), pop()
-                mstore(off, bytes([v & 0xFF]))
-            elif op == 0x54:  # SLOAD
-                use(G_SLOAD); slot = pop()
-                v = f.swrites.get(slot)
-                push(v if v is not None
-                     else self.state.storage_at(f.addr, slot))
-            elif op == 0x55:  # SSTORE
-                if f.static:
-                    raise EvmError("static sstore")
-                slot, v = pop(), pop()
-                cur = f.swrites.get(slot)
-                if cur is None:
-                    cur = self.state.storage_at(f.addr, slot)
-                # pre-Constantinople rules (gas_table.go:117 gasSStore):
-                # 0->nonzero SET, else RESET; nonzero->0 earns the
-                # 15 000 clear refund
-                if cur == 0 and v != 0:
-                    use(G_SSTORE_SET)
-                else:
-                    use(G_SSTORE_RESET)
-                    if cur != 0 and v == 0:
-                        self.refund += R_SCLEAR
-                f.swrites[slot] = v
-            elif op == 0x56:  # JUMP
-                use(G_MID); dst = pop()
-                if jumpdests is None:
-                    jumpdests = _jumpdests(code)
-                if dst not in jumpdests:
-                    raise EvmError("bad jump")
-                f.pc = dst
-            elif op == 0x57:  # JUMPI
-                use(G_HIGH); dst, cond = pop(), pop()
-                if cond:
+                        use(G_SSTORE_RESET)
+                        if cur != 0 and v == 0:
+                            self.refund += R_SCLEAR
+                            self.clears += 1
+                    f.swrites[slot] = v
+                elif op == 0x56:  # JUMP
+                    use(G_MID); dst = pop()
                     if jumpdests is None:
                         jumpdests = _jumpdests(code)
                     if dst not in jumpdests:
                         raise EvmError("bad jump")
                     f.pc = dst
-            elif op == 0x58:  # PC
-                use(G_BASE); push(f.pc - 1)
-            elif op == 0x59:  # MSIZE
-                use(G_BASE); push(len(f.mem))
-            elif op == 0x5A:  # GAS
-                use(G_BASE); push(f.gas)
-            elif op == 0x5B:  # JUMPDEST
-                use(G_JUMPDEST)
-            elif 0xA0 <= op <= 0xA4:  # LOG0..LOG4
-                if f.static:
-                    raise EvmError("static log")
-                n_topics = op - 0xA0
-                off, n = pop(), pop()
-                topics = tuple(pop().to_bytes(32, "big")
-                               for _ in range(n_topics))
-                use(G_LOG + G_LOG_TOPIC * n_topics + G_LOG_BYTE * n)
-                self.logs.append((f.addr, topics, mload(off, n)))
-            elif op == 0xF0:  # CREATE
-                if f.static:
-                    raise EvmError("static create")
-                value, off, n = pop(), pop(), pop()
-                use(G_CREATE)
-                init = mload(off, n)
-                gas_for = f.gas - f.gas // 64
-                f.gas -= gas_for
-                self._flush_storage(f)
-                self.state.bump_nonce(f.addr)
-                res = yield ("create", (f.addr, value, init, gas_for,
-                                        self.state.nonce(f.addr) - 1,
-                                        f.origin), "CREATE")
-                f.gas += gas_for - res.gas_used
-                f.ret = res.output if not res.success else b""
-                push(int.from_bytes(res.created, "big")
-                     if res.success and res.created else 0)
-            elif op in (0xF1, 0xF2, 0xF4, 0xFA):  # CALL/CALLCODE/DELEGATECALL/STATICCALL
-                gas_req = pop()
-                to = pop().to_bytes(32, "big")[12:]
-                if op in (0xF1, 0xF2):
-                    value = pop()
+                elif op == 0x57:  # JUMPI
+                    use(G_HIGH); dst, cond = pop(), pop()
+                    if cond:
+                        if jumpdests is None:
+                            jumpdests = _jumpdests(code)
+                        if dst not in jumpdests:
+                            raise EvmError("bad jump")
+                        f.pc = dst
+                elif op == 0x58:  # PC
+                    use(G_BASE); push(f.pc - 1)
+                elif op == 0x59:  # MSIZE
+                    use(G_BASE); push(len(f.mem))
+                elif op == 0x5A:  # GAS
+                    use(G_BASE); push(f.gas)
+                elif op == 0x5B:  # JUMPDEST
+                    use(G_JUMPDEST)
+                elif 0xA0 <= op <= 0xA4:  # LOG0..LOG4
+                    if f.static:
+                        raise EvmError("static log")
+                    n_topics = op - 0xA0
+                    off, n = pop(), pop()
+                    topics = tuple(pop().to_bytes(32, "big")
+                                   for _ in range(n_topics))
+                    use(G_LOG + G_LOG_TOPIC * n_topics + G_LOG_BYTE * n)
+                    self.logs.append((f.addr, topics, mload(off, n)))
+                elif op == 0xF0:  # CREATE
+                    if f.static:
+                        raise EvmError("static create")
+                    value, off, n = pop(), pop(), pop()
+                    use(G_CREATE)
+                    init = mload(off, n)
+                    gas_for = f.gas - f.gas // 64
+                    f.gas -= gas_for
+                    self._flush_storage(f)
+                    self.state.bump_nonce(f.addr)
+                    res = yield ("create", (f.addr, value, init, gas_for,
+                                            self.state.nonce(f.addr) - 1,
+                                            f.origin), "CREATE")
+                    f.gas += gas_for - res.gas_used
+                    f.ret = res.output if not res.success else b""
+                    push(int.from_bytes(res.created, "big")
+                         if res.success and res.created else 0)
+                elif op in (0xF1, 0xF2, 0xF4, 0xFA):  # CALL/CALLCODE/DELEGATECALL/STATICCALL
+                    gas_req = pop()
+                    to = pop().to_bytes(32, "big")[12:]
+                    if op in (0xF1, 0xF2):
+                        value = pop()
+                    else:
+                        value = 0
+                    in_off, in_n, out_off, out_n = pop(), pop(), pop(), pop()
+                    if op == 0xF1 and f.static and value:
+                        raise EvmError("static call with value")
+                    base = G_CALL + (G_CALL_VALUE if value else 0)
+                    to_int = int.from_bytes(to, "big")
+                    if (op == 0xF1 and value
+                            and self.state.account(to).balance == 0
+                            and self.state.nonce(to) == 0
+                            and not self.state.code(to)
+                            and not (1 <= to_int <= 8)):
+                        base += G_NEW_ACCOUNT
+                    use(base)
+                    data = mload(in_off, in_n)
+                    if out_n:
+                        grow(out_off + out_n)
+                    avail = f.gas - f.gas // 64
+                    gas_for = min(gas_req, avail)
+                    f.gas -= gas_for
+                    stipend = G_CALL_STIPEND if value else 0
+                    # reentrancy: nested frames must see this frame's storage
+                    # writes, and may write our storage themselves — flush
+                    # the cache down and re-read from state afterwards
+                    self._flush_storage(f)
+                    if op == 0xF2 and value > self.state.balance(f.addr):
+                        # CALLCODE checks but does not move the balance
+                        # (ref: evm.CallCode CanTransfer); gas is returned
+                        res = ExecResult(False, 0)
+                    elif op == 0xF1:  # CALL
+                        res = yield ("call", (f.addr, to, value, data,
+                                              gas_for + stipend, f.static,
+                                              f.origin), "CALL")
+                    elif op == 0xF2:  # CALLCODE: callee code, our storage
+                        res = yield ("codecall", (to, f.addr, value, data,
+                                                  gas_for + stipend, f.addr,
+                                                  f.origin, f.static),
+                                     "CALLCODE")
+                    elif op == 0xF4:  # DELEGATECALL: keep caller+value
+                        res = yield ("codecall", (to, f.addr, f.value, data,
+                                                  gas_for, f.caller,
+                                                  f.origin, f.static),
+                                     "DELEGATECALL")
+                    else:  # STATICCALL
+                        res = yield ("call", (f.addr, to, 0, data, gas_for,
+                                              True, f.origin), "STATICCALL")
+                    # leftover callee gas (incl. unused stipend) returns to
+                    # the caller, matching the reference's accounting
+                    # (contract.Gas += returnGas, core/vm/evm.go Call)
+                    used = min(res.gas_used, gas_for + stipend)
+                    f.gas += (gas_for + stipend) - used
+                    f.ret = res.output
+                    if out_n:
+                        # write only what the callee returned; the rest of
+                        # the reserved region keeps its prior contents
+                        # (ref: memory.Set in opCall — no zero-fill)
+                        mstore(out_off, res.output[:out_n])
+                    push(1 if res.success else 0)
+                elif op == 0xF3:  # RETURN
+                    off, n = pop(), pop()
+                    return mload(off, n)
+                elif op == 0xFD:  # REVERT
+                    off, n = pop(), pop()
+                    r = Revert(mload(off, n))
+                    r.gas_left = f.gas
+                    raise r
+                elif op == 0xFE:  # INVALID
+                    raise EvmError("invalid opcode 0xfe")
+                elif op == 0xFF:  # SELFDESTRUCT
+                    if f.static:
+                        raise EvmError("static selfdestruct")
+                    heir = pop().to_bytes(32, "big")[12:]
+                    bal = self.state.balance(f.addr)
+                    cost = G_SELF_DESTRUCT
+                    if bal and not self.state.nonce(heir) \
+                            and not self.state.balance(heir) \
+                            and not self.state.code(heir):
+                        # sweeping into a non-existent account pays the
+                        # account-creation surcharge (gas_table.go
+                        # gasSelfdestruct, EIP-150 rules)
+                        cost += G_NEW_ACCOUNT
+                    use(cost)
+                    if f.addr not in self.suicides:
+                        # 24 000 once per address per txn
+                        # (params.SuicideRefundGas via HasSuicided)
+                        self.refund += R_SELFDESTRUCT
+                        self.suicides.add(f.addr)
+                    if bal:
+                        self.state.sub_balance(f.addr, bal)
+                        self.state.add_balance(heir, bal)
+                    # the account itself is deleted at txn finalization
+                    # (state.apply_txn), matching Finalise-time deletion
+                    return b""
                 else:
-                    value = 0
-                in_off, in_n, out_off, out_n = pop(), pop(), pop(), pop()
-                if op == 0xF1 and f.static and value:
-                    raise EvmError("static call with value")
-                base = G_CALL + (G_CALL_VALUE if value else 0)
-                to_int = int.from_bytes(to, "big")
-                if (op == 0xF1 and value
-                        and self.state.account(to).balance == 0
-                        and self.state.nonce(to) == 0
-                        and not self.state.code(to)
-                        and not (1 <= to_int <= 8)):
-                    base += G_NEW_ACCOUNT
-                use(base)
-                data = mload(in_off, in_n)
-                if out_n:
-                    grow(out_off + out_n)
-                avail = f.gas - f.gas // 64
-                gas_for = min(gas_req, avail)
-                f.gas -= gas_for
-                stipend = G_CALL_STIPEND if value else 0
-                # reentrancy: nested frames must see this frame's storage
-                # writes, and may write our storage themselves — flush
-                # the cache down and re-read from state afterwards
-                self._flush_storage(f)
-                if op == 0xF2 and value > self.state.balance(f.addr):
-                    # CALLCODE checks but does not move the balance
-                    # (ref: evm.CallCode CanTransfer); gas is returned
-                    res = ExecResult(False, 0)
-                elif op == 0xF1:  # CALL
-                    res = yield ("call", (f.addr, to, value, data,
-                                          gas_for + stipend, f.static,
-                                          f.origin), "CALL")
-                elif op == 0xF2:  # CALLCODE: callee code, our storage
-                    res = yield ("codecall", (to, f.addr, value, data,
-                                              gas_for + stipend, f.addr,
-                                              f.origin, f.static),
-                                 "CALLCODE")
-                elif op == 0xF4:  # DELEGATECALL: keep caller+value
-                    res = yield ("codecall", (to, f.addr, f.value, data,
-                                              gas_for, f.caller,
-                                              f.origin, f.static),
-                                 "DELEGATECALL")
-                else:  # STATICCALL
-                    res = yield ("call", (f.addr, to, 0, data, gas_for,
-                                          True, f.origin), "STATICCALL")
-                # leftover callee gas (incl. unused stipend) returns to
-                # the caller, matching the reference's accounting
-                # (contract.Gas += returnGas, core/vm/evm.go Call)
-                used = min(res.gas_used, gas_for + stipend)
-                f.gas += (gas_for + stipend) - used
-                f.ret = res.output
-                if out_n:
-                    # write only what the callee returned; the rest of
-                    # the reserved region keeps its prior contents
-                    # (ref: memory.Set in opCall — no zero-fill)
-                    mstore(out_off, res.output[:out_n])
-                push(1 if res.success else 0)
-            elif op == 0xF3:  # RETURN
-                off, n = pop(), pop()
-                return mload(off, n)
-            elif op == 0xFD:  # REVERT
-                off, n = pop(), pop()
-                r = Revert(mload(off, n))
-                r.gas_left = f.gas
-                raise r
-            elif op == 0xFE:  # INVALID
-                raise EvmError("invalid opcode 0xfe")
-            elif op == 0xFF:  # SELFDESTRUCT
-                if f.static:
-                    raise EvmError("static selfdestruct")
-                heir = pop().to_bytes(32, "big")[12:]
-                bal = self.state.balance(f.addr)
-                cost = G_SELF_DESTRUCT
-                if bal and not self.state.nonce(heir) \
-                        and not self.state.balance(heir) \
-                        and not self.state.code(heir):
-                    # sweeping into a non-existent account pays the
-                    # account-creation surcharge (gas_table.go
-                    # gasSelfdestruct, EIP-150 rules)
-                    cost += G_NEW_ACCOUNT
-                use(cost)
-                if f.addr not in self.suicides:
-                    # 24 000 once per address per txn
-                    # (params.SuicideRefundGas via HasSuicided)
-                    self.refund += R_SELFDESTRUCT
-                    self.suicides.add(f.addr)
-                if bal:
-                    self.state.sub_balance(f.addr, bal)
-                    self.state.add_balance(heir, bal)
-                # the account itself is deleted at txn finalization
-                # (state.apply_txn), matching Finalise-time deletion
-                return b""
-            else:
-                raise EvmError(f"unknown opcode {op:#x}")
+                    raise EvmError(f"unknown opcode {op:#x}")
+        finally:
+            self.ops += ops
+            self.sloads += sloads
+            self.sstores += sstores
+
 
 def _jumpdests(code: bytes) -> set[int]:
     """Valid JUMPDEST offsets (PUSH data bytes excluded)."""

@@ -29,7 +29,8 @@ from dataclasses import dataclass, field, replace
 import numpy as np
 
 from eges_tpu.core import rlp
-from eges_tpu.core.trie import EMPTY_ROOT, derive_sha
+from eges_tpu.core.trie import (EMPTY_ROOT, SecureIncrementalTrie,
+                                derive_sha)
 from eges_tpu.crypto.keccak import keccak256
 
 EMPTY_CODE_HASH = keccak256(b"")
@@ -52,12 +53,13 @@ class ContractStorage:
     contract" fix, with the same incremental treatment the account trie
     already got."""
 
-    __slots__ = ("_trie", "_root")
+    __slots__ = ("_trie", "_root", "_unrooted")
 
-    def __init__(self, trie=None):
-        from eges_tpu.core.trie import SecureIncrementalTrie
+    def __init__(self, trie=None, unrooted: int = 0):
         self._trie = trie if trie is not None else SecureIncrementalTrie()
         self._root: bytes | None = None
+        # slot writes since a root was last taken on this lineage
+        self._unrooted = unrooted
 
     def get(self, slot: int) -> int:
         raw = self._trie.get(slot.to_bytes(32, "big"))
@@ -68,11 +70,13 @@ class ContractStorage:
         return ContractStorage(self._trie.update_many(
             [slot.to_bytes(32, "big") for slot in writes],
             [rlp.encode(value) if value else b""
-             for value in writes.values()]))
+             for value in writes.values()]),
+            (0 if self._root is not None else self._unrooted) + len(writes))
 
     def root(self) -> bytes:
         if self._root is None:
             self._root = self._trie.root()
+            self._unrooted = 0
         return self._root
 
     def items(self):
@@ -203,7 +207,6 @@ class StateDB:
         self._origin = None
         # addr -> Account (live) | None (deleted/empty)
         self._local: dict[bytes, Account | None] = dict(accounts or {})
-        from eges_tpu.core.trie import SecureIncrementalTrie
         self._trie = SecureIncrementalTrie()
         self._dirty: set[bytes] = set(self._local)
         self._root_cache: bytes | None = None
@@ -213,10 +216,28 @@ class StateDB:
         self._codes: dict[bytes, bytes] = {}
 
     @classmethod
-    def from_alloc(cls, alloc: dict[bytes, int]) -> "StateDB":
-        """Genesis allocation: address -> balance
-        (ref: core/genesis.go GenesisAlloc)."""
-        return cls({a: Account(balance=b) for a, b in alloc.items() if b})
+    def from_alloc(cls, alloc: dict) -> "StateDB":
+        """Genesis allocation (ref: core/genesis.go GenesisAlloc,
+        GenesisAccount): address -> balance, or address -> a dict under
+        the keys of upstream's ``genesis.json``: ``balance``, ``nonce``,
+        ``code`` (bytes) and ``storage`` (slot -> value, integers), each
+        optional.  An account's storage goes in as ONE
+        ``set_storage_many``."""
+        state = cls({a: Account(balance=b) for a, b in alloc.items()
+                     if b and not isinstance(b, dict)})
+        for addr, g in alloc.items():
+            if not isinstance(g, dict):
+                continue
+            unknown = set(g) - {"balance", "nonce", "code", "storage"}
+            if unknown:
+                raise ValueError(f"genesis account keys {sorted(unknown)}")
+            state.set_account(addr, Account(nonce=g.get("nonce", 0),
+                                            balance=g.get("balance", 0)))
+            if g.get("code"):
+                state.set_code(addr, bytes(g["code"]))
+            state.set_storage_many(addr, {
+                k: v for k, v in g.get("storage", {}).items() if v})
+        return state
 
     def copy(self) -> "StateDB":
         if self._depth() >= self._MAX_DEPTH:
@@ -377,13 +398,20 @@ class StateDB:
                 # order (byte-identical trie node churn under the chaos
                 # contract)
                 addrs = sorted(self._dirty)
+                accts = [self.account(addr) for addr in addrs]
+                # the storage roots an account's RLP will ask for
+                stores = [a.storage for a in accts
+                          if a.storage._root is None]
+                if stores:
+                    with tracing.DEFAULT.span(
+                            "state.storage_root", accounts=len(stores),
+                            slots=sum(st._unrooted for st in stores)):
+                        for st in stores:
+                            st.root()
                 empty = Account()
-                rlps = []
-                for addr in addrs:
-                    a = self.account(addr)
-                    # an emptied account leaves the trie: an empty value
-                    rlps.append(b"" if a == empty
-                                else rlp.encode(a.to_rlp()))
+                # an emptied account leaves the trie: an empty value
+                rlps = [b"" if a == empty else rlp.encode(a.to_rlp())
+                        for a in accts]
                 t = self._trie.update_many(addrs, rlps)
                 metrics.counter("state.root_accounts").inc(len(addrs))
                 self._trie = t
@@ -519,8 +547,23 @@ def _recover_senders(txns, verifier, sp) -> list:
     return senders
 
 
-BLOCK_GAS_LIMIT = 30_000_000  # default block gas cap (params.GenesisGasLimit
-#                               role) — bounds adversarial EVM work per block
+_evm = None  # core/evm.py, bound by the first transaction that needs it
+
+
+def _bind_evm():
+    """``core/evm.py`` imports this module (``StateError``,
+    ``BLOCK_GAS_LIMIT``), so the interpreter is bound here at its first
+    use, once, and not a transaction."""
+    global _evm
+    from eges_tpu.core import evm
+    _evm = evm
+    return evm
+
+
+# The block gas cap wherever a header says 0 (params.GenesisGasLimit
+# role): every header of a chain whose genesis names no gas limit.  It
+# bounds adversarial EVM work per block.
+BLOCK_GAS_LIMIT = 30_000_000
 
 
 def apply_txn(state: StateDB, txn, sender: bytes, coinbase: bytes,
@@ -554,15 +597,15 @@ def apply_txn(state: StateDB, txn, sender: bytes, coinbase: bytes,
             state.add_balance(coinbase, fee)
         return Receipt(status=1, cumulative_gas_used=gas_so_far + INTRINSIC_GAS)
 
-    from eges_tpu.core import evm as _evm
-
+    evm = _evm or _bind_evm()
     data = txn.payload or b""
-    intrinsic = _evm.intrinsic_gas(data, is_create)
+    intrinsic = evm.intrinsic_gas(data, is_create)
     gas_limit = txn.gas_limit or intrinsic
     if gas_limit < intrinsic:
         raise StateError("intrinsic gas too low")
-    block_cap = (ctx.gas_limit if ctx is not None else 0) or BLOCK_GAS_LIMIT
-    if gas_so_far + gas_limit > block_cap:
+    if ctx is None:
+        ctx = evm.BlockCtx(coinbase=coinbase)
+    if gas_so_far + gas_limit > ctx.gas_limit:
         # block gas limit bounds total EVM work per block (the liveness
         # guard: without it a zero-price txn could stuff enough pairing
         # calls to stall every validator past its timeouts)
@@ -573,8 +616,7 @@ def apply_txn(state: StateDB, txn, sender: bytes, coinbase: bytes,
     state.sub_balance(sender, upfront)
     state.bump_nonce(sender)
 
-    e = _evm.EVM(state, ctx if ctx is not None else _evm.BlockCtx(
-        coinbase=coinbase), verifier=verifier, tracer=tracer)
+    e = evm.EVM(state, ctx, verifier=verifier, tracer=tracer)
     exec_gas = gas_limit - intrinsic
     if is_create:
         res = e.create(sender, txn.value, data, exec_gas, txn.nonce)
@@ -585,7 +627,9 @@ def apply_txn(state: StateDB, txn, sender: bytes, coinbase: bytes,
     # core/state_transition.go refundGas: refund = gasUsed/2 min
     # state.GetRefund()).  A failed root frame rolled its refunds back
     # to zero inside the EVM, so applying unconditionally is exact.
-    gas_used -= min(e.refund, gas_used // 2)
+    refunded = min(e.refund, gas_used // 2)
+    gas_used -= refunded
+    ctx.tally.add(e, res, gas_used, refunded)
     if res.success:
         # accounts self-destructed by surviving frames are deleted at
         # txn finalization (ref: StateDB.Finalise deleteEmptyObjects
@@ -605,12 +649,10 @@ def apply_txn(state: StateDB, txn, sender: bytes, coinbase: bytes,
 
 def block_ctx(header, blockhash=None):
     """EVM block context from a header (ref: core/evm.go NewEVMContext)."""
-    from eges_tpu.core.evm import BlockCtx
-
-    return BlockCtx(coinbase=header.coinbase, number=header.number,
-                    time=header.time, difficulty=header.difficulty,
-                    gas_limit=header.gas_limit or 30_000_000,
-                    blockhash=blockhash)
+    return (_evm or _bind_evm()).BlockCtx(
+        coinbase=header.coinbase, number=header.number, time=header.time,
+        difficulty=header.difficulty,
+        gas_limit=header.gas_limit or BLOCK_GAS_LIMIT, blockhash=blockhash)
 
 
 def process_block(parent_state: StateDB, block, senders,
@@ -632,14 +674,18 @@ def process_block(parent_state: StateDB, block, senders,
     coinbase = block.header.coinbase
     ctx = block_ctx(block.header)
     with tracing.DEFAULT.span("chain.execute",
-                              txns=len(block.transactions)):
-        for t, sender in zip(block.transactions, senders):
-            if sender is None:
-                raise StateError("rooted transaction without a sender")
-            r = apply_txn(state, t, sender, coinbase, gas, ctx=ctx,
-                          verifier=verifier)
-            gas = r.cumulative_gas_used
-            receipts.append(r)
+                              txns=len(block.transactions),
+                              evm_calls=0, reverted=0) as sp:
+        try:
+            for t, sender in zip(block.transactions, senders):
+                if sender is None:
+                    raise StateError("rooted transaction without a sender")
+                r = apply_txn(state, t, sender, coinbase, gas, ctx=ctx,
+                              verifier=verifier)
+                gas = r.cumulative_gas_used
+                receipts.append(r)
+        finally:  # a refused block's calls ran too
+            ctx.tally.flush(sp)
     return state, tuple(receipts), gas
 
 
@@ -659,3 +705,4 @@ def receipts_bloom(receipts) -> bytes:
     for r in receipts:
         bits |= int.from_bytes(logs_bloom(r.logs), "big")
     return bits.to_bytes(256, "big")
+

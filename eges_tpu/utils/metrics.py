@@ -58,6 +58,11 @@ METRIC_FAMILIES = frozenset({
     # registry is read)
     "trie.native_nodes", "trie.native_updates", "trie.nodes",
     "trie.store_nodes",
+    # core/evm.py Tally (PR 51) — what the transactions that ran the
+    # interpreter did, summed a block and added once by chain.execute
+    # and chain.execute_preview
+    "evm.calls", "evm.gas_refunded", "evm.gas_used", "evm.ops",
+    "evm.reverts", "evm.sloads", "evm.slot_deletes", "evm.sstores",
     # consensus/
     "consensus.deferred_depth", "consensus.deferred_dropped",
     "consensus.elected", "consensus.forced_empties",
@@ -220,6 +225,26 @@ METRIC_HELP = {
     "trie.store_nodes": (
         "Live nodes of the library's trie node store (falls when a "
         "fork or a pruned height's state is dropped)."),
+    "evm.calls": (
+        "Transactions of executed blocks that ran the interpreter "
+        "(creations, calls into code, precompiles): one a transaction, "
+        "added once a block."),
+    "evm.reverts": (
+        "Of evm.calls, those whose root frame ended in REVERT (status "
+        "0, their gas charged, no write kept)."),
+    "evm.ops": (
+        "Opcodes the interpreter ran, every frame's, reverted or not."),
+    "evm.sloads": "SLOADs among evm.ops.",
+    "evm.sstores": "SSTOREs among evm.ops.",
+    "evm.slot_deletes": (
+        "Slots written 0 over a value by frames that were kept: each "
+        "leaves the storage trie and earns the 15,000 refund."),
+    "evm.gas_used": (
+        "Gas charged to the transactions counted in evm.calls, after "
+        "the refund."),
+    "evm.gas_refunded": (
+        "Gas given back from the refund counter, capped at half of "
+        "what a transaction used."),
     "chain.blocks_refused": (
         "Blocks whose sender recovery raised StateError (a signature "
         "that names no sender)."),

@@ -162,10 +162,12 @@ SPANS = {
                                   "the head state (core/chain.py): the "
                                   "senders, the apply_txn loop, the state "
                                   "root, the receipts' root, the bloom; "
-                                  "attrs txns, kept; counters "
-                                  "chain.executions (one inc a preview), "
-                                  "chain.preview_dropped; its state and "
-                                  "receipts are kept for chain.insert"),
+                                  "attrs txns, kept, evm_calls and "
+                                  "reverted (as chain.execute's); "
+                                  "counters chain.executions (one inc a "
+                                  "preview), chain.preview_dropped, the "
+                                  "evm.* eight; its state and receipts "
+                                  "are kept for chain.insert"),
     "chain.validate_candidate": ((), "an acceptor's whole check of a "
                                      "proposed block before its ACK "
                                      "(core/chain.py): body, senders, "
@@ -178,16 +180,30 @@ SPANS = {
     "chain.verify_body": ((), "the transaction root of a block's body "
                               "(derive_sha over its encodings); attr txns"),
     "chain.execute": ((), "the apply_txn loop of process_block "
-                          "(core/state.py); attr txns; counter "
-                          "chain.executions, one inc a _process: a "
-                          "validation's, or the insert's of a block "
-                          "that brings neither a validation nor a "
-                          "preview of its own (a proposer's preview "
-                          "counts there too)"),
+                          "(core/state.py); attrs txns, evm_calls (the "
+                          "transactions that ran the interpreter) and "
+                          "reverted (those of them that ended in "
+                          "REVERT); counter chain.executions, one inc a "
+                          "_process: a validation's, or the insert's of "
+                          "a block that brings neither a validation nor "
+                          "a preview of its own (a proposer's preview "
+                          "counts there too); counters evm.calls, "
+                          "evm.reverts, evm.ops, evm.sloads, "
+                          "evm.sstores, evm.slot_deletes, evm.gas_used "
+                          "and evm.gas_refunded, one inc a block "
+                          "(core/evm.py Tally)"),
     "state.root": ((), "StateDB.root() where it is not cached: the dirty "
                        "accounts into the secure trie, then the nodes' "
                        "hashes; attr dirty; counter state.root_accounts, "
                        "one inc(dirty) a call"),
+    "state.storage_root": ((), "inside state.root: the storage roots "
+                               "of the dirty accounts whose storage was "
+                               "written since its last root, before their "
+                               "RLP is taken (on the library's rung the "
+                               "nodes were hashed by the batch that wrote "
+                               "them, a call at a time, and this only "
+                               "reads the hash); attrs accounts, slots "
+                               "(the writes since)"),
     "chain.receipts_root": ((), "derive_sha over a block's receipts "
                                 "(core/state.py receipts_root); attr txns"),
     "chain.insert": ((), "execute, state root, index; attrs number, "
